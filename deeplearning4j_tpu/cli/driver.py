@@ -14,9 +14,15 @@ from __future__ import annotations
 
 import argparse
 import csv
+import itertools
 import json
+import math
+import os
 import sys
+import threading
 from typing import List, Optional
+
+from deeplearning4j_tpu.nd import platform
 
 
 def _parse_properties(props: Optional[str]) -> dict:
@@ -152,7 +158,8 @@ def cmd_train(args) -> int:
         # embedding layers consume integer ids [B,T]; text-scheme input
         # arrives one-hot [B,T,V] — convert by mechanism, not model name
         from deeplearning4j_tpu.datasets.dataset import DataSet
-        ds = DataSet(data.features.argmax(-1).astype("int32"), data.labels)
+        ds = DataSet(data.features.argmax(-1).astype("int32"), data.labels,
+                     data.label_rows)
         for attr in ("vocab_size", "seq_len", "index_to_char"):
             if hasattr(data, attr):
                 setattr(ds, attr, getattr(data, attr))
@@ -361,7 +368,8 @@ def cmd_train(args) -> int:
                       "cache_misses": cs.misses,
                       "infer_compile_seconds": round(
                           ic.total_compile_seconds, 3),
-                      "disk_cache": _disk_stats(net)}))
+                      "disk_cache": _disk_stats(net),
+                      **platform.describe()}))
     return 0
 
 
@@ -456,8 +464,6 @@ def cmd_tune(args) -> int:
     from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
     from deeplearning4j_tpu.optimize import tune as tune_mod
 
-    import os
-
     if args.model and os.path.isdir(args.model):
         net = _load_model(args.model)
     elif args.model:
@@ -474,6 +480,7 @@ def cmd_tune(args) -> int:
         net, store, force=args.force, groups=groups, rounds=args.rounds,
         seed=args.seed, max_seq=args.gen_max_seq)
     report["disk_cache"] = _disk_stats(net)
+    report.update(platform.describe())
     print(json.dumps(report))
     return 0
 
@@ -482,8 +489,6 @@ def cmd_warmup(args) -> int:
     """Precompile declared shape buckets into a persistent compile cache
     so a later serving/training process starts from disk hits instead of
     multi-second compiles."""
-    import os
-
     from deeplearning4j_tpu.nn.conf import MultiLayerConfiguration
     from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 
@@ -525,6 +530,7 @@ def cmd_warmup(args) -> int:
     summary["mesh_devices"] = mesh_devices
     summary["disk_cache"] = _disk_stats(net)
     summary["tuning"] = _tuning_status()
+    summary.update(platform.describe())
     print(json.dumps(summary))
     return 0
 
@@ -648,7 +654,8 @@ def cmd_generate(args) -> int:
         "ttft_ms": (None if stream.ttft_s is None
                     else round(stream.ttft_s * 1000.0, 3)),
         "fresh_compiles": net.infer_cache.stats.misses - warmed_misses,
-        "disk_cache": _disk_stats(net)}))
+        "disk_cache": _disk_stats(net),
+        **platform.describe()}))
     return 0
 
 
@@ -721,7 +728,8 @@ def _build_server(args):
                "precision_report": precision_report,
                "generation": gen_warmed,
                "disk_cache": _disk_stats(net),
-               "tuning": _tuning_status()}
+               "tuning": _tuning_status(),
+               **platform.describe()}
     return net, server, summary
 
 
@@ -811,12 +819,21 @@ def _remote_serve_argv(args, cache_sources: List[str]) -> List[str]:
 class ReplicaProcess:
     """One `serve` replica subprocess: spawn, read the startup JSON off
     its stdout (blocks until the replica warmed and is listening),
-    SIGTERM + collect the drained JSON at shutdown."""
+    SIGTERM + collect the drained JSON at shutdown.  `chip` pins the
+    child to `n_chips` chips of the host from that one on, through its
+    environment (`platform.chip_env`); None leaves it every device the
+    parent's environment shows."""
 
-    def __init__(self, cmd: List[str]):
+    def __init__(self, cmd: List[str], chip: Optional[int] = None,
+                 n_chips: int = 1):
         import subprocess
 
-        self.proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True)
+        env = None if chip is None else {
+            **os.environ, **platform.chip_env(chip, n_chips)}
+        self.chip = chip
+        self.n_chips = n_chips
+        self.proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True,
+                                     env=env)
         self.summary: Optional[dict] = None
 
     def wait_ready(self) -> dict:
@@ -865,6 +882,45 @@ class ReplicaProcess:
         return rc
 
 
+class ChipSlots:
+    """The chips of this host, shared out among the replicas this process
+    starts: each spawn takes the lowest run of `n_chips` chips that no
+    live replica holds, so a reaped replica's chips go to its
+    replacement.  The process that owns the slots stays off JAX — a
+    parent that initialised a backend would hold every chip its children
+    need."""
+
+    def __init__(self):
+        self._held: List[ReplicaProcess] = []
+        self._lock = threading.Lock()
+
+    def spawn(self, cmd: List[str], n_chips: int = 1) -> ReplicaProcess:
+        with self._lock:
+            self._held = [r for r in self._held if r.poll() is None]
+            taken = {c for r in self._held
+                     for c in range(r.chip, r.chip + r.n_chips)}
+            first = next(i for i in itertools.count(0, n_chips)
+                         if taken.isdisjoint(range(i, i + n_chips)))
+            replica = ReplicaProcess(cmd, chip=first, n_chips=n_chips)
+            self._held.append(replica)
+            return replica
+
+
+def _chips_per_replica(serve_argv: List[str]) -> int:
+    """How many chips the `--mesh` of a replica's `serve` command line
+    spans: the product of the axis sizes it names.  A bare `--mesh` and a
+    `-1` axis mean "every device the replica sees", which is whatever its
+    slot gives it, so they add nothing."""
+    if "--mesh" not in serve_argv:
+        return 1
+    from deeplearning4j_tpu.parallel.plan import parse_mesh_spec
+
+    value = serve_argv[serve_argv.index("--mesh") + 1:][:1]
+    bare = not value or value[0].startswith("--")
+    shape = parse_mesh_spec("all" if bare else value[0])
+    return math.prod(n for n in shape.values() if n > 0)
+
+
 def cmd_serve_router(args) -> int:
     """serve --replicas N: spawn N replica subprocesses sharing the
     --compile-cache dir, front them with `serving.Router`, supervise
@@ -885,6 +941,8 @@ def cmd_serve_router(args) -> int:
     min_replicas = getattr(args, "min_replicas", None) or args.replicas
     max_replicas = getattr(args, "max_replicas", None) or args.replicas
     cmd = _replica_cmd(args)
+    slots = ChipSlots()
+    n_chips = _chips_per_replica(cmd)
     cache_server = None
     remote_argv = None
     clients = []
@@ -907,7 +965,7 @@ def cmd_serve_router(args) -> int:
         replicas = [clients[i % len(clients)].spawn(remote_argv)
                     for i in range(args.replicas)]
     else:
-        replicas = [ReplicaProcess(cmd) for _ in range(args.replicas)]
+        replicas = [slots.spawn(cmd, n_chips) for _ in range(args.replicas)]
     router = supervisor = autoscaler = None
     try:
         summaries = [r.wait_ready() for r in replicas]
@@ -922,7 +980,7 @@ def cmd_serve_router(args) -> int:
         # respawn re-runs the same replica command line against the same
         # shared disk cache, so coming back is seconds, not compiles
         supervisor = FleetSupervisor(
-            spawn_fn=lambda: ReplicaProcess(cmd), router=router,
+            spawn_fn=lambda: slots.spawn(cmd, n_chips), router=router,
             initial=replicas, min_replicas=min_replicas,
             max_replicas=max_replicas,
             agents=clients, remote_argv=remote_argv,
@@ -943,6 +1001,11 @@ def cmd_serve_router(args) -> int:
             "agents": [c.url for c in clients],
             "fresh_compiles": [s.get("fresh_compiles") for s in summaries],
             "mesh_devices": summaries[0].get("mesh_devices"),
+            # what each replica's own JAX found; this parent asks for none
+            "replica_devices": [
+                {k: s.get(k) for k in ("platform", "device_kind",
+                                       "device_count", "chip")}
+                for s in summaries],
         }), flush=True)
         prev = {}
         for sig in (signal.SIGTERM, signal.SIGINT):
@@ -999,18 +1062,21 @@ def cmd_agent(args) -> int:
     HTTP server (POST /a/spawn, POST /a/stop, GET /a/health,
     GET /a/replicas, GET /a/cache/{key}) that owns this host's replica
     subprocesses on behalf of a remote `serve --agent` supervisor.
-    Model-free: the agent never imports jax — replicas are ordinary
-    `serve` subprocesses, and the agent pins each one to this host's
+    Model-free: the agent initialises no JAX backend — replicas are
+    ordinary `serve` subprocesses, each on chips of its own
+    (`ChipSlots`), and the agent pins each one to this host's
     --compile-cache dir so they share warm compiles locally and serve
     them to cold peers over /a/cache."""
     import signal
-    import threading
 
     from deeplearning4j_tpu.serving.agent import ReplicaAgent
 
+    slots = ChipSlots()
+
     def spawn_fn(argv):
-        return ReplicaProcess(
-            [sys.executable, "-m", "deeplearning4j_tpu.cli"] + list(argv))
+        return slots.spawn(
+            [sys.executable, "-m", "deeplearning4j_tpu.cli"] + list(argv),
+            _chips_per_replica(list(argv)))
 
     agent = ReplicaAgent(spawn_fn, host=args.host, port=args.port,
                          cache_dir=args.compile_cache,
@@ -1492,4 +1558,5 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    platform.place_compile_cache()
     return args.fn(args)

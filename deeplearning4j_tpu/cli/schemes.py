@@ -53,8 +53,9 @@ def load_input(uri: str, label_column: int = -1,
     if scheme == "text":
         # text:<path>[:seq_len] -> char-LM DataSet: features [B, T, V]
         # one-hot windows, labels [B*T, V] next-char targets (the shape
-        # char_lstm's rnn_to_ff output stage consumes); ds.vocab_size and
-        # ds.char_index carry the vocabulary for --zoo auto-sizing
+        # char_lstm's rnn_to_ff output stage consumes), T label rows to
+        # an example; ds.vocab_size and ds.char_index carry the
+        # vocabulary for --zoo auto-sizing
         path, _, slen = rest.rpartition(":")
         if path and slen.isdigit():
             seq_len = int(slen)
@@ -74,7 +75,7 @@ def load_input(uri: str, label_column: int = -1,
         xs = ids[:n_win * seq_len].reshape(n_win, seq_len)
         ys = ids[1:n_win * seq_len + 1].reshape(n_win, seq_len)
         eye = np.eye(v, dtype=np.float32)
-        ds = DataSet(eye[xs], eye[ys.reshape(-1)])
+        ds = DataSet(eye[xs], eye[ys.reshape(-1)], label_rows=seq_len)
         ds.vocab_size = v
         ds.char_index = idx
         return ds

@@ -68,10 +68,8 @@ class CharLSTM:
             from deeplearning4j_tpu.datasets.dataset import DataSet
             from deeplearning4j_tpu.datasets.iterator import PrefetchIterator
 
-            t = self.seq_len  # label rows are window-major blocks of T
-            batches = [DataSet(x[s:s + bs],
-                               y[s * t:(s + min(bs, n_win - s)) * t])
-                       for s in range(0, n_win, bs)]
+            # label rows are window-major blocks of T
+            batches = DataSet(x, y, label_rows=self.seq_len).batch_by(bs)
             # async input pipeline: each window batch device_puts one
             # step ahead of the compiled train step it feeds
             self.net.fit(PrefetchIterator(batches))

@@ -33,35 +33,28 @@ from jax.experimental.pallas import tpu as pltpu
 from deeplearning4j_tpu.nd.attention import blockwise_attention
 from deeplearning4j_tpu.nd.platform import is_tpu
 
-# jax 0.5 renamed TPUCompilerParams -> CompilerParams and grew a
-# has_side_effects field; build the params compatibly for either version
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
-
-def _compiler_params(**kw):
-    import dataclasses
-
-    fields = {f.name for f in dataclasses.fields(_CompilerParams)}
-    return _CompilerParams(**{k: v for k, v in kw.items() if k in fields})
-
 _NEG_BIG = -1e30
+
+#: fast-memory (VMEM) scope one kernel may allocate on a v5e before the
+#: compiler refuses it ("Scoped allocation ... limit 16.00M")
+VMEM_LIMIT_BYTES = 16 << 20
 
 
 def _interpret(flag: Optional[bool]) -> bool:
     if flag is not None:
         return flag
-    # cached: jax.devices() takes the backend lock and this runs on every
-    # kernel invocation site (satellite: was a per-call devices() query)
+    # off the chip the kernels run interpreted, which is how the CPU tests
+    # reach them; the CLI's JSON names the platform, so such a run cannot
+    # pass for a chip run.  Cached: this runs at every kernel call site.
     return not is_tpu()
 
 
 def _block_table():
-    """The measured (seq, head_dim) -> (fwd_q, fwd_k, bwd_q, bwd_k)
-    defaults — moved to the tunables registry
-    (`optimize.tunables.ATTENTION_BLOCK_TABLE`, TPU v5 lite provenance at
-    BENCH_r02 shapes); lazy-imported because the kernel layer sits below
-    optimize/ in the import graph."""
+    """The (seq, head_dim) -> (fwd_q, fwd_k, bwd_q, bwd_k) defaults —
+    kept in the tunables registry
+    (`optimize.tunables.ATTENTION_BLOCK_TABLE`; provenance: one v5e run of
+    2026-07-29, not reproduced); lazy-imported because the kernel layer
+    sits below optimize/ in the import graph."""
     from deeplearning4j_tpu.optimize import tunables
 
     return tunables.ATTENTION_BLOCK_TABLE
@@ -477,9 +470,7 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, block_skip,
     # pinned `interpret` (tests exercise the kernels that way on CPU).
     # Auto-detected interpret mode (interpret=None off-TPU) keeps the
     # jax-level recompute fallback: emulated per-tile kernels lose to
-    # XLA's batched blockwise scan on host CPUs, so fusing there would
-    # make the flag a de-optimization exactly where the bench is tagged
-    # cpu_fallback.
+    # XLA's batched blockwise scan on host CPUs, which only tests use.
     fused = (fused_bwd
              and (interpret is not None or is_tpu())
              and s % min(block_q, s) == 0 and s % min(block_k, s) == 0
@@ -559,14 +550,43 @@ def _lstm_reference(x, h, c, wx, wh, b):
     return o * jnp.tanh(c_new), c_new
 
 
+def fused_lstm_vmem_bytes(batch: int, n_in: int, n_hidden: int,
+                          dtype) -> int:
+    """Fast memory the gridless cell keeps resident: x, h, c, Wx, Wh, b
+    and both outputs in `dtype`, plus the f32 gate pre-activations
+    [B, 4H].  Within ~12% of the compiler's own scoped-allocation figure
+    at every width tried (compile only, described v5e: 40.95M reported
+    vs 41.0M here at B256·I1024·H1024 f32)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    resident = (batch * n_in + 4 * batch * n_hidden
+                + (n_in + n_hidden + 1) * 4 * n_hidden)
+    return itemsize * resident + 4 * batch * 4 * n_hidden
+
+
+def fused_lstm_fits(batch: int, n_in: int, n_hidden: int, dtype) -> bool:
+    """True when the cell compiles inside `VMEM_LIMIT_BYTES`; an eighth
+    of headroom covers what the estimate leaves out."""
+    need = fused_lstm_vmem_bytes(batch, n_in, n_hidden, dtype)
+    return need + need // 8 <= VMEM_LIMIT_BYTES
+
+
 def _fused_lstm_impl(x, h, c, wx, wh, b, interpret):
     bsz, hdim = h.shape
+    interpret = _interpret(interpret)
+    if not interpret and not fused_lstm_fits(bsz, x.shape[1], hdim, wx.dtype):
+        raise ValueError(
+            "fused LSTM cell at batch=%d n_in=%d hidden=%d %s keeps ~%d "
+            "bytes resident in fast memory, over the %d-byte limit of one "
+            "kernel; use lstm_impl='scan' (or 'auto', which picks it)"
+            % (bsz, x.shape[1], hdim, jnp.dtype(wx.dtype).name,
+               fused_lstm_vmem_bytes(bsz, x.shape[1], hdim, wx.dtype),
+               VMEM_LIMIT_BYTES))
     out_shape = (jax.ShapeDtypeStruct((bsz, hdim), h.dtype),
                  jax.ShapeDtypeStruct((bsz, hdim), c.dtype))
     return pl.pallas_call(
         _lstm_cell_kernel,
         out_shape=out_shape,
-        interpret=_interpret(interpret),
+        interpret=interpret,
     )(x, h, c, wx, wh, b[None, :])
 
 
@@ -595,6 +615,7 @@ fused_lstm_step.defvjp(_lstm_fwd, _lstm_bwd)
 # ------------------------------------------------------------- scatter-add
 
 _SCATTER_GROUP = 8  # update rows per grid step (sublane tile height)
+_LANES = 128  # row width must tile the lane axis
 
 
 def _scatter_add_kernel(idx_ref, upd_ref, tbl_ref, out_ref, scratch, sem):
@@ -631,6 +652,12 @@ def scatter_add_rows(table, indices, updates,
     `axpy` embedding updates (`InMemoryLookupTable.java:198-260`).
     """
     n, d = updates.shape
+    interpret = _interpret(interpret)
+    if not interpret and d % _LANES:
+        raise ValueError(
+            "scatter_add_rows: row width %d is not a multiple of the %d-lane "
+            "tile the chip's compiler requires; pad the table's rows"
+            % (d, _LANES))
     pad = (-n) % _SCATTER_GROUP
     if pad:
         # padded rows add zeros to row 0 — a no-op
@@ -645,9 +672,9 @@ def scatter_add_rows(table, indices, updates,
         in_specs=[
             pl.BlockSpec((_SCATTER_GROUP, d),
                          lambda g, idx_ref: (g, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((_SCATTER_GROUP, d), table.dtype),
             pltpu.SemaphoreType.DMA,
@@ -658,6 +685,6 @@ def scatter_add_rows(table, indices, updates,
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         grid_spec=grid_spec,
         input_output_aliases={2: 0},
-        compiler_params=_compiler_params(has_side_effects=True),
-        interpret=_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
+        interpret=interpret,
     )(indices.astype(jnp.int32), updates, table)

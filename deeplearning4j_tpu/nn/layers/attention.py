@@ -73,6 +73,39 @@ class MultiHeadAttentionLayer:
         return x + o
 
     @staticmethod
+    def resolve_impl(conf, b: int, s: int, h: int) -> str:
+        """The implementation `_attend` takes for [b, s, h, hd] inputs:
+        `conf.attention_impl`, with "auto" settled from what the code can
+        observe (platform, shape, the two flash-side flags)."""
+        impl = conf.attention_impl
+        if impl != "auto":
+            return impl
+        if not is_tpu():
+            return "blockwise" if conf.attention_block_size else "full"
+        # one v5e sweep of 2026-07-29, not reproduced: XLA's dense attention
+        # (heads batched into big MXU matmuls) beat the Pallas flash kernel
+        # up through S=2048; beyond that the [S,S] scores no longer fit
+        # HBM and flash is the only option. The 8 GiB bound was that
+        # sweep's per-layer failure boundary (S=2048/B=16/H=16 = 4.3 GiB
+        # trained, S=4096/B=8 = 8.6 GiB ran out of memory); it is
+        # per-LAYER because XLA rematerializes probs inside fusions rather
+        # than retaining one [B,H,S,S] per block, and b here is the
+        # per-device batch under shard_map. Overrides: conf.attention_impl
+        # pins an impl, conf.remat frees HBM.  Each flash-side improvement
+        # moves the crossover one doubling earlier (halves the bound): the
+        # causal block-skip halves the kernel's tile visits, and the fused
+        # backward removes the flash path's forward recompute.  Both
+        # shifts are analytic, not measured; bench.py's
+        # bench_attention_crossover exists to measure the boundary.
+        scores_bytes = 4 * b * h * s * s  # f32 fwd scores
+        bound = 8 << 30
+        if conf.attention_block_skip and conf.causal:
+            bound >>= 1
+        if conf.attention_fused_bwd:
+            bound >>= 1
+        return "full" if scores_bytes <= bound else "flash"
+
+    @staticmethod
     def _attend(conf, q, k, v):
         """Impl dispatch shared by `forward` and `prefill` — q/k/v are
         [b, s, h, hd] and the result matches elementwise whichever path
@@ -82,38 +115,7 @@ class MultiHeadAttentionLayer:
         blk = conf.attention_block_size
         skip = conf.attention_block_skip and conf.causal
         fused_bwd = conf.attention_fused_bwd
-        impl = conf.attention_impl
-        if impl == "auto":
-            if is_tpu():
-                # measured on v5e: XLA's dense attention (heads batched into
-                # big MXU matmuls) beats the Pallas flash kernel up through
-                # S=2048 (224 vs 432 ms/step at S=2048); beyond that the
-                # [S,S] scores no longer fit HBM and flash is the only
-                # option. The 8 GiB bound is the measured per-layer failure
-                # boundary (S=2048/B=16/H=16 = 4.3 GiB trains, S=4096/B=8 =
-                # 8.6 GiB OOMs); it is per-LAYER because XLA rematerializes
-                # probs inside fusions rather than retaining one [B,H,S,S]
-                # per block (8 blocks x 2 GiB at S=1024 runs fine), and b
-                # here is the per-device batch under shard_map. Overrides:
-                # conf.attention_impl pins an impl, conf.remat frees HBM.
-                # Each flash-side improvement moves the crossover one
-                # doubling earlier (halves the bound): the causal block-skip
-                # halves the kernel's tile visits, and the fused backward
-                # removes the flash path's forward recompute — dense
-                # attention's bwd was ~2x flash-recompute's cost advantage,
-                # so flash now wins a doubling sooner again.  Both shifts
-                # are analytic off the same v5e sweep; bench.py's
-                # bench_attention_crossover records the measured boundary
-                # to check these bounds on the next chip run.
-                scores_bytes = 4 * b * h * s * s  # f32 fwd scores
-                bound = 8 << 30
-                if skip:
-                    bound >>= 1
-                if fused_bwd:
-                    bound >>= 1
-                impl = "full" if scores_bytes <= bound else "flash"
-            else:
-                impl = "blockwise" if blk else "full"
+        impl = MultiHeadAttentionLayer.resolve_impl(conf, b, s, h)
         if impl == "flash":
             from deeplearning4j_tpu.nd.pallas_kernels import (
                 flash_attention, pick_attention_blocks)

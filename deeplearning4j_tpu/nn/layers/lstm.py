@@ -53,19 +53,21 @@ class LSTMLayer:
         return (h, c), h
 
     @staticmethod
-    def _use_fused(conf) -> bool:
-        # measured on v5e with host-synced timing: the Pallas cell beats
-        # XLA's scan fusion ~25% (70.6 vs 94.4 ms/fwd at B=64 T=64
-        # 256->512), so "auto" uses it on TPU; interpret-mode overhead
-        # makes scan the right default elsewhere.  NOTE: that measurement
-        # predates the hoisted input projection in the scan path below —
-        # re-measure on chip (lstm_impl="scan" vs "fused") before trusting
-        # "auto" for a new config.
+    def _use_fused(conf, batch: int, dtype) -> bool:
+        # "auto" takes the Pallas cell on a TPU only where the operands it
+        # keeps resident fit one kernel's fast memory (the cell has no
+        # grid; H=1024 f32 needs ~41 MB against a 16 MB limit and the
+        # compiler refuses it), and the scan path everywhere else.  Which
+        # of the two is faster where both compile: not measured since the
+        # scan path hoisted its input projection.  A pinned "fused" that
+        # does not fit raises from the kernel, naming bytes and limit.
         impl = getattr(conf, "lstm_impl", "auto")
         if impl == "auto":
+            from deeplearning4j_tpu.nd.pallas_kernels import fused_lstm_fits
             from deeplearning4j_tpu.nd.platform import is_tpu
 
-            return is_tpu()
+            return is_tpu() and fused_lstm_fits(batch, conf.n_in,
+                                                conf.n_out, dtype)
         return impl == "fused"
 
     @staticmethod
@@ -83,7 +85,7 @@ class LSTMLayer:
         h0 = jnp.zeros_like(x, shape=(B, n_h))
         c0 = jnp.zeros_like(x, shape=(B, n_h))
 
-        if LSTMLayer._use_fused(conf):
+        if LSTMLayer._use_fused(conf, B, params["W"].dtype):
             # Pallas cell: one kernel per step (both matmuls + gates +
             # state update fused); W splits into input/recurrent halves
             from deeplearning4j_tpu.nd.pallas_kernels import fused_lstm_step
@@ -195,10 +197,6 @@ class GravesLSTMLayer(LSTMLayer):
         o = jax.nn.sigmoid(z[..., 2 * n_h:3 * n_h] + params["p_o"] * c_new)
         h = o * jnp.tanh(c_new)
         return (h, c_new), h
-
-    @staticmethod
-    def _use_fused(conf) -> bool:
-        return False  # the Pallas cell has no peephole terms
 
     @staticmethod
     def forward(params, conf, x, key=None, training=False):

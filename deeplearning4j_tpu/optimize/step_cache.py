@@ -357,19 +357,18 @@ class CompiledProgramCache:
             # module is the exact module a later disk hit restores, so
             # cold and warm-disk runs match bit-for-bit — and the trace
             # happens once (export), never again for this artifact
-            try:
-                from jax import export as jax_export
+            from jax import export as jax_export
 
+            try:
                 exported = jax_export.export(jax.jit(build()))(*abstract)
-                fn = jax.jit(exported.call,
-                             donate_argnums=donate).lower(*abstract).compile()
             except Exception as e:  # noqa: BLE001 — non-exportable program
                 log.warning("%s: program %s is not exportable (%s); "
                             "compiling without persistence", self.kind, key, e)
-                exported, fn = None, None
-        if exported is None:
-            jitted = jax.jit(build(), donate_argnums=donate)
-            fn = jitted.lower(*abstract).compile()
+        # outside the try: what the compiler refuses is raised once, as
+        # itself, not relabelled as an export problem and compiled twice
+        program = build() if exported is None else exported.call
+        fn = jax.jit(program,
+                     donate_argnums=donate).lower(*abstract).compile()
         dt = time.perf_counter() - t0
         self.stats.compile_seconds[key] = dt
         log.info("%s miss: compiled %s in %.2fs (entry %d)",

@@ -45,11 +45,10 @@ class Tunable(NamedTuple):
     doc: str
 
 
-# Measured block-size table for the Pallas flash kernels, keyed by
-# (seq, head_dim) -> (fwd_q, fwd_k, bwd_q, bwd_k).  Moved verbatim from
-# nd/pallas_kernels.py (provenance: TPU v5 lite sweeps at BENCH_r02
-# shapes); these are now the *defaults* the kernel layer resolves through
-# the tuned-table override.
+# Block-size table for the Pallas flash kernels, keyed by
+# (seq, head_dim) -> (fwd_q, fwd_k, bwd_q, bwd_k) (provenance: one v5e run
+# of 2026-07-29, not reproduced); these are the *defaults* the kernel
+# layer resolves through the tuned-table override.
 ATTENTION_BLOCK_TABLE = {
     (256, 32): (128, 128, 128, 128),
     (256, 64): (128, 128, 128, 128),

@@ -22,6 +22,7 @@ serving never blocks on tuning).
 """
 from __future__ import annotations
 
+import logging
 import time
 from typing import Optional, Sequence
 
@@ -30,6 +31,8 @@ import numpy as np
 from deeplearning4j_tpu.optimize import tunables
 from deeplearning4j_tpu.optimize.step_cache import conf_fingerprint
 from deeplearning4j_tpu.reliability import faults
+
+log = logging.getLogger(__name__)
 
 #: candidates whose analytic cost is >= this multiple of the incumbent's
 #: are never compiled (TVM's "don't measure the obviously bad" pruning)
@@ -67,7 +70,8 @@ class _Search:
                 best = dt if best is None or dt < best else best
             self.candidates_measured += 1
             return best
-        except Exception:  # noqa: BLE001 — one bad candidate never ends a search
+        except Exception as e:  # noqa: BLE001 — one bad candidate never ends a search
+            log.warning("tune: candidate measurement failed: %r", e)
             self.measure_failures += 1
             return None
 
@@ -140,13 +144,13 @@ def _tune_attention(net, search, rng):
     where candidates tie and the measured defaults stand — the table only
     moves on hardware where blocks genuinely differ)."""
     import jax
+    import jax.numpy as jnp
 
     from deeplearning4j_tpu.nd.pallas_kernels import (flash_attention,
                                                       pick_attention_blocks)
     for seq, hd in _attention_shapes(net.conf):
-        q = np.asarray(rng.standard_normal((1, seq, 2, hd)), np.float32)
-        k = np.asarray(rng.standard_normal((1, seq, 2, hd)), np.float32)
-        v = np.asarray(rng.standard_normal((1, seq, 2, hd)), np.float32)
+        q, k, v = (jnp.asarray(rng.standard_normal((1, seq, 2, hd)),
+                               jnp.float32) for _ in range(3))
         qualifier = "%dx%d" % (seq, hd)
         for name, bwd in (("attention.block_fwd", False),
                           ("attention.block_bwd", True)):

@@ -27,30 +27,17 @@ from jax.sharding import Mesh, PartitionSpec as P
 from deeplearning4j_tpu.nd.attention import (  # noqa: F401  (re-export)
     _NEG_BIG, _finalize, _online_update, blockwise_attention, full_attention)
 
-try:
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
+from jax import shard_map as _shard_map_impl
 
 
 def _as_varying(a, axis: str):
     """Mark `a` as manual-axis-varying over `axis` for the check_vma pass;
-    no-op when already varying or on jax versions without the collective.
-    Loop carries that start as fresh (invariant) zeros but accumulate
-    ppermute-rotated values need this so the static check can type them."""
-    fns = []
-    if hasattr(lax, "pcast"):  # current spelling
-        fns.append(lambda x: lax.pcast(x, (axis,), to="varying"))
-    if hasattr(lax, "pvary"):  # one release earlier
-        fns.append(lambda x: lax.pvary(x, (axis,)))
-    for fn in fns:
-        try:
-            return fn(a)
-        except ValueError:  # already varying over `axis` — nothing to do
-            return a
-        except TypeError:  # signature drift in this spelling — try next
-            continue
-    return a
+    no-op when it already varies.  Loop carries that start as fresh
+    (invariant) zeros but accumulate ppermute-rotated values need this so
+    the static check can type them."""
+    if axis in jax.typeof(a).vma:
+        return a
+    return lax.pcast(a, (axis,), to="varying")
 
 
 def _shard_map(f, mesh, in_specs, out_specs, check: bool = True):
@@ -60,19 +47,9 @@ def _shard_map(f, mesh, in_specs, out_specs, check: bool = True):
 
     `check=False` opts out for bodies the checker rejects by construction:
     the ring-attention carry mixes axis-varying ppermute outputs with
-    invariant init values, which the v0.8 `check_vma` pass cannot type.
-    The kwarg name differs across jax versions (check_vma/check_rep), so
-    the disable probes both; enabling is just the default signature."""
-    if check:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs)
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        try:
-            return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise RuntimeError("no compatible shard_map signature found")
+    invariant init values, which `check_vma` cannot type."""
+    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=check)
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,

@@ -28,6 +28,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from deeplearning4j_tpu.nd.platform import chip_env
+
 
 # ------------------------------------------------------------ cluster spec
 
@@ -168,9 +170,14 @@ class LocalLauncher(Launcher):
               workdir: str) -> subprocess.Popen:
         cwd = os.path.join(self.host_dir(host), workdir.lstrip("/"))
         os.makedirs(cwd, exist_ok=True)
-        proc = subprocess.Popen(
-            ["/bin/sh", "-c", entry], cwd=cwd,
-            env={**os.environ, **env})
+        # the stand-in hosts share this machine's chips: the worker whose
+        # `jax.distributed` env names it process k takes chip k for
+        # itself, and this launching process stays off JAX.  A command
+        # that is no worker (`run_remote`) gets no chip
+        worker = env.get("JAX_PROCESS_ID")
+        pin = {} if worker is None else chip_env(int(worker))
+        proc = subprocess.Popen(["/bin/sh", "-c", entry], cwd=cwd,
+                                env={**os.environ, **pin, **env})
         self.procs.append(proc)
         return proc
 

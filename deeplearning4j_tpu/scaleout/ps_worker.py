@@ -59,10 +59,6 @@ def main(argv=None) -> int:
                         "simulation for async-mode tests)")
     args = p.parse_args(argv)
 
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")  # control-plane worker: CPU
-
     import numpy as np
 
     from deeplearning4j_tpu.scaleout.param_server import ParameterServerWorker

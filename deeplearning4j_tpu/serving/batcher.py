@@ -484,12 +484,15 @@ class MicroBatcher:
                 out = None
         t_done = time.monotonic()
         policy = self.net.infer_cache.policy
+        # output rows per input row: 1 for row-wise models, T for sequence
+        # models whose output stage flattens [B, T, V] to [B*T, V]
+        k = 1 if out is None else max(1, out.shape[0] // xb.shape[0])
         offset = 0
         for r in batch:
             if err is not None:
                 r.error = err
             else:
-                r.result = out[offset:offset + r.rows]
+                r.result = out[offset * k:(offset + r.rows) * k]
                 offset += r.rows
             r.done.set()
         with self._cv:

@@ -74,8 +74,7 @@ def test_report_schema_and_severity_ordering():
 # -- program auditor: seeded violations --------------------------------------
 
 def test_f64_op_detected_and_f32_clean():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         bad = jax.make_jaxpr(lambda x: x * 2.0)(
             jnp.zeros((3,), jnp.float64))
     assert _rules(audit_jaxpr(bad, where="f64")) == ["f64-op"]

@@ -2,17 +2,84 @@
 MovingWindowBaseDataSetIterator (VERDICT r3 missing #3)."""
 
 import numpy as np
+import pytest
 
 from deeplearning4j_tpu.datasets.dataset import DataSet, labels_to_one_hot
 from deeplearning4j_tpu.datasets.iterator import (
     ListDataSetIterator, MovingWindowBaseDataSetIterator,
-    ReconstructionDataSetIterator, moving_window_dataset)
+    ReconstructionDataSetIterator, SamplingDataSetIterator,
+    moving_window_dataset)
 
 
 def _ds(n=12, d=16, classes=3, seed=0):
     rng = np.random.RandomState(seed)
     return DataSet(rng.rand(n, d).astype(np.float32),
                    labels_to_one_hot(rng.randint(0, classes, n), classes))
+
+
+def _sequence_ds(n=5, t=3, v=4):
+    """Example i holds the ids i*t .. i*t+t-1, and label row r starts at
+    r*v: an example's label rows are recognisable from its features."""
+    feats = np.arange(n * t).reshape(n, t)
+    labels = np.arange(n * t * v).reshape(n * t, v)
+    return DataSet(feats, labels, label_rows=t), feats, labels
+
+
+def _assert_rows_follow_examples(ds, v=4):
+    np.testing.assert_array_equal(ds.labels[:, 0], ds.features.reshape(-1) * v)
+
+
+def test_batches_of_sequence_data_keep_their_label_rows():
+    """The `text:` scheme carries T label rows per example ([B*T, V]):
+    mini-batches must take the matching run of label rows, or `cli train
+    --properties batch=N` trains a char model on the wrong targets."""
+    ds, feats, labels = _sequence_ds()
+    n, t = feats.shape
+    batches = ds.batch_by(2)
+    assert [b.features.shape[0] for b in batches] == [2, 2, 1]
+    assert [b.labels.shape[0] for b in batches] == [2 * t, 2 * t, t]
+    np.testing.assert_array_equal(
+        np.concatenate([b.labels for b in batches]), labels)
+    np.testing.assert_array_equal(batches[1].labels, labels[2 * t:4 * t])
+    it = ListDataSetIterator(ds, 4)
+    np.testing.assert_array_equal(next(it).labels, labels[:4 * t])
+    # row-per-example data slices as before
+    plain = DataSet(feats, labels[:n]).batch_by(2)
+    np.testing.assert_array_equal(plain[2].labels, labels[4:5])
+    with pytest.raises(ValueError, match="label rows"):
+        DataSet(feats, labels[:n], label_rows=t)
+
+
+def test_shuffled_sequence_data_keeps_its_label_rows():
+    """Index arrays, not only slices: shuffle, split, sample, the sampling
+    iterator and per-example iteration all take an example's own rows."""
+    ds, feats, _ = _sequence_ds()
+    shuffled = ds.shuffle(seed=7)
+    assert not np.array_equal(shuffled.features, feats)
+    train, test = ds.split_test_and_train(3, seed=7)
+    sampled = SamplingDataSetIterator(ds, 4, total_batches=2).next()
+    picked = [shuffled, train, test, ds.sample(8), sampled,
+              shuffled.batch_by(2)[1], DataSet.merge([test, train]),
+              ds.copy(), *ds]
+    for d in picked:
+        assert d.label_rows == ds.label_rows
+        _assert_rows_follow_examples(d)
+    assert (train.num_examples(), test.num_examples()) == (3, 2)
+
+
+def test_text_scheme_records_label_rows(tmp_path):
+    from deeplearning4j_tpu.cli.schemes import load_input
+
+    path = tmp_path / "corpus.txt"
+    path.write_text("abcdefgh" * 8)
+    ds = load_input(f"text:{path}:4")
+    assert ds.label_rows == 4
+    shuffled = ds.shuffle(seed=1)
+    # every window's targets are its own ids shifted by one
+    x = shuffled.features.argmax(-1)
+    y = shuffled.labels.argmax(-1).reshape(x.shape)
+    np.testing.assert_array_equal(y[:, :-1], x[:, 1:])
+    np.testing.assert_array_equal(y[:, -1], (x[:, -1] + 1) % 8)
 
 
 def test_reconstruction_iterator_sets_labels_to_features():

@@ -155,6 +155,39 @@ def test_stop_drains_queued_requests():
     assert got and got[0].shape == (2, N_OUT)
 
 
+def test_sequence_model_requests_get_all_their_output_rows():
+    """A sequence model answers T rows per input row ([B*T, V]): coalesced
+    requests each get their own T-row runs back, not the first `rows` rows
+    of the batch (what the gateway answered for every transformer until
+    the first run on the chip compared it with `net.output`)."""
+    from deeplearning4j_tpu.models.zoo import char_transformer
+
+    seq, vocab = 8, 12
+    net = MultiLayerNetwork(char_transformer(
+        vocab, d_model=16, n_blocks=1, n_heads=2, max_seq_len=seq)).init()
+    rng = np.random.RandomState(0)
+    xs = [rng.randint(0, vocab, (rows, seq)).astype(np.float32)
+          for rows in (1, 2, 1)]
+    want = [np.asarray(net.output(x)) for x in xs]
+    batcher = MicroBatcher(net, max_delay_ms=5000.0, auto_start=False)
+    got = [None] * len(xs)
+    threads = [threading.Thread(
+        target=lambda i=i: got.__setitem__(
+            i, batcher.predict(xs[i], timeout=60.0))) for i in range(len(xs))]
+    for t in threads:
+        t.start()
+    deadline = time.time() + 10.0
+    while batcher.queue_depth() < len(xs) and time.time() < deadline:
+        time.sleep(0.01)
+    batcher.start()
+    batcher.stop()  # one coalesced flush of all four rows
+    for t in threads:
+        t.join(timeout=60.0)
+    for x, g, w in zip(xs, got, want):
+        assert g.shape == (x.shape[0] * seq, vocab)
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+
+
 def test_dispatcher_error_delivered_to_caller():
     net = _net()
     batcher = MicroBatcher(net, max_delay_ms=5.0)

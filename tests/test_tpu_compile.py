@@ -1,0 +1,149 @@
+"""The Pallas kernels of the main path, compiled for a described TPU v5e.
+
+Interpret mode, which every other kernel test runs in, cannot see what the
+chip's compiler refuses: a slice off the lane tiling, a kernel that wants
+more fast memory than it may have.  The compiler is installed here and
+compiles for a chip that is described and not attached, so these tests ask
+it, at real widths, at about two seconds a case and no chip time.  Nothing
+runs: a compile that passes says nothing about results or speed.
+
+One process at a time may load the TPU's library and it keeps it until it
+exits, so the topology is described inside a fixture (never while a module
+is imported), every compile happens in this process, and all such tests
+live in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.nd import pallas_kernels as pk
+from deeplearning4j_tpu.nd import platform
+from deeplearning4j_tpu.nn.conf import LayerType, NeuralNetConfiguration
+from deeplearning4j_tpu.nn.layers.lstm import LSTMLayer
+
+B, S, H, HD = 4, 512, 16, 128  # the flagship's attention shape
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: nothing to ask
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on the first described chip, with JAX's persistent cache
+    off around the module: an entry written for a described chip cannot be
+    read back without one, and the next compile would warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Compile for the described chip; returns the program text."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash(block_skip, fused_bwd=False):
+    bq, bk = pk.pick_attention_blocks(S, HD)
+    return lambda q, k, v: pk.flash_attention(
+        q, k, v, True, bq, bk, False, block_skip=block_skip,
+        fused_bwd=fused_bwd)
+
+
+@pytest.mark.parametrize("block_skip", [True, False],
+                         ids=["block-skip", "no-skip"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_flash_forward_compiles(one_chip, dtype, block_skip):
+    qkv = [((B, S, H, HD), dtype)] * 3
+    assert "tpu_custom_call" in _compile(_flash(block_skip), one_chip, *qkv)
+
+
+@pytest.mark.parametrize("block_skip", [True, False],
+                         ids=["block-skip", "no-skip"])
+def test_flash_fused_backward_compiles(one_chip, block_skip):
+    attend = _flash(block_skip, fused_bwd=True)
+    grad = jax.grad(lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))
+    text = _compile(grad, one_chip, *[((B, S, H, HD), jnp.bfloat16)] * 3)
+    # forward with residuals, delta, dK/dV and dQ: four kernels, and none
+    # of them gave way to the jax-level recompute
+    assert text.count("tpu_custom_call") >= 4
+
+
+def _lstm_shapes(batch, hidden, dtype):
+    return [((batch, hidden), dtype)] * 3 + [((hidden, 4 * hidden), dtype)] * 2 \
+        + [((4 * hidden,), dtype)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_fused_lstm_cell_h256_compiles(one_chip, dtype):
+    text = _compile(lambda *a: pk.fused_lstm_step(*a, False), one_chip,
+                    *_lstm_shapes(256, 256, dtype))
+    assert "tpu_custom_call" in text
+
+
+def test_fused_lstm_cell_h1024_pinned_says_why_not(one_chip):
+    """The cell has no grid: at H=1024 it wants ~41 MB of a 16 MB scope and
+    the compiler refuses it.  Pinned, it must say so itself, with the bytes
+    and the limit, before the compiler is asked."""
+    with pytest.raises(ValueError) as err:
+        _compile(lambda *a: pk.fused_lstm_step(*a, False), one_chip,
+                 *_lstm_shapes(256, 1024, jnp.float32))
+    need = pk.fused_lstm_vmem_bytes(256, 1024, 1024, jnp.float32)
+    assert str(need) in str(err.value)
+    assert str(pk.VMEM_LIMIT_BYTES) in str(err.value)
+    assert need > pk.VMEM_LIMIT_BYTES
+
+
+@pytest.mark.parametrize("hidden,kernels", [(256, True), (1024, False)],
+                         ids=["h256-fused", "h1024-scan"])
+def test_lstm_auto_dispatch_compiles_at_every_width(one_chip, monkeypatch,
+                                                    hidden, kernels):
+    """`lstm_impl="auto"` on a TPU takes the Pallas cell where it fits and
+    the scan path where it does not; either way the layer compiles."""
+    monkeypatch.setattr(platform, "is_tpu", lambda: True)
+    monkeypatch.setattr(pk, "is_tpu", lambda: True)
+    conf = NeuralNetConfiguration(layer_type=LayerType.LSTM, n_in=hidden,
+                                  n_out=hidden)
+    assert conf.lstm_impl == "auto"
+    text = _compile(
+        lambda w, b, x: LSTMLayer.forward({"W": w, "b": b}, conf, x),
+        one_chip, ((2 * hidden, 4 * hidden), jnp.float32),
+        ((4 * hidden,), jnp.float32), ((64, 8, hidden), jnp.float32))
+    assert ("tpu_custom_call" in text) == kernels
+
+
+def test_scatter_add_rows_lane_width(one_chip):
+    """Row widths that tile the 128 lanes compile; others are refused with
+    a reason before the compiler's own `Slice shape ... must be aligned`."""
+    def scatter(table, idx, upd):
+        return pk.scatter_add_rows(table, idx, upd, interpret=False)
+
+    def shapes(width):
+        return [((1000, width), jnp.float32), ((64,), jnp.int32),
+                ((64, width), jnp.float32)]
+
+    assert "tpu_custom_call" in _compile(scatter, one_chip, *shapes(128))
+    with pytest.raises(ValueError, match="multiple of the 128-lane"):
+        _compile(scatter, one_chip, *shapes(100))
