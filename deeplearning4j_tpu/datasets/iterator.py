@@ -19,6 +19,7 @@ import numpy as np
 
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.reliability import faults
+from deeplearning4j_tpu.utils.profiling import span
 
 
 class DataSetIterator:
@@ -339,13 +340,21 @@ class PrefetchIterator:
 
     def _worker(self, q: queue.Queue, stop: threading.Event) -> None:
         try:
-            for item in self.base:
+            items = iter(self.base)
+            while True:
+                with span("prefetch.next"):     # the base iterator's time
+                    try:
+                        item = next(items)
+                    except StopIteration:
+                        break
                 if stop.is_set():
                     return
                 # armed faults simulate a worker crash mid-epoch; the
                 # exception rides the ERROR message to exactly one consumer
                 faults.fire("prefetch.worker")
-                if not self._put(q, stop, (self._ITEM, self._transfer(item))):
+                with span("prefetch.transfer"):
+                    item = self._transfer(item)
+                if not self._put(q, stop, (self._ITEM, item)):
                     return
             self._put(q, stop, (self._DONE, None))
         except BaseException as e:  # noqa: BLE001 — re-raised at next()
@@ -384,25 +393,29 @@ class PrefetchIterator:
             if self._queue is None:
                 self._start_locked()
             q, stop = self._queue, self._stop
-        while True:
-            if stop.is_set():
-                raise StopIteration
-            try:
-                kind, payload = q.get(timeout=0.05)
-            except queue.Empty:
-                continue
-            if kind == self._ITEM:
-                return payload
-            if kind == self._ERROR:
-                stop.set()  # terminal: release the other consumers too
-                raise payload
-            # DONE: put it back so every other consumer also terminates
-            # (worker has exited, so the freed slot can't be re-filled)
-            try:
-                q.put_nowait((self._DONE, None))
-            except queue.Full:
-                pass
-            raise StopIteration
+        # what the consumer waits for the worker: about nothing while the
+        # queue holds a batch, the base iterator's time when it does not
+        with span("prefetch.wait", depth=q.qsize()):
+            while True:
+                if stop.is_set():
+                    raise StopIteration
+                try:
+                    kind, payload = q.get(timeout=0.05)
+                    break
+                except queue.Empty:
+                    continue
+        if kind == self._ITEM:
+            return payload
+        if kind == self._ERROR:
+            stop.set()  # terminal: release the other consumers too
+            raise payload
+        # DONE: put it back so every other consumer also terminates
+        # (worker has exited, so the freed slot can't be re-filled)
+        try:
+            q.put_nowait((self._DONE, None))
+        except queue.Full:
+            pass
+        raise StopIteration
 
     def __iter__(self):
         self.start()

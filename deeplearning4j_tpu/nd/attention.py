@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deeplearning4j_tpu.utils.profiling import scope
+
 _NEG_BIG = -1e30  # finite -inf stand-in: keeps exp() NaN-free on fully-masked rows
 
 
@@ -31,11 +33,14 @@ def _causal_mask(sq: int, sk: int, q_off, k_off, dtype) -> jax.Array:
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    causal: bool = False, q_offset=0, k_offset=0) -> jax.Array:
     """Reference softmax attention (materializes the [Sq,Sk] score matrix)."""
-    s = _scores(q, k)
-    if causal:
-        s = s + _causal_mask(q.shape[1], k.shape[1], q_offset, k_offset, s.dtype)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    with scope("scores"):
+        s = _scores(q, k)
+        if causal:
+            s = s + _causal_mask(q.shape[1], k.shape[1], q_offset, k_offset,
+                                 s.dtype)
+        p = jax.nn.softmax(s, axis=-1)
+    with scope("attend"):
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
 def _online_update(o, m, l, q, kblk, vblk, q_off, k_off, causal: bool):

@@ -204,6 +204,7 @@ def _flash_attention_fwd_impl(q, k, v, causal: bool, block_q: int,
                        pl.BlockSpec((None, block_q, 1),
                                     lambda i, j: (i, j, 0))),
             interpret=_interpret(interpret),
+            name="dl4j_flash_fwd",
         )(qr, kr, vr)
         return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse
     out = pl.pallas_call(
@@ -213,6 +214,7 @@ def _flash_attention_fwd_impl(q, k, v, causal: bool, block_q: int,
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
         interpret=_interpret(interpret),
+        name="dl4j_flash_fwd",
     )(qr, kr, vr)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
@@ -396,6 +398,7 @@ def _flash_fused_bwd_impl(q, k, v, out, lse, g, causal, block_q, block_k,
         in_specs=[tile_q, tile_q],
         out_specs=tile_r,
         interpret=interp,
+        name="dl4j_flash_bwd_delta",
     )(orr, gr)
 
     dkv_kernel = functools.partial(
@@ -409,6 +412,7 @@ def _flash_fused_bwd_impl(q, k, v, out, lse, g, causal, block_q, block_k,
         in_specs=[full_sd, tile_k, tile_k, full_sd, full_r, full_r],
         out_specs=(tile_k, tile_k),
         interpret=interp,
+        name="dl4j_flash_bwd_dkv",
     )(qr, kr, vr, gr, lse, delta)
 
     dq_kernel = functools.partial(
@@ -421,6 +425,7 @@ def _flash_fused_bwd_impl(q, k, v, out, lse, g, causal, block_q, block_k,
         in_specs=[tile_q, full_sd, full_sd, tile_q, tile_r, tile_r],
         out_specs=tile_q,
         interpret=interp,
+        name="dl4j_flash_bwd_dq",
     )(qr, kr, vr, gr, lse, delta)
 
     def from_bh(t):
@@ -587,6 +592,7 @@ def _fused_lstm_impl(x, h, c, wx, wh, b, interpret):
         _lstm_cell_kernel,
         out_shape=out_shape,
         interpret=interpret,
+        name="dl4j_lstm_cell",
     )(x, h, c, wx, wh, b[None, :])
 
 
@@ -687,4 +693,5 @@ def scatter_add_rows(table, indices, updates,
         input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
+        name="dl4j_scatter_add",
     )(indices.astype(jnp.int32), updates, table)

@@ -62,6 +62,7 @@ from deeplearning4j_tpu.nn.conf import LayerType, MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers import get_layer
 from deeplearning4j_tpu.nn.layers.base import compute_dtype
 from deeplearning4j_tpu.nn.layers.output import OutputLayer
+from deeplearning4j_tpu.utils.profiling import layer_scope, scope
 
 #: hidden layer types the decode path knows how to step one token at a time
 GENERATIVE_HIDDEN = (LayerType.LSTM, LayerType.GRAVES_LSTM,
@@ -174,11 +175,22 @@ def token_embed(conf: MultiLayerConfiguration, params, tok, pos):
     f32 rows the eager sampler feeds (`eye[cid]`)."""
     c0 = conf.conf(0)
     if LayerType(str(c0.layer_type)) == LayerType.EMBEDDING:
-        e = params[0]["W"][tok]
-        if "P" in params[0]:
-            e = e + params[0]["P"][pos]
-        return e
-    return jax.nn.one_hot(tok, c0.n_in, dtype=jnp.float32)
+        with layer_scope(0, c0), scope("embed"):
+            e = params[0]["W"][tok]
+            if "P" in params[0]:
+                e = e + params[0]["P"][pos]
+            return e
+    with scope("embed"):
+        return jax.nn.one_hot(tok, c0.n_in, dtype=jnp.float32)
+
+
+def _head_logp(conf: MultiLayerConfiguration, params, x):
+    """log(clip(probs)) of the OUTPUT layer over hidden rows x [rows, n],
+    under the layer's scope."""
+    last = conf.n_layers - 1
+    with layer_scope(last, conf.conf(last)):
+        probs = OutputLayer.forward(params[last], conf.conf(last), x)
+        return jnp.log(jnp.clip(probs, 1e-9, 1.0))
 
 
 def decode_step(conf: MultiLayerConfiguration, params, state, tok, pos):
@@ -192,23 +204,23 @@ def decode_step(conf: MultiLayerConfiguration, params, state, tok, pos):
     for i, t in enumerate(types[:-1]):
         c = conf.conf(i)
         impl = get_layer(c.layer_type)
-        if t in _RECURRENT:
-            h, cc = impl.step(params[i], c, x, state[i]["h"], state[i]["c"])
-            new_state.append({"h": h, "c": cc})
-            x = h
-        elif t == LayerType.ATTENTION:
-            x, kc, vc = impl.decode_step(params[i], c, x, state[i]["k"],
-                                         state[i]["v"], pos)
-            new_state.append({"k": kc, "v": vc})
-        elif t == LayerType.TRANSFORMER_FFN:
-            x = impl.forward(params[i], c, x)
-            new_state.append({})
-        else:  # EMBEDDING — consumed by token_embed above
-            new_state.append({})
-    out_conf = conf.conf(len(types) - 1)
-    probs = OutputLayer.forward(params[len(types) - 1], out_conf, x)
+        with layer_scope(i, c):
+            if t in _RECURRENT:
+                h, cc = impl.step(params[i], c, x, state[i]["h"],
+                                  state[i]["c"])
+                new_state.append({"h": h, "c": cc})
+                x = h
+            elif t == LayerType.ATTENTION:
+                x, kc, vc = impl.decode_step(params[i], c, x, state[i]["k"],
+                                             state[i]["v"], pos)
+                new_state.append({"k": kc, "v": vc})
+            elif t == LayerType.TRANSFORMER_FFN:
+                x = impl.forward(params[i], c, x)
+                new_state.append({})
+            else:  # EMBEDDING — consumed by token_embed above
+                new_state.append({})
     new_state.append({})
-    return jnp.log(jnp.clip(probs, 1e-9, 1.0)), tuple(new_state)
+    return _head_logp(conf, params, x), tuple(new_state)
 
 
 def decode_step_paged(conf: MultiLayerConfiguration, params, state, tok,
@@ -223,24 +235,24 @@ def decode_step_paged(conf: MultiLayerConfiguration, params, state, tok,
     for i, t in enumerate(types[:-1]):
         c = conf.conf(i)
         impl = get_layer(c.layer_type)
-        if t in _RECURRENT:
-            h, cc = impl.step(params[i], c, x, state[i]["h"], state[i]["c"])
-            new_state.append({"h": h, "c": cc})
-            x = h
-        elif t == LayerType.ATTENTION:
-            x, kc, vc = impl.decode_step_paged(
-                params[i], c, x, state[i]["k"], state[i]["v"], pos,
-                page_table)
-            new_state.append({"k": kc, "v": vc})
-        elif t == LayerType.TRANSFORMER_FFN:
-            x = impl.forward(params[i], c, x)
-            new_state.append({})
-        else:  # EMBEDDING
-            new_state.append({})
-    out_conf = conf.conf(len(types) - 1)
-    probs = OutputLayer.forward(params[len(types) - 1], out_conf, x)
+        with layer_scope(i, c):
+            if t in _RECURRENT:
+                h, cc = impl.step(params[i], c, x, state[i]["h"],
+                                  state[i]["c"])
+                new_state.append({"h": h, "c": cc})
+                x = h
+            elif t == LayerType.ATTENTION:
+                x, kc, vc = impl.decode_step_paged(
+                    params[i], c, x, state[i]["k"], state[i]["v"], pos,
+                    page_table)
+                new_state.append({"k": kc, "v": vc})
+            elif t == LayerType.TRANSFORMER_FFN:
+                x = impl.forward(params[i], c, x)
+                new_state.append({})
+            else:  # EMBEDDING
+                new_state.append({})
     new_state.append({})
-    return jnp.log(jnp.clip(probs, 1e-9, 1.0)), tuple(new_state)
+    return _head_logp(conf, params, x), tuple(new_state)
 
 
 def decode_block(conf: MultiLayerConfiguration, params, state, tok, pos,
@@ -331,43 +343,40 @@ def _verify_chunk_impl(conf, params, state, toks, pos, page_table):
     for i, t in enumerate(types[:-1]):
         c = conf.conf(i)
         impl = get_layer(c.layer_type)
-        if t in _RECURRENT:
-            h, cc = state[i]["h"], state[i]["c"]
-            outs, hs, cs = [], [], []
-            for j in range(kk):  # K is small and static — unrolled
-                h, cc = impl.step(params[i], c, x[:, j], h, cc)
-                outs.append(h)
-                hs.append(h)
-                cs.append(cc)
-            new_state.append({"h": h, "c": cc})
-            carries.append({"h": jnp.stack(hs, axis=1),
-                            "c": jnp.stack(cs, axis=1)})
-            x = jnp.stack(outs, axis=1)
-        elif t == LayerType.ATTENTION:
-            if page_table is None:
-                x, kc, vc = impl.verify_chunk(
-                    params[i], c, x, state[i]["k"], state[i]["v"], pos)
-            else:
-                x, kc, vc = impl.verify_chunk_paged(
-                    params[i], c, x, state[i]["k"], state[i]["v"], pos,
-                    page_table)
-            new_state.append({"k": kc, "v": vc})
-            carries.append({})
-        elif t == LayerType.TRANSFORMER_FFN:
-            x = impl.forward(params[i], c, x)
-            new_state.append({})
-            carries.append({})
-        else:  # EMBEDDING
-            new_state.append({})
-            carries.append({})
-    out_conf = conf.conf(len(types) - 1)
-    probs = OutputLayer.forward(params[len(types) - 1], out_conf,
-                                x.reshape(b * kk, -1))
-    probs = probs.reshape(b, kk, -1)
+        with layer_scope(i, c):
+            if t in _RECURRENT:
+                h, cc = state[i]["h"], state[i]["c"]
+                outs, hs, cs = [], [], []
+                for j in range(kk):  # K is small and static — unrolled
+                    h, cc = impl.step(params[i], c, x[:, j], h, cc)
+                    outs.append(h)
+                    hs.append(h)
+                    cs.append(cc)
+                new_state.append({"h": h, "c": cc})
+                carries.append({"h": jnp.stack(hs, axis=1),
+                                "c": jnp.stack(cs, axis=1)})
+                x = jnp.stack(outs, axis=1)
+            elif t == LayerType.ATTENTION:
+                if page_table is None:
+                    x, kc, vc = impl.verify_chunk(
+                        params[i], c, x, state[i]["k"], state[i]["v"], pos)
+                else:
+                    x, kc, vc = impl.verify_chunk_paged(
+                        params[i], c, x, state[i]["k"], state[i]["v"], pos,
+                        page_table)
+                new_state.append({"k": kc, "v": vc})
+                carries.append({})
+            elif t == LayerType.TRANSFORMER_FFN:
+                x = impl.forward(params[i], c, x)
+                new_state.append({})
+                carries.append({})
+            else:  # EMBEDDING
+                new_state.append({})
+                carries.append({})
+    logp = _head_logp(conf, params, x.reshape(b * kk, -1)).reshape(b, kk, -1)
     new_state.append({})
     carries.append({})
-    return (jnp.log(jnp.clip(probs, 1e-9, 1.0)), tuple(new_state),
-            tuple(carries))
+    return logp, tuple(new_state), tuple(carries)
 
 
 def verify_chunk(conf: MultiLayerConfiguration, params, state, toks, pos):
@@ -396,29 +405,31 @@ def prefill(conf: MultiLayerConfiguration, params, state, prompt, length):
     types = check_generative(conf)
     c0 = conf.conf(0)
     if types[0] == LayerType.EMBEDDING:
-        x = get_layer(c0.layer_type).forward(params[0], c0, prompt)
+        with layer_scope(0, c0), scope("embed"):
+            x = get_layer(c0.layer_type).forward(params[0], c0, prompt)
     else:
-        x = jax.nn.one_hot(prompt, c0.n_in, dtype=jnp.float32)
+        with scope("embed"):
+            x = jax.nn.one_hot(prompt, c0.n_in, dtype=jnp.float32)
     new_state = []
     for i, t in enumerate(types[:-1]):
         c = conf.conf(i)
         impl = get_layer(c.layer_type)
-        if t in _RECURRENT:
-            x, h, cc = impl.prefill(params[i], c, x, state[i]["h"],
-                                    state[i]["c"], length)
-            new_state.append({"h": h, "c": cc})
-        elif t == LayerType.ATTENTION:
-            x, kc, vc = impl.prefill(params[i], c, x, state[i]["k"],
-                                     state[i]["v"])
-            new_state.append({"k": kc, "v": vc})
-        elif t == LayerType.TRANSFORMER_FFN:
-            x = impl.forward(params[i], c, x)
-            new_state.append({})
-        else:  # EMBEDDING
-            new_state.append({})
+        with layer_scope(i, c):
+            if t in _RECURRENT:
+                x, h, cc = impl.prefill(params[i], c, x, state[i]["h"],
+                                        state[i]["c"], length)
+                new_state.append({"h": h, "c": cc})
+            elif t == LayerType.ATTENTION:
+                x, kc, vc = impl.prefill(params[i], c, x, state[i]["k"],
+                                         state[i]["v"])
+                new_state.append({"k": kc, "v": vc})
+            elif t == LayerType.TRANSFORMER_FFN:
+                x = impl.forward(params[i], c, x)
+                new_state.append({})
+            else:  # EMBEDDING
+                new_state.append({})
     b = prompt.shape[0]
-    last = x[jnp.arange(b), length - 1]
-    out_conf = conf.conf(len(types) - 1)
-    probs = OutputLayer.forward(params[len(types) - 1], out_conf, last)
+    with layer_scope(conf.n_layers - 1, conf.conf(conf.n_layers - 1)):
+        last = x[jnp.arange(b), length - 1]
     new_state.append({})
-    return jnp.log(jnp.clip(probs, 1e-9, 1.0)), tuple(new_state)
+    return _head_logp(conf, params, last), tuple(new_state)

@@ -20,6 +20,7 @@ from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.nn.layers.base import compute_dtype, mixed_matmul
 from deeplearning4j_tpu.nd.attention import (blockwise_attention,
                                              full_attention)
+from deeplearning4j_tpu.utils.profiling import scope
 
 
 def _dtype(conf):
@@ -56,18 +57,15 @@ class MultiHeadAttentionLayer:
         h = conf.n_heads
         hd = n // h
         cd = compute_dtype(conf)
-        xn = _layer_norm(x, params["ln_g"], params["ln_b"])
         # projections AND the S^2 score/value matmuls run in compute_dtype
         # (bf16 feeds the MXU at full rate; f32 runs at half peak) — the
         # residual stream and layer norm stay in the param dtype
-        qkv = mixed_matmul(xn, params["Wqkv"], conf) + params["bqkv"]
-        q, k, v = jnp.split(qkv.astype(cd), 3, axis=-1)
+        q, k, v = _qkv(params, conf, x, cd)
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, h, hd)
         v = v.reshape(b, s, h, hd)
         o = MultiHeadAttentionLayer._attend(conf, q, k, v)
-        o = mixed_matmul(o.reshape(b, s, n).astype(x.dtype),
-                         params["Wo"], conf) + params["bo"]
+        o = _proj(params, conf, o.reshape(b, s, n).astype(x.dtype))
         if training and conf.dropout > 0.0 and key is not None:
             o = o * ndr.dropout_mask(key, 1.0 - conf.dropout, o.shape, o.dtype)
         return x + o
@@ -122,12 +120,15 @@ class MultiHeadAttentionLayer:
             bq, bk = (blk, blk) if blk else pick_attention_blocks(s, hd)
             # pinned conf block pins the bwd tiles too; 0 -> bwd-aware
             # autotune inside flash_attention
-            o = flash_attention(q, k, v, conf.causal, bq, bk,
-                                block_skip=skip, fused_bwd=fused_bwd,
-                                block_q_bwd=blk, block_k_bwd=blk)
+            # one kernel computes scores and output: both under `attend`
+            with scope("attend"):
+                o = flash_attention(q, k, v, conf.causal, bq, bk,
+                                    block_skip=skip, fused_bwd=fused_bwd,
+                                    block_q_bwd=blk, block_k_bwd=blk)
         elif impl == "blockwise":
-            o = blockwise_attention(q, k, v, block_size=blk or 512,
-                                    causal=conf.causal)
+            with scope("attend"):
+                o = blockwise_attention(q, k, v, block_size=blk or 512,
+                                        causal=conf.causal)
         else:
             o = full_attention(q, k, v, causal=conf.causal)
         return o
@@ -149,19 +150,17 @@ class MultiHeadAttentionLayer:
         h = conf.n_heads
         hd = n // h
         cd = compute_dtype(conf)
-        xn = _layer_norm(x, params["ln_g"], params["ln_b"])
-        qkv = mixed_matmul(xn, params["Wqkv"], conf) + params["bqkv"]
-        q, k, v = jnp.split(qkv.astype(cd), 3, axis=-1)
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype), (0, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype), (0, 0, 0))
+        q, k, v = _qkv(params, conf, x, cd)
+        with scope("kv_write"):
+            k_cache = jax.lax.dynamic_update_slice(
+                k_cache, k.astype(k_cache.dtype), (0, 0, 0))
+            v_cache = jax.lax.dynamic_update_slice(
+                v_cache, v.astype(v_cache.dtype), (0, 0, 0))
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, h, hd)
         v = v.reshape(b, s, h, hd)
         o = MultiHeadAttentionLayer._attend(conf, q, k, v)
-        o = mixed_matmul(o.reshape(b, s, n).astype(x.dtype),
-                         params["Wo"], conf) + params["bo"]
+        o = _proj(params, conf, o.reshape(b, s, n).astype(x.dtype))
         return x + o, k_cache, v_cache
 
     @staticmethod
@@ -180,24 +179,26 @@ class MultiHeadAttentionLayer:
         h = conf.n_heads
         hd = n // h
         cd = compute_dtype(conf)
-        xn = _layer_norm(x, params["ln_g"], params["ln_b"])
-        qkv = mixed_matmul(xn, params["Wqkv"], conf) + params["bqkv"]
-        q, k, v = jnp.split(qkv.astype(cd), 3, axis=-1)
-        rows = jnp.arange(b)
-        k_cache = k_cache.at[rows, pos].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[rows, pos].set(v.astype(v_cache.dtype))
+        q, k, v = _qkv(params, conf, x, cd)
+        with scope("kv_write"):
+            rows = jnp.arange(b)
+            k_cache = k_cache.at[rows, pos].set(k.astype(k_cache.dtype))
+            v_cache = v_cache.at[rows, pos].set(v.astype(v_cache.dtype))
         max_s = k_cache.shape[1]
-        qh = q.reshape(b, h, hd)
-        kh = k_cache.astype(cd).reshape(b, max_s, h, hd)
-        vh = v_cache.astype(cd).reshape(b, max_s, h, hd)
-        s = jnp.einsum("bhd,bkhd->bhk", qh, kh) / jnp.sqrt(
-            jnp.asarray(hd, qh.dtype))
-        kpos = jnp.arange(max_s)[None, :]
-        mask = jnp.where(kpos <= pos[:, None], 0.0, -1e30).astype(s.dtype)
-        p = jax.nn.softmax(s + mask[:, None, :], axis=-1)
-        o = jnp.einsum("bhk,bkhd->bhd", p, vh)
-        o = mixed_matmul(o.reshape(b, n).astype(x.dtype),
-                         params["Wo"], conf) + params["bo"]
+        with scope("kv_read"):
+            qh = q.reshape(b, h, hd)
+            kh = k_cache.astype(cd).reshape(b, max_s, h, hd)
+            vh = v_cache.astype(cd).reshape(b, max_s, h, hd)
+        with scope("scores"):
+            s = jnp.einsum("bhd,bkhd->bhk", qh, kh) / jnp.sqrt(
+                jnp.asarray(hd, qh.dtype))
+            kpos = jnp.arange(max_s)[None, :]
+            mask = jnp.where(kpos <= pos[:, None], 0.0,
+                             -1e30).astype(s.dtype)
+            p = jax.nn.softmax(s + mask[:, None, :], axis=-1)
+        with scope("attend"):
+            o = jnp.einsum("bhk,bkhd->bhd", p, vh)
+        o = _proj(params, conf, o.reshape(b, n).astype(x.dtype))
         return x + o, k_cache, v_cache
 
     @staticmethod
@@ -219,27 +220,29 @@ class MultiHeadAttentionLayer:
         hd = n // h
         ps = k_pool.shape[1]
         cd = compute_dtype(conf)
-        xn = _layer_norm(x, params["ln_g"], params["ln_b"])
-        qkv = mixed_matmul(xn, params["Wqkv"], conf) + params["bqkv"]
-        q, k, v = jnp.split(qkv.astype(cd), 3, axis=-1)
-        rows = jnp.arange(b)
-        phys = page_table[rows, pos // ps]
-        off = pos % ps
-        k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
+        q, k, v = _qkv(params, conf, x, cd)
+        with scope("kv_write"):
+            rows = jnp.arange(b)
+            phys = page_table[rows, pos // ps]
+            off = pos % ps
+            k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
+            v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
         pp = page_table.shape[1]
         ctx = pp * ps
-        qh = q.reshape(b, h, hd)
-        kh = k_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
-        vh = v_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
-        s = jnp.einsum("bhd,bkhd->bhk", qh, kh) / jnp.sqrt(
-            jnp.asarray(hd, qh.dtype))
-        kpos = jnp.arange(ctx)[None, :]
-        mask = jnp.where(kpos <= pos[:, None], 0.0, -1e30).astype(s.dtype)
-        p = jax.nn.softmax(s + mask[:, None, :], axis=-1)
-        o = jnp.einsum("bhk,bkhd->bhd", p, vh)
-        o = mixed_matmul(o.reshape(b, n).astype(x.dtype),
-                         params["Wo"], conf) + params["bo"]
+        with scope("kv_read"):
+            qh = q.reshape(b, h, hd)
+            kh = k_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
+            vh = v_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
+        with scope("scores"):
+            s = jnp.einsum("bhd,bkhd->bhk", qh, kh) / jnp.sqrt(
+                jnp.asarray(hd, qh.dtype))
+            kpos = jnp.arange(ctx)[None, :]
+            mask = jnp.where(kpos <= pos[:, None], 0.0,
+                             -1e30).astype(s.dtype)
+            p = jax.nn.softmax(s + mask[:, None, :], axis=-1)
+        with scope("attend"):
+            o = jnp.einsum("bhk,bkhd->bhd", p, vh)
+        o = _proj(params, conf, o.reshape(b, n).astype(x.dtype))
         return x + o, k_pool, v_pool
 
     @staticmethod
@@ -258,25 +261,27 @@ class MultiHeadAttentionLayer:
         h = conf.n_heads
         hd = n // h
         cd = compute_dtype(conf)
-        xn = _layer_norm(x, params["ln_g"], params["ln_b"])
-        qkv = mixed_matmul(xn, params["Wqkv"], conf) + params["bqkv"]
-        q, k, v = jnp.split(qkv.astype(cd), 3, axis=-1)
-        rows = jnp.arange(b)[:, None]
-        idx = pos[:, None] + jnp.arange(kk)[None, :]
-        k_cache = k_cache.at[rows, idx].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[rows, idx].set(v.astype(v_cache.dtype))
+        q, k, v = _qkv(params, conf, x, cd)
+        with scope("kv_write"):
+            rows = jnp.arange(b)[:, None]
+            idx = pos[:, None] + jnp.arange(kk)[None, :]
+            k_cache = k_cache.at[rows, idx].set(k.astype(k_cache.dtype))
+            v_cache = v_cache.at[rows, idx].set(v.astype(v_cache.dtype))
         max_s = k_cache.shape[1]
-        qh = q.reshape(b, kk, h, hd)
-        kh = k_cache.astype(cd).reshape(b, max_s, h, hd)
-        vh = v_cache.astype(cd).reshape(b, max_s, h, hd)
-        s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / jnp.sqrt(
-            jnp.asarray(hd, qh.dtype))
-        kpos = jnp.arange(max_s)[None, None, :]
-        mask = jnp.where(kpos <= idx[:, :, None], 0.0, -1e30).astype(s.dtype)
-        p = jax.nn.softmax(s + mask[:, None, :, :], axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p, vh)
-        o = mixed_matmul(o.reshape(b, kk, n).astype(x.dtype),
-                         params["Wo"], conf) + params["bo"]
+        with scope("kv_read"):
+            qh = q.reshape(b, kk, h, hd)
+            kh = k_cache.astype(cd).reshape(b, max_s, h, hd)
+            vh = v_cache.astype(cd).reshape(b, max_s, h, hd)
+        with scope("scores"):
+            s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / jnp.sqrt(
+                jnp.asarray(hd, qh.dtype))
+            kpos = jnp.arange(max_s)[None, None, :]
+            mask = jnp.where(kpos <= idx[:, :, None], 0.0,
+                             -1e30).astype(s.dtype)
+            p = jax.nn.softmax(s + mask[:, None, :, :], axis=-1)
+        with scope("attend"):
+            o = jnp.einsum("bhqk,bkhd->bqhd", p, vh)
+        o = _proj(params, conf, o.reshape(b, kk, n).astype(x.dtype))
         return x + o, k_cache, v_cache
 
     @staticmethod
@@ -289,35 +294,52 @@ class MultiHeadAttentionLayer:
         hd = n // h
         ps = k_pool.shape[1]
         cd = compute_dtype(conf)
-        xn = _layer_norm(x, params["ln_g"], params["ln_b"])
-        qkv = mixed_matmul(xn, params["Wqkv"], conf) + params["bqkv"]
-        q, k, v = jnp.split(qkv.astype(cd), 3, axis=-1)
-        rows = jnp.arange(b)[:, None]
-        idx = pos[:, None] + jnp.arange(kk)[None, :]
-        phys = page_table[rows, idx // ps]
-        off = idx % ps
-        k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
+        q, k, v = _qkv(params, conf, x, cd)
+        with scope("kv_write"):
+            rows = jnp.arange(b)[:, None]
+            idx = pos[:, None] + jnp.arange(kk)[None, :]
+            phys = page_table[rows, idx // ps]
+            off = idx % ps
+            k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
+            v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
         pp = page_table.shape[1]
         ctx = pp * ps
-        qh = q.reshape(b, kk, h, hd)
-        kh = k_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
-        vh = v_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
-        s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / jnp.sqrt(
-            jnp.asarray(hd, qh.dtype))
-        kpos = jnp.arange(ctx)[None, None, :]
-        mask = jnp.where(kpos <= idx[:, :, None], 0.0, -1e30).astype(s.dtype)
-        p = jax.nn.softmax(s + mask[:, None, :, :], axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p, vh)
-        o = mixed_matmul(o.reshape(b, kk, n).astype(x.dtype),
-                         params["Wo"], conf) + params["bo"]
+        with scope("kv_read"):
+            qh = q.reshape(b, kk, h, hd)
+            kh = k_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
+            vh = v_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
+        with scope("scores"):
+            s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / jnp.sqrt(
+                jnp.asarray(hd, qh.dtype))
+            kpos = jnp.arange(ctx)[None, None, :]
+            mask = jnp.where(kpos <= idx[:, :, None], 0.0,
+                             -1e30).astype(s.dtype)
+            p = jax.nn.softmax(s + mask[:, None, :, :], axis=-1)
+        with scope("attend"):
+            o = jnp.einsum("bhqk,bkhd->bqhd", p, vh)
+        o = _proj(params, conf, o.reshape(b, kk, n).astype(x.dtype))
         return x + o, k_pool, v_pool
 
 
 def _layer_norm(x, g, b, eps: float = 1e-5):
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.var(x, axis=-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + eps) * g + b
+    with scope("ln"):
+        mu = jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.var(x, axis=-1, keepdims=True)
+        return (x - mu) * jax.lax.rsqrt(var + eps) * g + b
+
+
+def _qkv(params, conf, x, cd):
+    """Pre-LN, then the fused Q/K/V projection in `cd` (scopes `ln`, `qkv`)."""
+    xn = _layer_norm(x, params["ln_g"], params["ln_b"])
+    with scope("qkv"):
+        qkv = mixed_matmul(xn, params["Wqkv"], conf) + params["bqkv"]
+        return jnp.split(qkv.astype(cd), 3, axis=-1)
+
+
+def _proj(params, conf, o):
+    """The output projection of the attended rows (scope `proj`)."""
+    with scope("proj"):
+        return mixed_matmul(o, params["Wo"], conf) + params["bo"]
 
 
 class TransformerFFNLayer:
@@ -351,8 +373,10 @@ class TransformerFFNLayer:
     @staticmethod
     def forward(params, conf, x, key=None, training=False):
         xn = _layer_norm(x, params["ln_g"], params["ln_b"])
-        h = jax.nn.gelu(mixed_matmul(xn, params["W1"], conf) + params["b1"])
-        o = mixed_matmul(h, params["W2"], conf) + params["b2"]
+        with scope("ffn"):
+            h = jax.nn.gelu(
+                mixed_matmul(xn, params["W1"], conf) + params["b1"])
+            o = mixed_matmul(h, params["W2"], conf) + params["b2"]
         if training and conf.dropout > 0.0 and key is not None:
             o = o * ndr.dropout_mask(key, 1.0 - conf.dropout, o.shape, o.dtype)
         return x + o

@@ -14,11 +14,17 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nd import losses as L
 from deeplearning4j_tpu.nd.ops import activate
 from deeplearning4j_tpu.nn.layers.base import DenseLayer
+from deeplearning4j_tpu.utils.profiling import scope
 
 
 class OutputLayer(DenseLayer):
     @staticmethod
     def forward(params, conf, x, key=None, training=False):
+        with scope("head"):
+            return OutputLayer._head(params, conf, x, key, training)
+
+    @staticmethod
+    def _head(params, conf, x, key, training):
         # input dropout / dropconnect apply here exactly as in DenseLayer
         # (the reference's OutputLayer inherits BaseLayer's dropout path)
         kdrop = kdc = None
@@ -45,11 +51,13 @@ class OutputLayer(DenseLayer):
     @staticmethod
     def loss(params, conf, x, labels, key=None, training=False):
         out = OutputLayer.forward(params, conf, x, key, training)
-        l2n = jnp.sum(params["W"].astype(jnp.float32) ** 2)
-        l2 = conf.l2 if conf.use_regularization else 0.0
-        s = L.score(labels, conf.loss_function, out, l2, l2n)
-        if conf.use_regularization and conf.l1:
-            s = s + conf.l1 * jnp.sum(jnp.abs(params["W"].astype(jnp.float32)))
+        with scope("loss"):
+            l2n = jnp.sum(params["W"].astype(jnp.float32) ** 2)
+            l2 = conf.l2 if conf.use_regularization else 0.0
+            s = L.score(labels, conf.loss_function, out, l2, l2n)
+            if conf.use_regularization and conf.l1:
+                s = s + conf.l1 * jnp.sum(
+                    jnp.abs(params["W"].astype(jnp.float32)))
         return s
 
     @staticmethod
@@ -72,4 +80,5 @@ class OutputLayer(DenseLayer):
         owns those — they must be counted once per step, not per example).
         Backs sample-weighted / pad-masked training on remainder batches."""
         out = OutputLayer.forward(params, conf, x, key, training)
-        return L.get_rowwise(conf.loss_function)(labels, out)
+        with scope("loss"):
+            return L.get_rowwise(conf.loss_function)(labels, out)

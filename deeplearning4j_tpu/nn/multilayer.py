@@ -40,6 +40,7 @@ from deeplearning4j_tpu.optimize.infer_cache import InferCache
 from deeplearning4j_tpu.optimize.listeners import dispatch as dispatch_listeners
 from deeplearning4j_tpu.optimize.step_cache import TrainStepCache
 from deeplearning4j_tpu.reliability import TrainingInterrupted
+from deeplearning4j_tpu.utils import profiling
 
 log = logging.getLogger("deeplearning4j_tpu")
 
@@ -56,8 +57,10 @@ def init_params(conf: MultiLayerConfiguration, key) -> tuple:
     )
 
 
-def _layer_forward(impl, c, params, h, key, training):
-    """One layer's forward, optionally under jax.checkpoint (conf.remat):
+def _layer_forward(impl, c, params, h, key, training, index):
+    """One layer's forward under its scope `L<index>.<layer_type>` (so every
+    device operation of the layer, forward and backward, says whose it is),
+    optionally under jax.checkpoint (conf.remat):
     activations inside the layer are recomputed during backward instead of
     stored, trading ~1/3 extra FLOPs for HBM capacity — the standard TPU
     trick for fitting larger batches (SURVEY §7 / scaling-book recipe).
@@ -69,13 +72,14 @@ def _layer_forward(impl, c, params, h, key, training):
     order differs from plain trace-through autodiff by float noise.  One
     shared structure means flipping conf.remat changes memory, never a
     single grad bit."""
-    if training:
-        policy = (None if c.remat
-                  else jax.checkpoint_policies.everything_saveable)
-        return jax.checkpoint(
-            lambda p, hh, kk: impl.forward(p, c, hh, kk, training),
-            policy=policy)(params, h, key)
-    return impl.forward(params, c, h, key, training)
+    with profiling.layer_scope(index, c):
+        if training:
+            policy = (None if c.remat
+                      else jax.checkpoint_policies.everything_saveable)
+            return jax.checkpoint(
+                lambda p, hh, kk: impl.forward(p, c, hh, kk, training),
+                policy=policy)(params, h, key)
+        return impl.forward(params, c, h, key, training)
 
 
 def feed_forward(conf: MultiLayerConfiguration, params, x, key=None,
@@ -93,7 +97,7 @@ def feed_forward(conf: MultiLayerConfiguration, params, x, key=None,
         c = conf.conf(i)
         x = apply_preprocessor(conf.preprocessor(i), x)
         x = _layer_forward(get_layer(c.layer_type), c, params[i], x,
-                           keys[i], training)
+                           keys[i], training, i)
         acts.append(x)
     return acts
 
@@ -115,11 +119,12 @@ def network_loss(conf: MultiLayerConfiguration, params, x, labels, key=None,
         c = conf.conf(i)
         h = apply_preprocessor(conf.preprocessor(i), h)
         h = _layer_forward(get_layer(c.layer_type), c, params[i], h,
-                           keys[i], training)
+                           keys[i], training, i)
     out_conf = conf.conf(n - 1)
     h = apply_preprocessor(conf.preprocessor(n - 1), h)
-    loss = OutputLayer.loss(params[n - 1], out_conf, h, labels, keys[n - 1],
-                            training)
+    with profiling.layer_scope(n - 1, out_conf):
+        loss = OutputLayer.loss(params[n - 1], out_conf, h, labels,
+                                keys[n - 1], training)
     if out_conf.use_regularization and out_conf.l2:
         for i in range(n - 1):
             if "W" in params[i]:
@@ -158,19 +163,21 @@ def network_rowwise_loss(conf: MultiLayerConfiguration, params, x, labels,
         is_bn = LayerType(str(c.layer_type)) == LayerType.BATCH_NORM
         if is_bn and training and (row_weights is not None
                                    or return_bn_stats):
-            s1, s2, cnt = BatchNormLayer.moments(h, row_weights)
-            if return_bn_stats:
-                stats.append((s1, s2, cnt))
-            mean, var = BatchNormLayer.stats_of(s1, s2, cnt)
-            h = BatchNormLayer.apply_stats(params[i], h,
-                                           mean.astype(h.dtype),
-                                           var.astype(h.dtype))
+            with profiling.layer_scope(i, c):
+                s1, s2, cnt = BatchNormLayer.moments(h, row_weights)
+                if return_bn_stats:
+                    stats.append((s1, s2, cnt))
+                mean, var = BatchNormLayer.stats_of(s1, s2, cnt)
+                h = BatchNormLayer.apply_stats(params[i], h,
+                                               mean.astype(h.dtype),
+                                               var.astype(h.dtype))
         else:
-            h = _layer_forward(impl, c, params[i], h, keys[i], training)
+            h = _layer_forward(impl, c, params[i], h, keys[i], training, i)
     out_conf = conf.conf(n - 1)
     h = apply_preprocessor(conf.preprocessor(n - 1), h)
-    rows = OutputLayer.rowwise_loss(params[n - 1], out_conf, h, labels,
-                                    keys[n - 1], training)
+    with profiling.layer_scope(n - 1, out_conf):
+        rows = OutputLayer.rowwise_loss(params[n - 1], out_conf, h, labels,
+                                        keys[n - 1], training)
     if return_bn_stats:
         return rows, tuple(stats)
     return rows

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.optimize.step_cache import (CompiledProgramCache,
                                                     arg_signature,
                                                     conf_fingerprint)
+from deeplearning4j_tpu.utils import profiling
 
 
 def pad_rows(x, bucket: int):
@@ -717,13 +718,14 @@ def _sample_tokens(logp, keys, temps):
     split(key)`), rows with temperature <= 0 take argmax, the rest draw
     `categorical(sub, logp / temperature)`.  Returns (tok [B] int32,
     advanced keys [B, 2])."""
-    ks = jax.vmap(jax.random.split)(keys)          # [B, 2, 2]
-    new_keys, subs = ks[:, 0], ks[:, 1]
-    greedy = jnp.argmax(logp, axis=-1).astype(jnp.int32)
-    safe = jnp.where(temps > 0, temps, jnp.ones_like(temps))
-    sampled = jax.vmap(jax.random.categorical)(
-        subs, logp / safe[:, None]).astype(jnp.int32)
-    return jnp.where(temps > 0, sampled, greedy), new_keys
+    with profiling.scope("sample"):
+        ks = jax.vmap(jax.random.split)(keys)          # [B, 2, 2]
+        new_keys, subs = ks[:, 0], ks[:, 1]
+        greedy = jnp.argmax(logp, axis=-1).astype(jnp.int32)
+        safe = jnp.where(temps > 0, temps, jnp.ones_like(temps))
+        sampled = jax.vmap(jax.random.categorical)(
+            subs, logp / safe[:, None]).astype(jnp.int32)
+        return jnp.where(temps > 0, sampled, greedy), new_keys
 
 
 def _sample_chain(logp, keys, temps):

@@ -63,6 +63,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nd.platform import default_backend
 from deeplearning4j_tpu.optimize import solver as solver_mod
 from deeplearning4j_tpu.reliability import faults
+from deeplearning4j_tpu.utils import profiling
 
 log = logging.getLogger("deeplearning4j_tpu")
 
@@ -367,7 +368,9 @@ class CompiledProgramCache:
         # outside the try: what the compiler refuses is raised once, as
         # itself, not relabelled as an export problem and compiled twice
         program = build() if exported is None else exported.call
-        fn = jax.jit(program,
+        # the name is the trace's, made from the entry kind; it joins no
+        # key, and a disk hit gives the restored program the same one
+        fn = jax.jit(profiling.named(program, key[0]),
                      donate_argnums=donate).lower(*abstract).compile()
         dt = time.perf_counter() - t0
         self.stats.compile_seconds[key] = dt
@@ -400,7 +403,7 @@ class CompiledProgramCache:
         if exported is None:
             return None
         try:
-            fn = jax.jit(exported.call,
+            fn = jax.jit(profiling.named(exported.call, key[0]),
                          donate_argnums=donate).lower(*abstract).compile()
         except Exception as e:  # noqa: BLE001 — treat as corrupt: evict
             log.warning("%s: persisted entry for %s failed to compile "

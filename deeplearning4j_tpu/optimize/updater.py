@@ -19,6 +19,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.utils.profiling import scope
+
 
 class UpdaterState(NamedTuple):
     adagrad_hist: object   # pytree like params
@@ -165,6 +167,11 @@ def adjust_gradient(conf, iteration, grads, params, state: UpdaterState,
     elements rather than guaranteeing zero — see `adjust_gradient_auto`
     for how the parity claims are scoped per train path.
     """
+    with scope("updater"):
+        return _chain(conf, iteration, grads, params, state, _norm_fn)
+
+
+def _chain(conf, iteration, grads, params, state: UpdaterState, _norm_fn):
     eps = 1e-8
     lr = conf.lr
     which = (getattr(conf, "updater", "") or "").lower()
@@ -293,12 +300,14 @@ def adjust_gradient_auto(conf, iteration, grads, params,
     if not getattr(conf, "fused_updater", False):
         return adjust_gradient(conf, iteration, grads, params, state)
     spec = make_flat_spec(params)
-    fstate = UpdaterState(
-        adagrad_hist=flat_ravel(spec, state.adagrad_hist),
-        velocity=flat_ravel(spec, state.velocity))
-    adj, new = adjust_gradient_flat(conf, iteration,
-                                    flat_ravel(spec, grads),
-                                    flat_ravel(spec, params), fstate, spec)
-    return (flat_unravel(spec, adj),
-            UpdaterState(adagrad_hist=flat_unravel(spec, new.adagrad_hist),
-                         velocity=flat_unravel(spec, new.velocity)))
+    with scope("updater"):      # the ravel and the unravel are the chain's
+        fstate = UpdaterState(
+            adagrad_hist=flat_ravel(spec, state.adagrad_hist),
+            velocity=flat_ravel(spec, state.velocity))
+        adj, new = _chain(conf, iteration, flat_ravel(spec, grads),
+                          flat_ravel(spec, params), fstate,
+                          lambda t: flat_norm(spec, t))
+        return (flat_unravel(spec, adj),
+                UpdaterState(
+                    adagrad_hist=flat_unravel(spec, new.adagrad_hist),
+                    velocity=flat_unravel(spec, new.velocity)))

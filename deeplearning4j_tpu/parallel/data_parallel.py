@@ -42,6 +42,7 @@ from deeplearning4j_tpu.optimize.updater import (UpdaterState, adjust_gradient,
 from deeplearning4j_tpu.parallel.mesh import shard_batch
 from deeplearning4j_tpu.parallel.sequence import _as_varying, _shard_map
 from deeplearning4j_tpu.reliability import TrainingInterrupted, faults
+from deeplearning4j_tpu.utils import profiling
 
 import logging
 
@@ -69,6 +70,19 @@ def init_train_state(net: MultiLayerNetwork) -> TrainState:
     params = jax.tree_util.tree_map(jnp.copy, net.params)
     return TrainState(params=params, updater=init_updater(params),
                       step=jnp.asarray(0, jnp.int32))
+
+
+def _jit_step(fn, entry: str):
+    """`jax.jit` of a train step (state donated) under its name in the
+    trace, `dl4j_<entry>`: the name joins no key of `track_jit`."""
+    return jax.jit(profiling.named(fn, entry), donate_argnums=(0,))
+
+
+def _apply_step(params, adj):
+    """params - step, leaf by leaf, in the updater's scope."""
+    with profiling.scope("updater"):
+        return jax.tree_util.tree_map(
+            lambda p, a: p - a.astype(p.dtype), params, adj)
 
 
 def _feature_row_weights(w, x):
@@ -140,12 +154,13 @@ def make_dp_train_step(conf: MultiLayerConfiguration, mesh: Mesh,
                                        row_weights=wx,
                                        return_bn_stats=collect_bn)
             rows, stats = out if collect_bn else (out, ())
-            if w is None:
-                loss = jnp.mean(rows) + network_regularization(conf, p)
-            else:
-                # regularization / n_shards: the psum below re-sums it
-                loss = (jnp.sum(rows * w) / den
-                        + network_regularization(conf, p) / n_shards)
+            with profiling.scope("loss"):
+                if w is None:
+                    loss = jnp.mean(rows) + network_regularization(conf, p)
+                else:
+                    # regularization / n_shards: the psum below re-sums it
+                    loss = (jnp.sum(rows * w) / den
+                            + network_regularization(conf, p) / n_shards)
             return loss, stats
 
         if grad_accum > 1:
@@ -189,12 +204,12 @@ def make_dp_train_step(conf: MultiLayerConfiguration, mesh: Mesh,
                 loss_fn, has_aux=True)(var_params, key)
         # the all-reduce: what Hazelcast/Spark moved as whole param vectors
         reduce = jax.lax.pmean if w is None else jax.lax.psum
-        grads = reduce(grads, axis)
-        score = reduce(score, axis)
+        with profiling.scope("allreduce"):
+            grads = reduce(grads, axis)
+            score = reduce(score, axis)
         adj, upd = adjust_gradient_auto(out_conf, state.step, grads,
                                         state.params, state.updater)
-        params = jax.tree_util.tree_map(
-            lambda p, a: p - a.astype(p.dtype), state.params, adj)
+        params = _apply_step(state.params, adj)
         if collect_bn:
             # running inference stats from GLOBAL-batch statistics, reusing
             # the moments the loss forward already computed (no 2nd pass)
@@ -209,7 +224,9 @@ def make_dp_train_step(conf: MultiLayerConfiguration, mesh: Mesh,
             return local_step(state, x, y, None, key)
         in_specs = (rep, P(axis), P(axis), rep)
     sharded = _shard_map(fn, mesh, in_specs, (rep, rep))
-    jitted = jax.jit(sharded, donate_argnums=(0,))
+    name = "train_step" + ("_masked" if masked else "") + (
+        f"_accum{grad_accum}" if grad_accum > 1 else "")
+    jitted = _jit_step(sharded, name)
     if cache is not None:
         return cache.track_jit(
             ("dp_step", axis, masked, grad_accum), jitted)
@@ -242,13 +259,12 @@ def make_sharded_train_step(conf: MultiLayerConfiguration, mesh: Mesh,
             loss_fn, has_aux=True)(state.params, key)
         adj, upd = adjust_gradient_auto(out_conf, state.step, grads,
                                         state.params, state.updater)
-        params = jax.tree_util.tree_map(
-            lambda p, a: p - a.astype(p.dtype), state.params, adj)
+        params = _apply_step(state.params, adj)
         if collect_bn:
             params = update_bn_ema_from_stats(conf, params, stats)
         return TrainState(params, upd, state.step + 1), score
 
-    jitted = jax.jit(step_fn, donate_argnums=(0,))
+    jitted = _jit_step(step_fn, "sharded_step")
     if cache is not None:
         return cache.track_jit(("sharded_step",), jitted)
     return jitted
@@ -324,15 +340,15 @@ def make_zero1_train_step(conf: MultiLayerConfiguration, mesh: Mesh,
                 g, NamedSharding(mesh, s)), grads, gspecs)
         adj, upd = adjust_gradient(out_conf, state.step, grads,
                                    state.params, state.updater)
-        params = jax.tree_util.tree_map(
-            lambda p, a: p - a.astype(p.dtype), state.params, adj)
+        params = _apply_step(state.params, adj)
         # params come back replicated (all-gather of the sharded step)
         params = jax.tree_util.tree_map(
             lambda p: jax.lax.with_sharding_constraint(
                 p, NamedSharding(mesh, P())), params)
         return TrainState(params, upd, state.step + 1), score
 
-    jitted = jax.jit(step_fn, donate_argnums=(0,))
+    jitted = _jit_step(step_fn,
+                       "zero1_step" + ("_masked" if masked else ""))
     if cache is not None:
         return cache.track_jit(("zero1_step", axis, masked), jitted)
     return jitted
@@ -411,14 +427,14 @@ def make_plan_train_step(conf: MultiLayerConfiguration, plan,
         grads = pin(grads, gspec_fn(grads))
         adj, upd = adjust_gradient(out_conf, state.step, grads,
                                    params, state.updater)
-        new_params = jax.tree_util.tree_map(
-            lambda p, a: p - a.astype(p.dtype), params, adj)
+        new_params = _apply_step(params, adj)
         # params stay model-sharded across steps (never gathered); only
         # the zero1 batch-axis split of the step all-gathers back
         new_params = pin(new_params, plan.param_pspecs(new_params))
         return TrainState(new_params, upd, state.step + 1), score
 
-    jitted = jax.jit(step_fn, donate_argnums=(0,))
+    jitted = _jit_step(step_fn, "plan_step" + ("_masked" if masked else "")
+                       + ("_zero1" if zero1 else ""))
     if cache is not None:
         return cache.track_jit(
             ("plan_step", plan.sharding_tag(), masked, zero1), jitted)
@@ -578,7 +594,8 @@ def make_averaging_round(conf: MultiLayerConfiguration, mesh: Mesh,
             return round_fn(state, x, y, None, key)
         in_specs = (rep, P(axis), P(axis), rep)
     sharded = _shard_map(fn, mesh, in_specs, (rep, rep))
-    jitted = jax.jit(sharded, donate_argnums=(0,))
+    jitted = _jit_step(sharded,
+                       "averaging_round" + ("_masked" if masked else ""))
     if cache is not None:
         return cache.track_jit(
             ("dp_averaging", axis, masked, local_steps), jitted)
@@ -687,6 +704,7 @@ class DataParallelTrainer:
         self.resumed_from_step: Optional[int] = None
         self.checkpoint_write_seconds = 0.0
         self.checkpoints_written = 0
+        self._fit_calls = 0     # the rid of a `fit` call's spans
 
     def _next_key(self):
         self._key, sub = jax.random.split(self._key)
@@ -912,13 +930,25 @@ class DataParallelTrainer:
 
     def _fit_loop(self, data, epochs: int, checkpoint_dir: Optional[str],
                   every_n: int, start_batch: int) -> float:
+        """The loop's thread is tiled by its spans, all keyed by the number
+        of this `fit` call: `fit.next` (the iterator), `fit.step` (the step
+        call: placing the batch and dispatching the program, which blocks
+        once the device's queue is full) and `fit.sync` (each host read)."""
         score = float("nan")
         n_dp = self.mesh.shape[self.axis]
         n_done = 0
+        self._fit_calls += 1
+        rid = self._fit_calls
         for _ in range(epochs):
             if hasattr(data, "reset"):
                 data.reset()
-            for batch in data:
+            batches = iter(data)
+            while True:
+                with profiling.span("fit.next", rid=rid):
+                    try:
+                        batch = next(batches)
+                    except StopIteration:
+                        break
                 n_done += 1
                 if n_done <= start_batch:
                     # replaying the resumed prefix of the stream: the data
@@ -927,26 +957,28 @@ class DataParallelTrainer:
                     # RNG keys are consumed (the restored key already
                     # accounts for them)
                     continue
-                faults.fire("trainer.step", batch=n_done)
-                x, y = ((batch.features, batch.labels)
-                        if hasattr(batch, "features") else batch)
-                x, y = jnp.asarray(x), jnp.asarray(y)
-                if x.shape[0] % n_dp:
-                    # pad-and-mask: every real sample still contributes
-                    # exactly once (no silent remainder drop; zero1 and
-                    # plan modes route through their masked variants)
-                    self.state, s = self._step_padded(x, y)
-                else:
-                    x, y = shard_batch(self.mesh, (x, y), self.axis)
-                    self.state, s = self._step(self.state, x, y,
-                                               self._next_key())
+                with profiling.span("fit.step", rid=rid, step=n_done):
+                    faults.fire("trainer.step", batch=n_done)
+                    x, y = ((batch.features, batch.labels)
+                            if hasattr(batch, "features") else batch)
+                    x, y = jnp.asarray(x), jnp.asarray(y)
+                    if x.shape[0] % n_dp:
+                        # pad-and-mask: every real sample still contributes
+                        # exactly once (no silent remainder drop; zero1 and
+                        # plan modes route through their masked variants)
+                        self.state, s = self._step_padded(x, y)
+                    else:
+                        x, y = shard_batch(self.mesh, (x, y), self.axis)
+                        self.state, s = self._step(self.state, x, y,
+                                                   self._next_key())
                 score = s
                 if self.listeners:
                     # only a listener forces the host sync; otherwise steps
                     # stay async so dispatch pipelines ahead of the device
+                    with profiling.span("fit.sync", rid=rid):
+                        step, s = int(self.state.step), float(s)
                     for li in self.listeners:
-                        li.iteration_done(self, int(self.state.step),
-                                          float(s))
+                        li.iteration_done(self, step, s)
                 if checkpoint_dir is not None:
                     if self._stop_training.is_set():
                         self._save_checkpoint(checkpoint_dir, n_done)
@@ -974,4 +1006,5 @@ class DataParallelTrainer:
             self.net.params = jax.tree_util.tree_map(
                 lambda a: jax.device_put(a, self.mesh.devices.flat[0]),
                 self.state.params)
-        return float(score) if score is not None else float("nan")
+        with profiling.span("fit.sync", rid=rid):
+            return float(score) if score is not None else float("nan")

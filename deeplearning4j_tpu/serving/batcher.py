@@ -43,6 +43,7 @@ from __future__ import annotations
 import hashlib
 import heapq
 import io
+import itertools
 import queue
 import threading
 import time
@@ -53,6 +54,7 @@ import numpy as np
 
 from deeplearning4j_tpu.optimize import tunables
 from deeplearning4j_tpu.reliability import CircuitBreaker, DeadlineExceeded, faults
+from deeplearning4j_tpu.utils.profiling import span
 
 #: coalescing target when no row bucket is known yet and the caller set
 #: no `max_batch_rows` cap — now a registry default
@@ -645,6 +647,9 @@ class GenerationStream:
         #: preemption (the recompute re-derives the delivered prefix)
         self._replay = 0
         self._counted_admit = False
+        #: the batcher's number for this request (`submit` gives it):
+        #: every span of the stream's way through the loop carries it
+        self.rid: Optional[int] = None
         self.t_submit = time.monotonic()
         self.t_first: Optional[float] = None
         self.t_done: Optional[float] = None
@@ -887,6 +892,11 @@ class ContinuousBatcher:
         # minus the time spent blocked in device_get
         self._host_s = 0.0
         self._wall_s = 0.0
+        # summed from the closed `admit` spans (guarded by _cv's lock):
+        # what admissions held the loop for, and what their streams waited
+        self._rids = itertools.count(1)
+        self._admit_s = 0.0
+        self._queue_wait_s = 0.0
         self._blk_hist = {"counts": [0] * len(DECODE_BLOCK_STEPS_BOUNDS),
                             "inf": 0, "sum": 0.0, "count": 0}
 
@@ -953,6 +963,7 @@ class ContinuousBatcher:
                 raise ServerOverloaded(
                     f"{len(self._pending)} generation streams already "
                     f"pending (max_pending={self.max_pending})")
+            stream.rid = next(self._rids)
             self._pending.append(stream)
             self._cv.notify_all()
         if self._thread is None and self._auto_start:
@@ -986,11 +997,29 @@ class ContinuousBatcher:
         on the host from the cached logp with the stream's own key, or
         (longest match) the unmatched prompt suffix is queued to feed
         through the decode table.  Either way the token trajectory is
-        identical to a cold prefill."""
-        ic = self.net.infer_cache
-        faults.fire("generate.admit", slot=slot,
-                    prompt_tokens=int(stream.prompt.shape[0]))
+        identical to a cold prefill.
+
+        The whole of it is one `admit` span keyed by the stream's rid
+        (`queue_wait_ns`: from `submit` to here), its parts the children
+        `admit.init_row`, `admit.prefill`, `admit.scatter` and
+        `admit.deliver`; `admit_seconds_total` and
+        `queue_wait_seconds_total` add up the same spans."""
         n = int(stream.prompt.shape[0])
+        wait_ns = int((time.monotonic() - stream.t_submit) * 1e9)
+        sp = span("admit", rid=stream.rid, slot=slot, prompt_tokens=n,
+                  queue_wait_ns=wait_ns)
+        try:
+            with sp:
+                self._admit_spanned(slot, stream, n, sp)
+        finally:
+            with self._cv:
+                self._admit_s += sp.seconds
+                self._queue_wait_s += wait_ns / 1e9
+
+    def _admit_spanned(self, slot: int, stream: GenerationStream, n: int,
+                       sp: span) -> None:
+        ic = self.net.infer_cache
+        faults.fire("generate.admit", slot=slot, prompt_tokens=n)
         hit = (self._prefix_lookup(stream.prompt)
                if self.prefix_cache_enabled else None)
         m = n if hit is None else int(hit[0])
@@ -1002,33 +1031,45 @@ class ContinuousBatcher:
         tok0 = key1 = None
         if hit is None:
             bucket = self._prompt_bucket(n)
+            sp.set(bucket=bucket)
             prompt = np.zeros((1, bucket), np.int32)
             prompt[0, :n] = stream.prompt
             length = np.asarray([n], np.int32)
-            row = ic.init_decode_state(self.net.conf, 1, self.max_seq)
+            with span("admit.init_row"):
+                row = ic.init_decode_state(self.net.conf, 1, self.max_seq)
             if self.prefix_cache_enabled:
-                logp, row = ic.prefill_logp(self.net.conf, self.net.params,
-                                            row, prompt, length)
-                logp = np.asarray(logp[0], np.float32)
+                with span("admit.prefill"):     # to the program's host read
+                    logp, row = ic.prefill_logp(
+                        self.net.conf, self.net.params, row, prompt, length)
+                    logp = np.asarray(logp[0], np.float32)
                 self._prefix_store(stream.prompt, logp, row)
                 tok0, key1 = _host_sample(logp, stream.key,
                                           stream.temperature)
             else:
                 temps = np.asarray([stream.temperature], np.float32)
-                t0, keys1, row = ic.prefill(self.net.conf, self.net.params,
-                                            row, prompt, length,
-                                            stream.key[None], temps)
-                tok0, key1 = int(t0[0]), np.asarray(keys1[0])
+                with span("admit.prefill"):     # to the program's host read
+                    t0, keys1, row = ic.prefill(
+                        self.net.conf, self.net.params, row, prompt, length,
+                        stream.key[None], temps)
+                    tok0, key1 = int(t0[0]), np.asarray(keys1[0])
         else:
             row = hit[2]
             if hit[1] is not None:  # exact match: cached prefill logp
                 tok0, key1 = _host_sample(hit[1], stream.key,
                                           stream.temperature)
-        self._scatter_row(slot, row, pages)
+        with span("admit.scatter"):
+            self._scatter_row(slot, row, pages)
         if self.draft_net is not None:
             # the draft consumes exactly the m tokens the target row has
             # consumed, so feed rounds advance both in lockstep
             self._draft_admit(slot, stream.prompt[:m])
+        with span("admit.deliver"):
+            self._admit_deliver(slot, stream, n, m, tok0, key1)
+
+    def _admit_deliver(self, slot: int, stream: GenerationStream, n: int,
+                       m: int, tok0, key1) -> None:
+        """The host half of an admission: the slot's row of the table's
+        arguments, the first token to the caller, the counters."""
         self._slots[slot] = stream
         self._temps[slot] = stream.temperature
         self._ramp = 1  # slot set changed: fused blocks re-ramp from K=1
@@ -1351,6 +1392,14 @@ class ContinuousBatcher:
         emit per-slot tokens and free finished slots.  When speculative
         decoding is on and every active slot has room for a spec_k
         chunk, the step is a draft+verify round instead."""
+        with span("decode", k=1) as sp:
+            self._decode_spanned(sp)
+
+    def _decode_spanned(self, sp: span) -> None:
+        """`_decode_once` inside its `decode` span; the children are
+        `decode.dispatch` (argument copies and the program's call),
+        `decode.readback` (the one `device_get`) and `decode.deliver` (the
+        per-slot loop)."""
         import jax
 
         t0 = time.monotonic()
@@ -1363,6 +1412,7 @@ class ContinuousBatcher:
             except BaseException as e:  # noqa: BLE001 — isolate the stream
                 self._release_slot(slot, stream, error=e)
         active = [s for s, st in enumerate(self._slots) if st is not None]
+        sp.set(live=len(active))
         if not active:
             return
         if (self.spec_k
@@ -1372,60 +1422,62 @@ class ContinuousBatcher:
             self._spec_once()
             return
         ic = self.net.infer_cache
-        if self.paged:
-            self._lazy_alloc(1)
-            if not any(s is not None for s in self._slots):
-                return
-            tok2, keys2, self._state = ic.decode_paged(
-                self.net.conf, self.net.params, self._state,
-                self._tok.copy(), self._pos.copy(), self._keys.copy(),
-                self._temps.copy(), self._page_table.copy())
-        else:
-            tok2, keys2, self._state = ic.decode(
-                self.net.conf, self.net.params, self._state,
-                self._tok.copy(), self._pos.copy(), self._keys.copy(),
-                self._temps.copy())
-        if self.draft_net is not None:
-            # non-spec rounds (feeds pending, or a slot near the table
-            # edge) still advance the draft's carries over the same
-            # token, so the draft stays in lockstep with what each slot
-            # has consumed
-            dn = self.draft_net
-            _, _, self._draft_state = dn.infer_cache.decode(
-                dn.conf, dn.params, self._draft_state, self._tok.copy(),
-                self._pos.copy(), np.zeros((self.n_slots, 2), np.uint32),
-                np.zeros((self.n_slots,), np.float32))
+        with span("decode.dispatch"):
+            if self.paged:
+                self._lazy_alloc(1)
+                if not any(s is not None for s in self._slots):
+                    return
+                tok2, keys2, self._state = ic.decode_paged(
+                    self.net.conf, self.net.params, self._state,
+                    self._tok.copy(), self._pos.copy(), self._keys.copy(),
+                    self._temps.copy(), self._page_table.copy())
+            else:
+                tok2, keys2, self._state = ic.decode(
+                    self.net.conf, self.net.params, self._state,
+                    self._tok.copy(), self._pos.copy(), self._keys.copy(),
+                    self._temps.copy())
+            if self.draft_net is not None:
+                # non-spec rounds (feeds pending, or a slot near the table
+                # edge) still advance the draft's carries over the same
+                # token, so the draft stays in lockstep with what each
+                # slot has consumed
+                dn = self.draft_net
+                _, _, self._draft_state = dn.infer_cache.decode(
+                    dn.conf, dn.params, self._draft_state, self._tok.copy(),
+                    self._pos.copy(), np.zeros((self.n_slots, 2), np.uint32),
+                    np.zeros((self.n_slots,), np.float32))
         # ONE batched device->host transfer for the (tokens, keys) pair
         # instead of two blocking np.asarray round-trips (ISSUE 19)
-        t_get = time.monotonic()
-        tok2, keys2 = jax.device_get((tok2, keys2))
-        wait = time.monotonic() - t_get
+        with span("decode.readback") as readback:
+            tok2, keys2 = jax.device_get((tok2, keys2))
         now = time.monotonic()
         emitted = 0
-        for slot, stream in enumerate(self._slots):
-            if stream is None:
-                continue
-            if self._feed[slot]:
-                # prompt-feed step (longest-prefix admission): the
-                # table consumed one prompt token; the sampled output
-                # and advanced key are discarded so the stream's key
-                # stream stays identical to a cold prefill's
-                self._tok[slot] = self._feed[slot].pop(0)
+        with span("decode.deliver"):
+            for slot, stream in enumerate(self._slots):
+                if stream is None:
+                    continue
+                if self._feed[slot]:
+                    # prompt-feed step (longest-prefix admission): the
+                    # table consumed one prompt token; the sampled output
+                    # and advanced key are discarded so the stream's key
+                    # stream stays identical to a cold prefill's
+                    self._tok[slot] = self._feed[slot].pop(0)
+                    self._pos[slot] += 1
+                    continue
+                first = stream.tokens_emitted == 0
+                self._tok[slot] = tok2[slot]
                 self._pos[slot] += 1
-                continue
-            first = stream.tokens_emitted == 0
-            self._tok[slot] = tok2[slot]
-            self._pos[slot] += 1
-            self._keys[slot] = keys2[slot]
-            if stream._deliver(int(tok2[slot]), now):
-                emitted += 1
-                if first:
-                    with self._cv:
-                        self._record_ttft_locked(stream)
-            if (stream.tokens_emitted >= stream.max_new
-                    or int(self._pos[slot]) >= self.max_seq):
-                self._release_slot(slot, stream)
-        self._note_block(1, time.monotonic() - t0, wait, emitted, now)
+                self._keys[slot] = keys2[slot]
+                if stream._deliver(int(tok2[slot]), now):
+                    emitted += 1
+                    if first:
+                        with self._cv:
+                            self._record_ttft_locked(stream)
+                if (stream.tokens_emitted >= stream.max_new
+                        or int(self._pos[slot]) >= self.max_seq):
+                    self._release_slot(slot, stream)
+        self._note_block(1, time.monotonic() - t0, readback.seconds, emitted,
+                         now)
 
     def _note_block(self, k: int, wall: float, wait: float, emitted: int,
                     now: float) -> None:
@@ -1613,17 +1665,21 @@ class ContinuousBatcher:
         tok, keys = self._tok.copy(), self._keys.copy()
         inflight = None
         t_mark = time.monotonic()
-        while True:
-            blk = None
-            if int(rem.max(initial=0)) > 0 and not self._has_pending():
-                blk = self._dispatch_block(ic, streams, tok, keys, pos, rem)
-                if blk is not None:
-                    tok, keys = blk["tok"], blk["keys"]
-            if inflight is not None:
-                t_mark = self._readback_block(inflight, t_mark)
-            inflight = blk
-            if blk is None:
-                return
+        live = sum(1 for st in streams if st is not None)
+        # one `decode` span over the pipelined rounds
+        with span("decode", k=self.k_max, live=live):
+            while True:
+                blk = None
+                if int(rem.max(initial=0)) > 0 and not self._has_pending():
+                    blk = self._dispatch_block(ic, streams, tok, keys, pos,
+                                               rem)
+                    if blk is not None:
+                        tok, keys = blk["tok"], blk["keys"]
+                if inflight is not None:
+                    t_mark = self._readback_block(inflight, t_mark)
+                inflight = blk
+                if blk is None:
+                    return
 
     def _dispatch_block(self, ic, streams, tok, keys, pos, rem):
         """Dispatch ONE fused K-step block (no sync): fire the per-slot
@@ -1653,14 +1709,16 @@ class ContinuousBatcher:
                     rem[s] = 0  # preempted/failed during page growth
             if int(rem.max(initial=0)) <= 0:
                 return None
-            toks, tok2, keys2, self._state = ic.decode_multi_paged(
-                self.net.conf, self.net.params, self._state, tok,
-                pos.copy(), keys, self._temps.copy(), rem.copy(),
-                self._page_table.copy(), k)
+            with span("decode.dispatch"):
+                toks, tok2, keys2, self._state = ic.decode_multi_paged(
+                    self.net.conf, self.net.params, self._state, tok,
+                    pos.copy(), keys, self._temps.copy(), rem.copy(),
+                    self._page_table.copy(), k)
         else:
-            toks, tok2, keys2, self._state = ic.decode_multi(
-                self.net.conf, self.net.params, self._state, tok,
-                pos.copy(), keys, self._temps.copy(), rem.copy(), k)
+            with span("decode.dispatch"):
+                toks, tok2, keys2, self._state = ic.decode_multi(
+                    self.net.conf, self.net.params, self._state, tok,
+                    pos.copy(), keys, self._temps.copy(), rem.copy(), k)
         adv = np.minimum(rem, k).astype(np.int32)
         pos += adv
         rem -= adv
@@ -1675,38 +1733,42 @@ class ContinuousBatcher:
         host-overhead accounting.  Returns the new wall-clock mark."""
         import jax
 
-        t_get = time.monotonic()
-        toks, tok_last, keys_last = jax.device_get(
-            (blk["toks"], blk["tok"], blk["keys"]))
-        wait = time.monotonic() - t_get
+        with span("decode.readback") as readback:
+            toks, tok_last, keys_last = jax.device_get(
+                (blk["toks"], blk["tok"], blk["keys"]))
         now = time.monotonic()
         emitted = 0
-        for s, stream in enumerate(blk["streams"]):
-            if stream is None or int(blk["adv"][s]) <= 0:
-                continue
-            if self._slots[s] is not stream:
-                continue  # released or preempted since dispatch
-            first = stream.tokens_emitted == 0
-            sent_first = False
-            for j in range(int(blk["adv"][s])):
-                if stream._deliver(int(toks[j, s]), now):
-                    emitted += 1
-                    if first and not sent_first:
-                        sent_first = True
-            self._tok[s] = tok_last[s]
-            self._keys[s] = keys_last[s]
-            self._pos[s] = blk["pos_after"][s]
-            if sent_first:
-                with self._cv:
-                    self._record_ttft_locked(stream)
-            if (stream.tokens_emitted >= stream.max_new
-                    or int(self._pos[s]) >= self.max_seq):
-                self._release_slot(s, stream)
+        with span("decode.deliver"):
+            for s, stream in enumerate(blk["streams"]):
+                if stream is None or int(blk["adv"][s]) <= 0:
+                    continue
+                if self._slots[s] is not stream:
+                    continue  # released or preempted since dispatch
+                first = stream.tokens_emitted == 0
+                sent_first = False
+                for j in range(int(blk["adv"][s])):
+                    if stream._deliver(int(toks[j, s]), now):
+                        emitted += 1
+                        if first and not sent_first:
+                            sent_first = True
+                self._tok[s] = tok_last[s]
+                self._keys[s] = keys_last[s]
+                self._pos[s] = blk["pos_after"][s]
+                if sent_first:
+                    with self._cv:
+                        self._record_ttft_locked(stream)
+                if (stream.tokens_emitted >= stream.max_new
+                        or int(self._pos[s]) >= self.max_seq):
+                    self._release_slot(s, stream)
         t_end = time.monotonic()
-        self._note_block(blk["k"], t_end - t_mark, wait, emitted, now)
+        self._note_block(blk["k"], t_end - t_mark, readback.seconds, emitted,
+                         now)
         return t_end
 
     def _decode_loop(self) -> None:
+        """The loop's thread is tiled by three top-level spans: `admit`
+        (one a stream, in `_admit_pending`), `decode` (a table step or a
+        run of fused blocks) and `idle` (waiting for work)."""
         while True:
             self._admit_pending()
             if any(s is not None for s in self._slots):
@@ -1720,13 +1782,21 @@ class ContinuousBatcher:
                     continue
                 if self._stop:
                     return
-                self._cv.wait(timeout=0.5)
+                with span("idle"):
+                    self._cv.wait(timeout=0.5)
 
     # -- observability -------------------------------------------------------
     def stats(self) -> dict:
         """Generation counters for `/v1/stats`: slot occupancy, queue
         depth, tokens/sec over the trailing window, TTFT percentiles +
-        histogram, stream outcomes, and the fresh-compile count."""
+        histogram, stream outcomes, and the fresh-compile count.
+
+        Host time, by what covers what: `host_overhead_fraction` and
+        `decode_host_seconds_total` cover DECODE STEPS ONLY (each
+        dispatch-to-delivery less its blocked readback, `_note_block`);
+        an admission is in neither.  `admit_seconds_total` is the `admit`
+        spans' sum and `queue_wait_seconds_total` what those streams
+        waited from `submit` to their admission."""
         with self._cv:
             now = time.monotonic()
             recent = sum(c for t, c in self._recent_tokens
@@ -1767,6 +1837,8 @@ class ContinuousBatcher:
                     round(self._host_s / self._wall_s, 4)
                     if self._wall_s > 0 else 0.0),
                 "decode_host_seconds_total": round(self._host_s, 6),
+                "admit_seconds_total": round(self._admit_s, 6),
+                "queue_wait_seconds_total": round(self._queue_wait_s, 6),
                 "decode_block_steps": {
                     "bounds": list(DECODE_BLOCK_STEPS_BOUNDS),
                     "counts": list(bh["counts"]),

@@ -74,6 +74,8 @@ FAMILIES = {
     "dl4j_serving_accepted_tokens_per_step": ("histogram", ()),
     "dl4j_serving_decode_block_steps": ("histogram", ()),
     "dl4j_serving_decode_host_seconds_total": ("counter", ()),
+    "dl4j_serving_admit_seconds_total": ("counter", ()),
+    "dl4j_serving_queue_wait_seconds_total": ("counter", ()),
     "dl4j_router_ready": ("gauge", ()),
     "dl4j_router_inflight": ("gauge", ()),
     "dl4j_router_replicas_healthy": ("gauge", ()),
@@ -386,6 +388,16 @@ def replica_metrics(stats: dict, page: Optional[PrometheusText] = None,
                   "token delivery); with wall time this gives the "
                   "host-overhead fraction fused dispatch amortises.",
                   gen.get("decode_host_seconds_total", 0.0), lbl())
+        p.counter("dl4j_serving_admit_seconds_total",
+                  "Seconds the decode loop spent admitting streams "
+                  "(prefill, scatter into the slot table, first token): "
+                  "every live stream stalls for them, and the "
+                  "host-overhead fraction does not count them.",
+                  gen.get("admit_seconds_total", 0.0), lbl())
+        p.counter("dl4j_serving_queue_wait_seconds_total",
+                  "Seconds admitted streams waited between submit and "
+                  "the start of their admission.",
+                  gen.get("queue_wait_seconds_total", 0.0), lbl())
     return p.render() if own_page else ""
 
 
