@@ -391,7 +391,8 @@ def audit_cache(cache, *, expect_donation: Optional[bool] = None,
         # from swallowing "decode-multi-paged[..." entries
         if (rec["kind"] == "infer-cache" and rec["key"]
                 and (rec["key"][0] in ("decode", "prefill", "verify",
-                                       "prefill-logp")
+                                       "prefill-logp", "prefill-slot",
+                                       "prefill-logp-slot", "write-row")
                      or rec["key"][0].startswith("decode-multi["))
                 and not rec["donate_argnums"]
                 and _donation_expected(expect_donation)):

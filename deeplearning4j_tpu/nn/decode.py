@@ -167,6 +167,29 @@ def init_paged_state(conf: MultiLayerConfiguration, batch: int,
     return tuple(state)
 
 
+def zero_row(table):
+    """A fresh B=1 row state for one slot of `table`: leaf for leaf what
+    `init_state(conf, 1, max_seq)` gives for the table's own conf and
+    `max_seq`.  Traced inside a program the zeros are constants, not
+    dispatches."""
+    return jax.tree_util.tree_map(
+        lambda t: jnp.zeros((1,) + t.shape[1:], t.dtype), table)
+
+
+def write_row(table, row, slot):
+    """Write a B=1 row state into row `slot` of a table state, leaf by
+    leaf along axis 0 (`h`/`c` carries and `k`/`v` tables alike), and
+    return the table.  `slot` is a traced int32 scalar, so one program
+    serves every slot; with the table donated the write is in place and
+    no other row is touched."""
+    def put(t, r):
+        at = (slot,) + (jnp.zeros_like(slot),) * (t.ndim - 1)
+        return jax.lax.dynamic_update_slice(t, r.astype(t.dtype), at)
+
+    with scope("row_write"):
+        return jax.tree_util.tree_map(put, table, row)
+
+
 def token_embed(conf: MultiLayerConfiguration, params, tok, pos):
     """Embed one token id per row: EMBEDDING stacks gather W[tok]
     (+ P[pos] rowwise when a positional table exists — NOT

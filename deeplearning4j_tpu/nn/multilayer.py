@@ -560,16 +560,20 @@ class MultiLayerNetwork:
                         steps_per_dispatch: Optional[int] = None):
         """Precompile the autoregressive generation programs (ISSUE 14)
         ahead of traffic: ONE decode step over the `slots`-wide table
-        plus one prefill program per prompt bucket (each admission
-        prefills a single row, so prefill compiles at B=1).  The
-        optional decode accelerators (ISSUE 16) each swap or add
-        programs, and the warmup mirrors the serving batcher exactly so
-        `fresh_compiles == 0` holds for ANY flag combination:
+        plus one admission program per prompt bucket, `prefill_slot`:
+        compiled against the same slots-wide table, it prefills one row,
+        samples the first token and writes the row into its slot (the
+        B=1 `prefill` is the single-stream callers' and is not warmed
+        here).  The optional decode accelerators (ISSUE 16) each swap or
+        add programs, and the warmup mirrors the serving batcher exactly
+        so `fresh_compiles == 0` holds for ANY flag combination:
         `page_size > 0` warms the paged decode step over the shared
-        page pool instead of the dense one; `prefix_cache` warms the
-        logp-returning prefill the prefix cache records instead of the
-        sampling prefill; `draft_net` + `spec_k` warm the batched
-        verify step plus the draft model's own decode/prefill programs.
+        page pool instead of the dense one, and the B=1 `prefill` whose
+        row the batcher copies into pages; `prefix_cache` warms the
+        logp-returning `prefill_logp_slot` the prefix cache records
+        instead of the sampling one, and `write_row` for its hits;
+        `draft_net` + `spec_k` warm the batched verify step plus the
+        draft model's own decode and `prefill_slot` programs.
         With a persistent store attached the programs land on disk like
         every other warmup — a restarted serve process starts
         generating with `fresh_compiles == 0`.  Returns a summary with
@@ -644,7 +648,15 @@ class MultiLayerNetwork:
             dstate = dic.init_decode_state(draft_net.conf, slots, max_seq)
             dic.decode(draft_net.conf, draft_net.params, dstate, tok,
                        pos, keys, temps, compile_only=True)
-        row = ic.init_decode_state(self.conf, 1, max_seq)
+        # admissions: a dense table takes each stream in through ONE
+        # program a bucket, compiled against the slots-wide table (the
+        # prefill, the first token and the row's write); a paged pool
+        # keeps the B=1 prefill and writes the row's pages apart
+        paged = page_size > 0
+        row = (ic.init_decode_state(self.conf, 1, max_seq)
+               if paged or prefix_cache else None)
+        if prefix_cache and not paged:
+            ic.write_row(self.conf, state, row, 0, compile_only=True)
         buckets = sorted(int(b) for b in prompt_buckets)
         for tb in buckets:
             if tb > max_seq:
@@ -652,17 +664,22 @@ class MultiLayerNetwork:
                                  f"max_seq={max_seq}")
             prompt = jnp.zeros((1, tb), jnp.int32)
             length = jnp.ones((1,), jnp.int32)
-            if prefix_cache:
+            if paged and prefix_cache:
                 ic.prefill_logp(self.conf, self.params, row, prompt,
                                 length, compile_only=True)
-            else:
+            elif paged:
                 ic.prefill(self.conf, self.params, row, prompt, length,
                            keys[:1], temps[:1], compile_only=True)
+            elif prefix_cache:
+                ic.prefill_logp_slot(self.conf, self.params, state, 0,
+                                     prompt, length, compile_only=True)
+            else:
+                ic.prefill_slot(self.conf, self.params, state, 0, prompt,
+                                length, keys[:1], temps[:1],
+                                compile_only=True)
             if draft_net is not None:
-                drow = draft_net.infer_cache.init_decode_state(
-                    draft_net.conf, 1, max_seq)
-                draft_net.infer_cache.prefill(
-                    draft_net.conf, draft_net.params, drow, prompt,
+                draft_net.infer_cache.prefill_slot(
+                    draft_net.conf, draft_net.params, dstate, 0, prompt,
                     length, keys[:1], temps[:1], compile_only=True)
         return {
             "slots": int(slots),

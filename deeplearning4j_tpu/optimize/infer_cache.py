@@ -54,6 +54,7 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deeplearning4j_tpu.optimize.step_cache import (CompiledProgramCache,
                                                     arg_signature,
@@ -672,6 +673,89 @@ class InferCache(CompiledProgramCache):
             self.stats.steps += 1
         return fn(*self._decode_place(sp, state, prompt, length))
 
+    # -- admission into the slot table: one program a stream -----------------
+    def prefill_slot(self, conf, params, table, slot: int, prompt, length,
+                     keys, temps, compile_only: bool = False):
+        """A dense admission in ONE program: prefill prompt [1, T_bucket]
+        into a zero row made inside the trace, sample the stream's first
+        token as `prefill` does, and write the row into row `slot` of the
+        slots-wide `table`.  Returns (tok0 [1], advanced keys [1, 2],
+        table).  The decode family's contract: the table is argument 1,
+        donated off-CPU (the write is in place: no second table, no
+        other row touched), and last in the output; `slot` is a traced
+        int32 scalar, so one program a prompt bucket serves every slot."""
+        policy, sp = self._policy, self._serve_params(params)
+        slot = np.asarray(slot, np.int32)
+        key = ("prefill-slot", self._fingerprint(conf),
+               arg_signature(prompt, length, keys, temps,
+                             *jax.tree_util.tree_leaves(table)),
+               self._decode_tag()) + self._policy_suffix()
+        fn = self._get(
+            key, self._tp_build(lambda: _prefill_slot_program(conf, policy)),
+            (sp, table, slot, prompt, length, keys, temps),
+            donate=self._decode_donate(),
+            shardings=self._decode_shardings(sp, table, 5))
+        if compile_only:
+            return None
+        with self._lock:
+            self.stats.steps += 1
+        return fn(*self._decode_place(sp, table, slot, prompt, length, keys,
+                                      temps))
+
+    def prefill_logp_slot(self, conf, params, table, slot: int, prompt,
+                          length, compile_only: bool = False):
+        """`prefill_slot` for the prefix cache: no sampling (see
+        `prefill_logp`), and the filled B=1 row comes back beside the
+        table it was written into, for the cache to keep.  Returns
+        (logp [1, vocab] f32, row, table)."""
+        policy, sp = self._policy, self._serve_params(params)
+        slot = np.asarray(slot, np.int32)
+        key = ("prefill-logp-slot", self._fingerprint(conf),
+               arg_signature(prompt, length,
+                             *jax.tree_util.tree_leaves(table)),
+               self._decode_tag()) + self._policy_suffix()
+        fn = self._get(
+            key,
+            self._tp_build(lambda: _prefill_logp_slot_program(conf, policy)),
+            (sp, table, slot, prompt, length),
+            donate=self._decode_donate(),
+            shardings=self._decode_shardings(sp, table, 3))
+        if compile_only:
+            return None
+        with self._lock:
+            self.stats.steps += 1
+        return fn(*self._decode_place(sp, table, slot, prompt, length))
+
+    def write_row(self, conf, table, row, slot: int,
+                  compile_only: bool = False):
+        """`nn.decode.write_row` as a program of its own, for the
+        admissions that already hold a row (a prefix-cache hit): B=1
+        `row` (device or host tree) into row `slot` of `table`, which is
+        donated off-CPU and returned.  The row goes first so that the
+        table is argument 1, as in the rest of the decode family; the
+        program takes no params."""
+        plan = self.plan
+        slot = np.asarray(slot, np.int32)
+        key = ("write-row", self._fingerprint(conf),
+               arg_signature(*jax.tree_util.tree_leaves(row),
+                             *jax.tree_util.tree_leaves(table)),
+               self._decode_tag()) + self._policy_suffix()
+        shardings = None
+        if plan.has_model_axis:
+            shardings = (plan.state_shardings(row),
+                         plan.state_shardings(table), plan.replicated())
+        fn = self._get(key, self._tp_build(_write_row_program),
+                       (row, table, slot), donate=self._decode_donate(),
+                       shardings=shardings)
+        if compile_only:
+            return None
+        with self._lock:
+            self.stats.steps += 1
+        if shardings is not None:
+            slot = jax.device_put(slot, shardings[2])
+        return fn(self._place_decode_state(row),
+                  self._place_decode_state(table), slot)[0]
+
     def loss(self, conf, params, x, y, compile_only: bool = False):
         """`network_loss(training=False)` through the cache: the
         row-weighted mean loss over the real rows plus regularization.
@@ -904,6 +988,41 @@ def _prefill_logp_program(conf, policy: str = "f32") -> Callable:
         logp, state = decode_mod.prefill(
             pconf, _policy_args(params, policy), state, prompt, length)
         return logp.astype(jnp.float32), state
+
+    return program
+
+
+def _prefill_slot_program(conf, policy: str = "f32") -> Callable:
+    from deeplearning4j_tpu.nn import decode as decode_mod
+
+    prefill = _prefill_program(conf, policy)
+
+    def program(params, table, slot, prompt, length, keys, temps):
+        tok0, keys2, row = prefill(params, decode_mod.zero_row(table),
+                                   prompt, length, keys, temps)
+        return tok0, keys2, decode_mod.write_row(table, row, slot)
+
+    return program
+
+
+def _prefill_logp_slot_program(conf, policy: str = "f32") -> Callable:
+    from deeplearning4j_tpu.nn import decode as decode_mod
+
+    prefill_logp = _prefill_logp_program(conf, policy)
+
+    def program(params, table, slot, prompt, length):
+        logp, row = prefill_logp(params, decode_mod.zero_row(table),
+                                 prompt, length)
+        return logp, row, decode_mod.write_row(table, row, slot)
+
+    return program
+
+
+def _write_row_program() -> Callable:
+    from deeplearning4j_tpu.nn import decode as decode_mod
+
+    def program(row, table, slot):
+        return (decode_mod.write_row(table, row, slot),)
 
     return program
 

@@ -988,22 +988,29 @@ class ContinuousBatcher:
         return [i for i, s in enumerate(self._slots) if s is None]
 
     def _admit_one(self, slot: int, stream: GenerationStream) -> None:
-        """Prefill `stream` into `slot`: one B=1 prefill program fills a
-        row state and samples the stream's first token (TTFT = this
-        call), then the row is scattered into the slot table.
+        """Admit `stream` into `slot`.  A dense admission is ONE call of
+        one compiled program (`InferCache.prefill_slot`): it takes the
+        slot table, donated, prefills the prompt into a zero row made
+        inside the trace, samples the stream's first token (TTFT = this
+        call) and hands the table back with the row written in place.
+        Nothing is dispatched outside that program.
 
         A prefix-cache hit skips the prefill entirely: the cached row
-        state is scattered and (exact match) the first token is sampled
+        state is written into the table (`InferCache.write_row`, one
+        small program) and (exact match) the first token is sampled
         on the host from the cached logp with the stream's own key, or
         (longest match) the unmatched prompt suffix is queued to feed
         through the decode table.  Either way the token trajectory is
-        identical to a cold prefill.
+        identical to a cold prefill.  A paged table keeps the B=1
+        prefill and `_scatter_row`'s page writes.
 
         The whole of it is one `admit` span keyed by the stream's rid
-        (`queue_wait_ns`: from `submit` to here), its parts the children
-        `admit.init_row`, `admit.prefill`, `admit.scatter` and
-        `admit.deliver`; `admit_seconds_total` and
-        `queue_wait_seconds_total` add up the same spans."""
+        (`queue_wait_ns`: from `submit` to here; `path`: which way in,
+        "prefill_slot", "write_row" or "paged"), its parts the children
+        `admit.prefill` and `admit.deliver`, with `admit.scatter` between
+        them on the two paths that write a row they hold;
+        `admit_seconds_total` and `queue_wait_seconds_total` add up the
+        same spans."""
         n = int(stream.prompt.shape[0])
         wait_ns = int((time.monotonic() - stream.t_submit) * 1e9)
         sp = span("admit", rid=stream.rid, slot=slot, prompt_tokens=n,
@@ -1018,7 +1025,9 @@ class ContinuousBatcher:
 
     def _admit_spanned(self, slot: int, stream: GenerationStream, n: int,
                        sp: span) -> None:
-        ic = self.net.infer_cache
+        import jax
+
+        ic, conf, params = self.net.infer_cache, self.net.conf, self.net.params
         faults.fire("generate.admit", slot=slot, prompt_tokens=n)
         hit = (self._prefix_lookup(stream.prompt)
                if self.prefix_cache_enabled else None)
@@ -1028,37 +1037,51 @@ class ContinuousBatcher:
             # allocate the admission pages before any device work, so a
             # dry pool queues the stream instead of wasting a prefill
             pages = self._pool.alloc(-(-m // self.page_size), slot=slot)
-        tok0 = key1 = None
+        sp.set(path="paged" if self.paged
+               else "prefill_slot" if hit is None else "write_row")
+        tok0 = key1 = row = None        # a row in hand is still to be written
         if hit is None:
             bucket = self._prompt_bucket(n)
             sp.set(bucket=bucket)
             prompt = np.zeros((1, bucket), np.int32)
             prompt[0, :n] = stream.prompt
             length = np.asarray([n], np.int32)
-            with span("admit.init_row"):
-                row = ic.init_decode_state(self.net.conf, 1, self.max_seq)
+            with span("admit.prefill"):     # to the program's host read
+                if self.paged:  # B=1 programs; the pages are written below
+                    fill, fill_logp = ic.prefill, ic.prefill_logp
+                    into = (ic.init_decode_state(conf, 1, self.max_seq),)
+                else:           # one program writes the row into the table
+                    fill, fill_logp = ic.prefill_slot, ic.prefill_logp_slot
+                    into = (self._state, slot)
+                if self.prefix_cache_enabled:
+                    logp, *kept, state = fill_logp(conf, params, *into,
+                                                   prompt, length)
+                    logp = np.asarray(logp, np.float32)[0]
+                    # the filled row: the paged program's state itself,
+                    # handed back beside the table by the slot program
+                    kept = kept[0] if kept else state
+                else:
+                    t0, keys1, state = fill(
+                        conf, params, *into, prompt, length, stream.key[None],
+                        np.asarray([stream.temperature], np.float32))
+                    t0, keys1 = jax.device_get((t0, keys1))
+                    tok0, key1 = int(t0[0]), keys1[0]
+                if self.paged:
+                    row = state
+                else:
+                    self._state = state
             if self.prefix_cache_enabled:
-                with span("admit.prefill"):     # to the program's host read
-                    logp, row = ic.prefill_logp(
-                        self.net.conf, self.net.params, row, prompt, length)
-                    logp = np.asarray(logp[0], np.float32)
-                self._prefix_store(stream.prompt, logp, row)
+                self._prefix_store(stream.prompt, logp, kept)
                 tok0, key1 = _host_sample(logp, stream.key,
                                           stream.temperature)
-            else:
-                temps = np.asarray([stream.temperature], np.float32)
-                with span("admit.prefill"):     # to the program's host read
-                    t0, keys1, row = ic.prefill(
-                        self.net.conf, self.net.params, row, prompt, length,
-                        stream.key[None], temps)
-                    tok0, key1 = int(t0[0]), np.asarray(keys1[0])
         else:
             row = hit[2]
             if hit[1] is not None:  # exact match: cached prefill logp
                 tok0, key1 = _host_sample(hit[1], stream.key,
                                           stream.temperature)
-        with span("admit.scatter"):
-            self._scatter_row(slot, row, pages)
+        if row is not None:
+            with span("admit.scatter"):
+                self._scatter_row(slot, row, pages)
         if self.draft_net is not None:
             # the draft consumes exactly the m tokens the target row has
             # consumed, so feed rounds advance both in lockstep
@@ -1117,15 +1140,17 @@ class ContinuousBatcher:
             h["inf"] += 1
 
     def _scatter_row(self, slot: int, row, pages: Optional[List[int]]):
-        """Scatter a B=1 row state (device or host tree) into the slot
-        table: dense rows in one eager tree scatter; paged rows copy the
-        dense K/V into the freshly allocated physical pages, recurrent
-        carries per slot."""
-        import jax
-
+        """Write a B=1 row state in hand (device or host tree) into the
+        slot table: a prefix-cache hit's row, or a paged admission's.
+        Dense: one call of the `write_row` program, the table donated
+        and written in place.  Paged: the dense K/V copied into the
+        freshly allocated physical pages, recurrent carries per slot,
+        in eager `.at[].set` writes as before: pages are a different
+        write (per page, per layer), no benchmark cell runs it, and it
+        gets a program of its own when a paged cell exists."""
         if not self.paged:
-            self._state = jax.tree_util.tree_map(
-                lambda tbl, r: tbl.at[slot].set(r[0]), self._state, row)
+            self._state = self.net.infer_cache.write_row(
+                self.net.conf, self._state, row, slot)
             return
         ps = self.page_size
         new_state = []
@@ -1150,22 +1175,20 @@ class ContinuousBatcher:
         self._page_table[slot, : len(pages)] = pages
 
     def _draft_admit(self, slot: int, prompt: np.ndarray) -> None:
-        """Prefill the draft model's slot row over `prompt` (the tokens
-        the target row has consumed).  The draft decodes greedily with a
-        dummy key — its proposals only gate acceptance, never sampling."""
-        import jax
-
+        """Prefill the draft model's row `slot` over `prompt` (the tokens
+        the target row has consumed): the draft's own `prefill_slot`
+        program on its (always dense) table.  The draft decodes greedily
+        with a dummy key — its proposals only gate acceptance, never
+        sampling."""
         dn = self.draft_net
         m = int(prompt.shape[0])
         bucket = self._prompt_bucket(m)
         pb = np.zeros((1, bucket), np.int32)
         pb[0, :m] = prompt
-        row = dn.infer_cache.init_decode_state(dn.conf, 1, self.max_seq)
-        _, _, row = dn.infer_cache.prefill(
-            dn.conf, dn.params, row, pb, np.asarray([m], np.int32),
-            np.zeros((1, 2), np.uint32), np.zeros((1,), np.float32))
-        self._draft_state = jax.tree_util.tree_map(
-            lambda tbl, r: tbl.at[slot].set(r[0]), self._draft_state, row)
+        _, _, self._draft_state = dn.infer_cache.prefill_slot(
+            dn.conf, dn.params, self._draft_state, slot, pb,
+            np.asarray([m], np.int32), np.zeros((1, 2), np.uint32),
+            np.zeros((1,), np.float32))
 
     # -- prefix cache -------------------------------------------------------
     def _prefix_digest(self, prompt: np.ndarray) -> str:

@@ -390,7 +390,7 @@ def replica_metrics(stats: dict, page: Optional[PrometheusText] = None,
                   gen.get("decode_host_seconds_total", 0.0), lbl())
         p.counter("dl4j_serving_admit_seconds_total",
                   "Seconds the decode loop spent admitting streams "
-                  "(prefill, scatter into the slot table, first token): "
+                  "(prefill, row write into the slot table, first token): "
                   "every live stream stalls for them, and the "
                   "host-overhead fraction does not count them.",
                   gen.get("admit_seconds_total", 0.0), lbl())

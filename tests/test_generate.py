@@ -12,6 +12,7 @@ ONE stream cleanly while its neighbours keep decoding.
 
 Tier-1: CPU-only, tiny models."""
 
+import contextlib
 import json
 import threading
 import time
@@ -31,6 +32,7 @@ from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork, network_output
 from deeplearning4j_tpu.reliability import faults
 from deeplearning4j_tpu.serving.batcher import (ContinuousBatcher,
                                                 ServerOverloaded)
+from deeplearning4j_tpu.utils import profiling
 
 VOCAB = 13
 
@@ -761,9 +763,10 @@ def test_positional_bound_unbounded_for_recurrent(lstm_net):
 
 
 def test_flags_off_compiles_only_the_pre_issue16_programs():
-    """Flags off = byte-for-byte the ISSUE 14 serving path: the same
-    two program kinds ('decode', 'prefill'), the same cache keys, no
-    paged/verify/logp programs anywhere near the cache."""
+    """Flags off = two program kinds, the table's decode step and the
+    one admission program ('decode', 'prefill-slot'; the B=1 'prefill'
+    is the single-stream callers'), no paged/verify/logp/write-row
+    programs anywhere near the cache."""
     net = MultiLayerNetwork(char_lstm(VOCAB, hidden=16, n_layers=2),
                             seed=0).init()
     cb = ContinuousBatcher(net, n_slots=2, max_seq=16,
@@ -771,7 +774,7 @@ def test_flags_off_compiles_only_the_pre_issue16_programs():
     try:
         assert len(cb.generate([1, 2], max_new_tokens=4)) == 4
         kinds = {r["entry"] for r in net.infer_cache.programs_summary()}
-        assert kinds == {"decode", "prefill"}
+        assert kinds == {"decode", "prefill-slot"}
         st = cb.stats()
         assert "kv_pages" not in st
         assert "prefix_cache" not in st
@@ -803,3 +806,212 @@ def test_warmup_generate_covers_every_flag_combination():
         assert cb.stats()["fresh_compiles"] == after
     finally:
         cb.stop()
+
+
+# -- ISSUE 26: one compiled program per admission ----------------------------
+
+_ADMIT_FLAGS = {"plain": {}, "prefix": {"prefix_cache": True},
+                "draft": {"spec_k": 3}, "prefix+draft": {"prefix_cache": True,
+                                                         "spec_k": 3}}
+
+
+def _bucket_of(buckets, n):
+    return next(b for b in buckets if b >= n)
+
+
+@pytest.mark.parametrize("buckets", [(8,), (4, 8)])
+@pytest.mark.parametrize("flags", sorted(_ADMIT_FLAGS))
+@pytest.mark.parametrize("which", ["lstm", "transformer"])
+def test_fused_admission_streams_equal_the_b1_prefill_path(
+        which, flags, buckets, lstm_net, transformer_net):
+    """Seven streams over three slots (every slot admitted into at least
+    twice, both buckets, greedy and sampled, a repeated prompt for the
+    prefix cache's `write_row` path): each stream's tokens are those of
+    the parent's admission, a B=1 `prefill` and the decode step."""
+    net = lstm_net if which == "lstm" else transformer_net
+    kw = dict(_ADMIT_FLAGS[flags])
+    if "spec_k" in kw:
+        kw["draft_net"] = _draft_net()
+    asks = [([1, 2, 3], 0.0, 0), ([4, 5, 6, 7, 2, 1], 0.8, 1),
+            ([2, 2], 0.0, 2), ([1, 2, 3], 0.6, 3), ([7], 0.0, 4),
+            ([4, 5, 6, 7, 2, 1], 0.0, 5), ([3, 1, 2, 5, 6], 1.1, 6)]
+    refs = [_compiled_tokens(net, p, 6, temperature=t, rng_seed=s,
+                             bucket=_bucket_of(buckets, len(p)))
+            for p, t, s in asks]
+    cb = ContinuousBatcher(net, n_slots=3, max_seq=16,
+                           prompt_buckets=buckets, **kw)
+    profiling.clear()
+    try:
+        streams = [cb.submit(p, max_new_tokens=6, temperature=t, rng_seed=s)
+                   for p, t, s in asks]
+        assert _drain(streams) == refs
+    finally:
+        cb.stop()
+    admits = [s for s in profiling.spans() if s.name == "admit"]
+    assert len(admits) == 7
+    assert {a.attrs["slot"] for a in admits} == {0, 1, 2}
+    assert {a.attrs["bucket"] for a in admits if "bucket" in a.attrs} \
+        == set(buckets)
+    paths = [a.attrs["path"] for a in admits]
+    if "prefix_cache" in kw:
+        assert paths.count("write_row") == 2       # the two repeated prompts
+    assert paths.count("prefill_slot") == 7 - paths.count("write_row")
+
+
+def _random_table(net, slots, max_seq, seed):
+    """A slots-wide table with no zero in it, so that a write that strays
+    shows in any row."""
+    zero = net.infer_cache.init_decode_state(net.conf, slots, max_seq)
+    leaves, tree = jax.tree_util.tree_flatten(zero)
+    keys = jax.random.split(jax.random.PRNGKey(seed), max(1, len(leaves)))
+    return jax.tree_util.tree_unflatten(tree, [
+        (1.0 + jax.random.uniform(k, l.shape)).astype(l.dtype)
+        for k, l in zip(keys, leaves)])
+
+
+@pytest.mark.parametrize("slot", [0, 1, 2])
+@pytest.mark.parametrize("entry", ["prefill_slot", "prefill_logp_slot",
+                                   "write_row"])
+@pytest.mark.parametrize("which", ["lstm", "transformer"])
+def test_admission_writes_its_row_and_no_other(which, entry, slot, lstm_net,
+                                               transformer_net):
+    """Row `slot` of every leaf becomes the B=1 prefill's row, zeros past
+    the prompt included; every other row of every leaf keeps its bits;
+    first token and key are the B=1 program's."""
+    net = lstm_net if which == "lstm" else transformer_net
+    ic, conf, params = net.infer_cache, net.conf, net.params
+    prompt = np.zeros((1, 8), np.int32)
+    prompt[0, :5] = [3, 1, 4, 1, 5]
+    length = np.asarray([5], np.int32)
+    keys = np.asarray(jax.random.PRNGKey(7))[None]
+    temps = np.asarray([0.9], np.float32)
+    tok_ref, keys_ref, row_ref = ic.prefill(
+        conf, params, ic.init_decode_state(conf, 1, 16), prompt, length,
+        keys, temps)
+    before = _random_table(net, 3, 16, seed=slot)
+    if entry == "prefill_slot":
+        tok, keys2, after = ic.prefill_slot(conf, params, before, slot,
+                                            prompt, length, keys, temps)
+        assert int(tok[0]) == int(tok_ref[0])
+        np.testing.assert_array_equal(np.asarray(keys2), np.asarray(keys_ref))
+    elif entry == "prefill_logp_slot":
+        logp_ref, _ = ic.prefill_logp(
+            conf, params, ic.init_decode_state(conf, 1, 16), prompt, length)
+        logp, row, after = ic.prefill_logp_slot(conf, params, before, slot,
+                                                prompt, length)
+        np.testing.assert_array_equal(np.asarray(logp), np.asarray(logp_ref))
+        for got, want in zip(jax.tree_util.tree_leaves(row),
+                             jax.tree_util.tree_leaves(row_ref)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        host_row = jax.tree_util.tree_map(np.asarray, row_ref)
+        after = ic.write_row(conf, before, host_row, slot)
+    assert jax.tree_util.tree_structure(after) \
+        == jax.tree_util.tree_structure(before)
+    leaves = list(zip(jax.tree_util.tree_leaves(before),
+                      jax.tree_util.tree_leaves(after),
+                      jax.tree_util.tree_leaves(row_ref)))
+    assert leaves
+    for was, now, row in leaves:
+        was, now, row = np.asarray(was), np.asarray(now), np.asarray(row)
+        assert now.shape == was.shape and now.dtype == was.dtype
+        np.testing.assert_array_equal(now[slot], row[0])
+        others = [i for i in range(3) if i != slot]
+        np.testing.assert_array_equal(now[others], was[others])
+
+
+def test_zero_row_is_init_state_of_one_row(lstm_net, transformer_net):
+    for net in (lstm_net, transformer_net):
+        table = decode_mod.init_state(net.conf, 3, 16)
+        got = decode_mod.zero_row(table)
+        want = decode_mod.init_state(net.conf, 1, 16)
+        assert jax.tree_util.tree_structure(got) \
+            == jax.tree_util.tree_structure(want)
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert g.shape == w.shape and g.dtype == w.dtype
+            assert not np.asarray(g).any()
+
+
+@contextlib.contextmanager
+def _watch_compiles():
+    """What `benchmark/run.py::watch_compiles` counts: every backend
+    compile and every fetch from JAX's persistent cache.  An eager
+    `zeros` or `scatter` after `jax.clear_caches()` is one."""
+    import jax.monitoring as mon
+
+    seen = {"count": 0, "names": []}
+
+    def on_duration(name, seconds, **_):
+        if name in ("/jax/core/compile/backend_compile_duration",
+                    "/jax/compilation_cache/cache_retrieval_time_sec"):
+            seen["count"] += 1
+            seen["names"].append(name)
+
+    mon.register_event_duration_secs_listener(on_duration)
+    try:
+        yield seen
+    finally:
+        mon.unregister_event_duration_listener(on_duration)
+
+
+@pytest.mark.parametrize("draft", [False, True])
+@pytest.mark.parametrize("which", ["lstm", "transformer"])
+def test_warm_admissions_compile_nothing_and_call_the_cache_once(which, draft):
+    """After `jax.clear_caches()` and `warmup_generate`, a dense admission
+    dispatches nothing outside its one compiled cache entry: no compile
+    of any kind in the window, and one cache call a stream (streams of
+    one token end at their admission, so no decode step runs)."""
+    conf = (char_lstm(VOCAB, hidden=16, n_layers=2) if which == "lstm" else
+            char_transformer(VOCAB, d_model=16, n_blocks=2, n_heads=2,
+                             max_seq_len=32))
+    net = MultiLayerNetwork(conf, seed=0).init()
+    kw = {"draft_net": _draft_net(), "spec_k": 3} if draft else {}
+    jax.clear_caches()
+    net.warmup_generate(slots=3, max_seq=16, prompt_buckets=(4, 8), **kw)
+    cb = ContinuousBatcher(net, n_slots=3, max_seq=16,
+                           prompt_buckets=(4, 8), **kw).start()
+    caches = [net.infer_cache] + ([kw["draft_net"].infer_cache] if draft
+                                  else [])
+    jax.random.PRNGKey(0)   # `submit` makes the stream's key, on the caller
+    try:
+        with _watch_compiles() as seen:
+            misses = [ic.stats.misses for ic in caches]
+            calls = [ic.stats.steps for ic in caches]
+            streams = [cb.submit(p, max_new_tokens=1, temperature=t,
+                                 rng_seed=i)
+                       for i, (p, t) in enumerate(
+                           [([1, 2, 3], 0.0), ([4, 5, 6, 7, 1], 0.7),
+                            ([2], 0.0), ([1, 2, 3], 0.0), ([5, 5], 1.3)])]
+            assert [len(t) for t in _drain(streams)] == [1] * 5
+            cb.stop()
+            assert seen["count"] == 0, seen["names"]
+        assert [ic.stats.misses for ic in caches] == misses
+        assert [ic.stats.steps - c for ic, c in zip(caches, calls)] \
+            == [5] * len(caches)
+    finally:
+        cb.stop()
+
+
+@pytest.mark.parametrize("path", ["prefill_slot", "write_row", "paged"])
+def test_admit_span_says_which_way_in(path, lstm_net):
+    """The `admit` span's `path` attr and its children on each path."""
+    kw = {"prefill_slot": {}, "write_row": {"prefix_cache": True},
+          "paged": {"page_size": 4}}[path]
+    cb = ContinuousBatcher(lstm_net, n_slots=2, max_seq=16,
+                           prompt_buckets=(8,), **kw)
+    try:
+        if path == "write_row":
+            cb.generate([1, 2, 3], max_new_tokens=2)    # seeds the cache
+        profiling.clear()
+        assert len(cb.generate([1, 2, 3], max_new_tokens=2)) == 2
+    finally:
+        cb.stop()
+    record = profiling.spans()
+    admit, = [s for s in record if s.name == "admit"]
+    assert admit.attrs["path"] == path
+    kids = [s.name for s in record if s.parent == admit.sid]
+    assert kids == {"prefill_slot": ["admit.prefill", "admit.deliver"],
+                    "write_row": ["admit.scatter", "admit.deliver"],
+                    "paged": ["admit.prefill", "admit.scatter",
+                              "admit.deliver"]}[path]

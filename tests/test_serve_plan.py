@@ -143,8 +143,8 @@ class TestKeyByteIdentity:
         net.set_serve_mesh()
         net.warmup_generate(slots=2, max_seq=16, prompt_buckets=(4,))
         decode_keys = [k for k in net.infer_cache._programs
-                       if k[0] in ("decode", "prefill")]
-        assert decode_keys
+                       if k[0] in ("decode", "prefill-slot")]
+        assert {k[0] for k in decode_keys} == {"decode", "prefill-slot"}
         assert all(k[3] == "single" for k in decode_keys)
 
     def test_decode_keys_carry_plan_tag_with_model_axis(self):

@@ -100,6 +100,41 @@ class TestTwoDDecode:
             cb.stop()
         assert got == ref
 
+    @pytest.mark.parametrize("prefix_cache", [False, True])
+    def test_admission_programs_under_the_model_axis(self, prefix_cache):
+        """`prefill_slot`, and with the prefix cache `prefill_logp_slot`
+        (the miss) and `write_row` (the hit), take the sharded table in
+        and hand it back on the plan's specs: warm, no fresh compile, and
+        the single chip's tokens, sampled streams included."""
+        asks = [([1, 7, 3], 0.0, 0), ([4, 4, 9, 2], 0.8, 1),
+                ([1, 7, 3], 0.7, 2)]
+
+        def tokens(net):
+            net.warmup_generate(slots=2, max_seq=32, prompt_buckets=(8,),
+                                prefix_cache=prefix_cache)
+            warm = net.infer_cache.stats.misses
+            cb = ContinuousBatcher(net, n_slots=2, max_seq=32,
+                                   prompt_buckets=(8,),
+                                   prefix_cache=prefix_cache)
+            try:
+                got = [cb.generate(p, max_new_tokens=6, temperature=t,
+                                   rng_seed=s, timeout=120.0)
+                       for p, t, s in asks]
+            finally:
+                cb.stop()
+            assert net.infer_cache.stats.misses == warm
+            return got
+
+        ref = tokens(_net())
+        net = _net()
+        net.set_serve_mesh(spec="batch=1,model=4")
+        assert tokens(net) == ref
+        entries = {k[0] for k in net.infer_cache._programs}
+        assert entries == ({"decode", "prefill-logp-slot", "write-row"}
+                           if prefix_cache else {"decode", "prefill-slot"})
+        assert all(k[3] == ("mesh", ("batch", "model"), (1, 4))
+                   for k in net.infer_cache._programs)
+
     def test_decode_state_sharded_over_model_axis(self):
         net = _net()
         net.set_serve_mesh(spec="batch=1,model=4")

@@ -65,7 +65,7 @@ def test_every_infer_program_has_its_own_dl4j_name_and_its_old_key():
     for key, name in names.items():
         assert name == "jit_" + profiling.program_name(key[0])
         assert "dl4j" not in repr(key)
-    assert {"jit_dl4j_decode", "jit_dl4j_prefill", "jit_dl4j_output",
+    assert {"jit_dl4j_decode", "jit_dl4j_prefill_slot", "jit_dl4j_output",
             "jit_dl4j_decode_multi_4"} <= set(names.values())
     # the key of the pre-name schema, built by hand
     xp = jnp.zeros((ic._serve_bucket(8), 16), jnp.int32)
@@ -156,7 +156,8 @@ def test_train_step_carries_every_layer_scope_and_the_same_equations(
         assert str(jax.make_jaxpr(bare)(*args)) == scoped
 
 
-@pytest.mark.parametrize("entry", ["decode", "prefill", "decode-multi[2]"])
+@pytest.mark.parametrize("entry", ["decode", "prefill-slot",
+                                   "decode-multi[2]"])
 def test_decode_programs_carry_every_layer_scope_and_the_same_equations(
         entry, monkeypatch):
     net = _net()
@@ -207,8 +208,8 @@ def test_batcher_leaves_one_admit_span_a_stream_with_its_children():
     loop_thread = admits[0].thread
     for a in admits:
         kids = [s for s in record if s.parent == a.sid]
-        assert [k.name for k in kids] == ["admit.init_row", "admit.prefill",
-                                          "admit.scatter", "admit.deliver"]
+        assert [k.name for k in kids] == ["admit.prefill", "admit.deliver"]
+        assert a.attrs["path"] == "prefill_slot"
         assert all(k.rid == a.rid and k.thread == loop_thread for k in kids)
         assert all(a.start_ns <= k.start_ns <= k.end_ns <= a.end_ns
                    for k in kids)
