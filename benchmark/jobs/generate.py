@@ -13,7 +13,7 @@ import time
 
 import numpy as np
 
-from benchmark import program, reference, trace_reduce, traffic
+from benchmark import families, program, trace_reduce, traffic
 
 _TOKEN_TIMEOUT_S = 60.0     # an answer may come a minute late; later is never
 
@@ -98,11 +98,11 @@ class Load:
             th.start()
             self.threads.append(th)
 
-    def live(self):
-        """(rows in flight, their positions in all) as the callers see it."""
+    def live(self) -> list:
+        """The position of every row in flight, as the callers see it."""
         with self.lock:
             open_ = [s for s in self.sent if not s.done and s.stamps]
-        return len(open_), sum(len(s.prompt) + len(s.stamps) for s in open_)
+        return [len(s.prompt) + len(s.stamps) for s in open_]
 
     def join(self, timeout: float) -> bool:
         end = time.perf_counter() + timeout
@@ -116,7 +116,8 @@ def build(ctx):
     from deeplearning4j_tpu.serving.batcher import ContinuousBatcher
 
     srv = ctx.mix["server"]
-    net = MultiLayerNetwork(program.build_conf(ctx.cfg), seed=ctx.seed & 0x7FFFFFFF)
+    net = MultiLayerNetwork(families.of(ctx.cfg).program.build_conf(ctx.cfg),
+                            seed=ctx.seed & 0x7FFFFFFF)
     net.params = program.program_weights(ctx.cfg, ctx.seed)
     net.warmup_generate(slots=int(srv["n_slots"]), max_seq=int(srv["max_seq"]),
                         prompt_buckets=tuple(srv["prompt_buckets"]))
@@ -161,7 +162,8 @@ def served_gaps(cfg: dict, seed: int, sample: list, precisions=("f32",)) -> dict
         ids[r, :n] = s.prompt
         ids[r, n:n + k] = s.tokens
         mask[r, n - 1:n + k - 1] = True       # logits that chose a served token
-    logits = reference.teacher_forced_logits(cfg, seed, ids, precisions)
+    logits = families.of(cfg).reference.teacher_forced_logits(
+        cfg, seed, ids, precisions)
     ref = logits["f32"]
     best = jnp.max(ref, axis=-1)
     nxt = jnp.asarray(np.roll(ids, -1, axis=1))
@@ -191,7 +193,7 @@ def run(ctx) -> dict:
     import jax
 
     mix = ctx.mix
-    vocab = reference.sizes(ctx.cfg)["vocab"]
+    vocab = families.of(ctx.cfg).reference.sizes(ctx.cfg)["vocab"]
     net, batcher = build(ctx)
     load = Load(batcher, mix, traffic.requests(mix, vocab, ctx.seed))
     ramp = float(mix["arrival"].get("ramp_s", 0.0))
@@ -206,7 +208,7 @@ def run(ctx) -> dict:
     load.stop_at = t1
     trace_at = t0 + ctx.seconds / 3.0 if ctx.trace_dir else None
     trace_end, window, traced = None, None, {}
-    samples = []
+    samples, positions = [], []
     with ctx.watch_compiles() as compiles:
         while True:
             now = time.perf_counter()
@@ -229,7 +231,9 @@ def run(ctx) -> dict:
                           "samples": (traced["samples"], len(samples))}
                 jax.profiler.stop_trace()
                 trace_at, trace_end = None, None
-            samples.append(load.live() + (batcher.stats()["slots"]["active"],))
+            rows = load.live()
+            positions.append(rows)
+            samples.append((len(rows), sum(rows), batcher.stats()["slots"]["active"]))
             time.sleep(0.05)
         if trace_end is not None:           # a window shorter than the trace
             window.__exit__(None, None, None)
@@ -265,6 +269,7 @@ def run(ctx) -> dict:
         "traced_admitted": traced.get("admitted"),
         "traced_live_rows": float(np.mean(live[lo:hi, 0])) if hi > lo else None,
         "traced_live_positions": float(np.mean(live[lo:hi, 1])) if hi > lo else None,
+        "traced_live_row_positions": [p for rows in positions[lo:hi] for p in rows],
         "drained": drained,
         "ttfts_ms": ttfts, "gaps_ms": gaps,
     }
@@ -307,7 +312,7 @@ def readings(ctx, seeds, control_seeds) -> list:
     from deeplearning4j_tpu.serving.batcher import ContinuousBatcher
 
     mix, srv = ctx.mix, ctx.mix["server"]
-    vocab = reference.sizes(ctx.cfg)["vocab"]
+    vocab = families.of(ctx.cfg).reference.sizes(ctx.cfg)["vocab"]
     net, out = None, []
     for seed in seeds:
         if net is None:

@@ -8,7 +8,7 @@ import time
 
 import numpy as np
 
-from benchmark import correct, program, reference, trace_reduce, traffic
+from benchmark import correct, families, program, reference, trace_reduce, traffic
 
 
 class TokenStream:
@@ -90,7 +90,8 @@ def build(ctx):
     from deeplearning4j_tpu.parallel.data_parallel import DataParallelTrainer
     from deeplearning4j_tpu.parallel.mesh import make_mesh
 
-    net = MultiLayerNetwork(program.build_conf(ctx.cfg), seed=ctx.seed & 0x7FFFFFFF)
+    net = MultiLayerNetwork(families.of(ctx.cfg).program.build_conf(ctx.cfg),
+                            seed=ctx.seed & 0x7FFFFFFF)
     net.params = program.program_weights(ctx.cfg, ctx.seed)
     return DataParallelTrainer(net, make_mesh({"dp": ctx.chips}), mode="sync")
 
@@ -121,12 +122,12 @@ def first_steps(ctx, trainer, stream) -> dict:
         loss, _ = fit(trainer, stream)
         out["losses"].append(float(loss))
         if step == 0:
-            moment = program.norms_of(trainer.state.updater.velocity)
+            moment = program.norms_of(ctx.cfg, trainer.state.updater.velocity)
             scale = 1.0 - reference.ADAM["beta1"]    # m1 = (1 - beta1) g1
             out["grad_norms"] = [n / scale for n in moment]
             out["grad_projections"] = [[x / scale for x in n]
                                        for n in program.projections_of(
-                ctx.seed, trainer.state.updater.velocity)]
+                ctx.cfg, ctx.seed, trainer.state.updater.velocity)]
     out["change_norms"] = program.change_norms(ctx.cfg, ctx.seed,
                                                trainer.state.params)
     stream.limit = None
@@ -138,7 +139,8 @@ def run(ctx) -> dict:
     import jax
 
     mix = ctx.mix
-    vocab = reference.sizes(ctx.cfg)["vocab"]
+    model = families.of(ctx.cfg).reference
+    vocab = model.sizes(ctx.cfg)["vocab"]
     rows, seq = int(mix["rows"]) * ctx.chips, int(mix["seq"])
     mix = dict(mix, rows=rows)
     stream = TokenStream(traffic.token_batches(mix, vocab, ctx.seed))
@@ -180,7 +182,7 @@ def run(ctx) -> dict:
     firsts = [(x, y.reshape(rows, seq)) for x, y in
               (next(batches) for _ in range(len(seen["losses"])))]
     t_ref = time.perf_counter()
-    ref = reference.first_steps(ctx.cfg, ctx.seed, firsts)
+    ref = model.first_steps(ctx.cfg, ctx.seed, firsts)
     numbers = correct.train_numbers(seen, ref)
     counters["reference_s"] = time.perf_counter() - t_ref
     return {
@@ -202,7 +204,8 @@ def readings(ctx, seeds, control_seeds) -> list:
 
     from deeplearning4j_tpu.parallel.data_parallel import init_train_state
 
-    vocab = reference.sizes(ctx.cfg)["vocab"]
+    model = families.of(ctx.cfg).reference
+    vocab = model.sizes(ctx.cfg)["vocab"]
     rows, seq = int(ctx.mix["rows"]) * ctx.chips, int(ctx.mix["seq"])
     mix = dict(ctx.mix, rows=rows)
     trainer, seen = None, {}
@@ -223,7 +226,7 @@ def readings(ctx, seeds, control_seeds) -> list:
         batches = traffic.token_batches(mix, vocab, seed)
         firsts = [(x, y.reshape(rows, seq)) for x, y in
                   (next(batches) for _ in range(len(seen[seed]["losses"])))]
-        ref = reference.first_steps(ctx.cfg, seed, firsts)
+        ref = model.first_steps(ctx.cfg, seed, firsts)
         rec = {"seed": seed, "program": correct.train_numbers(seen[seed], ref),
                "leaves": {"grad": correct.leaf_gaps(
                    seen[seed]["grad_norms"], ref["grad_norms"]).tolist(),
@@ -232,8 +235,8 @@ def readings(ctx, seeds, control_seeds) -> list:
                    "moving": correct.moving_leaves(ref["grad_norms"]).tolist()}}
         if seed in control_seeds:
             rec["control_int8"] = correct.train_numbers(
-                reference.first_steps(ctx.cfg, seed, firsts, precision="int8"), ref)
+                model.first_steps(ctx.cfg, seed, firsts, precision="int8"), ref)
             rec["fault_half_batch"] = correct.train_numbers(
-                reference.first_steps(ctx.cfg, seed, firsts, rows=rows // 2), ref)
+                model.first_steps(ctx.cfg, seed, firsts, rows=rows // 2), ref)
         out.append(rec)
     return out

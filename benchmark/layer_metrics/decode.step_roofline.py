@@ -1,8 +1,8 @@
 """The decode program taken as one kernel, against its roofline.  It is
-bound by bytes: every weight once in the compute type, and K and V of the
-live positions, over the chip's memory bandwidth, over the program's median
-device time."""
-from benchmark import flops
+bound by bytes: what the configuration's family says a step has to read
+(weights, and K and V of the live positions), over the chip's memory
+bandwidth, over the program's median device time."""
+from benchmark import families
 from benchmark.jobs.generate import traced_program_seconds
 
 
@@ -11,5 +11,5 @@ def read(seen):
     t = traced_program_seconds(seen, "decode")
     if t is None or not seen["peaks"] or c.get("traced_live_positions") is None:
         return None
-    need = flops.decode_step_bytes(seen["cfg"], c["traced_live_positions"])
+    need = families.of(seen["cfg"]).flops.decode_step_bytes(seen["cfg"], c)
     return 100.0 * need / seen["peaks"]["hbm_bytes_per_s"] / t

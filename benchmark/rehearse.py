@@ -48,14 +48,14 @@ def train(cell, topo) -> None:
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from benchmark import program
+    from benchmark import families
     from deeplearning4j_tpu.nn.multilayer import init_params
     from deeplearning4j_tpu.optimize.updater import init_updater
     from deeplearning4j_tpu.parallel.data_parallel import (TrainState,
                                                            make_dp_train_step)
 
     chips = int(cell.cell["chips"])
-    conf = program.build_conf(cell.cfg)
+    conf = families.of(cell.cfg).program.build_conf(cell.cfg)
     mesh = Mesh(topo.devices[:chips], ("dp",))
     rows, seq = int(cell.mix["rows"]) * chips, int(cell.mix["seq"])
 
@@ -77,11 +77,11 @@ def generate(cell, topo) -> None:
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from benchmark import program
+    from benchmark import families
     from deeplearning4j_tpu.nn import decode
     from deeplearning4j_tpu.nn.multilayer import init_params
 
-    conf = program.build_conf(cell.cfg)
+    conf = families.of(cell.cfg).program.build_conf(cell.cfg)
     srv = cell.mix["server"]
     one = SingleDeviceSharding(topo.devices[0])
     key = jax.random.PRNGKey(0)

@@ -5,8 +5,9 @@
 One process, which holds the chip.  It finds the cell in `BENCHMARK.json`,
 its configuration under `configs/`, its traffic under `traffic/`, the
 traffic's job under `jobs/`, its limits under `limits/` and each per-layer
-metric's reader under `layer_metrics/`, all by name.  Nothing that belongs
-to one cell lives here.
+metric's reader under `layer_metrics/`, all by name; the job and the readers
+find the model's family under `families/` by the configuration's
+`model_type`.  Nothing that belongs to one cell or one model lives here.
 """
 
 from __future__ import annotations

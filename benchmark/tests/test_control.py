@@ -9,7 +9,8 @@ import types
 import numpy as np
 import pytest
 
-from benchmark import correct, reference, traffic
+from benchmark import correct, traffic
+from benchmark.families.gpt2 import reference
 from benchmark.jobs import generate
 
 CFG = {"n_embd": 256, "n_head": 2, "n_inner": 1024, "n_layer": 2,
@@ -49,8 +50,8 @@ def test_half_the_batch_fails_the_training_cell(first_steps):
 
 #: the serving control needs the cell's width and half its depth to read as
 #: it does on the chip: rounding shows in the first token after many layers
-SERVE_CFG = {"n_embd": 2048, "n_head": 16, "n_inner": 8192, "n_layer": 12,
-             "n_positions": 128, "vocab_size": 16384}
+SERVE_CFG = {"model_type": "gpt2", "n_embd": 2048, "n_head": 16, "n_inner": 8192,
+             "n_layer": 12, "n_positions": 128, "vocab_size": 16384}
 
 
 def test_int8_tokens_fail_the_serving_cell():
