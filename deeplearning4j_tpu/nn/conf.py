@@ -51,6 +51,10 @@ class LayerType(str, enum.Enum):
     EMBEDDING = "embedding"
     ATTENTION = "attention"
     TRANSFORMER_FFN = "transformer_ffn"
+    KDA = "kda"                    # gated delta-rule linear attention
+    MLA = "mla"                    # multi-head latent attention
+    SWIGLU = "swiglu"              # gated (SiLU) FFN under an RMSNorm
+    MOE = "moe"                    # routed experts + one shared expert
 
     def __str__(self) -> str:
         return self.value
@@ -99,6 +103,83 @@ class Distribution:
         if self.kind == "binomial":
             return lambda key, shape: ndr.binomial(key, self.p, shape)
         raise ValueError(f"unknown distribution kind {self.kind}")
+
+
+# -- typed settings of the layer types that carry their own ------------------
+# One frozen object a layer type, under `NeuralNetConfiguration.layer_spec`:
+# a layer type's sizes live with it, not as flat fields every other layer
+# type ignores.  A conf without one serialises exactly as it did before
+# the field existed (`to_dict` leaves a None out), so its fingerprint and
+# every cache key made from it are unchanged.
+
+@dataclass(frozen=True)
+class KDASpec:
+    """LayerType.KDA (Kimi Delta Attention, arXiv:2510.26692): `n_heads`
+    heads whose keys and values are both `head_dim` wide, a depthwise causal
+    convolution of `conv_kernel` taps on q, k and v, and a per-channel decay
+    `exp(gate_lower_bound * sigmoid(.))`."""
+
+    n_heads: int
+    head_dim: int
+    conv_kernel: int = 4
+    gate_lower_bound: float = -5.0
+    eps: float = 1e-6
+
+
+@dataclass(frozen=True)
+class MLASpec:
+    """LayerType.MLA (arXiv:2405.04434): keys and values from one cached
+    latent of `kv_lora_rank` and one rotary key of `qk_rope_head_dim` shared
+    by all heads; no low-rank query."""
+
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+
+
+@dataclass(frozen=True)
+class SwiGLUSpec:
+    """LayerType.SWIGLU: `down(silu(gate x) * up x)` of width `hidden`."""
+
+    hidden: int
+    eps: float = 1e-6
+
+
+@dataclass(frozen=True)
+class MoESpec:
+    """LayerType.MOE: a sigmoid router over `n_routed` experts in `n_group`
+    groups (the best `topk_group` groups stay, then the `top_k` best
+    experts among them), of which this layer holds `n_held` from
+    `first_held` on and computes those picks alone; one shared expert of
+    `shared_hidden` is whole here."""
+
+    n_routed: int
+    n_held: int
+    hidden: int
+    shared_hidden: int
+    first_held: int = 0
+    top_k: int = 8
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    eps: float = 1e-6
+
+
+@dataclass(frozen=True)
+class HeadSpec:
+    """LayerType.OUTPUT as a language model's head: an RMSNorm, then a
+    matrix with no bias whose logits come out in float32 whatever type the
+    weights are kept in."""
+
+    eps: float = 1e-6
+
+
+LAYER_SPECS = {c.__name__: c for c in (KDASpec, MLASpec, SwiGLUSpec, MoESpec,
+                                       HeadSpec)}
 
 
 @dataclass(frozen=True)
@@ -205,6 +286,9 @@ class NeuralNetConfiguration:
     remat: bool = False             # jax.checkpoint this layer's forward:
                                     # recompute activations in backward,
                                     # trading FLOPs for HBM (big batches)
+    # the layer type's own typed settings (one of LAYER_SPECS), for the
+    # layer types that have them; None everywhere else
+    layer_spec: Optional[Any] = None
 
     def replace(self, **kwargs) -> "NeuralNetConfiguration":
         return dataclasses.replace(self, **kwargs)
@@ -217,6 +301,11 @@ class NeuralNetConfiguration:
                 d[k] = v.value
         if d.get("dist") is not None and isinstance(self.dist, Distribution):
             d["dist"] = dataclasses.asdict(self.dist)
+        if self.layer_spec is None:
+            del d["layer_spec"]     # as before the field existed
+        else:
+            d["layer_spec"] = {"kind": type(self.layer_spec).__name__,
+                               **dataclasses.asdict(self.layer_spec)}
         return d
 
     def to_json(self) -> str:
@@ -240,6 +329,9 @@ class NeuralNetConfiguration:
                 d[k] = e(d[k])
         if d.get("dist") is not None:
             d["dist"] = Distribution(**d["dist"])
+        if d.get("layer_spec") is not None:
+            spec = dict(d["layer_spec"])
+            d["layer_spec"] = LAYER_SPECS[spec.pop("kind")](**spec)
         for k in ("momentum_after",):
             if k in d and d[k] is not None:
                 d[k] = tuple(tuple(x) for x in d[k])

@@ -23,7 +23,18 @@ wrappers (key schema, donation, sampling) live in
 State layout: one dict per layer, in layer order, as a tuple —
   LSTM/GRAVES_LSTM  {"h": [B, H] f32, "c": [B, H] f32}
   ATTENTION         {"k": [B, max_S, n] compute_dtype, "v": same}
+  KDA               {"S": [B, H, dk, dv] f32, "conv": [B, K-1, 3 H dk]}
+  MLA               {"c": [B, max_S, rank], "kr": [B, max_S, rope]}
   everything else   {}
+A layer type that keeps a state says what it is: its class has
+`init_state(conf, batch, max_seq)`, `prefill(params, conf, x, state,
+length)` and `decode_step(params, conf, x, state, pos)`, both returning
+(hidden, state), and `CARRY`, whether the state advances with every token
+(so that `decode_block` must hold a finished row's still) or is a table
+written at `pos`.  LSTM's (h, c) and attention's K/V are the two kinds that
+were here first; `zero_row` and `write_row` treat every kind alike, leaf by
+leaf along axis 0.  Such a state lives in the dense table only: the paged
+pool and the verify chunk refuse it (`dense_only`).
 The tuple-of-dicts shape makes the whole state one donatable jit
 argument whose leaves keep their shapes/dtypes across steps, so the
 compiled step can alias its cache buffers in place.
@@ -66,9 +77,15 @@ from deeplearning4j_tpu.utils.profiling import layer_scope, scope
 
 #: hidden layer types the decode path knows how to step one token at a time
 GENERATIVE_HIDDEN = (LayerType.LSTM, LayerType.GRAVES_LSTM,
-                     LayerType.ATTENTION, LayerType.TRANSFORMER_FFN)
+                     LayerType.ATTENTION, LayerType.TRANSFORMER_FFN,
+                     LayerType.KDA, LayerType.MLA, LayerType.SWIGLU,
+                     LayerType.MOE)
 
 _RECURRENT = (LayerType.LSTM, LayerType.GRAVES_LSTM)
+
+#: no state: one token's row goes through `forward` as a sequence's would
+#: (an MOE layer's `apply` also counts its picks, which `_step` keeps)
+_STATELESS = (LayerType.TRANSFORMER_FFN, LayerType.SWIGLU, LayerType.MOE)
 
 #: token emitted by `decode_block` for scan steps a row sat frozen
 #: (its `rem` budget exhausted mid-block) — never a valid token id
@@ -119,6 +136,38 @@ def positional_bound(conf: MultiLayerConfiguration) -> int:
     return 0
 
 
+def _own_state(t) -> bool:
+    """Does layer type `t` say itself what its decode state is?"""
+    return hasattr(get_layer(t), "init_state")
+
+
+def _is_carry(t) -> bool:
+    return t in _RECURRENT or getattr(get_layer(t), "CARRY", False)
+
+
+def dense_only(conf: MultiLayerConfiguration):
+    """The layer types of `conf` whose state lives in the dense slot table
+    alone, as strings ([] when there is none): the paged pool holds K/V
+    pages and nothing else, a cached prefix row would have to carry a
+    recurrent state that is only right at the prompt's end, and a verify
+    chunk cannot roll such a state back."""
+    return sorted({str(t) for t in check_generative(conf) if _own_state(t)})
+
+
+def has_experts(conf: MultiLayerConfiguration) -> bool:
+    """Does a decode step of `conf` count expert picks (an MOE layer)?"""
+    return any(LayerType(str(c.layer_type)) == LayerType.MOE
+               for c in conf.confs)
+
+
+def _refuse_dense_only(conf, what: str) -> None:
+    kinds = dense_only(conf)
+    if kinds:
+        raise ValueError(
+            f"{what} cannot hold the state of layer types {kinds}: it lives "
+            f"in the dense slot table only")
+
+
 def init_state(conf: MultiLayerConfiguration, batch: int, max_seq: int):
     """Fresh decode state for `batch` rows and a `max_seq`-token table."""
     types = check_generative(conf)
@@ -139,6 +188,8 @@ def init_state(conf: MultiLayerConfiguration, batch: int, max_seq: int):
             cd = compute_dtype(c)
             state.append({"k": jnp.zeros((batch, max_seq, c.n_in), cd),
                           "v": jnp.zeros((batch, max_seq, c.n_in), cd)})
+        elif _own_state(t):
+            state.append(get_layer(t).init_state(c, batch, max_seq))
         else:
             state.append({})
     return tuple(state)
@@ -152,6 +203,7 @@ def init_paged_state(conf: MultiLayerConfiguration, batch: int,
     per-call page table — memory scales with pages, not
     batch x max_seq."""
     types = check_generative(conf)
+    _refuse_dense_only(conf, "a paged K/V pool")
     state = []
     for i, t in enumerate(types):
         c = conf.conf(i)
@@ -216,14 +268,16 @@ def _head_logp(conf: MultiLayerConfiguration, params, x):
         return jnp.log(jnp.clip(probs, 1e-9, 1.0))
 
 
-def decode_step(conf: MultiLayerConfiguration, params, state, tok, pos):
-    """Advance every row one token: tok [B] int32 (the row's current
-    token), pos [B] int32 (the sequence position that token occupies).
-    Returns (logp [B, vocab] — log(clip(probs)) for the NEXT token —
-    and the updated state tuple)."""
+def _step(conf: MultiLayerConfiguration, params, state, tok, pos,
+          page_table=None):
+    """One token a row through every layer: (logp, state, counts).  With a
+    `page_table`, ATTENTION reads and writes the shared page pool.  `counts`
+    is None unless the stack has MOE layers, else their `[picks on held
+    experts, distinct held experts hit]` summed over the layers."""
     types = check_generative(conf)
     x = token_embed(conf, params, tok, pos)
     new_state = []
+    counts = None
     for i, t in enumerate(types[:-1]):
         c = conf.conf(i)
         impl = get_layer(c.layer_type)
@@ -234,16 +288,44 @@ def decode_step(conf: MultiLayerConfiguration, params, state, tok, pos):
                 new_state.append({"h": h, "c": cc})
                 x = h
             elif t == LayerType.ATTENTION:
-                x, kc, vc = impl.decode_step(params[i], c, x, state[i]["k"],
-                                             state[i]["v"], pos)
+                if page_table is None:
+                    x, kc, vc = impl.decode_step(
+                        params[i], c, x, state[i]["k"], state[i]["v"], pos)
+                else:
+                    x, kc, vc = impl.decode_step_paged(
+                        params[i], c, x, state[i]["k"], state[i]["v"], pos,
+                        page_table)
                 new_state.append({"k": kc, "v": vc})
-            elif t == LayerType.TRANSFORMER_FFN:
+            elif _own_state(t):
+                x, st = impl.decode_step(params[i], c, x, state[i], pos)
+                new_state.append(st)
+            elif t == LayerType.MOE:
+                x, n = impl.apply(params[i], c, x)
+                counts = n if counts is None else counts + n
+                new_state.append({})
+            elif t in _STATELESS:
                 x = impl.forward(params[i], c, x)
                 new_state.append({})
             else:  # EMBEDDING — consumed by token_embed above
                 new_state.append({})
     new_state.append({})
-    return _head_logp(conf, params, x), tuple(new_state)
+    return _head_logp(conf, params, x), tuple(new_state), counts
+
+
+def decode_step(conf: MultiLayerConfiguration, params, state, tok, pos):
+    """Advance every row one token: tok [B] int32 (the row's current
+    token), pos [B] int32 (the sequence position that token occupies).
+    Returns (logp [B, vocab] — log(clip(probs)) for the NEXT token —
+    and the updated state tuple)."""
+    logp, state, _ = _step(conf, params, state, tok, pos)
+    return logp, state
+
+
+def decode_step_counted(conf: MultiLayerConfiguration, params, state, tok,
+                        pos):
+    """`decode_step` with the expert layers' counts of the step beside it:
+    (logp, state, counts [2] int32, or None where `has_experts` is false)."""
+    return _step(conf, params, state, tok, pos)
 
 
 def decode_step_paged(conf: MultiLayerConfiguration, params, state, tok,
@@ -252,30 +334,8 @@ def decode_step_paged(conf: MultiLayerConfiguration, params, state, tok,
     [B, pages_per_slot] int32 routes each row's cache reads/writes
     through the shared physical pool.  Token-identical to the dense
     step (see layers/attention.py:decode_step_paged)."""
-    types = check_generative(conf)
-    x = token_embed(conf, params, tok, pos)
-    new_state = []
-    for i, t in enumerate(types[:-1]):
-        c = conf.conf(i)
-        impl = get_layer(c.layer_type)
-        with layer_scope(i, c):
-            if t in _RECURRENT:
-                h, cc = impl.step(params[i], c, x, state[i]["h"],
-                                  state[i]["c"])
-                new_state.append({"h": h, "c": cc})
-                x = h
-            elif t == LayerType.ATTENTION:
-                x, kc, vc = impl.decode_step_paged(
-                    params[i], c, x, state[i]["k"], state[i]["v"], pos,
-                    page_table)
-                new_state.append({"k": kc, "v": vc})
-            elif t == LayerType.TRANSFORMER_FFN:
-                x = impl.forward(params[i], c, x)
-                new_state.append({})
-            else:  # EMBEDDING
-                new_state.append({})
-    new_state.append({})
-    return _head_logp(conf, params, x), tuple(new_state)
+    logp, state, _ = _step(conf, params, state, tok, pos, page_table)
+    return logp, state
 
 
 def decode_block(conf: MultiLayerConfiguration, params, state, tok, pos,
@@ -305,26 +365,26 @@ def decode_block(conf: MultiLayerConfiguration, params, state, tok, pos,
 
     Returns (toks [k, B] int32 scan outputs, tok [B] (last real token
     per row), keys [B, 2], state) — state LAST, the donation/TP
-    contract every decode-family program shares."""
+    contract every decode-family program shares.  Where the stack has MOE
+    layers (`has_experts`), their counts summed over the k steps, [2]
+    int32, come before the state."""
     types = check_generative(conf)
+
+    def hold(active, new, old):
+        """`new` for the rows still going, `old` for the finished ones."""
+        return jnp.where(active.reshape((-1,) + (1,) * (new.ndim - 1)),
+                         new, old)
 
     def body(carry, _):
         st, t, p, ks, r = carry
         active = r > 0
-        if page_table is None:
-            logp, st2 = decode_step(conf, params, st, t, p)
-        else:
-            logp, st2 = decode_step_paged(conf, params, st, t, p,
-                                          page_table)
+        logp, st2, counts = _step(conf, params, st, t, p, page_table)
         t2, ks2 = sample(logp, ks, temps)
         frozen = []
         for i, lt in enumerate(types):
-            if lt in _RECURRENT:
-                frozen.append(
-                    {"h": jnp.where(active[:, None], st2[i]["h"],
-                                    st[i]["h"]),
-                     "c": jnp.where(active[:, None], st2[i]["c"],
-                                    st[i]["c"])})
+            if _is_carry(lt):
+                frozen.append({name: hold(active, st2[i][name], st[i][name])
+                               for name in st2[i]})
             else:
                 frozen.append(st2[i])
         out = jnp.where(active, t2, jnp.int32(BLOCK_SENTINEL))
@@ -332,12 +392,16 @@ def decode_block(conf: MultiLayerConfiguration, params, state, tok, pos,
         ks3 = jnp.where(active[:, None], ks2, ks)
         p3 = jnp.where(active, p + 1, p)
         r3 = jnp.where(active, r - 1, r)
-        return (tuple(frozen), t3, p3, ks3, r3), out
+        return (tuple(frozen), t3, p3, ks3, r3), (
+            out if counts is None else (out, counts))
 
-    carry, toks = jax.lax.scan(
+    carry, outs = jax.lax.scan(
         body, (state, tok, pos, keys, rem), xs=None, length=int(k))
     state, tok, _, keys, _ = carry
-    return toks, tok, keys, state
+    if has_experts(conf):
+        toks, counts = outs
+        return toks, tok, keys, jnp.sum(counts, axis=0), state
+    return outs, tok, keys, state
 
 
 def _verify_chunk_impl(conf, params, state, toks, pos, page_table):
@@ -358,6 +422,7 @@ def _verify_chunk_impl(conf, params, state, toks, pos, page_table):
     e < K tokens.
     """
     types = check_generative(conf)
+    _refuse_dense_only(conf, "a verify chunk")
     b, kk = toks.shape
     idx = pos[:, None] + jnp.arange(kk)[None, :]
     x = token_embed(conf, params, toks, idx)  # [B, K, n]
@@ -389,7 +454,7 @@ def _verify_chunk_impl(conf, params, state, toks, pos, page_table):
                         page_table)
                 new_state.append({"k": kc, "v": vc})
                 carries.append({})
-            elif t == LayerType.TRANSFORMER_FFN:
+            elif t in _STATELESS:
                 x = impl.forward(params[i], c, x)
                 new_state.append({})
                 carries.append({})
@@ -422,9 +487,9 @@ def prefill(conf: MultiLayerConfiguration, params, state, prompt, length):
     what the first generated token samples from — and the filled state).
 
     Padding is inert by construction: LSTM carries freeze at
-    t >= length, attention's causal mask hides later positions from
-    every real one, and `decode_step` overwrites cache position `pos`
-    before attending to it."""
+    t >= length (as a KDA layer's state does), attention's causal mask
+    hides later positions from every real one, and `decode_step`
+    overwrites cache position `pos` before attending to it."""
     types = check_generative(conf)
     c0 = conf.conf(0)
     if types[0] == LayerType.EMBEDDING:
@@ -446,7 +511,10 @@ def prefill(conf: MultiLayerConfiguration, params, state, prompt, length):
                 x, kc, vc = impl.prefill(params[i], c, x, state[i]["k"],
                                          state[i]["v"])
                 new_state.append({"k": kc, "v": vc})
-            elif t == LayerType.TRANSFORMER_FFN:
+            elif _own_state(t):
+                x, st = impl.prefill(params[i], c, x, state[i], length)
+                new_state.append(st)
+            elif t in _STATELESS:
                 x = impl.forward(params[i], c, x)
                 new_state.append({})
             else:  # EMBEDDING

@@ -13,7 +13,7 @@ replacing the reference's `Model.gradientAndScore` contract
 
 from deeplearning4j_tpu.nn.conf import LayerType
 from deeplearning4j_tpu.nn.layers import (base, output, autoencoder, rbm, lstm,
-                                          conv, attention)
+                                          conv, attention, experts, kda, mla)
 
 _REGISTRY = {
     LayerType.DENSE: base.DenseLayer,
@@ -31,6 +31,10 @@ _REGISTRY = {
     LayerType.EMBEDDING: base.EmbeddingLayer,
     LayerType.ATTENTION: attention.MultiHeadAttentionLayer,
     LayerType.TRANSFORMER_FFN: attention.TransformerFFNLayer,
+    LayerType.KDA: kda.KDALayer,
+    LayerType.MLA: mla.MLALayer,
+    LayerType.SWIGLU: experts.SwiGLULayer,
+    LayerType.MOE: experts.MoELayer,
 }
 
 

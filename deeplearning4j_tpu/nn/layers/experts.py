@@ -1,0 +1,150 @@
+"""The gated FFNs: `SwiGLULayer` (LayerType.SWIGLU, `SwiGLUSpec`) and
+`MoELayer` (LayerType.MOE, `MoESpec`), both residual over [.., n_in] under
+an RMSNorm.
+
+The expert layer is told which experts it holds.  Its router scores all
+`n_routed` published experts (sigmoid, float32), picks `top_k` of them a
+token from the best `topk_group` of `n_group` groups (a group scores the
+sum of its two best; a learned bias enters the choice and not the weights),
+and weighs the picks by their scores, normalised over all `top_k` and
+scaled.  The layer then computes the picks that landed on its own `n_held`
+experts, `[first_held, first_held + n_held)`, adds its shared expert, and
+leaves out what the absent experts would add: on one chip of an
+expert-parallel group this is that chip's part of the layer, without the
+exchange.
+
+Dropless: the picks are sorted by expert and every one of a held expert is
+computed by `jax.lax.ragged_dot` over the sorted rows; there is no capacity
+and no token is dropped, at 1 row or at 1024.  The picks of absent experts
+sort to the end and fall outside every group.  As this layer holds a
+fraction of the experts, its picks usually fit a fraction of the rows: when
+they fit the first 3/8 the products run over those alone, otherwise over
+all of them (`jax.lax.cond`; the result is the same either way).
+
+`MoELayer.apply` also returns two counts of the call, `[picks that landed
+on held experts, distinct held experts hit]`, which the decode programs hand
+to the batcher.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.nn.layers.base import compute_dtype
+from deeplearning4j_tpu.nn.layers.rms import (F32, initializer, pre_norm,
+                                              swiglu)
+from deeplearning4j_tpu.utils.profiling import scope
+
+
+class SwiGLULayer:
+    @staticmethod
+    def init(key, conf):
+        s = conf.layer_spec
+        d, n = jnp.dtype(conf.dtype), conf.n_in
+        k1, k2 = jax.random.split(key)
+        w = initializer(conf)
+        return {"ln": jnp.ones((n,), d), "Wgu": w(k1, (n, 2 * s.hidden)),
+                "Wd": w(k2, (s.hidden, n))}
+
+    @staticmethod
+    def forward(params, conf, x, key=None, training=False):
+        u = pre_norm(params, x, conf.layer_spec.eps)
+        with scope("ffn"):
+            out = swiglu(u, params["Wgu"], params["Wd"], compute_dtype(conf))
+        return x.astype(F32) + out
+
+
+def route(scores, bias, spec):
+    """scores [R, n_routed] float32 -> (ids [R, top_k] int32, weights
+    [R, top_k] float32): group-limited top-k on `scores + bias`, weights
+    from `scores` alone."""
+    r, n = scores.shape
+    sp = (scores + bias).reshape(r, spec.n_group, n // spec.n_group)
+    group = jnp.sum(jax.lax.top_k(sp, 2)[0], axis=-1)
+    kept = jax.lax.top_k(group, spec.topk_group)[1]
+    mask = jnp.sum(jax.nn.one_hot(kept, spec.n_group, dtype=jnp.int32), axis=1) > 0
+    ids = jax.lax.top_k(jnp.where(mask[..., None], sp, -jnp.inf).reshape(r, n),
+                        spec.top_k)[1]
+    picked = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = spec.routed_scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), weights
+
+
+def held_experts(params, spec, cd, u, ids, weights):
+    """What the held experts give for rows u [R, n]: (y [R, n] float32,
+    counts [2] int32).  Every pick of a held expert is computed."""
+    r, n = u.shape
+    picks = r * spec.top_k
+    with scope("dispatch"):
+        local = ids.reshape(picks) - spec.first_held
+        held = (local >= 0) & (local < spec.n_held)
+        group = jnp.where(held, local, spec.n_held)         # the absent sort last
+        order = jnp.argsort(group, stable=True)
+        place = jnp.zeros((picks,), jnp.int32).at[order].set(
+            jnp.arange(picks, dtype=jnp.int32))             # a pick's sorted row
+        sizes = jnp.zeros((spec.n_held + 1,), jnp.int32).at[group].add(1)[:-1]
+        here = jnp.sum(sizes)
+
+    def over(m: int):
+        """The products over the first `m` sorted rows, back in pick order."""
+        with scope("dispatch"):
+            rows = u[order[:m] // spec.top_k].astype(cd)
+        with scope("experts"):
+            h = jax.lax.ragged_dot(rows, params["Wgu"].astype(cd), sizes,
+                                   preferred_element_type=F32)
+            f = h.shape[-1] // 2
+            a = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(cd)
+            y = jax.lax.ragged_dot(a, params["Wd"].astype(cd), sizes,
+                                   preferred_element_type=F32)
+        with scope("combine"):
+            # rows outside every group are not defined: select, do not scale
+            mine = jnp.where((held & (place < m))[:, None],
+                             y[jnp.minimum(place, m - 1)], 0.0)
+            return jnp.sum(mine.reshape(r, spec.top_k, n)
+                           * weights[..., None], axis=1)
+
+    few = (3 * picks // 8) // 8 * 8
+    if few >= 64:
+        y = jax.lax.cond(here <= few, lambda: over(few), lambda: over(picks))
+    else:
+        y = over(picks)
+    return y, jnp.stack([here, jnp.sum(sizes > 0)]).astype(jnp.int32)
+
+
+class MoELayer:
+    @staticmethod
+    def init(key, conf):
+        s = conf.layer_spec
+        d, n = jnp.dtype(conf.dtype), conf.n_in
+        ks = jax.random.split(key, 5)
+        w = initializer(conf)
+        return {
+            "ln": jnp.ones((n,), d),
+            "Wr": w(ks[0], (n, s.n_routed)),
+            "rb": jnp.zeros((s.n_routed,), d),
+            "Wgu": w(ks[1], (s.n_held, n, 2 * s.hidden)),
+            "Wd": w(ks[2], (s.n_held, s.hidden, n)),
+            "sWgu": w(ks[3], (n, 2 * s.shared_hidden)),
+            "sWd": w(ks[4], (s.shared_hidden, n)),
+        }
+
+    @staticmethod
+    def apply(params, conf, x):
+        """x [.., n] -> (hidden [.., n], counts [2] int32)."""
+        s, cd = conf.layer_spec, compute_dtype(conf)
+        u = pre_norm(params, x, s.eps)
+        rows = u.reshape(-1, u.shape[-1])
+        with scope("router"):
+            scores = jax.nn.sigmoid(jnp.matmul(
+                rows, params["Wr"].astype(F32),
+                precision=jax.lax.Precision.HIGHEST))
+            ids, weights = route(scores, params["rb"].astype(F32), s)
+        y, counts = held_experts(params, s, cd, rows, ids, weights)
+        with scope("shared"):
+            y = y + swiglu(rows, params["sWgu"], params["sWd"], cd)
+        return x.astype(F32) + y.reshape(u.shape), counts
+
+    @staticmethod
+    def forward(params, conf, x, key=None, training=False):
+        return MoELayer.apply(params, conf, x)[0]

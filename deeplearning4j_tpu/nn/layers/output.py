@@ -13,15 +13,36 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nd import losses as L
 from deeplearning4j_tpu.nd.ops import activate
-from deeplearning4j_tpu.nn.layers.base import DenseLayer
+from deeplearning4j_tpu.nn.layers.base import DenseLayer, compute_dtype
+from deeplearning4j_tpu.nn.layers.rms import mm, rms_norm
 from deeplearning4j_tpu.utils.profiling import scope
 
 
 class OutputLayer(DenseLayer):
     @staticmethod
+    def init(key, conf):
+        params = DenseLayer.init(key, conf)
+        if conf.layer_spec is None:
+            return params
+        # a language model's head (`HeadSpec`): its norm, a matrix, no bias
+        return {"W": params["W"],
+                "norm": jnp.ones((conf.n_in,), params["W"].dtype)}
+
+    @staticmethod
     def forward(params, conf, x, key=None, training=False):
+        if conf.layer_spec is not None:
+            return OutputLayer._lm_head(params, conf, x)
         with scope("head"):
             return OutputLayer._head(params, conf, x, key, training)
+
+    @staticmethod
+    def _lm_head(params, conf, x):
+        """Under a `HeadSpec`: RMSNorm, then logits in float32 from weights
+        of any type, then softmax."""
+        with scope("ln"):
+            x = rms_norm(x, params["norm"], conf.layer_spec.eps)
+        with scope("head"):
+            return activate("softmax", mm(x, params["W"], compute_dtype(conf)))
 
     @staticmethod
     def _head(params, conf, x, key, training):

@@ -1,0 +1,76 @@
+"""What the RMSNorm-family layer types share (`kda`, `mla`, `swiglu`, `moe`,
+and the OUTPUT layer under a `HeadSpec`): the norms, rotary positions, and
+the one matrix product they all take.
+
+Their precision contract: parameters are kept in `conf.dtype`, matrix
+products take operands in `compute_dtype(conf)` and accumulate in float32,
+and everything between the products (the residual stream, norms, softmax,
+router scores, the KDA state) is float32.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.nn.weights import init_weights
+from deeplearning4j_tpu.utils.profiling import scope
+
+F32 = jnp.float32
+
+
+def initializer(conf):
+    """`w(key, shape)`: a leaf in `conf.dtype` by the conf's weight init."""
+    dist = conf.dist.sampler() if conf.dist is not None else None
+    dtype = jnp.dtype(conf.dtype)
+    return lambda key, shape: init_weights(key, shape, conf.weight_init, dist,
+                                           dtype)
+
+
+def precision_of(cd):
+    """float32 operands ask for float32 products (the TPU's default would
+    round them to bfloat16); narrower operands take the MXU's own."""
+    return jax.lax.Precision.HIGHEST if jnp.dtype(cd) == F32 else None
+
+
+def mm(x, w, cd):
+    """x @ w with operands in `cd`, accumulated and returned in float32."""
+    return jnp.matmul(x.astype(cd), w.astype(cd), precision=precision_of(cd),
+                      preferred_element_type=F32)
+
+
+def rms_norm(x, g, eps: float):
+    x = x.astype(F32)
+    return (x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                              + eps) * g.astype(F32))
+
+
+def pre_norm(params, x, eps: float):
+    """The block's input norm, under the scope `ln`."""
+    with scope("ln"):
+        return rms_norm(x, params["ln"], eps)
+
+
+def l2norm(x, eps: float = 1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def rope(x, positions, theta: float):
+    """Rotate-half rotary embedding over the whole last axis of `x`;
+    `positions` broadcasts against x's leading axes (x [..., n], positions
+    [...])."""
+    n = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, n, 2, dtype=F32) / n)
+    ang = positions.astype(F32)[..., None] * inv
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+    half = jnp.concatenate([-x[..., n // 2:], x[..., : n // 2]], axis=-1)
+    return x * cos + half * sin
+
+
+def swiglu(u, w_gate_up, w_down, cd):
+    """down(silu(gate u) * up u), gate and up being the two halves of one
+    matrix's columns."""
+    h = mm(u, w_gate_up, cd)
+    f = h.shape[-1] // 2
+    return mm(jax.nn.silu(h[..., :f]) * h[..., f:], w_down, cd)

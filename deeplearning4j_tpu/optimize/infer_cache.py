@@ -463,7 +463,10 @@ class InferCache(CompiledProgramCache):
         tok/pos [B] int32, keys [B, 2] uint32 per-row PRNG keys, temps
         [B] f32 (<= 0 rows decode greedily).  Returns (next_tok [B]
         int32, advanced keys, new state); the state argument is donated
-        off-CPU.  Under a 1-D (or no) mesh generation is single-chip and
+        off-CPU.  A stack with expert layers (`nn.decode.has_experts`)
+        returns their counts of the step, [2] int32, before the state (as
+        `decode_multi` does, summed over its steps).  Under a 1-D (or no)
+        mesh generation is single-chip and
         the key carries the SINGLE tag exactly as before; a plan with a
         `model` axis re-keys the program by its sharding tag and shards
         params + KV state per the plan."""
@@ -916,12 +919,14 @@ def _decode_program(conf, policy: str = "f32") -> Callable:
     pconf = _policy_conf(conf, policy)
 
     def program(params, state, tok, pos, keys, temps):
-        logp, state = decode_mod.decode_step(
+        logp, state, counts = decode_mod.decode_step_counted(
             pconf, _policy_args(params, policy), state, tok, pos)
         if policy != "f32":
             logp = logp.astype(jnp.float32)
         tok2, keys2 = _sample_tokens(logp, keys, temps)
-        return tok2, keys2, state
+        if counts is None:
+            return tok2, keys2, state
+        return tok2, keys2, counts, state   # a stack with expert layers
 
     return program
 

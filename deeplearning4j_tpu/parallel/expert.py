@@ -1,5 +1,10 @@
 """Expert parallelism: Switch-style top-1 mixture-of-experts FFN.
 
+Not the supported expert layer: that is `nn/layers/experts.MoELayer`
+(LayerType.MOE), a layer type of the conf that is told which experts it
+holds.  This side module waits for the `simplicity` PR that folds or removes
+it (ROADMAP.md, Design 6).
+
 New-scope capability (no MoE anywhere in the 2015 reference — SURVEY.md §2
 parallelism census lists EP as absent): the TPU-native expert-parallel
 design.  Experts are sharded over an `ep` mesh axis; tokens are routed

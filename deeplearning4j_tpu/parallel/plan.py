@@ -72,6 +72,11 @@ ROW_SPLIT_NAMES = frozenset({"Wo", "W2"})
 #: over `model`: attention K/V tables (dense [B,S,n] and paged
 #: [pages,page,n]) split by head; recurrent carries split their hidden
 STATE_SPLIT_NAMES = frozenset({"k", "v", "h", "c"})
+#: Every other leaf is whole on every chip: a KDA layer's `S` and `conv`,
+#: an MLA layer's `kr`, and its latent, which is also called `c` but has a
+#: position axis ([B, max_S, rank]; an LSTM's is [B, H]): one latent serves
+#: all heads, so it cannot be split by head, and the plan has no expert or
+#: data axis for decode state to lie along yet
 
 
 def parse_mesh_spec(spec: str) -> Dict[str, int]:
@@ -293,7 +298,7 @@ class ShardPlan:
         m = self.model_size
         nd = len(shape)
         if (m <= 1 or nd < 2 or name not in STATE_SPLIT_NAMES
-                or shape[-1] % m):
+                or shape[-1] % m or (name == "c" and nd == 3)):
             return P()
         return P(*((None,) * (nd - 1) + (self.model_axis,)))
 

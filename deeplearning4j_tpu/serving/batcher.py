@@ -771,6 +771,21 @@ class ContinuousBatcher:
         self.continuous = bool(continuous)
         self._auto_start = auto_start
         self._layer_types = decode_mod.check_generative(net.conf)
+        # a state a layer type defines itself (KDA's matrix, MLA's latents)
+        # lives in the dense slot table only
+        dense_only = decode_mod.dense_only(net.conf)
+        asked = [name for name, on in (
+            ("page_size", int(page_size) > 0),
+            ("prefix_cache", prefix_cache),
+            ("spec_k", draft_net is not None)) if on]
+        if dense_only and asked:
+            raise ValueError(
+                f"{asked} cannot serve layer types {dense_only} yet: their "
+                f"state is a recurrent matrix or a latent cache a slot, which "
+                f"the paged pool has no pages for, a cached prefix row is "
+                f"right for only at the prompt's end, and a verify chunk "
+                f"cannot roll back")
+        self._has_experts = decode_mod.has_experts(net.conf)
         # silent positional-table overrun fix: `token_embed` gathers
         # P[pos] with no bound check, and jit CLAMPS out-of-range
         # gathers — a stream decoding past the learned table would read
@@ -899,6 +914,9 @@ class ContinuousBatcher:
         self._queue_wait_s = 0.0
         self._blk_hist = {"counts": [0] * len(DECODE_BLOCK_STEPS_BOUNDS),
                             "inf": 0, "sum": 0.0, "count": 0}
+        # what the expert layers counted, summed over steps and layers
+        self._expert_picks = 0
+        self._experts_hit = 0
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> "ContinuousBatcher":
@@ -1446,6 +1464,7 @@ class ContinuousBatcher:
             return
         ic = self.net.infer_cache
         with span("decode.dispatch"):
+            counts = []     # a stack with expert layers: their [picks, hit]
             if self.paged:
                 self._lazy_alloc(1)
                 if not any(s is not None for s in self._slots):
@@ -1455,7 +1474,7 @@ class ContinuousBatcher:
                     self._tok.copy(), self._pos.copy(), self._keys.copy(),
                     self._temps.copy(), self._page_table.copy())
             else:
-                tok2, keys2, self._state = ic.decode(
+                tok2, keys2, *counts, self._state = ic.decode(
                     self.net.conf, self.net.params, self._state,
                     self._tok.copy(), self._pos.copy(), self._keys.copy(),
                     self._temps.copy())
@@ -1472,7 +1491,8 @@ class ContinuousBatcher:
         # ONE batched device->host transfer for the (tokens, keys) pair
         # instead of two blocking np.asarray round-trips (ISSUE 19)
         with span("decode.readback") as readback:
-            tok2, keys2 = jax.device_get((tok2, keys2))
+            tok2, keys2, counts = jax.device_get((tok2, keys2, counts))
+        self._note_experts(sp, counts, 1)
         now = time.monotonic()
         emitted = 0
         with span("decode.deliver"):
@@ -1501,6 +1521,22 @@ class ContinuousBatcher:
                     self._release_slot(slot, stream)
         self._note_block(1, time.monotonic() - t0, readback.seconds, emitted,
                          now)
+
+    def _note_experts(self, sp: span, counts, steps: int) -> None:
+        """What the expert layers counted over the `steps` table steps one
+        dispatch made, `[[picks that landed on held experts, distinct held
+        experts hit]]` summed over the layers (and empty for a stack
+        without them): added to the open `decode` span's `picks_here`,
+        `experts_hit` and `steps`, and to the totals of `stats()`."""
+        if not counts:
+            return
+        picks, hit = (int(n) for n in counts[0])
+        sp.set(picks_here=sp.attrs.get("picks_here", 0) + picks,
+               experts_hit=sp.attrs.get("experts_hit", 0) + hit,
+               steps=sp.attrs.get("steps", 0) + steps)
+        with self._cv:
+            self._expert_picks += picks
+            self._experts_hit += hit
 
     def _note_block(self, k: int, wall: float, wait: float, emitted: int,
                     now: float) -> None:
@@ -1690,7 +1726,7 @@ class ContinuousBatcher:
         t_mark = time.monotonic()
         live = sum(1 for st in streams if st is not None)
         # one `decode` span over the pipelined rounds
-        with span("decode", k=self.k_max, live=live):
+        with span("decode", k=self.k_max, live=live) as sp:
             while True:
                 blk = None
                 if int(rem.max(initial=0)) > 0 and not self._has_pending():
@@ -1699,7 +1735,7 @@ class ContinuousBatcher:
                     if blk is not None:
                         tok, keys = blk["tok"], blk["keys"]
                 if inflight is not None:
-                    t_mark = self._readback_block(inflight, t_mark)
+                    t_mark = self._readback_block(inflight, t_mark, sp)
                 inflight = blk
                 if blk is None:
                     return
@@ -1725,6 +1761,7 @@ class ContinuousBatcher:
                 rem[s] = 0
         if int(rem.max(initial=0)) <= 0:
             return None
+        counts = []
         if self.paged:
             self._lazy_alloc(k, pos=pos, steps=np.minimum(rem, k))
             for s, stream in enumerate(streams):
@@ -1739,7 +1776,7 @@ class ContinuousBatcher:
                     self._page_table.copy(), k)
         else:
             with span("decode.dispatch"):
-                toks, tok2, keys2, self._state = ic.decode_multi(
+                toks, tok2, keys2, *counts, self._state = ic.decode_multi(
                     self.net.conf, self.net.params, self._state, tok,
                     pos.copy(), keys, self._temps.copy(), rem.copy(), k)
         adv = np.minimum(rem, k).astype(np.int32)
@@ -1747,18 +1784,21 @@ class ContinuousBatcher:
         rem -= adv
         self._ramp = min(self._ramp * 2, self.k_max)
         return {"k": k, "streams": streams, "toks": toks, "tok": tok2,
-                "keys": keys2, "adv": adv, "pos_after": pos.copy()}
+                "keys": keys2, "adv": adv, "pos_after": pos.copy(),
+                "counts": counts}
 
-    def _readback_block(self, blk, t_mark: float) -> float:
+    def _readback_block(self, blk, t_mark: float, sp: span) -> float:
         """Read back ONE in-flight block — a single device_get for the
         ([K, slots] tokens, last token, keys) triple — then the host
         side: per-stream delivery (replay-aware), TTFT, releases, and
-        host-overhead accounting.  Returns the new wall-clock mark."""
+        host-overhead accounting.  `sp` is the rounds' open `decode` span.
+        Returns the new wall-clock mark."""
         import jax
 
         with span("decode.readback") as readback:
-            toks, tok_last, keys_last = jax.device_get(
-                (blk["toks"], blk["tok"], blk["keys"]))
+            toks, tok_last, keys_last, counts = jax.device_get(
+                (blk["toks"], blk["tok"], blk["keys"], blk["counts"]))
+        self._note_experts(sp, counts, blk["k"])
         now = time.monotonic()
         emitted = 0
         with span("decode.deliver"):
@@ -1870,6 +1910,10 @@ class ContinuousBatcher:
                     "count": bh["count"],
                 },
             }
+        if self._has_experts:
+            with self._cv:
+                out["expert_picks_total"] = self._expert_picks
+                out["experts_hit_total"] = self._experts_hit
         if self.paged:
             with self._cv:
                 live_tokens = sum(

@@ -76,6 +76,8 @@ FAMILIES = {
     "dl4j_serving_decode_host_seconds_total": ("counter", ()),
     "dl4j_serving_admit_seconds_total": ("counter", ()),
     "dl4j_serving_queue_wait_seconds_total": ("counter", ()),
+    "dl4j_serving_expert_picks_total": ("counter", ()),
+    "dl4j_serving_experts_hit_total": ("counter", ()),
     "dl4j_router_ready": ("gauge", ()),
     "dl4j_router_inflight": ("gauge", ()),
     "dl4j_router_replicas_healthy": ("gauge", ()),
@@ -398,6 +400,17 @@ def replica_metrics(stats: dict, page: Optional[PrometheusText] = None,
                   "Seconds admitted streams waited between submit and "
                   "the start of their admission.",
                   gen.get("queue_wait_seconds_total", 0.0), lbl())
+        if "expert_picks_total" in gen:     # a model with expert layers
+            p.counter("dl4j_serving_expert_picks_total",
+                      "Picks of the router that landed on experts this "
+                      "replica holds, summed over decode steps and expert "
+                      "layers; every one was computed.",
+                      gen["expert_picks_total"], lbl())
+            p.counter("dl4j_serving_experts_hit_total",
+                      "Distinct held experts picked in a decode step, "
+                      "summed over steps and expert layers: the expert "
+                      "weights a step had to read.",
+                      gen["experts_hit_total"], lbl())
     return p.render() if own_page else ""
 
 

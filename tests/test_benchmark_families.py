@@ -1,0 +1,55 @@
+"""The benchmark's own tests of its model families, run from tier 1: a
+benchmark PR may add no file outside `benchmark/`, so the cases live there
+(`benchmark/tests/test_families.py`, the toy family and GPT-2's pins, PR 27;
+`benchmark/tests/test_family_ling3.py`, PR 28) and this file collects them,
+so that tier 1 counts both families.
+
+Two cases of `test_families.py` were written when GPT-2 was the only family
+and cannot hold beside a second one; a `model_config` PR may not edit them,
+so this file defines them anew under their names (a `benchmark` PR can
+bring the originals up to date)."""
+
+import os
+import re
+
+import pytest
+
+from benchmark import families
+from benchmark.tests.test_families import *          # noqa: F401,F403
+from benchmark.tests.test_family_ling3 import *      # noqa: F401,F403
+
+BENCHMARK = os.path.dirname(os.path.abspath(families.__path__[0]))
+
+
+def test_a_model_type_with_no_family_ends_the_run():
+    """The original expects the message to list GPT-2 alone."""
+    with pytest.raises(SystemExit, match=r"'mamba'.*\['gpt2', 'ling3'\]"):
+        families.of({"model_type": "mamba"})
+
+
+def test_no_file_outside_a_family_names_one():
+    """ISSUE 27's grep, for two families: GPT-2's keys and leaves appear
+    under `families/gpt2`, in the configuration files and in tests, and
+    nowhere else; nor do Ling's under anything but `families/ling3`.  The
+    original's pattern takes `num_attention_heads`, a published key of the
+    second family, for GPT-2's `n_head`; a key is matched whole here."""
+    own = {"gpt2": r"char_transformer|\b(n_embd|n_head|n_inner|wte)\b|Wqkv|gpt2",
+           "ling3": r"kv_lora_rank|moe_intermediate_size|layer_group_size|"
+                    r"\b(Wkva|Wkvb|conv_q|A_log)\b|ling3"}
+    found = []
+    for where, _, files in os.walk(BENCHMARK):
+        rel = os.path.relpath(where, BENCHMARK)
+        top = rel.split(os.sep)
+        if top[0] in ("tests", "configs", "testdata", "__pycache__"):
+            continue
+        inside = top[1] if top[0] == "families" and len(top) > 1 else None
+        # the program's own KDA leaf is called Wqkv as its attention's is
+        names = re.compile("|".join(
+            p if inside is None else p.replace("|Wqkv", "")
+            for family, p in own.items() if family != inside))
+        for name in files:
+            if name.endswith((".py", ".md", ".json")):
+                with open(os.path.join(where, name)) as f:
+                    found += [f"{rel}/{name}: {line.strip()}" for line in f
+                              if names.search(line)]
+    assert found == []

@@ -147,3 +147,68 @@ def test_scatter_add_rows_lane_width(one_chip):
     assert "tpu_custom_call" in _compile(scatter, one_chip, *shapes(128))
     with pytest.raises(ValueError, match="multiple of the 128-lane"):
         _compile(scatter, one_chip, *shapes(100))
+
+
+# -- the layer types of PR 28 at Ling-3.0-flash's widths: plain XLA, no kernel
+# of this repo's, but each leans on something only the chip's compiler can
+# refuse (a grouped matrix product, a triangular solve, a 5-D reduction)
+
+def _ling3_conf(layer_type, spec):
+    return NeuralNetConfiguration(layer_type=layer_type, n_in=2560, n_out=2560,
+                                  dtype="bfloat16", compute_dtype="bfloat16",
+                                  layer_spec=spec)
+
+
+def _compile_layer(fn, one_chip, impl, conf, *args):
+    """`fn(params, *args)` compiled with `impl.init`'s shapes for params."""
+    shaped = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = shaped(jax.eval_shape(lambda k: impl.init(k, conf), jax.random.PRNGKey(0)))
+    return jax.jit(fn).lower(params, *[shaped(a) for a in args]).compile().as_text()
+
+
+@pytest.mark.parametrize("rows", [64, 1024], ids=["decode-64", "prefill-1024"])
+def test_expert_layer_is_a_grouped_product_on_the_chip(one_chip, rows):
+    from deeplearning4j_tpu.nn.conf import MoESpec
+    from deeplearning4j_tpu.nn.layers.experts import MoELayer
+
+    conf = _ling3_conf(LayerType.MOE, MoESpec(
+        n_routed=512, n_held=128, hidden=768, shared_hidden=768, top_k=8,
+        n_group=8, topk_group=4, routed_scaling=2.5))
+    text = _compile_layer(lambda p, x: MoELayer.apply(p, conf, x), one_chip,
+                          MoELayer, conf,
+                          jax.ShapeDtypeStruct((rows, 2560), jnp.float32))
+    # XLA's own grouped kernel, in both branches: over 3/8 of the picks and
+    # over all of them; never a product over every expert for every row
+    assert text.count("ragged_dot_tiling") >= 4 and "conditional" in text
+
+
+def test_kda_prefill_and_decode_compile(one_chip):
+    from deeplearning4j_tpu.nn.conf import KDASpec
+    from deeplearning4j_tpu.nn.layers.kda import KDALayer
+
+    conf = _ling3_conf(LayerType.KDA, KDASpec(n_heads=32, head_dim=128))
+    state = lambda b: jax.eval_shape(lambda: KDALayer.init_state(conf, b, 0))  # noqa: E731
+    text = _compile_layer(
+        lambda p, x, s, n: KDALayer.prefill(p, conf, x, s, n), one_chip, KDALayer,
+        conf, jax.ShapeDtypeStruct((1, 1024, 2560), jnp.bfloat16), state(1),
+        jax.ShapeDtypeStruct((1,), jnp.int32))
+    assert "while" in text          # a loop over chunks, not over tokens
+    _compile_layer(
+        lambda p, x, s, q: KDALayer.decode_step(p, conf, x, s, q), one_chip,
+        KDALayer, conf, jax.ShapeDtypeStruct((64, 2560), jnp.float32), state(64),
+        jax.ShapeDtypeStruct((64,), jnp.int32))
+
+
+def test_mla_absorbed_decode_compiles(one_chip):
+    from deeplearning4j_tpu.nn.conf import MLASpec
+    from deeplearning4j_tpu.nn.layers.mla import MLALayer
+
+    conf = _ling3_conf(LayerType.MLA, MLASpec(
+        n_heads=32, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, rope_theta=6e6))
+    _compile_layer(
+        lambda p, x, s, q: MLALayer.decode_step(p, conf, x, s, q), one_chip,
+        MLALayer, conf, jax.ShapeDtypeStruct((64, 2560), jnp.float32),
+        jax.eval_shape(lambda: MLALayer.init_state(conf, 64, 2048)),
+        jax.ShapeDtypeStruct((64,), jnp.int32))
