@@ -7,10 +7,7 @@ or source produces none.  The repo itself is asserted clean at the end
 (the same invariant the tier-1 `analyze` gate enforces).
 """
 
-import json
 import os
-import subprocess
-import sys
 import textwrap
 
 import jax
@@ -530,31 +527,7 @@ def test_unguarded_shared_write_rule():
 def test_repo_is_lint_clean():
     """The invariant floor, in-process: zero findings of ANY severity
     over the whole package (the CLI gate re-checks this plus the zoo
-    programs in a subprocess below)."""
+    programs in a subprocess, `test_analysis_cli.py`)."""
     findings, n_files = lint_package()
     assert n_files > 100
     assert findings == []
-
-
-# -- the CLI gate ------------------------------------------------------------
-
-def test_cli_analyze_gate_json_schema():
-    """`analyze --fail-on error --format json` exits 0 on this repo and
-    emits the versioned report over the package + all four zoo models'
-    compiled programs (the ISSUE 12 acceptance command)."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, "-m", "deeplearning4j_tpu.cli", "analyze",
-         "--fail-on", "error", "--format", "json"],
-        capture_output=True, text=True, cwd=REPO_ROOT, env=env,
-        timeout=420)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    rep = json.loads(proc.stdout)
-    assert rep["version"] == REPORT_VERSION
-    assert set(rep["counts"]) == {"info", "warn", "error"}
-    assert rep["counts"]["error"] == 0
-    assert rep["checked"]["files"] > 100
-    assert rep["checked"]["programs"] >= 10  # 4 models x (serve+step) + attn
-    assert isinstance(rep["findings"], list)
-    for f in rep["findings"]:
-        assert set(f) == {"rule", "severity", "location", "message"}

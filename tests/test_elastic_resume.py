@@ -14,6 +14,7 @@ test_mesh_infer).
 
 import json
 import os
+import re
 import signal
 import subprocess
 import sys
@@ -474,8 +475,9 @@ def test_chaos_kill_n4_resume_m2_subprocess(tmp_path):
 
     def run(n_dev, fault_plan=None):
         env = {**os.environ, "JAX_PLATFORMS": "cpu",
-               "XLA_FLAGS": " --xla_force_host_platform_device_count="
-                            f"{n_dev}"}
+               "XLA_FLAGS": re.sub(     # the rig's flags, this many devices
+                   r"(host_platform_device_count=)\d+", rf"\g<1>{n_dev}",
+                   os.environ["XLA_FLAGS"])}
         env.pop("DL4J_FAULT_PLAN", None)
         if fault_plan:
             env["DL4J_FAULT_PLAN"] = fault_plan

@@ -20,7 +20,8 @@ def _setup(seed=0):
 
 def test_dense_moe_routes_and_transforms():
     params, x = _setup()
-    y, aux = moe_ffn_dense(params, x, capacity_factor=8.0)
+    y, aux = jax.jit(lambda p, v: moe_ffn_dense(p, v, capacity_factor=8.0))(
+        params, x)
     assert y.shape == x.shape
     assert float(aux) > 0
     assert not np.allclose(np.asarray(y), np.asarray(x))  # experts acted
@@ -30,8 +31,10 @@ def test_ep_matches_dense_with_ample_capacity():
     mesh = make_mesh({"ep": 8})
     params, x = _setup(1)
     # capacity high enough that neither variant drops any token
-    y_dense, aux_dense = moe_ffn_dense(params, x, capacity_factor=float(E))
-    y_ep, aux_ep = moe_ffn(params, x, mesh, capacity_factor=float(E))
+    y_dense, aux_dense = jax.jit(
+        lambda p, v: moe_ffn_dense(p, v, capacity_factor=float(E)))(params, x)
+    y_ep, aux_ep = jax.jit(
+        lambda p, v: moe_ffn(p, v, mesh, capacity_factor=float(E)))(params, x)
     np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_dense),
                                rtol=2e-4, atol=2e-4)
     # Aux loss must equal the DENSE global statistic, not a mean of
@@ -54,8 +57,8 @@ def test_ep_full_loss_and_grads_match_dense():
                                           capacity_factor=float(E)))
     loss_de = make_loss(lambda p: moe_ffn_dense(p, x,
                                                 capacity_factor=float(E)))
-    v_ep, g_ep = jax.value_and_grad(loss_ep)(params)
-    v_de, g_de = jax.value_and_grad(loss_de)(params)
+    v_ep, g_ep = jax.jit(jax.value_and_grad(loss_ep))(params)
+    v_de, g_de = jax.jit(jax.value_and_grad(loss_de))(params)
     np.testing.assert_allclose(float(v_ep), float(v_de), rtol=1e-4)
     for k in ("router", "W1", "b1", "W2", "b2"):
         np.testing.assert_allclose(
@@ -67,7 +70,8 @@ def test_ep_capacity_drops_fall_through_residual():
     mesh = make_mesh({"ep": 8})
     params, x = _setup(2)
     # capacity 1 forces drops: dropped tokens must equal their input
-    y, _ = moe_ffn(params, x, mesh, capacity_factor=0.01)
+    y, _ = jax.jit(lambda p, v: moe_ffn(p, v, mesh, capacity_factor=0.01))(
+        params, x)
     diff = np.abs(np.asarray(y) - np.asarray(x)).sum(axis=1)
     assert (diff < 1e-6).any(), "expected some tokens to ride the residual"
 
@@ -80,7 +84,7 @@ def test_ep_grads_flow_and_aux_loss_balances():
         y, aux = moe_ffn(p, x, mesh, capacity_factor=float(E))
         return jnp.mean(y ** 2) + 0.01 * aux
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     for k in ("router", "W1", "W2"):
         assert np.isfinite(np.asarray(g[k])).all()
         assert float(jnp.abs(g[k]).sum()) > 0, f"zero grad for {k}"

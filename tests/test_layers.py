@@ -90,7 +90,8 @@ def test_lstm_shapes_and_grad():
     # so agreement is approximate in float32
     np.testing.assert_allclose(h1, h[0], rtol=0.2, atol=3e-3)
     # BPTT via jax.grad is finite
-    g = jax.grad(lambda pp: jnp.sum(LSTMLayer.forward(pp, conf, x) ** 2))(p)
+    g = jax.jit(jax.grad(
+        lambda pp: jnp.sum(LSTMLayer.forward(pp, conf, x) ** 2)))(p)
     for leaf in jax.tree_util.tree_leaves(g):
         assert np.all(np.isfinite(np.asarray(leaf)))
 
@@ -149,7 +150,7 @@ def test_vgg_cifar_forward_shape():
     conf = vgg_cifar10(width=8)
     params = init_params(conf, jax.random.PRNGKey(0))
     x = jnp.zeros((2, 3 * 32 * 32), jnp.float32)
-    out = network_output(conf, params, x)
+    out = jax.jit(lambda p, v: network_output(conf, p, v))(params, x)
     assert out.shape == (2, 10)
 
 
@@ -257,7 +258,7 @@ def test_graves_lstm_peepholes_train_and_differ():
     def loss(p):
         return jnp.sum(GravesLSTMLayer.forward(p, conf, x) ** 2)
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     assert float(jnp.abs(g["p_i"]).sum()) > 0
     assert float(jnp.abs(g["p_o"]).sum()) > 0
     # non-zero peepholes change the output

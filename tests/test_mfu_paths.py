@@ -6,7 +6,8 @@ identity (sparse labels, fused updater) or reference-tolerance identity
 claim's enforcement.  Flag combinations are also exercised end-to-end
 through `MultiLayerNetwork.finetune` (the compiled step-cache program),
 so the parity holds through tracing, donation and the solver scan, not
-just at the op level.
+just at the op level (`test_mfu_end_to_end.py`); the fused flash backward is in
+`test_mfu_fused_bwd.py`.
 """
 
 import jax
@@ -26,17 +27,7 @@ from deeplearning4j_tpu.optimize.updater import (UpdaterState,
                                                  flat_norm, flat_ravel,
                                                  flat_unravel, init_updater,
                                                  make_flat_spec, tree_norm)
-
-
-def _assert_tree_bitwise(a, b, where=""):
-    la, ta = jax.tree_util.tree_flatten(a)
-    lb, tb = jax.tree_util.tree_flatten(b)
-    assert ta == tb, f"tree structure mismatch {where}"
-    for i, (x, y) in enumerate(zip(la, lb)):
-        assert x.dtype == y.dtype and x.shape == y.shape, \
-            f"leaf {i} meta mismatch {where}"
-        assert np.array_equal(np.asarray(x), np.asarray(y)), \
-            f"leaf {i} bits differ {where}"
+from mfu_helpers import _assert_tree_bitwise
 
 
 # -- sparse-label loss path --------------------------------------------------
@@ -253,290 +244,3 @@ def test_pick_attention_blocks_table_and_fallback():
     bq, bk = pick_attention_blocks(192, 48, bwd=True)
     assert 192 % bq == 0 and 192 % bk == 0 and bq <= 128 and bk <= 256
     assert pick_attention_blocks(100, 64, bwd=True) == (128, 128)
-
-
-# -- fused flash backward ----------------------------------------------------
-#
-# The fused path (attention_fused_bwd) swaps the jax-level recompute VJP for
-# three Pallas kernels fed by saved logsumexp residuals.  Claims enforced
-# here: grads allclose (tight f32) to full_attention autodiff across
-# causal/non-causal x block_skip x shapes, in interpret AND jit-compiled
-# modes; the forward output is bitwise-unchanged by residual emission; every
-# fallback (flag off, ragged S, auto-detected interpret mode) stays bitwise
-# identical to the pre-fused recompute path; the flag never touches
-# serving-cache keys; and no [S,S] intermediate appears in the lowering.
-
-def _qkvg(seed, B, S, H, D):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    return [jax.random.normal(k, (B, S, H, D), jnp.float32) for k in ks]
-
-
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("block_skip", [False, True])
-@pytest.mark.parametrize(
-    "shape,fwd_blocks,bwd_blocks",
-    [((2, 64, 2, 8), (32, 16), (16, 32)),    # asymmetric fwd vs bwd tiles
-     ((1, 128, 2, 16), (32, 32), (32, 32))])
-def test_fused_bwd_grad_parity_vs_full_attention(causal, block_skip, shape,
-                                                 fwd_blocks, bwd_blocks):
-    B, S, H, D = shape
-    q, k, v, g = _qkvg(20, B, S, H, D)
-    bq, bk = fwd_blocks
-    bqb, bkb = bwd_blocks
-
-    def loss_fused(q, k, v):
-        o = flash_attention(q, k, v, causal, bq, bk, interpret=True,
-                            block_skip=block_skip, fused_bwd=True,
-                            block_q_bwd=bqb, block_k_bwd=bkb)
-        return jnp.sum(o * g)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(full_attention(q, k, v, causal=causal) * g)
-
-    ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for mode, fn in [("interpret", jax.grad(loss_fused, argnums=(0, 1, 2))),
-                     ("compiled",
-                      jax.jit(jax.grad(loss_fused, argnums=(0, 1, 2))))]:
-        got = fn(q, k, v)
-        for name, a, b in zip("qkv", got, ref):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5,
-                err_msg=f"d{name} {mode} causal={causal} "
-                        f"skip={block_skip} S={S}")
-
-
-def test_fused_bwd_forward_output_bitwise():
-    """Emitting the logsumexp residual must not perturb o: the fused
-    forward (under vjp, residuals saved) is bitwise the plain flash
-    forward."""
-    q, k, v, _ = _qkvg(21, 2, 64, 2, 8)
-    plain = flash_attention(q, k, v, True, 32, 16, interpret=True,
-                            block_skip=True)
-    fused_primal = flash_attention(q, k, v, True, 32, 16, interpret=True,
-                                   block_skip=True, fused_bwd=True)
-    out_vjp, _ = jax.vjp(
-        lambda q, k, v: flash_attention(q, k, v, True, 32, 16,
-                                        interpret=True, block_skip=True,
-                                        fused_bwd=True), q, k, v)
-    _assert_tree_bitwise(plain, fused_primal, "primal")
-    _assert_tree_bitwise(plain, out_vjp, "vjp forward")
-
-
-@pytest.mark.parametrize("case", ["flag_off", "ragged_s", "auto_interpret"])
-def test_fused_bwd_fallbacks_bitwise_vs_recompute(case):
-    """Every fused-path degrade keeps the pre-PR backward bit for bit:
-    flag off, ragged S (no Pallas block divides it), and auto-detected
-    interpret mode (interpret=None off-TPU — the fused kernels are gated
-    to real TPU lowerings or an explicit interpret pin)."""
-    from deeplearning4j_tpu.nd.attention import blockwise_attention
-    from deeplearning4j_tpu.nd.platform import is_tpu
-
-    if case == "auto_interpret" and is_tpu():
-        pytest.skip("auto-detect resolves to the real kernels on TPU")
-    S = 70 if case == "ragged_s" else 64
-    q, k, v, g = _qkvg(22, 2, S, 2, 8)
-    kwargs = {"fused_bwd": case != "flag_off"}
-    if case != "auto_interpret":
-        kwargs["interpret"] = True
-
-    def f(q, k, v):
-        return flash_attention(q, k, v, True, 32, 16, **kwargs)
-
-    _, vjp = jax.vjp(f, q, k, v)
-    _, vjp_ref = jax.vjp(
-        lambda q, k, v: blockwise_attention(q, k, v, block_size=16,
-                                            causal=True), q, k, v)
-    _assert_tree_bitwise(vjp(g), vjp_ref(g), case)
-    # and under jit, as the train step runs it
-    jg = jax.jit(jax.grad(lambda q, k, v: jnp.sum(f(q, k, v) * g),
-                          argnums=(0, 1, 2)))(q, k, v)
-    rg = jax.jit(jax.grad(
-        lambda q, k, v: jnp.sum(blockwise_attention(
-            q, k, v, block_size=16, causal=True) * g),
-        argnums=(0, 1, 2)))(q, k, v)
-    _assert_tree_bitwise(jg, rg, f"{case} jit")
-
-
-# the recursive jaxpr walk that used to live here is library code now
-# (analysis/program_audit.py) so the `analyze` gate and this test assert
-# the exact same structural contract
-from deeplearning4j_tpu.analysis.program_audit import (  # noqa: E402
-    assert_no_materialized_scores as _assert_no_ss_lib)
-
-
-def _assert_no_ss(fn, args, S, where):
-    _assert_no_ss_lib(fn, args, seq_threshold=S, where=where)
-
-
-@pytest.mark.parametrize("fused", [True, False])
-def test_no_ss_intermediate_at_long_seq(fused):
-    """The flash memory contract, asserted structurally: at S=1024 neither
-    the forward nor the backward jaxpr (fused kernels or the blockwise
-    recompute fallback) contains an intermediate with two S-sized dims.
-    Trace-only — nothing executes."""
-    S, D = 1024, 8
-    q = jax.ShapeDtypeStruct((1, S, 1, D), jnp.float32)
-
-    def fwd(q, k, v):
-        return flash_attention(q, k, v, True, 256, 256, interpret=True,
-                               block_skip=True, fused_bwd=fused,
-                               block_q_bwd=256, block_k_bwd=256)
-
-    _assert_no_ss(fwd, (q, q, q), S, f"forward fused={fused}")
-    _assert_no_ss(
-        jax.grad(lambda a, b, c: jnp.sum(fwd(a, b, c)), argnums=(0, 1, 2)),
-        (q, q, q), S, f"backward fused={fused}")
-
-
-def test_fused_bwd_flag_never_changes_infer_cache_key():
-    """Serving programs are gradient-free: flipping attention_fused_bwd
-    must not re-key (or invalidate on disk) any inference program — and
-    the normalized fingerprint equals the flag-off fingerprint, so pre-PR
-    artifacts stay live.  The training step cache, by contrast, must
-    re-key."""
-    from deeplearning4j_tpu.models.zoo import char_transformer
-    from deeplearning4j_tpu.optimize.infer_cache import InferCache
-    from deeplearning4j_tpu.optimize.step_cache import (CompiledProgramCache,
-                                                        conf_fingerprint)
-
-    conf_off = char_transformer(17, d_model=32, n_blocks=1, n_heads=2,
-                                max_seq_len=16)
-    conf_on = char_transformer(17, d_model=32, n_blocks=1, n_heads=2,
-                               max_seq_len=16, attention_fused_bwd=True)
-    ic = InferCache()
-    assert ic._fingerprint(conf_on) == ic._fingerprint(conf_off)
-    assert ic._fingerprint(conf_off) == conf_fingerprint(conf_off)
-    base = CompiledProgramCache()
-    assert base._fingerprint(conf_on) != base._fingerprint(conf_off)
-
-
-def test_end_to_end_fused_bwd_through_step_cache():
-    """char-transformer finetune through the compiled step cache with
-    attention_impl pinned to flash and the fused-bwd flag flipped: params
-    must agree at tight tolerance (the fused backward is allclose, not
-    bitwise, by contract; on CPU the auto-interpret gate makes both runs
-    take the recompute fallback, where agreement is exact)."""
-    from deeplearning4j_tpu.models.zoo import char_transformer
-    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-
-    vocab, batch, seq = 17, 4, 16
-
-    def train(fused):
-        conf = char_transformer(vocab, d_model=32, n_blocks=1, n_heads=2,
-                                max_seq_len=seq, iterations=2,
-                                attention_fused_bwd=fused)
-        conf = conf.replace(confs=tuple(
-            c.replace(attention_impl="flash", attention_block_size=8)
-            for c in conf.confs))
-        net = MultiLayerNetwork(conf, seed=42).init()
-        net.finetune(*_char_batch(vocab, batch, seq, False))
-        return net.params
-
-    ref, got = train(False), train(True)
-    for i, (a, b) in enumerate(zip(jax.tree_util.tree_leaves(ref),
-                                   jax.tree_util.tree_leaves(got))):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-5,
-                                   err_msg=f"leaf {i}")
-
-
-# -- end-to-end through the compiled train step ------------------------------
-
-def _char_batch(vocab, batch, seq, sparse):
-    rng = np.random.RandomState(7)
-    ids = rng.randint(0, vocab, (batch, seq + 1))
-    x = jnp.asarray(ids[:, :-1].astype(np.int32))
-    if sparse:
-        return x, jnp.asarray(ids[:, 1:].reshape(-1).astype(np.int32))
-    return x, jnp.asarray(
-        np.eye(vocab, dtype=np.float32)[ids[:, 1:].reshape(-1)])
-
-
-def test_end_to_end_flag_combos_bitwise():
-    """char-transformer `finetune` through the step cache: every flag
-    combination must land on bitwise-identical parameters after the
-    solver scan (donation, bucketing and fingerprinting included)."""
-    from deeplearning4j_tpu.models.zoo import char_transformer
-    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-
-    vocab, batch, seq = 17, 4, 16
-
-    def train(fused, sparse):
-        conf = char_transformer(vocab, d_model=32, n_blocks=1, n_heads=2,
-                                max_seq_len=seq, iterations=2,
-                                fused_updater=fused, sparse_labels=sparse)
-        net = MultiLayerNetwork(conf, seed=42).init()
-        net.finetune(*_char_batch(vocab, batch, seq, sparse))
-        return net.params
-
-    ref = train(False, False)
-    for combo in [(True, False), (False, True), (True, True)]:
-        _assert_tree_bitwise(ref, train(*combo), f"combo {combo}")
-
-def _dp_train(vocab, batch, seq, steps, sparse, fused):
-    from deeplearning4j_tpu.models.zoo import char_transformer
-    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-    from deeplearning4j_tpu.parallel.data_parallel import DataParallelTrainer
-    from deeplearning4j_tpu.parallel.mesh import make_mesh
-
-    conf = char_transformer(vocab, d_model=32, n_blocks=1, n_heads=2,
-                            max_seq_len=seq, sparse_labels=sparse,
-                            fused_updater=fused)
-    rng = np.random.RandomState(0)
-    ids = rng.randint(0, vocab, size=(steps, batch, seq)).astype(np.int32)
-    net = MultiLayerNetwork(conf).init()
-    tr = DataParallelTrainer(net, mesh=make_mesh({"dp": 8}))
-    batches = []
-    for i in range(steps):
-        flat = ids[i].reshape(batch * seq)
-        y = (jnp.asarray(flat, jnp.int32) if sparse
-             else jnp.asarray(np.eye(vocab, dtype=np.float32)[flat]))
-        batches.append((jnp.asarray(ids[i]), y))
-    score = tr.fit(batches)
-    return jax.device_get(tr.state.params), score
-
-
-def test_dp_step_sparse_labels_bitwise():
-    """8-way dp train, 3 batches: `sparse_labels` is fully bitwise in the
-    dp step too — params AND reported score."""
-    ref, ref_score = _dp_train(17, 16, 16, 3, sparse=False, fused=False)
-    sp, sp_score = _dp_train(17, 16, 16, 3, sparse=True, fused=False)
-    _assert_tree_bitwise(ref, sp, "sparse_labels dp")
-    assert sp_score == ref_score
-
-
-def test_dp_step_fused_updater_single_step_bitwise():
-    """One 8-way dp step: the fused updater must land on bitwise-identical
-    params even though tree- and flat-layout steps are separately
-    compiled programs — a single application has no accumulated state for
-    fusion-level rounding to amplify."""
-    ref, ref_score = _dp_train(17, 16, 16, 1, sparse=False, fused=False)
-    for sparse, fused in [(False, True), (True, True)]:
-        got, score = _dp_train(17, 16, 16, 1, sparse=sparse, fused=fused)
-        _assert_tree_bitwise(ref, got, f"dp 1-step combo {(sparse, fused)}")
-        # the score is a mean over bitwise-identical per-row losses, but
-        # the scalar reduce can fuse in a different summation order in a
-        # reshaped program — a reporting value, not training state
-        np.testing.assert_allclose(score, ref_score, rtol=1e-6,
-                                   err_msg=f"combo {(sparse, fused)}")
-
-
-def test_dp_step_fused_updater_iterated_close():
-    """Iterated 8-way dp steps: across *separately compiled* tree- vs
-    flat-layout programs XLA may duplicate the moment updates into the
-    step fusion with different FMA contraction — a last-ulp seed the
-    barriers in `adjust_gradient` cannot pin across layouts (see
-    `adjust_gradient_auto`).  Adam's `m / (sqrt(v) + eps)` then amplifies
-    that seed to step scale on coordinates whose moments sit near zero
-    (observed: ~1e-10 absolute on weights, up to ~4e-5 on a handful of
-    bias entries after 3 steps).  So the iterated claim is closeness at
-    step-scale tolerance; the exactness claims live in the single-step
-    and solver-path tests."""
-    ref, _ = _dp_train(17, 16, 16, 3, sparse=False, fused=False)
-    for sparse, fused in [(False, True), (True, True)]:
-        got, _ = _dp_train(17, 16, 16, 3, sparse=sparse, fused=fused)
-        for a, b in zip(jax.tree_util.tree_leaves(ref),
-                        jax.tree_util.tree_leaves(got)):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-4,
-                err_msg=f"dp 3-step combo {(sparse, fused)}")

@@ -33,11 +33,11 @@ def test_flash_attention_grads():
     kk_ = jax.random.normal(kk, (B, S, H, D), jnp.float32)
     v = jax.random.normal(kv, (B, S, H, D), jnp.float32)
 
-    g_fl = jax.grad(lambda q, k, v: jnp.sum(
-        flash_attention(q, k, v, True, 8, 8) ** 2), argnums=(0, 1, 2))(
+    g_fl = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, True, 8, 8) ** 2), argnums=(0, 1, 2)))(
         q, kk_, v)
-    g_ref = jax.grad(lambda q, k, v: jnp.sum(
-        full_attention(q, k, v, causal=True) ** 2), argnums=(0, 1, 2))(
+    g_ref = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        full_attention(q, k, v, causal=True) ** 2), argnums=(0, 1, 2)))(
         q, kk_, v)
     for a, b in zip(g_fl, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -136,8 +136,9 @@ def test_lstm_layer_fused_grads_match_scan():
     def loss(p, c):
         return jnp.sum(layer.forward(p, c, x) ** 2)
 
-    g_scan = jax.grad(loss)(params, conf)
-    g_fused = jax.grad(loss)(params, conf.replace(lstm_impl="fused"))
+    g_scan = jax.jit(jax.grad(loss), static_argnums=1)(params, conf)
+    g_fused = jax.jit(jax.grad(loss), static_argnums=1)(
+        params, conf.replace(lstm_impl="fused"))
     for k in g_scan:
         np.testing.assert_allclose(np.asarray(g_fused[k]),
                                    np.asarray(g_scan[k]),

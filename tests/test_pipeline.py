@@ -52,8 +52,8 @@ def test_pipeline_grads_match_sequential():
         out = jnp.stack([_sequential(p, x[i]) for i in range(4)])
         return jnp.mean((out - y) ** 2)
 
-    g_pipe = jax.grad(loss_pipe)(params)
-    g_seq = jax.grad(loss_seq)(params)
+    g_pipe = jax.jit(jax.grad(loss_pipe))(params)
+    g_seq = jax.jit(jax.grad(loss_seq))(params)
     for k in g_pipe:
         np.testing.assert_allclose(np.asarray(g_pipe[k]),
                                    np.asarray(g_seq[k]),

@@ -3,6 +3,7 @@ compile cache placed from outside (`nd/platform.py` and its callers)."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -46,14 +47,20 @@ def test_cli_train_json_says_which_device_ran(tmp_path, capsys):
 
 def test_cli_warmup_and_tune_json_say_which_device_ran(tmp_path, capsys):
     from deeplearning4j_tpu.models.zoo import mlp
+    from deeplearning4j_tpu.optimize import tunables
 
     conf = tmp_path / "conf.json"
     conf.write_text(mlp(4, [8], 3).to_json())
     assert main(["warmup", "--model", str(conf), "--compile-cache",
                  str(tmp_path / "cc"), "--shapes", "4"]) == 0
     assert _last_json(capsys)["platform"] == "cpu"
-    assert main(["tune", "--model", str(conf), "--groups", "serve",
-                 "--rounds", "1"]) == 0
+    try:
+        assert main(["tune", "--model", str(conf), "--groups", "serve",
+                     "--rounds", "1"]) == 0
+    finally:
+        # `tune` installs its table for the whole process: left in place,
+        # its bucket ladder reached every file this worker ran afterwards
+        tunables.clear()
     out = _last_json(capsys)
     assert out["platform"] == "cpu" and out["device_count"] == 8
 
@@ -209,7 +216,7 @@ def test_local_launcher_gives_each_worker_its_own_chip(tmp_path):
 
 
 _ROUTER_PARENT = r"""
-import io, json, os, signal, sys, threading
+import io, json, os, signal, sys, threading, time
 from deeplearning4j_tpu.cli import driver
 from jax._src import xla_bridge
 
@@ -231,6 +238,12 @@ def look():
     print(json.dumps({"backends": sorted(xla_bridge._backends),
                       "initialized": xla_bridge.backends_are_initialized()}),
           file=sys.stderr, flush=True)
+    # `serve` prints its startup line before it takes SIGTERM for itself: a
+    # signal in between ends the router by default and orphans the replicas
+    deadline = time.monotonic() + 60
+    while (signal.getsignal(signal.SIGTERM) is signal.SIG_DFL
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
     os.kill(os.getpid(), signal.SIGTERM)
 
 
@@ -252,8 +265,10 @@ def test_router_parent_initialises_no_backend(tmp_path):
     net = MultiLayerNetwork(mlp(4, [8], 3), seed=0).init()
     ckpt = str(tmp_path / "ckpt")
     checkpoint.save(ckpt, net.params, conf=net.conf)
-    env = {**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu"}
-    env.pop("XLA_FLAGS", None)
+    env = {**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": re.sub(         # the rig's flags, one device
+               r"--xla_force_host_platform_device_count=\d+", "",
+               os.environ["XLA_FLAGS"])}
     proc = subprocess.run([sys.executable, "-c", _ROUTER_PARENT, ckpt],
                           env=env, capture_output=True, text=True,
                           timeout=300)

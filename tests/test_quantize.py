@@ -3,7 +3,8 @@ serve-precision policy (optimize/quantize.py) — bf16 cast-on-load,
 weight-only per-channel int8 with calibrated clip — threads through the
 AOT infer cache as a cache-key dimension, persists the quantized-weight
 artifact in the disk store, keeps the f32 path bitwise-identical, and
-holds the declared accuracy budgets on all four zoo models.
+holds the declared accuracy budgets on all four zoo models
+(`test_quantize_budgets.py`).
 
 Tier-1: CPU-only, tmpdir-backed; the two-subprocess disk-coexistence
 check is the cross-process acceptance test.
@@ -18,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deeplearning4j_tpu.models.zoo import PRECISION_ERROR_BUDGETS, mlp
+from deeplearning4j_tpu.models.zoo import mlp
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.optimize import quantize
 from deeplearning4j_tpu.optimize.persist import PersistentProgramStore
@@ -232,19 +233,6 @@ def test_corrupt_artifact_is_evicted_and_recalibrated(tmp_path):
     assert rep["calibration"]["clip"] in quantize.CLIP_GRID
     assert net2.infer_cache.persist.corrupt_evicted == 1
     assert net2.infer_cache.persist.writes >= 1  # rewritten clean
-
-
-# -- error budgets (acceptance criterion) ------------------------------------
-
-def test_error_budgets_hold_on_all_four_zoo_models():
-    """bf16 and int8 stay within the budgets declared in
-    `zoo.PRECISION_ERROR_BUDGETS` for LeNet, char-LSTM, charTransformer,
-    and the deep autoencoder (small variants; CPU-deterministic)."""
-    report = quantize.error_budget_report(small=True)
-    assert set(report) == set(PRECISION_ERROR_BUDGETS)
-    for model, by_policy in report.items():
-        for policy, row in by_policy.items():
-            assert row["within_budget"], (model, policy, row)
 
 
 # -- cross-process disk coexistence (acceptance criterion) -------------------

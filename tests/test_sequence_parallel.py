@@ -84,8 +84,8 @@ def test_ring_attention_grads_flow():
     def loss_full(q, k, v):
         return jnp.sum(full_attention(q, k, v, causal=True) ** 2)
 
-    g_ring = jax.grad(loss_ring)(q, k, v)
-    g_full = jax.grad(loss_full)(q, k, v)
+    g_ring = jax.jit(jax.grad(loss_ring))(q, k, v)
+    g_full = jax.jit(jax.grad(loss_full))(q, k, v)
     np.testing.assert_allclose(np.asarray(g_ring), np.asarray(g_full),
                                rtol=1e-3, atol=1e-3)
 
