@@ -72,6 +72,7 @@ the four zoo models' serve and train-step programs and audits the lot
 
 from __future__ import annotations
 
+import functools
 from typing import Iterable, List, Optional, Sequence, Tuple
 
 from deeplearning4j_tpu.analysis.report import Finding
@@ -543,9 +544,7 @@ def audit_decode_structure(S: int = 1024) -> List[Finding]:
     tok = jnp.zeros((1,), jnp.int32)
     pos = jnp.zeros((1,), jnp.int32)
 
-    def step(params, state, tok, pos):
-        return decode_mod.decode_step(conf, params, state, tok, pos)
-
+    step = functools.partial(decode_mod.decode_step, conf)
     findings = audit_fn(step, (net.params, state, tok, pos),
                         where=f"decode-step:S={S}", seq_threshold=S)
 
@@ -557,12 +556,7 @@ def audit_decode_structure(S: int = 1024) -> List[Finding]:
     pstate = decode_mod.init_paged_state(conf, 1, n_pages + 1, page_size)
     page_table = jnp.zeros((1, n_pages), jnp.int32)
 
-    def paged_step(params, state, tok, pos, page_table):
-        return decode_mod.decode_step_paged(conf, params, state, tok,
-                                            pos, page_table)
-
-    findings += audit_fn(paged_step,
-                         (net.params, pstate, tok, pos, page_table),
+    findings += audit_fn(step, (net.params, pstate, tok, pos, page_table),
                          where=f"decode-step-paged:S={S}",
                          seq_threshold=S)
 
@@ -571,16 +565,16 @@ def audit_decode_structure(S: int = 1024) -> List[Finding]:
     # covers all K), stay free of host callbacks (the whole point is K
     # device-resident tokens per host round-trip), and keep sampling
     # in-program — trace the exact builders the infer cache compiles
-    from deeplearning4j_tpu.optimize.infer_cache import (
-        _decode_multi_paged_program, _decode_multi_program)
+    from deeplearning4j_tpu.optimize.infer_cache import _decode_multi_program
 
     keys = jnp.zeros((1, 2), jnp.uint32)
     temps = jnp.zeros((1,), jnp.float32)
     rem = jnp.full((1,), 4, jnp.int32)
-    findings += audit_fn(_decode_multi_program(conf, "f32", 4),
+    block = _decode_multi_program(conf, "f32", 4)
+    findings += audit_fn(block,
                          (net.params, state, tok, pos, keys, temps, rem),
                          where=f"decode-multi[4]:S={S}", seq_threshold=S)
-    findings += audit_fn(_decode_multi_paged_program(conf, "f32", 4),
+    findings += audit_fn(block,
                          (net.params, pstate, tok, pos, keys, temps, rem,
                           page_table),
                          where=f"decode-multi-paged[4]:S={S}",

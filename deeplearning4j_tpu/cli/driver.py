@@ -1426,7 +1426,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "largest warmed bucket)")
     s.add_argument("--no-batching", dest="no_batching", action="store_true",
                    help="bypass the micro-batcher (per-request device "
-                        "calls; the bench_serve control arm)")
+                        "calls; the control arm)")
     s.add_argument("--drain-timeout", dest="drain_timeout", type=float,
                    default=10.0, metavar="SECONDS",
                    help="bound on the SIGTERM graceful drain (stop "

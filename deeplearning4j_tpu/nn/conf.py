@@ -391,8 +391,8 @@ class MultiLayerConfiguration:
     def with_compute_dtype(self, compute_dtype: str) -> "MultiLayerConfiguration":
         """Every layer's matmul/conv compute dtype flipped at once (the
         `layers.base.mixed_matmul` lever) — params/master dtype stays
-        put.  The serve-precision policy and the mixed-precision bench
-        both derive their bf16 confs through this."""
+        put.  The serve-precision policy derives its bf16 confs through
+        this."""
         return self.replace(confs=tuple(
             c.replace(compute_dtype=compute_dtype) for c in self.confs))
 

@@ -18,7 +18,10 @@ Both entries return `log(clip(probs, 1e-9, 1))` — byte-for-byte the
 transform the eager sampler applies — so a greedy compiled decode
 reproduces the eager token trajectory exactly in f32.  The compiled
 wrappers (key schema, donation, sampling) live in
-`optimize/infer_cache.py`; this module is pure layer math.
+`optimize/infer_cache.py`; the layer math and what each layer type keeps
+between tokens live in `nn/layers/`.  This module is one loop over the
+layers a phase: it calls the protocol that `nn/layers/__init__.py` states
+and never asks a layer's type to know what its state is or how to step it.
 
 State layout: one dict per layer, in layer order, as a tuple —
   LSTM/GRAVES_LSTM  {"h": [B, H] f32, "c": [B, H] f32}
@@ -26,15 +29,12 @@ State layout: one dict per layer, in layer order, as a tuple —
   KDA               {"S": [B, H, dk, dv] f32, "conv": [B, K-1, 3 H dk]}
   MLA               {"c": [B, max_S, rank], "kr": [B, max_S, rope]}
   everything else   {}
-A layer type that keeps a state says what it is: its class has
-`init_state(conf, batch, max_seq)`, `prefill(params, conf, x, state,
-length)` and `decode_step(params, conf, x, state, pos)`, both returning
-(hidden, state), and `CARRY`, whether the state advances with every token
-(so that `decode_block` must hold a finished row's still) or is a table
-written at `pos`.  LSTM's (h, c) and attention's K/V are the two kinds that
-were here first; `zero_row` and `write_row` treat every kind alike, leaf by
-leaf along axis 0.  Such a state lives in the dense table only: the paged
-pool and the verify chunk refuse it (`dense_only`).
+each made by its layer class's `init_state`; `CARRY` on the class says
+whether the state advances with every token (so that `decode_block` must
+hold a finished row's still) or is a table written at `pos`.  `zero_row`
+and `write_row` treat every kind alike, leaf by leaf along axis 0.  A type
+whose class has no `init_paged_state` and `verify_chunk` lives in the dense
+table only: the paged pool and the verify chunk refuse it (`dense_only`).
 The tuple-of-dicts shape makes the whole state one donatable jit
 argument whose leaves keep their shapes/dtypes across steps, so the
 compiled step can alias its cache buffers in place.
@@ -44,13 +44,10 @@ layer's dense [B, max_S, n] table with a shared physical page pool
   ATTENTION         {"k": [n_pages, page_size, n], "v": same}
 addressed through a per-call `page_table` [B, pages_per_slot] int32 of
 physical page ids — cache memory scales with LIVE pages, not
-slots x max_seq.  `decode_step_paged` scatters the new K/V row at
-(page_table[b, pos // page_size], pos % page_size) and gathers the
-slot's pages back into one [B, pages_per_slot * page_size, n] view
-before the same masked [B, H, ctx] score math as the dense step —
-positions the slot has not written yet sit behind the additive mask, so
-junk in unallocated pages is inert and the paged trajectory is
-token-identical to the dense one.  The host (serving/batcher.py) owns
+slots x max_seq.  `decode_step`, `decode_block` and `verify_chunk` take the
+table as `page_table=None` and hand it to the layers; how attention writes
+and reads through it, token-identical to the dense step, is told at
+`layers/attention.py:decode_step`.  The host (serving/batcher.py) owns
 the free list and keeps physical page 0 as a scratch page every
 inactive slot's table rows point at.
 
@@ -71,7 +68,6 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf import LayerType, MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers import get_layer
-from deeplearning4j_tpu.nn.layers.base import compute_dtype
 from deeplearning4j_tpu.nn.layers.output import OutputLayer
 from deeplearning4j_tpu.utils.profiling import layer_scope, scope
 
@@ -81,12 +77,6 @@ GENERATIVE_HIDDEN = (LayerType.LSTM, LayerType.GRAVES_LSTM,
                      LayerType.KDA, LayerType.MLA, LayerType.SWIGLU,
                      LayerType.MOE)
 
-_RECURRENT = (LayerType.LSTM, LayerType.GRAVES_LSTM)
-
-#: no state: one token's row goes through `forward` as a sequence's would
-#: (an MOE layer's `apply` also counts its picks, which `_step` keeps)
-_STATELESS = (LayerType.TRANSFORMER_FFN, LayerType.SWIGLU, LayerType.MOE)
-
 #: token emitted by `decode_block` for scan steps a row sat frozen
 #: (its `rem` budget exhausted mid-block) — never a valid token id
 BLOCK_SENTINEL = -1
@@ -94,9 +84,9 @@ BLOCK_SENTINEL = -1
 
 def check_generative(conf: MultiLayerConfiguration):
     """Validate that `conf` is a decodable generative stack and return
-    its layer types: optional leading EMBEDDING, then
-    LSTM/GRAVES_LSTM/ATTENTION/TRANSFORMER_FFN hidden layers (causal
-    attention only), then a final OUTPUT layer; the only preprocessor
+    its layer types: optional leading EMBEDDING, then hidden layers of
+    `GENERATIVE_HIDDEN` (causal attention only), then a final OUTPUT layer;
+    the only preprocessor
     allowed is the trailing rnn_to_ff (which the per-token decode skips —
     its activations are already [B, n])."""
     n = conf.n_layers
@@ -136,27 +126,32 @@ def positional_bound(conf: MultiLayerConfiguration) -> int:
     return 0
 
 
-def _own_state(t) -> bool:
-    """Does layer type `t` say itself what its decode state is?"""
-    return hasattr(get_layer(t), "init_state")
-
-
-def _is_carry(t) -> bool:
-    return t in _RECURRENT or getattr(get_layer(t), "CARRY", False)
+def _hidden(conf: MultiLayerConfiguration):
+    """(index, layer conf, layer class) of every layer between the optional
+    EMBEDDING and the OUTPUT layer: the ones that step a token and may keep
+    a state.  The class is looked up anew at every walk."""
+    types = check_generative(conf)
+    first = 1 if types[0] == LayerType.EMBEDDING else 0
+    return [(i, conf.conf(i), get_layer(conf.conf(i).layer_type))
+            for i in range(first, conf.n_layers - 1)]
 
 
 def dense_only(conf: MultiLayerConfiguration):
     """The layer types of `conf` whose state lives in the dense slot table
-    alone, as strings ([] when there is none): the paged pool holds K/V
-    pages and nothing else, a cached prefix row would have to carry a
+    alone, as strings ([] when there is none): those whose class declares
+    neither `init_paged_state` nor `verify_chunk`.  The paged pool holds
+    K/V pages and nothing else, a cached prefix row would have to carry a
     recurrent state that is only right at the prompt's end, and a verify
     chunk cannot roll such a state back."""
-    return sorted({str(t) for t in check_generative(conf) if _own_state(t)})
+    return sorted({str(c.layer_type) for _, c, impl in _hidden(conf)
+                   if not (hasattr(impl, "init_paged_state")
+                           and hasattr(impl, "verify_chunk"))})
 
 
 def has_experts(conf: MultiLayerConfiguration) -> bool:
-    """Does a decode step of `conf` count expert picks (an MOE layer)?"""
-    return any(LayerType(str(c.layer_type)) == LayerType.MOE
+    """Does a decode step of `conf` count expert picks (a layer type with
+    a `counted_step`)?"""
+    return any(hasattr(get_layer(c.layer_type), "counted_step")
                for c in conf.confs)
 
 
@@ -168,31 +163,23 @@ def _refuse_dense_only(conf, what: str) -> None:
             f"in the dense slot table only")
 
 
+def _states(conf, of) -> tuple:
+    """One dict a layer: `of(layer conf, layer class)` for the hidden
+    layers, {} for the EMBEDDING and the OUTPUT layer."""
+    state = [{} for _ in range(conf.n_layers)]
+    for i, c, impl in _hidden(conf):
+        state[i] = of(c, impl)
+    return tuple(state)
+
+
 def init_state(conf: MultiLayerConfiguration, batch: int, max_seq: int):
     """Fresh decode state for `batch` rows and a `max_seq`-token table."""
-    types = check_generative(conf)
-    if types[0] == LayerType.EMBEDDING:
-        table = conf.conf(0).max_seq_len
-        if table and max_seq > table:
-            raise ValueError(
-                f"max_seq={max_seq} exceeds the learned positional table "
-                f"(max_seq_len={table})")
-    state = []
-    for i, t in enumerate(types):
-        c = conf.conf(i)
-        if t in _RECURRENT:
-            # f32 like the eager sampler's zeros-init carries
-            state.append({"h": jnp.zeros((batch, c.n_out), jnp.float32),
-                          "c": jnp.zeros((batch, c.n_out), jnp.float32)})
-        elif t == LayerType.ATTENTION:
-            cd = compute_dtype(c)
-            state.append({"k": jnp.zeros((batch, max_seq, c.n_in), cd),
-                          "v": jnp.zeros((batch, max_seq, c.n_in), cd)})
-        elif _own_state(t):
-            state.append(get_layer(t).init_state(c, batch, max_seq))
-        else:
-            state.append({})
-    return tuple(state)
+    table = positional_bound(conf)
+    if table and max_seq > table:
+        raise ValueError(
+            f"max_seq={max_seq} exceeds the learned positional table "
+            f"(max_seq_len={table})")
+    return _states(conf, lambda c, impl: impl.init_state(c, batch, max_seq))
 
 
 def init_paged_state(conf: MultiLayerConfiguration, batch: int,
@@ -202,21 +189,9 @@ def init_paged_state(conf: MultiLayerConfiguration, batch: int,
     physical pool [n_pages, page_size, n] addressed through the
     per-call page table — memory scales with pages, not
     batch x max_seq."""
-    types = check_generative(conf)
     _refuse_dense_only(conf, "a paged K/V pool")
-    state = []
-    for i, t in enumerate(types):
-        c = conf.conf(i)
-        if t in _RECURRENT:
-            state.append({"h": jnp.zeros((batch, c.n_out), jnp.float32),
-                          "c": jnp.zeros((batch, c.n_out), jnp.float32)})
-        elif t == LayerType.ATTENTION:
-            cd = compute_dtype(c)
-            state.append({"k": jnp.zeros((n_pages, page_size, c.n_in), cd),
-                          "v": jnp.zeros((n_pages, page_size, c.n_in), cd)})
-        else:
-            state.append({})
-    return tuple(state)
+    return _states(conf, lambda c, impl: impl.init_paged_state(
+        c, batch, n_pages, page_size))
 
 
 def zero_row(table):
@@ -268,73 +243,40 @@ def _head_logp(conf: MultiLayerConfiguration, params, x):
         return jnp.log(jnp.clip(probs, 1e-9, 1.0))
 
 
-def _step(conf: MultiLayerConfiguration, params, state, tok, pos,
-          page_table=None):
-    """One token a row through every layer: (logp, state, counts).  With a
-    `page_table`, ATTENTION reads and writes the shared page pool.  `counts`
-    is None unless the stack has MOE layers, else their `[picks on held
-    experts, distinct held experts hit]` summed over the layers."""
-    types = check_generative(conf)
+def step(conf: MultiLayerConfiguration, params, state, tok, pos,
+         page_table=None):
+    """One token a row through every layer: (logp, state, counts).  A
+    `page_table` is handed on to the layers, of which one whose state lives
+    in the shared page pool reads and writes it there (a class that cannot
+    page takes no such argument and never meets one: `init_paged_state`
+    refused it).  `counts` is None unless the stack has layers that count
+    their expert picks (`has_experts`), else their `[picks on held experts,
+    distinct held experts hit]` summed over the layers."""
     x = token_embed(conf, params, tok, pos)
-    new_state = []
+    pages = () if page_table is None else (page_table,)
+    new_state = [{} for _ in range(conf.n_layers)]
     counts = None
-    for i, t in enumerate(types[:-1]):
-        c = conf.conf(i)
-        impl = get_layer(c.layer_type)
+    for i, c, impl in _hidden(conf):
         with layer_scope(i, c):
-            if t in _RECURRENT:
-                h, cc = impl.step(params[i], c, x, state[i]["h"],
-                                  state[i]["c"])
-                new_state.append({"h": h, "c": cc})
-                x = h
-            elif t == LayerType.ATTENTION:
-                if page_table is None:
-                    x, kc, vc = impl.decode_step(
-                        params[i], c, x, state[i]["k"], state[i]["v"], pos)
-                else:
-                    x, kc, vc = impl.decode_step_paged(
-                        params[i], c, x, state[i]["k"], state[i]["v"], pos,
-                        page_table)
-                new_state.append({"k": kc, "v": vc})
-            elif _own_state(t):
-                x, st = impl.decode_step(params[i], c, x, state[i], pos)
-                new_state.append(st)
-            elif t == LayerType.MOE:
-                x, n = impl.apply(params[i], c, x)
+            if hasattr(impl, "counted_step"):
+                x, new_state[i], n = impl.counted_step(
+                    params[i], c, x, state[i], pos, *pages)
                 counts = n if counts is None else counts + n
-                new_state.append({})
-            elif t in _STATELESS:
-                x = impl.forward(params[i], c, x)
-                new_state.append({})
-            else:  # EMBEDDING — consumed by token_embed above
-                new_state.append({})
-    new_state.append({})
+            else:
+                x, new_state[i] = impl.decode_step(
+                    params[i], c, x, state[i], pos, *pages)
     return _head_logp(conf, params, x), tuple(new_state), counts
 
 
-def decode_step(conf: MultiLayerConfiguration, params, state, tok, pos):
+def decode_step(conf: MultiLayerConfiguration, params, state, tok, pos,
+                page_table=None):
     """Advance every row one token: tok [B] int32 (the row's current
     token), pos [B] int32 (the sequence position that token occupies).
     Returns (logp [B, vocab] — log(clip(probs)) for the NEXT token —
-    and the updated state tuple)."""
-    logp, state, _ = _step(conf, params, state, tok, pos)
-    return logp, state
-
-
-def decode_step_counted(conf: MultiLayerConfiguration, params, state, tok,
-                        pos):
-    """`decode_step` with the expert layers' counts of the step beside it:
-    (logp, state, counts [2] int32, or None where `has_experts` is false)."""
-    return _step(conf, params, state, tok, pos)
-
-
-def decode_step_paged(conf: MultiLayerConfiguration, params, state, tok,
-                      pos, page_table):
-    """`decode_step` over paged ATTENTION state: page_table
-    [B, pages_per_slot] int32 routes each row's cache reads/writes
-    through the shared physical pool.  Token-identical to the dense
-    step (see layers/attention.py:decode_step_paged)."""
-    logp, state, _ = _step(conf, params, state, tok, pos, page_table)
+    and the updated state tuple); `step` is the same with the expert
+    layers' counts beside them, and says what a `page_table`
+    [B, pages_per_slot] int32 over a paged state does."""
+    logp, state, _ = step(conf, params, state, tok, pos, page_table)
     return logp, state
 
 
@@ -342,7 +284,7 @@ def decode_block(conf: MultiLayerConfiguration, params, state, tok, pos,
                  keys, temps, rem, k: int, sample, page_table=None):
     """Fused multi-step decode (ISSUE 19): advance every row up to `k`
     tokens in ONE program — a `lax.scan` whose body is exactly
-    `decode_step` (or `decode_step_paged` when `page_table` is given)
+    `decode_step` (over the paged state when `page_table` is given)
     followed by the injected `sample(logp, keys, temps) -> (tok, keys)`
     on-device sampler.  One host dispatch per K tokens instead of per
     token; the token trajectory is bitwise-identical to K sequential
@@ -365,10 +307,10 @@ def decode_block(conf: MultiLayerConfiguration, params, state, tok, pos,
 
     Returns (toks [k, B] int32 scan outputs, tok [B] (last real token
     per row), keys [B, 2], state) — state LAST, the donation/TP
-    contract every decode-family program shares.  Where the stack has MOE
-    layers (`has_experts`), their counts summed over the k steps, [2]
-    int32, come before the state."""
-    types = check_generative(conf)
+    contract every decode-family program shares.  Where the stack has
+    layers that count (`has_experts`), their counts summed over the k steps,
+    [2] int32, come before the state."""
+    carried = [i for i, _, impl in _hidden(conf) if impl.CARRY]
 
     def hold(active, new, old):
         """`new` for the rows still going, `old` for the finished ones."""
@@ -378,15 +320,12 @@ def decode_block(conf: MultiLayerConfiguration, params, state, tok, pos,
     def body(carry, _):
         st, t, p, ks, r = carry
         active = r > 0
-        logp, st2, counts = _step(conf, params, st, t, p, page_table)
+        logp, st2, counts = step(conf, params, st, t, p, page_table)
         t2, ks2 = sample(logp, ks, temps)
-        frozen = []
-        for i, lt in enumerate(types):
-            if _is_carry(lt):
-                frozen.append({name: hold(active, st2[i][name], st[i][name])
-                               for name in st2[i]})
-            else:
-                frozen.append(st2[i])
+        frozen = list(st2)
+        for i in carried:
+            frozen[i] = {name: hold(active, st2[i][name], st[i][name])
+                         for name in st2[i]}
         out = jnp.where(active, t2, jnp.int32(BLOCK_SENTINEL))
         t3 = jnp.where(active, t2, t)
         ks3 = jnp.where(active[:, None], ks2, ks)
@@ -404,80 +343,38 @@ def decode_block(conf: MultiLayerConfiguration, params, state, tok, pos,
     return outs, tok, keys, state
 
 
-def _verify_chunk_impl(conf, params, state, toks, pos, page_table):
-    """Shared body of `verify_chunk` / `verify_chunk_paged`: advance
-    every row K tokens in one pass and return per-position log-probs.
+def verify_chunk(conf: MultiLayerConfiguration, params, state, toks, pos,
+                 page_table=None):
+    """Speculative verification over a dense or (with `page_table`) a paged
+    decode state: advance every row K tokens in one pass and return
+    per-position log-probs.
 
     toks [B, K] int32 — toks[:, 0] is the row's current token, the rest
     are draft continuations; pos [B] int32 is the position of
     toks[:, 0].  Returns (logp [B, K, vocab], new_state, carries):
     logp[:, i] is the next-token distribution AFTER consuming
     toks[:, :i+1], exactly what `decode_step` would return on the i-th
-    of K sequential calls.  `carries` holds, per recurrent layer, the
-    INTERMEDIATE carries {"h"/"c": [B, K, hidden]} after each of the K
-    steps ({} for every other layer): attention state self-heals on
-    mis-speculation (rejected positions are rewritten before they are
-    read) but a recurrent carry does not, so the caller must roll the
-    returned final state back to carry index e-1 when it accepts only
+    of K sequential calls.  `carries` holds, per layer whose state is a
+    carry, the INTERMEDIATE carries (for an LSTM {"h"/"c": [B, K, hidden]})
+    after each of the K steps ({} for every other layer): attention state
+    self-heals on mis-speculation (rejected positions are rewritten before
+    they are read) but a recurrent carry does not, so the caller must roll
+    the returned final state back to carry index e-1 when it accepts only
     e < K tokens.
     """
-    types = check_generative(conf)
     _refuse_dense_only(conf, "a verify chunk")
     b, kk = toks.shape
     idx = pos[:, None] + jnp.arange(kk)[None, :]
     x = token_embed(conf, params, toks, idx)  # [B, K, n]
-    new_state = []
-    carries = []
-    for i, t in enumerate(types[:-1]):
-        c = conf.conf(i)
-        impl = get_layer(c.layer_type)
+    pages = () if page_table is None else (page_table,)
+    new_state = [{} for _ in range(conf.n_layers)]
+    carries = [{} for _ in range(conf.n_layers)]
+    for i, c, impl in _hidden(conf):
         with layer_scope(i, c):
-            if t in _RECURRENT:
-                h, cc = state[i]["h"], state[i]["c"]
-                outs, hs, cs = [], [], []
-                for j in range(kk):  # K is small and static — unrolled
-                    h, cc = impl.step(params[i], c, x[:, j], h, cc)
-                    outs.append(h)
-                    hs.append(h)
-                    cs.append(cc)
-                new_state.append({"h": h, "c": cc})
-                carries.append({"h": jnp.stack(hs, axis=1),
-                                "c": jnp.stack(cs, axis=1)})
-                x = jnp.stack(outs, axis=1)
-            elif t == LayerType.ATTENTION:
-                if page_table is None:
-                    x, kc, vc = impl.verify_chunk(
-                        params[i], c, x, state[i]["k"], state[i]["v"], pos)
-                else:
-                    x, kc, vc = impl.verify_chunk_paged(
-                        params[i], c, x, state[i]["k"], state[i]["v"], pos,
-                        page_table)
-                new_state.append({"k": kc, "v": vc})
-                carries.append({})
-            elif t in _STATELESS:
-                x = impl.forward(params[i], c, x)
-                new_state.append({})
-                carries.append({})
-            else:  # EMBEDDING
-                new_state.append({})
-                carries.append({})
+            x, new_state[i], carries[i] = impl.verify_chunk(
+                params[i], c, x, state[i], pos, *pages)
     logp = _head_logp(conf, params, x.reshape(b * kk, -1)).reshape(b, kk, -1)
-    new_state.append({})
-    carries.append({})
     return logp, tuple(new_state), tuple(carries)
-
-
-def verify_chunk(conf: MultiLayerConfiguration, params, state, toks, pos):
-    """Speculative verification over dense decode state (see
-    `_verify_chunk_impl`)."""
-    return _verify_chunk_impl(conf, params, state, toks, pos, None)
-
-
-def verify_chunk_paged(conf: MultiLayerConfiguration, params, state, toks,
-                       pos, page_table):
-    """Speculative verification over paged decode state (see
-    `_verify_chunk_impl`)."""
-    return _verify_chunk_impl(conf, params, state, toks, pos, page_table)
 
 
 def prefill(conf: MultiLayerConfiguration, params, state, prompt, length):
@@ -490,37 +387,19 @@ def prefill(conf: MultiLayerConfiguration, params, state, prompt, length):
     t >= length (as a KDA layer's state does), attention's causal mask
     hides later positions from every real one, and `decode_step`
     overwrites cache position `pos` before attending to it."""
-    types = check_generative(conf)
+    layers = _hidden(conf)
     c0 = conf.conf(0)
-    if types[0] == LayerType.EMBEDDING:
+    if layers[0][0] == 1:       # an EMBEDDING layer stands before them
         with layer_scope(0, c0), scope("embed"):
             x = get_layer(c0.layer_type).forward(params[0], c0, prompt)
     else:
         with scope("embed"):
             x = jax.nn.one_hot(prompt, c0.n_in, dtype=jnp.float32)
-    new_state = []
-    for i, t in enumerate(types[:-1]):
-        c = conf.conf(i)
-        impl = get_layer(c.layer_type)
+    new_state = [{} for _ in range(conf.n_layers)]
+    for i, c, impl in layers:
         with layer_scope(i, c):
-            if t in _RECURRENT:
-                x, h, cc = impl.prefill(params[i], c, x, state[i]["h"],
-                                        state[i]["c"], length)
-                new_state.append({"h": h, "c": cc})
-            elif t == LayerType.ATTENTION:
-                x, kc, vc = impl.prefill(params[i], c, x, state[i]["k"],
-                                         state[i]["v"])
-                new_state.append({"k": kc, "v": vc})
-            elif _own_state(t):
-                x, st = impl.prefill(params[i], c, x, state[i], length)
-                new_state.append(st)
-            elif t in _STATELESS:
-                x = impl.forward(params[i], c, x)
-                new_state.append({})
-            else:  # EMBEDDING
-                new_state.append({})
+            x, new_state[i] = impl.prefill(params[i], c, x, state[i], length)
     b = prompt.shape[0]
     with layer_scope(conf.n_layers - 1, conf.conf(conf.n_layers - 1)):
         last = x[jnp.arange(b), length - 1]
-    new_state.append({})
     return _head_logp(conf, params, last), tuple(new_state)

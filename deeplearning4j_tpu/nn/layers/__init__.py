@@ -9,6 +9,31 @@ registered by `LayerType`.  Pretrainable layers additionally expose
     pretrain_grad_and_score(params, conf, x, key) -> (grads, score)
 replacing the reference's `Model.gradientAndScore` contract
 (`nn/api/Model.java`) used by layer-wise pretraining.
+
+A layer type that can stand in a generative stack (`nn.decode.
+GENERATIVE_HIDDEN`) says itself what it keeps between tokens.  `nn/decode.py`
+walks the layers and calls, never asking which type it has before it:
+    init_state(conf, batch, max_seq)            -> state, a dict of arrays
+                                                   with the rows on axis 0
+    prefill(params, conf, x, state, length)     -> (hidden [B, T, n], state)
+    decode_step(params, conf, x, state, pos)    -> (hidden [B, n], state)
+    CARRY   does the state advance with every token (a recurrent carry: a
+            fused K-step block must hold a finished row's still), or is it
+            a table written at `pos` (rewriting a cell changes nothing)?
+What only some types can do, a class declares by having it:
+    init_paged_state(conf, batch, n_pages, page_size) -> state
+            its state can live in the page pool; `decode_step`,
+            `verify_chunk` and `counted_step` then take a `page_table=None`
+            argument
+    verify_chunk(params, conf, x, state, pos)   -> (hidden [B, K, n], state,
+            carries)   K tokens a row in one pass; `carries` the state after
+            each of them where it is a carry ({} otherwise), to roll back to
+    counted_step(params, conf, x, state, pos)   -> (hidden, state, counts)
+            a step that counts its expert picks ([2] int32)
+A type with a state but without `init_paged_state` and `verify_chunk` lives
+in the dense slot table only (`nn.decode.dense_only`).  A type with no state
+gets all of it from `base.StatelessDecode`.  A new kind of state is a layer
+file, its `LayerType` in `nn/conf.py` and a line of the registry below.
 """
 
 from deeplearning4j_tpu.nn.conf import LayerType

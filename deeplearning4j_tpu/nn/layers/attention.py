@@ -17,7 +17,8 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nd import random as ndr
 from deeplearning4j_tpu.nd.platform import is_tpu
 from deeplearning4j_tpu.nn.weights import init_weights
-from deeplearning4j_tpu.nn.layers.base import compute_dtype, mixed_matmul
+from deeplearning4j_tpu.nn.layers.base import (StatelessDecode,
+                                                compute_dtype, mixed_matmul)
 from deeplearning4j_tpu.nd.attention import (blockwise_attention,
                                              full_attention)
 from deeplearning4j_tpu.utils.profiling import scope
@@ -93,8 +94,7 @@ class MultiHeadAttentionLayer:
         # moves the crossover one doubling earlier (halves the bound): the
         # causal block-skip halves the kernel's tile visits, and the fused
         # backward removes the flash path's forward recompute.  Both
-        # shifts are analytic, not measured; bench.py's
-        # bench_attention_crossover exists to measure the boundary.
+        # shifts are analytic, not measured.
         scores_bytes = 4 * b * h * s * s  # f32 fwd scores
         bound = 8 << 30
         if conf.attention_block_skip and conf.causal:
@@ -133,18 +133,31 @@ class MultiHeadAttentionLayer:
             o = full_attention(q, k, v, causal=conf.causal)
         return o
 
+    # -- decode protocol (`nn/layers/__init__.py`): a K/V table, or pages ----
+    CARRY = False       # a finished row rewrites one cell with what it holds
+
     @staticmethod
-    def prefill(params, conf, x, k_cache, v_cache):
+    def init_state(conf, batch: int, max_seq: int) -> dict:
+        return _kv_zeros(conf, (batch, max_seq, conf.n_in))
+
+    @staticmethod
+    def init_paged_state(conf, batch: int, n_pages: int, page_size: int) -> dict:
+        """One physical pool for all rows, addressed through each call's
+        `page_table`: memory scales with pages, not batch x max_seq."""
+        return _kv_zeros(conf, (n_pages, page_size, conf.n_in))
+
+    @staticmethod
+    def prefill(params, conf, x, state, length):
         """Prompt phase of KV-cache generation: run the normal causal
         forward over the whole prompt and record the projected K/V rows
         into the pre-allocated caches.
 
-        x: [B, T, n]; caches: [B, max_S, n] (T <= max_S).  Returns
-        (hidden [B, T, n], k_cache, v_cache).  Bucket padding beyond each
-        row's true prompt length writes junk K/V at positions >= length,
-        which is harmless: the causal mask hides them from every prompt
-        position, and `decode_step` overwrites position `pos` before it
-        ever attends to it.
+        x: [B, T, n]; state: K and V [B, max_S, n] (T <= max_S).  Returns
+        (hidden [B, T, n], state).  Bucket padding beyond each row's true
+        prompt `length` writes junk K/V at positions >= length, which is
+        harmless: the causal mask hides them from every prompt position,
+        and `decode_step` overwrites position `pos` before it ever attends
+        to it.
         """
         b, s, n = x.shape
         h = conf.n_heads
@@ -152,110 +165,70 @@ class MultiHeadAttentionLayer:
         cd = compute_dtype(conf)
         q, k, v = _qkv(params, conf, x, cd)
         with scope("kv_write"):
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k.astype(k_cache.dtype), (0, 0, 0))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v.astype(v_cache.dtype), (0, 0, 0))
+            state = {"k": jax.lax.dynamic_update_slice(
+                         state["k"], k.astype(state["k"].dtype), (0, 0, 0)),
+                     "v": jax.lax.dynamic_update_slice(
+                         state["v"], v.astype(state["v"].dtype), (0, 0, 0))}
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, h, hd)
         v = v.reshape(b, s, h, hd)
         o = MultiHeadAttentionLayer._attend(conf, q, k, v)
         o = _proj(params, conf, o.reshape(b, s, n).astype(x.dtype))
-        return x + o, k_cache, v_cache
+        return x + o, state
 
     @staticmethod
-    def decode_step(params, conf, x, k_cache, v_cache, pos):
+    def decode_step(params, conf, x, state, pos, page_table=None):
         """One generated token against the KV cache.
 
-        x: [B, n] (current token's hidden row); caches: [B, max_S, n];
-        pos: [B] int32, the sequence position each row is writing.  The
-        new K/V row is scattered at `pos`, scores are [B, H, max_S] — one
-        sequence-scaled axis, never [S, S] — and key positions > pos get
-        the same additive -1e30 mask as `nd.attention.full_attention`,
-        so a greedy decode reproduces the eager full-forward trajectory
-        exactly in f32.
-        """
-        b, n = x.shape
-        h = conf.n_heads
-        hd = n // h
-        cd = compute_dtype(conf)
-        q, k, v = _qkv(params, conf, x, cd)
-        with scope("kv_write"):
-            rows = jnp.arange(b)
-            k_cache = k_cache.at[rows, pos].set(k.astype(k_cache.dtype))
-            v_cache = v_cache.at[rows, pos].set(v.astype(v_cache.dtype))
-        max_s = k_cache.shape[1]
-        with scope("kv_read"):
-            qh = q.reshape(b, h, hd)
-            kh = k_cache.astype(cd).reshape(b, max_s, h, hd)
-            vh = v_cache.astype(cd).reshape(b, max_s, h, hd)
-        with scope("scores"):
-            s = jnp.einsum("bhd,bkhd->bhk", qh, kh) / jnp.sqrt(
-                jnp.asarray(hd, qh.dtype))
-            kpos = jnp.arange(max_s)[None, :]
-            mask = jnp.where(kpos <= pos[:, None], 0.0,
-                             -1e30).astype(s.dtype)
-            p = jax.nn.softmax(s + mask[:, None, :], axis=-1)
-        with scope("attend"):
-            o = jnp.einsum("bhk,bkhd->bhd", p, vh)
-        o = _proj(params, conf, o.reshape(b, n).astype(x.dtype))
-        return x + o, k_cache, v_cache
-
-    @staticmethod
-    def decode_step_paged(params, conf, x, k_pool, v_pool, pos, page_table):
-        """`decode_step` against a shared physical page pool.
-
-        x: [B, n]; pools: [n_pages, page_size, n]; pos: [B] int32;
-        page_table: [B, pages_per_slot] int32 of physical page ids.  The
-        new K/V row is scattered at (page_table[b, pos // ps], pos % ps)
-        and the row's pages are gathered back into one
-        [B, pages_per_slot * ps, n] view before the identical masked
-        score math as the dense step — unallocated table entries point
-        at the host's scratch page, whose junk sits behind the additive
-        mask (exp(-1e30 + ·) underflows to exactly 0.0), so paged and
+        x: [B, n] (current token's hidden row); pos: [B] int32, the
+        sequence position each row is writing.  Dense state: K and V
+        [B, max_S, n], the new row scattered at `pos`.  With a `page_table`
+        [B, pages_per_slot] int32 of physical page ids the state is the
+        shared pool [n_pages, page_size, n]: the new row is scattered at
+        (page_table[b, pos // ps], pos % ps) and the row's pages are
+        gathered back into one [B, pages_per_slot * ps, n] view.  Either
+        way scores are [B, H, ctx] — one sequence-scaled axis, never
+        [S, S] — and key positions > pos get the same additive -1e30 mask
+        as `nd.attention.full_attention`, so a greedy decode reproduces the
+        eager full-forward trajectory exactly in f32.  Unallocated table
+        entries point at the host's scratch page, whose junk sits behind
+        the mask (exp(-1e30 + .) underflows to exactly 0.0), so paged and
         dense trajectories are token-identical.
         """
         b, n = x.shape
         h = conf.n_heads
         hd = n // h
-        ps = k_pool.shape[1]
         cd = compute_dtype(conf)
         q, k, v = _qkv(params, conf, x, cd)
         with scope("kv_write"):
-            rows = jnp.arange(b)
-            phys = page_table[rows, pos // ps]
-            off = pos % ps
-            k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-            v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
-        pp = page_table.shape[1]
-        ctx = pp * ps
+            state = _kv_write(state, k, v, jnp.arange(b), pos, page_table)
         with scope("kv_read"):
             qh = q.reshape(b, h, hd)
-            kh = k_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
-            vh = v_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
+            kh, vh = _kv_read(state, page_table, b, h, hd, cd)
         with scope("scores"):
             s = jnp.einsum("bhd,bkhd->bhk", qh, kh) / jnp.sqrt(
                 jnp.asarray(hd, qh.dtype))
-            kpos = jnp.arange(ctx)[None, :]
+            kpos = jnp.arange(kh.shape[1])[None, :]
             mask = jnp.where(kpos <= pos[:, None], 0.0,
                              -1e30).astype(s.dtype)
             p = jax.nn.softmax(s + mask[:, None, :], axis=-1)
         with scope("attend"):
             o = jnp.einsum("bhk,bkhd->bhd", p, vh)
         o = _proj(params, conf, o.reshape(b, n).astype(x.dtype))
-        return x + o, k_pool, v_pool
+        return x + o, state
 
     @staticmethod
-    def verify_chunk(params, conf, x, k_cache, v_cache, pos):
+    def verify_chunk(params, conf, x, state, pos, page_table=None):
         """Speculative verification: advance every row K tokens at once.
 
-        x: [B, K, n] (chunk hidden rows); caches: [B, max_S, n]; pos:
-        [B] int32, the position of each row's FIRST chunk token.  Token
-        i is written at pos + i and attends causally at kpos <= pos + i
-        — the same mask `decode_step` would apply i calls later — so the
-        chunk's hidden rows match K sequential decode steps exactly.
-        Mis-speculated suffixes need no rollback: the next call simply
-        rewrites those positions before attending to them.
+        x: [B, K, n] (chunk hidden rows); state and `page_table` as in
+        `decode_step`; pos: [B] int32, the position of each row's FIRST
+        chunk token.  Token i is written at pos + i and attends causally at
+        kpos <= pos + i — the same mask `decode_step` would apply i calls
+        later — so the chunk's hidden rows match K sequential decode steps
+        exactly.  Mis-speculated suffixes need no rollback: the next call
+        rewrites those positions before attending to them, hence no
+        carries: returns (hidden [B, K, n], state, {}).
         """
         b, kk, n = x.shape
         h = conf.n_heads
@@ -265,60 +238,49 @@ class MultiHeadAttentionLayer:
         with scope("kv_write"):
             rows = jnp.arange(b)[:, None]
             idx = pos[:, None] + jnp.arange(kk)[None, :]
-            k_cache = k_cache.at[rows, idx].set(k.astype(k_cache.dtype))
-            v_cache = v_cache.at[rows, idx].set(v.astype(v_cache.dtype))
-        max_s = k_cache.shape[1]
+            state = _kv_write(state, k, v, rows, idx, page_table)
         with scope("kv_read"):
             qh = q.reshape(b, kk, h, hd)
-            kh = k_cache.astype(cd).reshape(b, max_s, h, hd)
-            vh = v_cache.astype(cd).reshape(b, max_s, h, hd)
+            kh, vh = _kv_read(state, page_table, b, h, hd, cd)
         with scope("scores"):
             s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / jnp.sqrt(
                 jnp.asarray(hd, qh.dtype))
-            kpos = jnp.arange(max_s)[None, None, :]
+            kpos = jnp.arange(kh.shape[1])[None, None, :]
             mask = jnp.where(kpos <= idx[:, :, None], 0.0,
                              -1e30).astype(s.dtype)
             p = jax.nn.softmax(s + mask[:, None, :, :], axis=-1)
         with scope("attend"):
             o = jnp.einsum("bhqk,bkhd->bqhd", p, vh)
         o = _proj(params, conf, o.reshape(b, kk, n).astype(x.dtype))
-        return x + o, k_cache, v_cache
+        return x + o, state, {}
 
-    @staticmethod
-    def verify_chunk_paged(params, conf, x, k_pool, v_pool, pos, page_table):
-        """`verify_chunk` against the physical page pool — scatter each
-        chunk token at its (page, offset) and gather the paged context
-        once; mask semantics identical to the dense chunk."""
-        b, kk, n = x.shape
-        h = conf.n_heads
-        hd = n // h
-        ps = k_pool.shape[1]
-        cd = compute_dtype(conf)
-        q, k, v = _qkv(params, conf, x, cd)
-        with scope("kv_write"):
-            rows = jnp.arange(b)[:, None]
-            idx = pos[:, None] + jnp.arange(kk)[None, :]
-            phys = page_table[rows, idx // ps]
-            off = idx % ps
-            k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-            v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
-        pp = page_table.shape[1]
-        ctx = pp * ps
-        with scope("kv_read"):
-            qh = q.reshape(b, kk, h, hd)
-            kh = k_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
-            vh = v_pool[page_table].reshape(b, ctx, h, hd).astype(cd)
-        with scope("scores"):
-            s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / jnp.sqrt(
-                jnp.asarray(hd, qh.dtype))
-            kpos = jnp.arange(ctx)[None, None, :]
-            mask = jnp.where(kpos <= idx[:, :, None], 0.0,
-                             -1e30).astype(s.dtype)
-            p = jax.nn.softmax(s + mask[:, None, :, :], axis=-1)
-        with scope("attend"):
-            o = jnp.einsum("bhqk,bkhd->bqhd", p, vh)
-        o = _proj(params, conf, o.reshape(b, kk, n).astype(x.dtype))
-        return x + o, k_pool, v_pool
+
+def _kv_zeros(conf, shape) -> dict:
+    cd = compute_dtype(conf)
+    return {"k": jnp.zeros(shape, cd), "v": jnp.zeros(shape, cd)}
+
+
+def _kv_write(state, k, v, rows, idx, page_table):
+    """The new rows k, v at positions `idx` of rows `rows`: into the rows'
+    own tables, or through `page_table` into the pool's (page, offset)."""
+    kc, vc = state["k"], state["v"]
+    if page_table is None:
+        at = (rows, idx)
+    else:
+        ps = kc.shape[1]
+        at = (page_table[rows, idx // ps], idx % ps)
+    return {"k": kc.at[at].set(k.astype(kc.dtype)),
+            "v": vc.at[at].set(v.astype(vc.dtype))}
+
+
+def _kv_read(state, page_table, b, h, hd, cd):
+    """Every row's keys and values as [B, ctx, H, hd] in `cd`: its table
+    (ctx = max_S), or its pages gathered (ctx = pages_per_slot * ps)."""
+    if page_table is None:
+        return tuple(state[name].astype(cd).reshape(b, -1, h, hd)
+                     for name in ("k", "v"))
+    return tuple(state[name][page_table].reshape(b, -1, h, hd).astype(cd)
+                 for name in ("k", "v"))
 
 
 def _layer_norm(x, g, b, eps: float = 1e-5):
@@ -342,12 +304,12 @@ def _proj(params, conf, o):
         return mixed_matmul(o, params["Wo"], conf) + params["bo"]
 
 
-class TransformerFFNLayer:
+class TransformerFFNLayer(StatelessDecode):
     """Pre-LN residual MLP — the second half of a transformer block.
 
     Hidden width = conf.ffn_hidden, defaulting to 4*n_in.  Pairs with
     MultiHeadAttentionLayer to form [attention, ffn] blocks in a
-    MultiLayerConfiguration stack.
+    MultiLayerConfiguration stack.  Keeps no decode state.
     """
 
     @staticmethod

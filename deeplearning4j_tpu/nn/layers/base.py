@@ -52,6 +52,35 @@ def rows_broadcast(v, n_rows, dtype=None):
     return jnp.ones((n_rows, 1), dt) @ v[None, :].astype(dt)
 
 
+class StatelessDecode:
+    """The decode protocol (`nn/layers/__init__.py`) of a layer type that
+    keeps nothing between tokens: `{}` for a state, and one token's row, a
+    chunk's rows and a prompt's all go through `forward` as a sequence's
+    would.  With nothing to keep it can page and be verified in a chunk."""
+
+    CARRY = False
+
+    @staticmethod
+    def init_state(conf, batch: int, max_seq: int) -> dict:
+        return {}
+
+    @staticmethod
+    def init_paged_state(conf, batch: int, n_pages: int, page_size: int) -> dict:
+        return {}
+
+    @classmethod
+    def prefill(cls, params, conf, x, state, length):
+        return cls.forward(params, conf, x), state
+
+    @classmethod
+    def decode_step(cls, params, conf, x, state, pos, page_table=None):
+        return cls.forward(params, conf, x), state
+
+    @classmethod
+    def verify_chunk(cls, params, conf, x, state, pos, page_table=None):
+        return cls.forward(params, conf, x), state, {}
+
+
 class DenseLayer:
     """f(x.W + b) with optional dropout/dropconnect."""
 

@@ -21,9 +21,10 @@ fraction of the experts, its picks usually fit a fraction of the rows: when
 they fit the first 3/8 the products run over those alone, otherwise over
 all of them (`jax.lax.cond`; the result is the same either way).
 
-`MoELayer.apply` also returns two counts of the call, `[picks that landed
-on held experts, distinct held experts hit]`, which the decode programs hand
-to the batcher.
+Neither keeps a decode state (`base.StatelessDecode`).  `MoELayer.apply`
+also returns two counts of the call, `[picks that landed on held experts,
+distinct held experts hit]`; `MoELayer.counted_step` is the decode step that
+keeps them (`nn.decode.has_experts`), for the programs to hand the batcher.
 """
 
 from __future__ import annotations
@@ -31,13 +32,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.nn.layers.base import compute_dtype
+from deeplearning4j_tpu.nn.layers.base import StatelessDecode, compute_dtype
 from deeplearning4j_tpu.nn.layers.rms import (F32, initializer, pre_norm,
                                               swiglu)
 from deeplearning4j_tpu.utils.profiling import scope
 
 
-class SwiGLULayer:
+class SwiGLULayer(StatelessDecode):
     @staticmethod
     def init(key, conf):
         s = conf.layer_spec
@@ -112,7 +113,7 @@ def held_experts(params, spec, cd, u, ids, weights):
     return y, jnp.stack([here, jnp.sum(sizes > 0)]).astype(jnp.int32)
 
 
-class MoELayer:
+class MoELayer(StatelessDecode):
     @staticmethod
     def init(key, conf):
         s = conf.layer_spec
@@ -148,3 +149,9 @@ class MoELayer:
     @staticmethod
     def forward(params, conf, x, key=None, training=False):
         return MoELayer.apply(params, conf, x)[0]
+
+    @staticmethod
+    def counted_step(params, conf, x, state, pos, page_table=None):
+        """`decode_step` with the counts of the call: (hidden, state, counts)."""
+        x, counts = MoELayer.apply(params, conf, x)
+        return x, state, counts

@@ -122,14 +122,29 @@ class LSTMLayer:
         (_, _), hs = jax.lax.scan(step, (h0, c0), jnp.swapaxes(z_x, 0, 1))
         return jnp.swapaxes(hs, 0, 1)
 
-    @staticmethod
-    def step(params, conf, x_t, h, c):
+    @classmethod
+    def step(cls, params, conf, x_t, h, c):
         """Single decode step (used by sampling / beam search)."""
-        (h, c), _ = LSTMLayer._step(params, conf.n_out, (h, c), x_t)
+        (h, c), _ = cls._step(params, conf.n_out, (h, c), x_t)
         return h, c
 
+    # -- decode protocol (`nn/layers/__init__.py`): the carry (h, c) ----------
+    CARRY = True        # a finished row's carry must not advance
+
+    @staticmethod
+    def init_state(conf, batch: int, max_seq: int) -> dict:
+        # f32 like the eager sampler's zeros-init carries
+        return {"h": jnp.zeros((batch, conf.n_out), jnp.float32),
+                "c": jnp.zeros((batch, conf.n_out), jnp.float32)}
+
     @classmethod
-    def prefill(cls, params, conf, x, h0, c0, length):
+    def init_paged_state(cls, conf, batch: int, n_pages: int,
+                         page_size: int) -> dict:
+        """A carry is a row a slot wherever attention keeps its K/V."""
+        return cls.init_state(conf, batch, 0)
+
+    @classmethod
+    def prefill(cls, params, conf, x, state, length):
         """Prompt phase of cached generation: scan the prompt through the
         per-step concat form (`cls._step`, the exact math `step()` runs
         one token at a time — NOT the reassociated `_hoisted_scan`), so
@@ -138,7 +153,7 @@ class LSTMLayer:
         padding never advances a carry.
 
         x: [B, T, n_in]; length: [B] int32.  Returns
-        (hs [B, T, n_out], h [B, n_out], c [B, n_out]).
+        (hs [B, T, n_out], {"h": [B, n_out], "c": [B, n_out]}).
         """
         n_h = conf.n_out
 
@@ -152,8 +167,32 @@ class LSTMLayer:
 
         T = x.shape[1]
         (h, c), hs = jax.lax.scan(
-            scan_step, (h0, c0), (jnp.arange(T), jnp.swapaxes(x, 0, 1)))
-        return jnp.swapaxes(hs, 0, 1), h, c
+            scan_step, (state["h"], state["c"]),
+            (jnp.arange(T), jnp.swapaxes(x, 0, 1)))
+        return jnp.swapaxes(hs, 0, 1), {"h": h, "c": c}
+
+    @classmethod
+    def decode_step(cls, params, conf, x, state, pos, page_table=None):
+        """One token a row: the eager sampler's `step()`."""
+        h, c = cls.step(params, conf, x, state["h"], state["c"])
+        return h, {"h": h, "c": c}
+
+    @classmethod
+    def verify_chunk(cls, params, conf, x, state, pos, page_table=None):
+        """x [B, K, n_in] through K steps.  Returns (hs [B, K, n_out], the
+        carry after the last, and the carries after each, {"h"/"c":
+        [B, K, n_out]}): a carry does not heal as a table does, so the
+        caller rolls it back to index e - 1 when it accepts e < K tokens."""
+        h, c = state["h"], state["c"]
+        hs, cs = [], []
+        for j in range(x.shape[1]):  # K is small and static — unrolled
+            h, c = cls.step(params, conf, x[:, j], h, c)
+            hs.append(h)
+            cs.append(c)
+        carries = {"h": jnp.stack(hs, axis=1), "c": jnp.stack(cs, axis=1)}
+        # the hidden rows are the h carries, stacked anew: the verify
+        # programs on disk have both, and XLA keeps one
+        return jnp.stack(hs, axis=1), {"h": h, "c": c}, carries
 
 
 class GravesLSTMLayer(LSTMLayer):
@@ -211,8 +250,3 @@ class GravesLSTMLayer(LSTMLayer):
         return LSTMLayer._hoisted_scan(
             params, conf.n_in, x, h0, c0,
             lambda carry, z: GravesLSTMLayer._gates(params, n_h, carry, z))
-
-    @staticmethod
-    def step(params, conf, x_t, h, c):
-        (h, c), _ = GravesLSTMLayer._step(params, conf.n_out, (h, c), x_t)
-        return h, c

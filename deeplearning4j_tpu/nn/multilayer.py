@@ -613,37 +613,25 @@ class MultiLayerNetwork:
             state = ic.init_paged_decode_state(
                 self.conf, slots, pool_pages + 1, page_size)
             page_table = jnp.zeros((slots, pages_per_slot), jnp.int32)
-            ic.decode_paged(self.conf, self.params, state, tok, pos,
-                            keys, temps, page_table, compile_only=True)
-            # the adaptive-K loop dispatches every ladder K up to k_max
-            # while ramping — k=1 included (a ramp reset dispatches the
-            # fused block at K=1, not the classic step) — so warm the
-            # whole ladder
-            if k_max > 1:
-                for k in tunables.decode_k_ladder(k_max):
-                    ic.decode_multi_paged(self.conf, self.params, state,
-                                          tok, pos, keys, temps, rem,
-                                          page_table, k, compile_only=True)
         else:
             state = ic.init_decode_state(self.conf, slots, max_seq)
-            ic.decode(self.conf, self.params, state, tok, pos, keys,
-                      temps, compile_only=True)
-            if k_max > 1:
-                for k in tunables.decode_k_ladder(k_max):
-                    ic.decode_multi(self.conf, self.params, state, tok,
-                                    pos, keys, temps, rem, k,
-                                    compile_only=True)
+        ic.decode(self.conf, self.params, state, tok, pos, keys, temps,
+                  page_table=page_table, compile_only=True)
+        # the adaptive-K loop dispatches every ladder K up to k_max
+        # while ramping — k=1 included (a ramp reset dispatches the
+        # fused block at K=1, not the classic step) — so warm the
+        # whole ladder
+        if k_max > 1:
+            for k in tunables.decode_k_ladder(k_max):
+                ic.decode_multi(self.conf, self.params, state, tok, pos,
+                                keys, temps, rem, k, page_table=page_table,
+                                compile_only=True)
         if draft_net is not None:
             if int(spec_k) < 2:
                 raise ValueError("draft_net requires spec_k >= 2")
             toks = jnp.zeros((slots, int(spec_k)), jnp.int32)
-            if page_size > 0:
-                ic.verify_paged(self.conf, self.params, state, toks,
-                                pos, keys, temps, page_table,
-                                compile_only=True)
-            else:
-                ic.verify(self.conf, self.params, state, toks, pos,
-                          keys, temps, compile_only=True)
+            ic.verify(self.conf, self.params, state, toks, pos, keys, temps,
+                      page_table=page_table, compile_only=True)
             dic = draft_net.infer_cache
             dstate = dic.init_decode_state(draft_net.conf, slots, max_seq)
             dic.decode(draft_net.conf, draft_net.params, dstate, tok,

@@ -382,32 +382,53 @@ class InferCache(CompiledProgramCache):
 
         return (1,) if default_backend() != "cpu" else ()
 
-    def _decode_shardings(self, sp, state, n_rest: int) -> Optional[Tuple]:
-        """Per-arg shardings for a decode-family program under a
-        tensor-parallel plan: params and KV state per the plan's
-        per-leaf specs, the small host args (tok/pos/keys/temps/
-        page_table) replicated.  None without a `model` axis —
-        generation stays a single-chip program exactly as before."""
-        plan = self.plan
-        if not plan.has_model_axis:
-            return None
-        rep = plan.replicated()
-        return ((plan.param_shardings(sp), plan.state_shardings(state))
-                + (rep,) * int(n_rest))
+    def _run_decode(self, entry: str, program, conf, head, state, rest: Tuple,
+                    keyed: Optional[Tuple] = None, head_is_row: bool = False,
+                    compile_only: bool = False):
+        """What every decode-family entry does, in one place: the key, the
+        program, the step count, the placement and the call.
 
-    def _decode_place(self, sp, state, *rest) -> Tuple:
-        """Execution placement for a TP decode call: params memoized
-        per-leaf, state leaves pinned to the plan's specs (a no-op for
-        the steady-state loop — the program's output constraint keeps
-        the donated state on-spec), host args replicated."""
-        plan = self.plan
-        if not plan.has_model_axis:
-            return (sp, state) + rest
-        rep = plan.replicated()
-        state = jax.tree_util.tree_map(jax.device_put, state,
-                                       plan.state_shardings(state))
-        return (self._place_params(sp), state) + tuple(
-            jax.device_put(a, rep) for a in rest)
+        `program(conf, policy)` builds the function to compile.  Its
+        arguments are `(head, state) + rest`: `head` the params as the
+        policy serves them (for `write_row` the row: `head_is_row`),
+        `state` the decode state or slot table — argument 1, donated
+        off-CPU, and last among the outputs — and `rest` the small host
+        arguments.  The key is (entry, fingerprint, the signature of
+        `keyed` (default: `rest`) and then of the state's leaves, the
+        decode tag) + the policy suffix.  Under a plan with a `model` axis
+        the head and the state are sharded leaf by leaf per the plan and
+        the rest replicated, at compile time and at the call (params
+        memoized; a no-op for the state in the steady loop, whose output
+        the program pins to the same specs); without one, generation stays
+        a single-chip program and nothing is placed.  compile_only=True
+        (warmup) compiles, or restores from disk, and returns None."""
+        plan, policy = self.plan, self._policy
+        if not head_is_row:
+            head = self._serve_params(head)
+        key = (entry, self._fingerprint(conf),
+               arg_signature(*(rest if keyed is None else keyed),
+                             *jax.tree_util.tree_leaves(state)),
+               self._decode_tag()) + self._policy_suffix()
+        shardings = None
+        if plan.has_model_axis:
+            rep = plan.replicated()
+            shardings = ((plan.state_shardings(head) if head_is_row
+                          else plan.param_shardings(head),
+                          plan.state_shardings(state))
+                         + (rep,) * len(rest))
+        fn = self._get(key, self._tp_build(lambda: program(conf, policy)),
+                       (head, state) + rest, donate=self._decode_donate(),
+                       shardings=shardings)
+        if compile_only:
+            return None
+        with self._lock:
+            self.stats.steps += 1
+        if shardings is not None:
+            head = (self._place_decode_state(head) if head_is_row
+                    else self._place_params(head))
+            state = self._place_decode_state(state)
+            rest = tuple(jax.device_put(a, rep) for a in rest)
+        return fn(head, state, *rest)
 
     def _tp_build(self, build):
         """Wrap a decode-family program builder for a tensor-parallel
@@ -458,7 +479,7 @@ class InferCache(CompiledProgramCache):
                                   batch, max_seq))
 
     def decode(self, conf, params, state, tok, pos, keys, temps,
-               compile_only: bool = False):
+               page_table=None, compile_only: bool = False):
         """One compiled KV-cache decode step over the whole slot table:
         tok/pos [B] int32, keys [B, 2] uint32 per-row PRNG keys, temps
         [B] f32 (<= 0 rows decode greedily).  Returns (next_tok [B]
@@ -466,27 +487,21 @@ class InferCache(CompiledProgramCache):
         off-CPU.  A stack with expert layers (`nn.decode.has_experts`)
         returns their counts of the step, [2] int32, before the state (as
         `decode_multi` does, summed over its steps).  Under a 1-D (or no)
-        mesh generation is single-chip and
-        the key carries the SINGLE tag exactly as before; a plan with a
-        `model` axis re-keys the program by its sharding tag and shards
-        params + KV state per the plan."""
-        policy, sp = self._policy, self._serve_params(params)
-        key = ("decode", self._fingerprint(conf),
-               arg_signature(tok, pos, keys, temps,
-                             *jax.tree_util.tree_leaves(state)),
-               self._decode_tag()) + self._policy_suffix()
-        fn = self._get(key,
-                       self._tp_build(lambda: _decode_program(conf, policy)),
-                       (sp, state, tok, pos, keys, temps),
-                       donate=self._decode_donate(),
-                       shardings=self._decode_shardings(sp, state, 4))
-        if compile_only:
-            return None
-        with self._lock:
-            self.stats.steps += 1
-        return fn(*self._decode_place(sp, state, tok, pos, keys, temps))
+        mesh generation is single-chip and the key carries the SINGLE tag
+        exactly as before; a plan with a `model` axis re-keys the program
+        by its sharding tag and shards params + KV state per the plan.
 
-    # -- paged decode + speculative verification (ISSUE 16) ------------------
+        Over a paged state (ISSUE 16, `init_paged_decode_state`),
+        `page_table` [B, pages_per_slot] int32 is a tiny per-call host
+        argument, the program's last, routing each row through the shared
+        physical pool; the key entry is then "decode-paged", so paged and
+        dense programs coexist."""
+        return self._run_decode(
+            "decode" if page_table is None else "decode-paged",
+            _decode_program, conf, params, state,
+            _with_pages((tok, pos, keys, temps), page_table),
+            compile_only=compile_only)
+
     def init_paged_decode_state(self, conf, batch: int, n_pages: int,
                                 page_size: int):
         """Fresh paged decode state (shared K/V page pool) shaped for
@@ -497,32 +512,9 @@ class InferCache(CompiledProgramCache):
         return self._place_decode_state(decode_mod.init_paged_state(
             _policy_conf(conf, self._policy), batch, n_pages, page_size))
 
-    def decode_paged(self, conf, params, state, tok, pos, keys, temps,
-                     page_table, compile_only: bool = False):
-        """`decode` over the paged state: page_table [B, pages_per_slot]
-        int32 is a tiny per-call host argument routing each row through
-        the shared physical pool.  Same donation contract as `decode`
-        (the pool is arg 1, donated off-CPU); its key entry is
-        "decode-paged" so paged and dense programs coexist."""
-        policy, sp = self._policy, self._serve_params(params)
-        key = ("decode-paged", self._fingerprint(conf),
-               arg_signature(tok, pos, keys, temps, page_table,
-                             *jax.tree_util.tree_leaves(state)),
-               self._decode_tag()) + self._policy_suffix()
-        fn = self._get(
-            key, self._tp_build(lambda: _decode_paged_program(conf, policy)),
-            (sp, state, tok, pos, keys, temps, page_table),
-            donate=self._decode_donate(),
-            shardings=self._decode_shardings(sp, state, 5))
-        if compile_only:
-            return None
-        with self._lock:
-            self.stats.steps += 1
-        return fn(*self._decode_place(sp, state, tok, pos, keys, temps,
-                                      page_table))
-
     def decode_multi(self, conf, params, state, tok, pos, keys, temps,
-                     rem, k: int, compile_only: bool = False):
+                     rem, k: int, page_table=None,
+                     compile_only: bool = False):
         """Fused K-step decode (ISSUE 19): ONE program advances every
         row up to `k` tokens — `lax.scan` over the decode step with
         in-program sampling, bitwise the trajectory `k` sequential
@@ -532,52 +524,21 @@ class InferCache(CompiledProgramCache):
         tok_last [B], keys [B, 2], new state).  K is folded into the
         key's ENTRY name ("decode-multi[k]") so the (entry, sig, tag,
         policy) key layout every summary/audit consumer parses is
-        unchanged.  Same donation/sharding contract as `decode`."""
-        policy, sp = self._policy, self._serve_params(params)
-        key = ("decode-multi[%d]" % int(k), self._fingerprint(conf),
-               arg_signature(tok, pos, keys, temps, rem,
-                             *jax.tree_util.tree_leaves(state)),
-               self._decode_tag()) + self._policy_suffix()
-        fn = self._get(
-            key,
-            self._tp_build(lambda: _decode_multi_program(conf, policy, k)),
-            (sp, state, tok, pos, keys, temps, rem),
-            donate=self._decode_donate(),
-            shardings=self._decode_shardings(sp, state, 5))
-        if compile_only:
-            return None
-        with self._lock:
-            self.stats.steps += 1
-        return fn(*self._decode_place(sp, state, tok, pos, keys, temps,
-                                      rem))
+        unchanged.  Same donation/sharding contract as `decode`.
 
-    def decode_multi_paged(self, conf, params, state, tok, pos, keys,
-                           temps, rem, page_table, k: int,
-                           compile_only: bool = False):
-        """`decode_multi` over the paged state ("decode-multi-paged[k]"
-        key entry): the page_table rides the whole block, so the host
-        must have allocated pages for all `k` positions up front."""
-        policy, sp = self._policy, self._serve_params(params)
-        key = ("decode-multi-paged[%d]" % int(k), self._fingerprint(conf),
-               arg_signature(tok, pos, keys, temps, rem, page_table,
-                             *jax.tree_util.tree_leaves(state)),
-               self._decode_tag()) + self._policy_suffix()
-        fn = self._get(
-            key,
-            self._tp_build(
-                lambda: _decode_multi_paged_program(conf, policy, k)),
-            (sp, state, tok, pos, keys, temps, rem, page_table),
-            donate=self._decode_donate(),
-            shardings=self._decode_shardings(sp, state, 6))
-        if compile_only:
-            return None
-        with self._lock:
-            self.stats.steps += 1
-        return fn(*self._decode_place(sp, state, tok, pos, keys, temps,
-                                      rem, page_table))
+        Over a paged state the entry is "decode-multi-paged[k]": the
+        `page_table` rides the whole block, so the host must have
+        allocated pages for all `k` positions up front."""
+        return self._run_decode(
+            ("decode-multi[%d]" if page_table is None
+             else "decode-multi-paged[%d]") % int(k),
+            lambda c, policy: _decode_multi_program(c, policy, k), conf,
+            params, state,
+            _with_pages((tok, pos, keys, temps, rem), page_table),
+            compile_only=compile_only)
 
     def verify(self, conf, params, state, toks, pos, keys, temps,
-               compile_only: bool = False):
+               page_table=None, compile_only: bool = False):
         """Speculative verification step: toks [B, K] int32 (column 0 is
         each row's current token, columns 1..K-1 the draft
         continuations), pos [B] int32 the position of column 0.  One
@@ -588,42 +549,13 @@ class InferCache(CompiledProgramCache):
         accepting 1..K tokens), new state).  The host accepts the
         longest prefix where draft and sample agree; mis-speculated
         cache rows are rewritten by the next call before being read, so
-        rollback is free."""
-        policy, sp = self._policy, self._serve_params(params)
-        key = ("verify", self._fingerprint(conf),
-               arg_signature(toks, pos, keys, temps,
-                             *jax.tree_util.tree_leaves(state)),
-               self._decode_tag()) + self._policy_suffix()
-        fn = self._get(key,
-                       self._tp_build(lambda: _verify_program(conf, policy)),
-                       (sp, state, toks, pos, keys, temps),
-                       donate=self._decode_donate(),
-                       shardings=self._decode_shardings(sp, state, 4))
-        if compile_only:
-            return None
-        with self._lock:
-            self.stats.steps += 1
-        return fn(*self._decode_place(sp, state, toks, pos, keys, temps))
-
-    def verify_paged(self, conf, params, state, toks, pos, keys, temps,
-                     page_table, compile_only: bool = False):
-        """`verify` over the paged state ("verify-paged" key entry)."""
-        policy, sp = self._policy, self._serve_params(params)
-        key = ("verify-paged", self._fingerprint(conf),
-               arg_signature(toks, pos, keys, temps, page_table,
-                             *jax.tree_util.tree_leaves(state)),
-               self._decode_tag()) + self._policy_suffix()
-        fn = self._get(
-            key, self._tp_build(lambda: _verify_paged_program(conf, policy)),
-            (sp, state, toks, pos, keys, temps, page_table),
-            donate=self._decode_donate(),
-            shardings=self._decode_shardings(sp, state, 5))
-        if compile_only:
-            return None
-        with self._lock:
-            self.stats.steps += 1
-        return fn(*self._decode_place(sp, state, toks, pos, keys, temps,
-                                      page_table))
+        rollback is free.  Over a paged state, with its `page_table`, the
+        key entry is "verify-paged"."""
+        return self._run_decode(
+            "verify" if page_table is None else "verify-paged",
+            _verify_program, conf, params, state,
+            _with_pages((toks, pos, keys, temps), page_table),
+            compile_only=compile_only)
 
     def prefill(self, conf, params, state, prompt, length, keys, temps,
                 compile_only: bool = False):
@@ -633,21 +565,9 @@ class InferCache(CompiledProgramCache):
         one program execution).  Same donation/key contract as
         `decode`; one program per (fingerprint, rows, prompt bucket,
         max_seq) via the state leaves in the signature."""
-        policy, sp = self._policy, self._serve_params(params)
-        key = ("prefill", self._fingerprint(conf),
-               arg_signature(prompt, length, keys, temps,
-                             *jax.tree_util.tree_leaves(state)),
-               self._decode_tag()) + self._policy_suffix()
-        fn = self._get(key,
-                       self._tp_build(lambda: _prefill_program(conf, policy)),
-                       (sp, state, prompt, length, keys, temps),
-                       donate=self._decode_donate(),
-                       shardings=self._decode_shardings(sp, state, 4))
-        if compile_only:
-            return None
-        with self._lock:
-            self.stats.steps += 1
-        return fn(*self._decode_place(sp, state, prompt, length, keys, temps))
+        return self._run_decode(
+            "prefill", _prefill_program, conf, params, state,
+            (prompt, length, keys, temps), compile_only=compile_only)
 
     def prefill_logp(self, conf, params, state, prompt, length,
                      compile_only: bool = False):
@@ -660,21 +580,9 @@ class InferCache(CompiledProgramCache):
         every later stream sharing the prompt regardless of key or
         temperature.  Only the prefix-cache flag routes admissions here;
         with the flag off this program is never built."""
-        policy, sp = self._policy, self._serve_params(params)
-        key = ("prefill-logp", self._fingerprint(conf),
-               arg_signature(prompt, length,
-                             *jax.tree_util.tree_leaves(state)),
-               self._decode_tag()) + self._policy_suffix()
-        fn = self._get(
-            key, self._tp_build(lambda: _prefill_logp_program(conf, policy)),
-            (sp, state, prompt, length),
-            donate=self._decode_donate(),
-            shardings=self._decode_shardings(sp, state, 2))
-        if compile_only:
-            return None
-        with self._lock:
-            self.stats.steps += 1
-        return fn(*self._decode_place(sp, state, prompt, length))
+        return self._run_decode(
+            "prefill-logp", _prefill_logp_program, conf, params, state,
+            (prompt, length), compile_only=compile_only)
 
     # -- admission into the slot table: one program a stream -----------------
     def prefill_slot(self, conf, params, table, slot: int, prompt, length,
@@ -686,24 +594,13 @@ class InferCache(CompiledProgramCache):
         table).  The decode family's contract: the table is argument 1,
         donated off-CPU (the write is in place: no second table, no
         other row touched), and last in the output; `slot` is a traced
-        int32 scalar, so one program a prompt bucket serves every slot."""
-        policy, sp = self._policy, self._serve_params(params)
-        slot = np.asarray(slot, np.int32)
-        key = ("prefill-slot", self._fingerprint(conf),
-               arg_signature(prompt, length, keys, temps,
-                             *jax.tree_util.tree_leaves(table)),
-               self._decode_tag()) + self._policy_suffix()
-        fn = self._get(
-            key, self._tp_build(lambda: _prefill_slot_program(conf, policy)),
-            (sp, table, slot, prompt, length, keys, temps),
-            donate=self._decode_donate(),
-            shardings=self._decode_shardings(sp, table, 5))
-        if compile_only:
-            return None
-        with self._lock:
-            self.stats.steps += 1
-        return fn(*self._decode_place(sp, table, slot, prompt, length, keys,
-                                      temps))
+        int32 scalar outside the key, so one program a prompt bucket
+        serves every slot."""
+        keyed = (prompt, length, keys, temps)
+        return self._run_decode(
+            "prefill-slot", _prefill_slot_program, conf, params, table,
+            (np.asarray(slot, np.int32),) + keyed, keyed=keyed,
+            compile_only=compile_only)
 
     def prefill_logp_slot(self, conf, params, table, slot: int, prompt,
                           length, compile_only: bool = False):
@@ -711,23 +608,11 @@ class InferCache(CompiledProgramCache):
         `prefill_logp`), and the filled B=1 row comes back beside the
         table it was written into, for the cache to keep.  Returns
         (logp [1, vocab] f32, row, table)."""
-        policy, sp = self._policy, self._serve_params(params)
-        slot = np.asarray(slot, np.int32)
-        key = ("prefill-logp-slot", self._fingerprint(conf),
-               arg_signature(prompt, length,
-                             *jax.tree_util.tree_leaves(table)),
-               self._decode_tag()) + self._policy_suffix()
-        fn = self._get(
-            key,
-            self._tp_build(lambda: _prefill_logp_slot_program(conf, policy)),
-            (sp, table, slot, prompt, length),
-            donate=self._decode_donate(),
-            shardings=self._decode_shardings(sp, table, 3))
-        if compile_only:
-            return None
-        with self._lock:
-            self.stats.steps += 1
-        return fn(*self._decode_place(sp, table, slot, prompt, length))
+        keyed = (prompt, length)
+        return self._run_decode(
+            "prefill-logp-slot", _prefill_logp_slot_program, conf, params,
+            table, (np.asarray(slot, np.int32),) + keyed, keyed=keyed,
+            compile_only=compile_only)
 
     def write_row(self, conf, table, row, slot: int,
                   compile_only: bool = False):
@@ -736,28 +621,14 @@ class InferCache(CompiledProgramCache):
         `row` (device or host tree) into row `slot` of `table`, which is
         donated off-CPU and returned.  The row goes first so that the
         table is argument 1, as in the rest of the decode family; the
-        program takes no params."""
-        plan = self.plan
-        slot = np.asarray(slot, np.int32)
-        key = ("write-row", self._fingerprint(conf),
-               arg_signature(*jax.tree_util.tree_leaves(row),
-                             *jax.tree_util.tree_leaves(table)),
-               self._decode_tag()) + self._policy_suffix()
-        shardings = None
-        if plan.has_model_axis:
-            shardings = (plan.state_shardings(row),
-                         plan.state_shardings(table), plan.replicated())
-        fn = self._get(key, self._tp_build(_write_row_program),
-                       (row, table, slot), donate=self._decode_donate(),
-                       shardings=shardings)
-        if compile_only:
-            return None
-        with self._lock:
-            self.stats.steps += 1
-        if shardings is not None:
-            slot = jax.device_put(slot, shardings[2])
-        return fn(self._place_decode_state(row),
-                  self._place_decode_state(table), slot)[0]
+        program takes no params, and its key is the row's leaves and then
+        the table's."""
+        out = self._run_decode(
+            "write-row", _write_row_program, conf, row, table,
+            (np.asarray(slot, np.int32),),
+            keyed=tuple(jax.tree_util.tree_leaves(row)), head_is_row=True,
+            compile_only=compile_only)
+        return None if out is None else out[0]
 
     def loss(self, conf, params, x, y, compile_only: bool = False):
         """`network_loss(training=False)` through the cache: the
@@ -829,21 +700,10 @@ def _sample_chain(logp, keys, temps):
     return jnp.stack(toks, axis=1), jnp.stack(keys_after, axis=1)
 
 
-def _decode_paged_program(conf, policy: str = "f32") -> Callable:
-    from deeplearning4j_tpu.nn import decode as decode_mod
-
-    pconf = _policy_conf(conf, policy)
-
-    def program(params, state, tok, pos, keys, temps, page_table):
-        logp, state = decode_mod.decode_step_paged(
-            pconf, _policy_args(params, policy), state, tok, pos,
-            page_table)
-        if policy != "f32":
-            logp = logp.astype(jnp.float32)
-        tok2, keys2 = _sample_tokens(logp, keys, temps)
-        return tok2, keys2, state
-
-    return program
+def _with_pages(rest: Tuple, page_table) -> Tuple:
+    """A decode-family program's host arguments: over a paged state the
+    page table is the last of them."""
+    return rest if page_table is None else rest + (page_table,)
 
 
 def _accepted_len(toks, sampled):
@@ -881,26 +741,8 @@ def _verify_program(conf, policy: str = "f32") -> Callable:
 
     pconf = _policy_conf(conf, policy)
 
-    def program(params, state, toks, pos, keys, temps):
+    def program(params, state, toks, pos, keys, temps, page_table=None):
         logp, state, carries = decode_mod.verify_chunk(
-            pconf, _policy_args(params, policy), state, toks, pos)
-        if policy != "f32":
-            logp = logp.astype(jnp.float32)
-        sampled, keys_after = _sample_chain(logp, keys, temps)
-        state = _rollback_carries(state, carries,
-                                  _accepted_len(toks, sampled))
-        return sampled, keys_after, state
-
-    return program
-
-
-def _verify_paged_program(conf, policy: str = "f32") -> Callable:
-    from deeplearning4j_tpu.nn import decode as decode_mod
-
-    pconf = _policy_conf(conf, policy)
-
-    def program(params, state, toks, pos, keys, temps, page_table):
-        logp, state, carries = decode_mod.verify_chunk_paged(
             pconf, _policy_args(params, policy), state, toks, pos,
             page_table)
         if policy != "f32":
@@ -918,9 +760,10 @@ def _decode_program(conf, policy: str = "f32") -> Callable:
 
     pconf = _policy_conf(conf, policy)
 
-    def program(params, state, tok, pos, keys, temps):
-        logp, state, counts = decode_mod.decode_step_counted(
-            pconf, _policy_args(params, policy), state, tok, pos)
+    def program(params, state, tok, pos, keys, temps, page_table=None):
+        logp, state, counts = decode_mod.step(
+            pconf, _policy_args(params, policy), state, tok, pos,
+            page_table)
         if policy != "f32":
             logp = logp.astype(jnp.float32)
         tok2, keys2 = _sample_tokens(logp, keys, temps)
@@ -941,29 +784,10 @@ def _decode_multi_program(conf, policy: str = "f32", k: int = 1) -> Callable:
             logp = logp.astype(jnp.float32)
         return _sample_tokens(logp, keys, temps)
 
-    def program(params, state, tok, pos, keys, temps, rem):
+    def program(params, state, tok, pos, keys, temps, rem, page_table=None):
         return decode_mod.decode_block(
             pconf, _policy_args(params, policy), state, tok, pos, keys,
-            temps, rem, k, sample)
-
-    return program
-
-
-def _decode_multi_paged_program(conf, policy: str = "f32",
-                                k: int = 1) -> Callable:
-    from deeplearning4j_tpu.nn import decode as decode_mod
-
-    pconf = _policy_conf(conf, policy)
-
-    def sample(logp, keys, temps):
-        if policy != "f32":
-            logp = logp.astype(jnp.float32)
-        return _sample_tokens(logp, keys, temps)
-
-    def program(params, state, tok, pos, keys, temps, rem, page_table):
-        return decode_mod.decode_block(
-            pconf, _policy_args(params, policy), state, tok, pos, keys,
-            temps, rem, k, sample, page_table=page_table)
+            temps, rem, k, sample, page_table)
 
     return program
 
@@ -1023,7 +847,8 @@ def _prefill_logp_slot_program(conf, policy: str = "f32") -> Callable:
     return program
 
 
-def _write_row_program() -> Callable:
+def _write_row_program(conf=None, policy: str = "f32") -> Callable:
+    # a copy of leaves: neither the conf nor the policy changes it
     from deeplearning4j_tpu.nn import decode as decode_mod
 
     def program(row, table, slot):

@@ -451,7 +451,7 @@ class CompiledProgramCache:
             self.stats.steps += 1
             return fn(*args)
 
-        # callers that AOT-compile explicitly (bench MFU) reach through
+        # callers that AOT-compile explicitly reach through
         wrapped.lower = jitted.lower
         wrapped.__wrapped__ = jitted
         return wrapped

@@ -2,8 +2,8 @@
 
 Every hand-tuned constant that governs a hot path declares itself here:
 name, owning subsystem, default value (exactly the constant the call site
-used to hard-code), legal search space, and an analytic cost hint tied to
-the flops/bytes model in `optimize/profiling.py`.  Call sites resolve
+used to hard-code), legal search space, and an analytic cost hint (for the
+flash kernel's blocks, `attention_block_bytes` below).  Call sites resolve
 through :func:`resolve`, which consults the process-wide installed
 :class:`TunedTable` first and falls back to the registry default — so with
 no table installed behavior is byte-identical to the pre-registry code
@@ -61,10 +61,25 @@ ATTENTION_BLOCK_TABLE = {
 }
 
 
+def attention_block_bytes(seq: int, head_dim: int, block_q: int,
+                          block_k: int, dtype_bytes: int = 4) -> float:
+    """HBM traffic of one flash-attention head at (block_q, block_k):
+    each of the S/bq Q tiles streams the full K and V ([S, D] each), Q
+    itself and the output are read/written once, and every (q, k) tile
+    pair touches a [bq, bk] f32 scores tile in VMEM.  This is the
+    autotuner's pruning signal: relative cost across candidate blocks,
+    not an absolute roofline — halving block_q doubles the K/V streaming
+    term, which is exactly the 2x the pruner cuts on."""
+    q_tiles = max(1, -(-seq // block_q))
+    stream = q_tiles * 2 * seq * head_dim           # K + V per Q tile
+    once = 2 * seq * head_dim                       # Q in, O out
+    scores = q_tiles * max(1, -(-seq // block_k)) * block_q * block_k
+    return float(dtype_bytes) * (stream + once + scores)
+
+
 def _attention_cost(value, seq: int = 1024, head_dim: int = 64, **_):
     """Analytic bytes moved by the flash kernel at (bq, bk) — the pruning
     signal: candidates >= 2x the incumbent's traffic are never compiled."""
-    from deeplearning4j_tpu.optimize.profiling import attention_block_bytes
     bq, bk = value
     return attention_block_bytes(seq, head_dim, bq, bk)
 

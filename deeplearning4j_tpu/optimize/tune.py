@@ -1,14 +1,13 @@
 """Search-based autotuning over the compiled-program space (ROADMAP 6).
 
 TVM-style flow: for each tunable group, enumerate the registry's declared
-search space, prune candidates whose analytic cost (the flops/bytes model
-in `optimize/profiling.py`) is >= 2x the incumbent's *before* compiling
+search space, prune candidates whose analytic cost (the registry's cost
+hints, `optimize/tunables.py`) is >= 2x the incumbent's *before* compiling
 anything, then compile and measure the survivors as real programs through
 the existing step-cache/infer-cache machinery — warm call outside the
 timed region, min-of-rounds with an injectable clock.  Winners beat the
 incumbent by a margin (default 2%) or the default stands, so a tuned
-table is never slower than stock (the CPU no-slower criterion in
-`bench_tune`).
+table is never slower than stock.
 
 The winning :class:`~deeplearning4j_tpu.optimize.tunables.TunedTable` is
 keyed per (conf fingerprint, device kind) and persisted through the disk

@@ -712,8 +712,8 @@ class ContinuousBatcher:
     `n_slots` rows; a sequence that finishes frees its slot and the next
     queued stream is admitted — prefilled and emitting its first token —
     on the very next step instead of waiting for the longest neighbour
-    to finish.  `continuous=False` is the sequential control arm
-    (`bench_generate`): admission only happens when EVERY slot is free,
+    to finish.  `continuous=False` is the sequential control arm:
+    admission only happens when EVERY slot is free,
     so each wave barriers on its longest sequence.
 
     Correctness: rows are independent (each slot carries its own K/V
@@ -1389,6 +1389,12 @@ class ContinuousBatcher:
                     self._failed += 1
                 stream._finish(e)
 
+    def _pages(self):
+        """The `page_table` argument of a decode-family call: a copy of the
+        host's table (the loop goes on writing it while the device reads),
+        or None over a dense table, which selects the dense program."""
+        return self._page_table.copy() if self.paged else None
+
     def _lazy_alloc(self, k: int, pos=None, steps=None) -> None:
         """Ensure every active slot has physical pages for its next `k`
         positions, allocating from the pool as streams cross page
@@ -1464,20 +1470,15 @@ class ContinuousBatcher:
             return
         ic = self.net.infer_cache
         with span("decode.dispatch"):
-            counts = []     # a stack with expert layers: their [picks, hit]
             if self.paged:
                 self._lazy_alloc(1)
                 if not any(s is not None for s in self._slots):
                     return
-                tok2, keys2, self._state = ic.decode_paged(
-                    self.net.conf, self.net.params, self._state,
-                    self._tok.copy(), self._pos.copy(), self._keys.copy(),
-                    self._temps.copy(), self._page_table.copy())
-            else:
-                tok2, keys2, *counts, self._state = ic.decode(
-                    self.net.conf, self.net.params, self._state,
-                    self._tok.copy(), self._pos.copy(), self._keys.copy(),
-                    self._temps.copy())
+            # counts: a stack with expert layers returns their [picks, hit]
+            tok2, keys2, *counts, self._state = ic.decode(
+                self.net.conf, self.net.params, self._state,
+                self._tok.copy(), self._pos.copy(), self._keys.copy(),
+                self._temps.copy(), page_table=self._pages())
             if self.draft_net is not None:
                 # non-spec rounds (feeds pending, or a slot near the table
                 # edge) still advance the draft's carries over the same
@@ -1608,14 +1609,10 @@ class ContinuousBatcher:
             self._lazy_alloc(k)
             if not any(s is not None for s in self._slots):
                 return
-            g, keys_all, self._state = ic.verify_paged(
-                self.net.conf, self.net.params, self._state, toks,
-                self._pos.copy(), self._keys.copy(), self._temps.copy(),
-                self._page_table.copy())
-        else:
-            g, keys_all, self._state = ic.verify(
-                self.net.conf, self.net.params, self._state, toks,
-                self._pos.copy(), self._keys.copy(), self._temps.copy())
+        g, keys_all, self._state = ic.verify(
+            self.net.conf, self.net.params, self._state, toks,
+            self._pos.copy(), self._keys.copy(), self._temps.copy(),
+            page_table=self._pages())
         g = np.asarray(g)
         keys_all = np.asarray(keys_all)
         now = time.monotonic()
@@ -1761,7 +1758,6 @@ class ContinuousBatcher:
                 rem[s] = 0
         if int(rem.max(initial=0)) <= 0:
             return None
-        counts = []
         if self.paged:
             self._lazy_alloc(k, pos=pos, steps=np.minimum(rem, k))
             for s, stream in enumerate(streams):
@@ -1769,16 +1765,11 @@ class ContinuousBatcher:
                     rem[s] = 0  # preempted/failed during page growth
             if int(rem.max(initial=0)) <= 0:
                 return None
-            with span("decode.dispatch"):
-                toks, tok2, keys2, self._state = ic.decode_multi_paged(
-                    self.net.conf, self.net.params, self._state, tok,
-                    pos.copy(), keys, self._temps.copy(), rem.copy(),
-                    self._page_table.copy(), k)
-        else:
-            with span("decode.dispatch"):
-                toks, tok2, keys2, *counts, self._state = ic.decode_multi(
-                    self.net.conf, self.net.params, self._state, tok,
-                    pos.copy(), keys, self._temps.copy(), rem.copy(), k)
+        with span("decode.dispatch"):
+            toks, tok2, keys2, *counts, self._state = ic.decode_multi(
+                self.net.conf, self.net.params, self._state, tok,
+                pos.copy(), keys, self._temps.copy(), rem.copy(), k,
+                page_table=self._pages())
         adv = np.minimum(rem, k).astype(np.int32)
         pos += adv
         rem -= adv

@@ -248,8 +248,8 @@ class ModelServer:
     """Serve a `MultiLayerNetwork` over HTTP through the micro-batcher.
 
     batching=False bypasses the gateway (each handler thread calls
-    `net.output` directly) — the control arm of `bench_serve`, and an
-    escape hatch for debugging.
+    `net.output` directly) — the control arm, and an escape hatch for
+    debugging.
 
     default_deadline_ms applies to requests that carry no `deadline_ms`
     of their own (None = unbounded queue wait up to `request_timeout_s`).

@@ -332,7 +332,7 @@ def test_prune_drops_analytically_bad_candidates():
 
 
 def test_attention_pruning_uses_profiling_cost_model():
-    from deeplearning4j_tpu.optimize.profiling import attention_block_bytes
+    from deeplearning4j_tpu.optimize.tunables import attention_block_bytes
 
     # fewer q tiles restream K/V fewer times: block_q=256 moves less
     assert attention_block_bytes(1024, 64, 128, 128) > \
