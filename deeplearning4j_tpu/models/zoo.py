@@ -243,7 +243,8 @@ def char_transformer(vocab: int, d_model: int = 128, n_blocks: int = 2,
     The keyword flags are the MFU-campaign hot-path switches (all
     value-preserving; see tests/test_mfu_paths.py): `sparse_labels` trains
     against int class-id targets via the mcxent gather path,
-    `fused_updater` runs the optimizer on flat buffers,
+    `fused_updater` is accepted and does nothing (the updater has one
+    layout since PR 31; the benchmark's configurations still pass the key),
     `attention_block_skip` drops mask arithmetic on fully-causal flash
     tiles, and `attention_fused_bwd` replaces the flash backward's forward
     recompute with fused Pallas dK/dV + dQ kernels over saved logsumexp

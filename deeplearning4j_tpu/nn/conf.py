@@ -257,8 +257,9 @@ class NeuralNetConfiguration:
     # it replaces; parity-tested in tests/test_mfu_paths.py)
     sparse_labels: bool = False    # int class-id labels: gather mcxent, no
                                    # [rows, vocab] one-hot gemm
-    fused_updater: bool = False    # flat-buffer updater step instead of
-                                   # O(leaves) per-leaf tree_maps
+    fused_updater: bool = False    # accepted and inert (PR 31): the updater
+                                   # has one layout; the benchmark's GPT-2
+                                   # configurations still pass the key
     attention_fused_bwd: bool = False  # flash bwd via fused Pallas kernels
                                    # over saved logsumexp residuals (no
                                    # fwd recompute); only consulted when

@@ -33,10 +33,7 @@ from jax.flatten_util import ravel_pytree
 from deeplearning4j_tpu.nn.conf import OptimizationAlgorithm
 from deeplearning4j_tpu.optimize.linesearch import backtrack
 from deeplearning4j_tpu.optimize.updater import (adjust_gradient,
-                                                 adjust_gradient_flat,
-                                                 flat_ravel, flat_unravel,
-                                                 flat_norm, init_updater,
-                                                 make_flat_spec, tree_norm)
+                                                 init_updater, tree_norm)
 
 EPS_TERMINATION = 1e-6   # |score - old_score| tolerance (EpsTermination parity)
 NORM2_TERMINATION = 1e-8  # gradient-norm tolerance (Norm2Termination parity)
@@ -229,60 +226,21 @@ def _grad_score_aux(objective: Objective, params, key):
 def _sgd(objective: Objective, params0, conf, key):
     """ITERATION_GRADIENT_DESCENT: updater-chain steps, no line search.
 
-    With `conf.fused_updater` the scan carries params/grads/updater state as
-    a few contiguous same-dtype buffers (raveled once before the scan,
-    unraveled once after — reshape/slice views, so jit-level donation is
-    untouched): the whole updater chain plus the step application run as a
-    handful of full-width kernels instead of O(leaves x ops) small ones.
-    The gradient itself is still computed on the unraveled tree (same
-    leaves, same shapes), and the norms reduce per original leaf, so every
-    carried bit matches the tree path (see tests/test_mfu_paths.py).
+    The scan carries the params and the updater's two trees as they are;
+    `adjust_gradient` maps over them leaf by leaf (`conf.fused_updater` is
+    accepted and inert: there is no other layout to carry).
     """
-    fused = getattr(conf, "fused_updater", False)
-    if fused:
-        spec = make_flat_spec(params0)
-        carry_p0 = flat_ravel(spec, params0)
-
-        def to_tree(p):
-            return flat_unravel(spec, p)
-
-        def ravel_grads(g):
-            return flat_ravel(spec, g)
-
-        def norm(t):
-            return flat_norm(spec, t)
-
-        def adjust(it, g, p, u):
-            return adjust_gradient_flat(conf, it, g, p, u, spec)
-    else:
-        carry_p0 = params0
-
-        def to_tree(p):
-            return p
-
-        def ravel_grads(g):
-            return g
-
-        norm = tree_norm
-
-        def adjust(it, g, p, u):
-            return adjust_gradient(conf, it, g, p, u)
-
-    # init_updater is tree_map(zeros_like): shapes the state like whatever
-    # container the carry uses (leaf trees or flat buffer tuples)
-    upd0 = init_updater(carry_p0)
+    upd0 = init_updater(params0)
     terminated = make_termination(conf)
     aux0 = _aux_zeros(objective, params0, key)
 
     def step(carry, it):
         params, upd, k, done, old_score, stall_n, aux = carry
         k, sub = jax.random.split(k)
-        grads, score, aux_new = _grad_score_aux(objective, to_tree(params),
-                                                sub)
-        grads = ravel_grads(grads)
-        adj, upd_new = adjust(it, grads, params, upd)
-        gnorm = norm(grads)
-        dnorm = norm(adj)
+        grads, score, aux_new = _grad_score_aux(objective, params, sub)
+        adj, upd_new = adjust_gradient(conf, it, grads, params, upd)
+        gnorm = tree_norm(grads)
+        dnorm = tree_norm(adj)
         # direction is -adj (a descent step), alpha fixed at 1 — the
         # configured step function still applies (stepfunctions parity)
         new_params = jax.tree_util.tree_map(
@@ -302,11 +260,11 @@ def _sgd(objective: Objective, params0, conf, key):
             hard, stall_n >= STALL_PATIENCE))
         return (params, upd, k, done, score, stall_n, aux), score
 
-    init = (carry_p0, upd0, key, jnp.asarray(False), jnp.inf,
+    init = (params0, upd0, key, jnp.asarray(False), jnp.inf,
             jnp.asarray(0), aux0)
     (params, _, _, _, _, _, aux), scores = jax.lax.scan(
         step, init, jnp.arange(conf.num_iterations))
-    return to_tree(params), scores, aux
+    return params, scores, aux
 
 
 def _line_searched(objective: Objective, params0, conf, key, algo):

@@ -37,8 +37,7 @@ from deeplearning4j_tpu.nn.multilayer import (MultiLayerNetwork, has_batchnorm,
                                               network_rowwise_loss,
                                               update_bn_ema_from_stats)
 from deeplearning4j_tpu.optimize.updater import (UpdaterState, adjust_gradient,
-                                                 adjust_gradient_auto,
-                                                 init_updater)
+                                                 init_updater, update_params)
 from deeplearning4j_tpu.parallel.mesh import shard_batch
 from deeplearning4j_tpu.parallel.sequence import _as_varying, _shard_map
 from deeplearning4j_tpu.reliability import TrainingInterrupted, faults
@@ -76,13 +75,6 @@ def _jit_step(fn, entry: str):
     """`jax.jit` of a train step (state donated) under its name in the
     trace, `dl4j_<entry>`: the name joins no key of `track_jit`."""
     return jax.jit(profiling.named(fn, entry), donate_argnums=(0,))
-
-
-def _apply_step(params, adj):
-    """params - step, leaf by leaf, in the updater's scope."""
-    with profiling.scope("updater"):
-        return jax.tree_util.tree_map(
-            lambda p, a: p - a.astype(p.dtype), params, adj)
 
 
 def _feature_row_weights(w, x):
@@ -207,9 +199,8 @@ def make_dp_train_step(conf: MultiLayerConfiguration, mesh: Mesh,
         with profiling.scope("allreduce"):
             grads = reduce(grads, axis)
             score = reduce(score, axis)
-        adj, upd = adjust_gradient_auto(out_conf, state.step, grads,
-                                        state.params, state.updater)
-        params = _apply_step(state.params, adj)
+        params, upd = update_params(out_conf, state.step, grads,
+                                    state.params, state.updater)
         if collect_bn:
             # running inference stats from GLOBAL-batch statistics, reusing
             # the moments the loss forward already computed (no 2nd pass)
@@ -257,9 +248,8 @@ def make_sharded_train_step(conf: MultiLayerConfiguration, mesh: Mesh,
 
         (score, stats), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params, key)
-        adj, upd = adjust_gradient_auto(out_conf, state.step, grads,
-                                        state.params, state.updater)
-        params = _apply_step(state.params, adj)
+        params, upd = update_params(out_conf, state.step, grads,
+                                    state.params, state.updater)
         if collect_bn:
             params = update_bn_ema_from_stats(conf, params, stats)
         return TrainState(params, upd, state.step + 1), score
@@ -338,9 +328,8 @@ def make_zero1_train_step(conf: MultiLayerConfiguration, mesh: Mesh,
         grads = jax.tree_util.tree_map(
             lambda g, s: jax.lax.with_sharding_constraint(
                 g, NamedSharding(mesh, s)), grads, gspecs)
-        adj, upd = adjust_gradient(out_conf, state.step, grads,
-                                   state.params, state.updater)
-        params = _apply_step(state.params, adj)
+        params, upd = update_params(out_conf, state.step, grads,
+                                    state.params, state.updater)
         # params come back replicated (all-gather of the sharded step)
         params = jax.tree_util.tree_map(
             lambda p: jax.lax.with_sharding_constraint(
@@ -425,9 +414,8 @@ def make_plan_train_step(conf: MultiLayerConfiguration, plan,
         score, grads = jax.value_and_grad(loss_fn)(params, key)
         gspec_fn = plan.zero1_pspecs if zero1 else plan.param_pspecs
         grads = pin(grads, gspec_fn(grads))
-        adj, upd = adjust_gradient(out_conf, state.step, grads,
-                                   params, state.updater)
-        new_params = _apply_step(params, adj)
+        new_params, upd = update_params(out_conf, state.step, grads,
+                                        params, state.updater)
         # params stay model-sharded across steps (never gathered); only
         # the zero1 batch-axis split of the step all-gathers back
         new_params = pin(new_params, plan.param_pspecs(new_params))

@@ -1,9 +1,11 @@
 """Parity tests for the MFU-campaign hot paths.
 
 Each optimized path is gated by a conf flag and claims BITWISE f32
-identity (sparse labels, fused updater) or reference-tolerance identity
-(flash block-skip) with the path it replaces — these tests are the
-claim's enforcement.  Flag combinations are also exercised end-to-end
+identity (sparse labels) or reference-tolerance identity (flash
+block-skip) with the path it replaces — these tests are the claim's
+enforcement.  The updater chain has one layout since PR 31 (the
+`fused_updater` flag is inert), so its cases check the mathematics against
+NumPy; what the flag no longer does is held in `test_updater_one_layout.py`.  Flag combinations are also exercised end-to-end
 through `MultiLayerNetwork.finetune` (the compiled step-cache program),
 so the parity holds through tracing, donation and the solver scan, not
 just at the op level (`test_mfu_end_to_end.py`); the fused flash backward is in
@@ -20,14 +22,9 @@ from deeplearning4j_tpu.nd.attention import full_attention
 from deeplearning4j_tpu.nd.pallas_kernels import (flash_attention,
                                                   pick_attention_blocks)
 from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
-from deeplearning4j_tpu.optimize.updater import (UpdaterState,
-                                                 adjust_gradient,
-                                                 adjust_gradient_auto,
-                                                 adjust_gradient_flat,
-                                                 flat_norm, flat_ravel,
-                                                 flat_unravel, init_updater,
-                                                 make_flat_spec, tree_norm)
-from mfu_helpers import _assert_tree_bitwise
+from deeplearning4j_tpu.optimize.updater import (adjust_gradient,
+                                                 init_updater, update_params)
+from mfu_helpers import _assert_tree_bitwise, _f64, _numpy_chain
 
 
 # -- sparse-label loss path --------------------------------------------------
@@ -91,11 +88,11 @@ def test_sparse_labels_rejected_outside_mcxent_family():
     losses.get_loss("negativeloglikelihood")(ids, out)
 
 
-# -- fused updater -----------------------------------------------------------
+# -- the updater chain -------------------------------------------------------
 
 def _param_tree(key):
-    """Odd, MXU-unfriendly shapes on purpose: strided slices into the flat
-    buffer are exactly where a reduction could reorder its accumulation."""
+    """Odd, MXU-unfriendly shapes on purpose, and leaves of several sizes:
+    the two global norms run over all of them."""
     ks = jax.random.split(key, 4)
     return {"blk": {"W": jax.random.normal(ks[0], (13, 7), jnp.float32),
                     "b": jax.random.normal(ks[1], (7,), jnp.float32)},
@@ -117,77 +114,51 @@ _UPDATER_OPTIONS = [
 @pytest.mark.parametrize("opts", _UPDATER_OPTIONS,
                          ids=[",".join(o) or "plain"
                               for o in _UPDATER_OPTIONS])
-def test_fused_updater_bitwise(which, opts):
+def test_updater_chain_matches_numpy(which, opts):
+    """Three iterations of `adjust_gradient` under `jit`, state carried,
+    against the NumPy float64 statement of each algorithm: clip, unit
+    norm, l2 and the AdaGrad reset (iteration 0 and 2) included."""
     conf = NeuralNetConfiguration(lr=0.05, momentum=0.9, updater=which,
                                   **opts)
     params = _param_tree(jax.random.PRNGKey(10))
-    spec = make_flat_spec(params)
-    pbufs = flat_ravel(spec, params)
-    state_t = init_updater(params)
-    state_f = init_updater(pbufs)
-
-    @jax.jit
-    def both(it, grads):
-        st, tree_state = adjust_gradient(conf, it, grads, params, state_t)
-        sf, flat_state = adjust_gradient_flat(
-            conf, it, flat_ravel(spec, grads), pbufs, state_f, spec)
-        return st, tree_state, sf, flat_state
-
-    for it in range(3):  # cross the adagrad reset boundary
+    state = init_updater(params)
+    chain = jax.jit(lambda it, g, s: adjust_gradient(conf, it, g, params, s))
+    hist, vel = _f64(state.adagrad_hist), _f64(state.velocity)
+    for it in range(3):
         grads = jax.tree_util.tree_map(
             lambda p: jax.random.normal(jax.random.fold_in(
                 jax.random.PRNGKey(20), it), p.shape, p.dtype) * 0.1, params)
-        st, state_t, sf, state_f = both(jnp.asarray(it), grads)
-        _assert_tree_bitwise(st, flat_unravel(spec, sf),
-                             f"{which or 'legacy'} step it={it}")
-        _assert_tree_bitwise(
-            state_t,
-            UpdaterState(
-                adagrad_hist=flat_unravel(spec, state_f.adagrad_hist),
-                velocity=flat_unravel(spec, state_f.velocity)),
-            f"{which or 'legacy'} state it={it}")
+        step, state = chain(jnp.asarray(it), grads, state)
+        want, hist, vel = _numpy_chain(conf, it, _f64(grads), _f64(params),
+                                       hist, vel)
+        for name, got, ref in [("step", step, want),
+                               ("hist", state.adagrad_hist, hist),
+                               ("velocity", state.velocity, vel)]:
+            for i, (x, y) in enumerate(zip(_f64(got), ref)):
+                np.testing.assert_allclose(
+                    x, y, rtol=2e-5, atol=1e-7,
+                    err_msg=f"{which or 'legacy'} {name} leaf {i} it={it}")
 
 
-def test_flat_norm_matches_tree_norm_bitwise():
-    params = _param_tree(jax.random.PRNGKey(11))
-    spec = make_flat_spec(params)
-    a = jax.jit(lambda t: tree_norm(t))(params)
-    b = jax.jit(lambda bufs: flat_norm(spec, bufs))(
-        flat_ravel(spec, params))
-    _assert_tree_bitwise(a, b, "global norm")
-
-
-def test_adjust_gradient_auto_dispatch_bitwise():
-    """The tree-in / tree-out fused dispatcher (what the dp train step
-    calls) must reproduce the plain path exactly when the flag is on."""
-    params = _param_tree(jax.random.PRNGKey(12))
-    grads = jax.tree_util.tree_map(lambda p: 0.3 * p, params)
-    state = init_updater(params)
-    base = NeuralNetConfiguration(lr=0.01, momentum=0.9, updater="adam",
+@pytest.mark.parametrize("which", ["", "sgd", "adagrad", "nesterov",
+                                   "adam", "rmsprop"])
+def test_update_params_is_params_minus_the_step(which):
+    """What the train steps call: `update_params` lands where subtracting
+    `adjust_gradient`'s step does, with the same new state (two programs,
+    so a fused multiply-add may round a last bit differently)."""
+    conf = NeuralNetConfiguration(lr=0.05, momentum=0.9, updater=which,
                                   gradient_clip_norm=0.05)
-    # jit both sides: the claim is compiled-vs-compiled (how either path
-    # runs in a train step); eager-vs-jit differs by ulps on any path
-    ref_step, ref_state = jax.jit(
-        lambda g, p, s: adjust_gradient(base, 0, g, p, s))(
+    params = _param_tree(jax.random.PRNGKey(13))
+    grads = jax.tree_util.tree_map(lambda p: 0.3 * p + 0.01, params)
+    state = init_updater(params)
+    step, want_state = jax.jit(
+        lambda g, p, s: adjust_gradient(conf, 1, g, p, s))(
         grads, params, state)
-    fused_conf = base.replace(fused_updater=True)
-    out_step, out_state = jax.jit(
-        lambda g, p, s: adjust_gradient_auto(fused_conf, 0, g, p, s))(
-        grads, params, state)
-    _assert_tree_bitwise(ref_step, out_step, "auto step")
-    _assert_tree_bitwise(ref_state, out_state, "auto state")
-
-
-def test_flat_ravel_roundtrip_mixed_dtypes():
-    tree = {"a": jnp.arange(6, dtype=jnp.float32).reshape(2, 3),
-            "b": jnp.arange(4, dtype=jnp.bfloat16),
-            "c": jnp.arange(3, dtype=jnp.float32) * 1.5}
-    spec = make_flat_spec(tree)
-    assert spec.group_dtypes == (jnp.dtype(jnp.float32),
-                                 jnp.dtype(jnp.bfloat16))
-    assert spec.group_sizes == (9, 4)
-    _assert_tree_bitwise(tree, flat_unravel(spec, flat_ravel(spec, tree)),
-                         "roundtrip")
+    got, got_state = jax.jit(
+        lambda g, p, s: update_params(conf, 1, g, p, s))(grads, params, state)
+    want = jax.tree_util.tree_map(lambda p, a: p - a, params, step)
+    for x, y in zip(_f64((got, got_state)), _f64((want, want_state))):
+        np.testing.assert_allclose(x, y, rtol=1e-6, atol=1e-7)
 
 
 # -- causal flash block-skip -------------------------------------------------
