@@ -53,6 +53,7 @@ class LayerType(str, enum.Enum):
     TRANSFORMER_FFN = "transformer_ffn"
     KDA = "kda"                    # gated delta-rule linear attention
     MLA = "mla"                    # multi-head latent attention
+    GQA = "gqa"                    # grouped K/V heads, full or windowed
     SWIGLU = "swiglu"              # gated (SiLU) FFN under an RMSNorm
     MOE = "moe"                    # routed experts + one shared expert
 
@@ -142,6 +143,36 @@ class MLASpec:
 
 
 @dataclass(frozen=True)
+class GQASpec:
+    """LayerType.GQA: `n_heads` query heads of `head_dim` over `n_kv_heads`
+    key/value heads (query head j reads K/V head j // (n_heads //
+    n_kv_heads)), rotary positions, no bias.  `window` 0 is full causal
+    attention, whose decode state is a K/V table of `max_seq` positions;
+    `window` W > 0 lets a token see itself and the W - 1 before it, and the
+    state is a ring of W cells written at `pos % W`.  `yarn`, where given,
+    is `(factor, original_max_position_embeddings, beta_fast, beta_slow,
+    attention_factor)`: the rotary frequencies are blended toward
+    `1 / factor` of themselves and cos and sin scaled by the last (arXiv:
+    2309.00071); None is plain rotary at `rope_theta`.  `qk_norm` puts an
+    RMSNorm over each head's `head_dim` of q and of k before the rotation."""
+
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    window: int = 0
+    rope_theta: float = 10000.0
+    yarn: Optional[Tuple[float, ...]] = None
+    qk_norm: bool = False
+    eps: float = 1e-6
+
+    @property
+    def scope_kind(self) -> str:
+        """What `layer_scope` calls the layer: a trace read by scope then
+        tells window layers from full ones."""
+        return "gqa_window" if self.window else "gqa_full"
+
+
+@dataclass(frozen=True)
 class SwiGLUSpec:
     """LayerType.SWIGLU: `down(silu(gate x) * up x)` of width `hidden`."""
 
@@ -151,11 +182,15 @@ class SwiGLUSpec:
 
 @dataclass(frozen=True)
 class MoESpec:
-    """LayerType.MOE: a sigmoid router over `n_routed` experts in `n_group`
-    groups (the best `topk_group` groups stay, then the `top_k` best
-    experts among them), of which this layer holds `n_held` from
-    `first_held` on and computes those picks alone; one shared expert of
-    `shared_hidden` is whole here."""
+    """LayerType.MOE: a router over `n_routed` experts (`score` `sigmoid`,
+    or `softmax` over all of them) in `n_group` groups (the best
+    `topk_group` groups stay, then the `top_k` best experts among them), of
+    which this layer holds `n_held` from `first_held` on and computes those
+    picks alone; one shared expert of `shared_hidden` is whole here
+    (`shared_hidden` 0: there is none, and no such leaves), and
+    `router_bias` says whether a learned bias enters the choice.  The two
+    fields of `OMIT_AT_DEFAULT` are left out of the conf's JSON where they
+    hold their defaults: a conf from before them serialises as it did."""
 
     n_routed: int
     n_held: int
@@ -167,6 +202,10 @@ class MoESpec:
     topk_group: int = 1
     routed_scaling: float = 1.0
     eps: float = 1e-6
+    score: str = "sigmoid"
+    router_bias: bool = True
+
+    OMIT_AT_DEFAULT = ("score", "router_bias")
 
 
 @dataclass(frozen=True)
@@ -178,8 +217,18 @@ class HeadSpec:
     eps: float = 1e-6
 
 
-LAYER_SPECS = {c.__name__: c for c in (KDASpec, MLASpec, SwiGLUSpec, MoESpec,
-                                       HeadSpec)}
+LAYER_SPECS = {c.__name__: c for c in (KDASpec, MLASpec, GQASpec, SwiGLUSpec,
+                                       MoESpec, HeadSpec)}
+
+
+def _spec_dict(spec) -> Dict[str, Any]:
+    """A typed spec as JSON: its kind, then its fields, without those of its
+    `OMIT_AT_DEFAULT` that hold their defaults."""
+    out = {"kind": type(spec).__name__, **dataclasses.asdict(spec)}
+    for name in getattr(spec, "OMIT_AT_DEFAULT", ()):
+        if out[name] == type(spec).__dataclass_fields__[name].default:
+            del out[name]
+    return out
 
 
 @dataclass(frozen=True)
@@ -305,8 +354,7 @@ class NeuralNetConfiguration:
         if self.layer_spec is None:
             del d["layer_spec"]     # as before the field existed
         else:
-            d["layer_spec"] = {"kind": type(self.layer_spec).__name__,
-                               **dataclasses.asdict(self.layer_spec)}
+            d["layer_spec"] = _spec_dict(self.layer_spec)
         return d
 
     def to_json(self) -> str:
@@ -332,6 +380,8 @@ class NeuralNetConfiguration:
             d["dist"] = Distribution(**d["dist"])
         if d.get("layer_spec") is not None:
             spec = dict(d["layer_spec"])
+            if spec.get("yarn") is not None:    # JSON has no tuples
+                spec["yarn"] = tuple(spec["yarn"])
             d["layer_spec"] = LAYER_SPECS[spec.pop("kind")](**spec)
         for k in ("momentum_after",):
             if k in d and d[k] is not None:

@@ -28,6 +28,7 @@ State layout: one dict per layer, in layer order, as a tuple —
   ATTENTION         {"k": [B, max_S, n] compute_dtype, "v": same}
   KDA               {"S": [B, H, dk, dv] f32, "conv": [B, K-1, 3 H dk]}
   MLA               {"c": [B, max_S, rank], "kr": [B, max_S, rope]}
+  GQA               {"k": [B, G, max_S or window, h] compute_dtype, "v": same}
   everything else   {}
 each made by its layer class's `init_state`; `CARRY` on the class says
 whether the state advances with every token (so that `decode_block` must
@@ -74,8 +75,8 @@ from deeplearning4j_tpu.utils.profiling import layer_scope, scope
 #: hidden layer types the decode path knows how to step one token at a time
 GENERATIVE_HIDDEN = (LayerType.LSTM, LayerType.GRAVES_LSTM,
                      LayerType.ATTENTION, LayerType.TRANSFORMER_FFN,
-                     LayerType.KDA, LayerType.MLA, LayerType.SWIGLU,
-                     LayerType.MOE)
+                     LayerType.KDA, LayerType.MLA, LayerType.GQA,
+                     LayerType.SWIGLU, LayerType.MOE)
 
 #: token emitted by `decode_block` for scan steps a row sat frozen
 #: (its `rem` budget exhausted mid-block) — never a valid token id
@@ -153,6 +154,14 @@ def has_experts(conf: MultiLayerConfiguration) -> bool:
     a `counted_step`)?"""
     return any(hasattr(get_layer(c.layer_type), "counted_step")
                for c in conf.confs)
+
+
+def kv_cells(conf: MultiLayerConfiguration, max_seq: int) -> list:
+    """For every layer whose class counts its state in cells a position
+    (`kv_cells`): how many a row holds at `max_seq`, and how many of them
+    its decode step reads (`kv_cells_read`)."""
+    return [(impl.kv_cells(c, max_seq), impl.kv_cells_read(c, max_seq))
+            for _, c, impl in _hidden(conf) if hasattr(impl, "kv_cells")]
 
 
 def _refuse_dense_only(conf, what: str) -> None:

@@ -30,6 +30,11 @@ What only some types can do, a class declares by having it:
             each of them where it is a carry ({} otherwise), to roll back to
     counted_step(params, conf, x, state, pos)   -> (hidden, state, counts)
             a step that counts its expert picks ([2] int32)
+    kv_cells(conf, max_seq), kv_cells_read(conf, max_seq)   -> int
+            its state holds one cell a position, so many a row (a table's
+            length, a ring's), and `decode_step` reads so many of them for
+            every row of the table: the batcher counts the cells a step
+            needs beside those the layer says its read covers
 A type with a state but without `init_paged_state` and `verify_chunk` lives
 in the dense slot table only (`nn.decode.dense_only`).  A type with no state
 gets all of it from `base.StatelessDecode`.  A new kind of state is a layer
@@ -38,7 +43,8 @@ file, its `LayerType` in `nn/conf.py` and a line of the registry below.
 
 from deeplearning4j_tpu.nn.conf import LayerType
 from deeplearning4j_tpu.nn.layers import (base, output, autoencoder, rbm, lstm,
-                                          conv, attention, experts, kda, mla)
+                                          conv, attention, experts, gqa, kda,
+                                          mla)
 
 _REGISTRY = {
     LayerType.DENSE: base.DenseLayer,
@@ -58,6 +64,7 @@ _REGISTRY = {
     LayerType.TRANSFORMER_FFN: attention.TransformerFFNLayer,
     LayerType.KDA: kda.KDALayer,
     LayerType.MLA: mla.MLALayer,
+    LayerType.GQA: gqa.GQALayer,
     LayerType.SWIGLU: experts.SwiGLULayer,
     LayerType.MOE: experts.MoELayer,
 }

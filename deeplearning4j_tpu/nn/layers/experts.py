@@ -3,23 +3,25 @@
 an RMSNorm.
 
 The expert layer is told which experts it holds.  Its router scores all
-`n_routed` published experts (sigmoid, float32), picks `top_k` of them a
-token from the best `topk_group` of `n_group` groups (a group scores the
-sum of its two best; a learned bias enters the choice and not the weights),
-and weighs the picks by their scores, normalised over all `top_k` and
-scaled.  The layer then computes the picks that landed on its own `n_held`
-experts, `[first_held, first_held + n_held)`, adds its shared expert, and
-leaves out what the absent experts would add: on one chip of an
-expert-parallel group this is that chip's part of the layer, without the
-exchange.
+`n_routed` published experts in float32 (`spec.score`: a sigmoid each, or a
+softmax over all of them), picks `top_k` of them a token from the best
+`topk_group` of `n_group` groups (a group scores the sum of its two best;
+with one group it is a plain top-k; a learned bias, where the spec has one,
+enters the choice and not the weights), and weighs the picks by their
+scores, normalised over all `top_k` and scaled.  The layer then computes the
+picks that landed on its own `n_held` experts, `[first_held, first_held +
+n_held)`, adds its shared expert where it has one, and leaves out what the
+absent experts would add: on one chip of an expert-parallel group this is
+that chip's part of the layer, without the exchange.
 
 Dropless: the picks are sorted by expert and every one of a held expert is
 computed by `jax.lax.ragged_dot` over the sorted rows; there is no capacity
 and no token is dropped, at 1 row or at 1024.  The picks of absent experts
-sort to the end and fall outside every group.  As this layer holds a
+sort to the end and fall outside every group.  Where this layer holds a
 fraction of the experts, its picks usually fit a fraction of the rows: when
 they fit the first 3/8 the products run over those alone, otherwise over
-all of them (`jax.lax.cond`; the result is the same either way).
+all of them (`jax.lax.cond`; the result is the same either way).  A layer
+that holds every expert has nothing to save there and builds no such choice.
 
 Neither keeps a decode state (`base.StatelessDecode`).  `MoELayer.apply`
 also returns two counts of the call, `[picks that landed on held experts,
@@ -58,15 +60,18 @@ class SwiGLULayer(StatelessDecode):
 
 def route(scores, bias, spec):
     """scores [R, n_routed] float32 -> (ids [R, top_k] int32, weights
-    [R, top_k] float32): group-limited top-k on `scores + bias`, weights
-    from `scores` alone."""
+    [R, top_k] float32): group-limited top-k on `scores + bias` (`bias`
+    None: on the scores), weights from `scores` alone."""
     r, n = scores.shape
-    sp = (scores + bias).reshape(r, spec.n_group, n // spec.n_group)
-    group = jnp.sum(jax.lax.top_k(sp, 2)[0], axis=-1)
-    kept = jax.lax.top_k(group, spec.topk_group)[1]
-    mask = jnp.sum(jax.nn.one_hot(kept, spec.n_group, dtype=jnp.int32), axis=1) > 0
-    ids = jax.lax.top_k(jnp.where(mask[..., None], sp, -jnp.inf).reshape(r, n),
-                        spec.top_k)[1]
+    sp = scores if bias is None else scores + bias
+    if spec.n_group > 1:
+        sp = sp.reshape(r, spec.n_group, n // spec.n_group)
+        group = jnp.sum(jax.lax.top_k(sp, 2)[0], axis=-1)
+        kept = jax.lax.top_k(group, spec.topk_group)[1]
+        mask = jnp.sum(jax.nn.one_hot(kept, spec.n_group, dtype=jnp.int32),
+                       axis=1) > 0
+        sp = jnp.where(mask[..., None], sp, -jnp.inf).reshape(r, n)
+    ids = jax.lax.top_k(sp, spec.top_k)[1]
     picked = jnp.take_along_axis(scores, ids, axis=-1)
     weights = spec.routed_scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
     return ids.astype(jnp.int32), weights
@@ -106,11 +111,15 @@ def held_experts(params, spec, cd, u, ids, weights):
                            * weights[..., None], axis=1)
 
     few = (3 * picks // 8) // 8 * 8
-    if few >= 64:
+    if few >= 64 and spec.n_held < spec.n_routed:
         y = jax.lax.cond(here <= few, lambda: over(few), lambda: over(picks))
     else:
         y = over(picks)
     return y, jnp.stack([here, jnp.sum(sizes > 0)]).astype(jnp.int32)
+
+
+#: `MoESpec.score` -> what turns the router's logits into scores
+_SCORES = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}
 
 
 class MoELayer(StatelessDecode):
@@ -120,15 +129,16 @@ class MoELayer(StatelessDecode):
         d, n = jnp.dtype(conf.dtype), conf.n_in
         ks = jax.random.split(key, 5)
         w = initializer(conf)
-        return {
-            "ln": jnp.ones((n,), d),
-            "Wr": w(ks[0], (n, s.n_routed)),
-            "rb": jnp.zeros((s.n_routed,), d),
-            "Wgu": w(ks[1], (s.n_held, n, 2 * s.hidden)),
-            "Wd": w(ks[2], (s.n_held, s.hidden, n)),
-            "sWgu": w(ks[3], (n, 2 * s.shared_hidden)),
-            "sWd": w(ks[4], (s.shared_hidden, n)),
-        }
+        out = {"ln": jnp.ones((n,), d),
+               "Wr": w(ks[0], (n, s.n_routed)),
+               "Wgu": w(ks[1], (s.n_held, n, 2 * s.hidden)),
+               "Wd": w(ks[2], (s.n_held, s.hidden, n))}
+        if s.router_bias:
+            out["rb"] = jnp.zeros((s.n_routed,), d)
+        if s.shared_hidden:
+            out.update(sWgu=w(ks[3], (n, 2 * s.shared_hidden)),
+                       sWd=w(ks[4], (s.shared_hidden, n)))
+        return out
 
     @staticmethod
     def apply(params, conf, x):
@@ -137,13 +147,15 @@ class MoELayer(StatelessDecode):
         u = pre_norm(params, x, s.eps)
         rows = u.reshape(-1, u.shape[-1])
         with scope("router"):
-            scores = jax.nn.sigmoid(jnp.matmul(
+            scores = _SCORES[s.score](jnp.matmul(
                 rows, params["Wr"].astype(F32),
                 precision=jax.lax.Precision.HIGHEST))
-            ids, weights = route(scores, params["rb"].astype(F32), s)
+            ids, weights = route(
+                scores, params["rb"].astype(F32) if s.router_bias else None, s)
         y, counts = held_experts(params, s, cd, rows, ids, weights)
-        with scope("shared"):
-            y = y + swiglu(rows, params["sWgu"], params["sWd"], cd)
+        if s.shared_hidden:
+            with scope("shared"):
+                y = y + swiglu(rows, params["sWgu"], params["sWd"], cd)
         return x.astype(F32) + y.reshape(u.shape), counts
 
     @staticmethod

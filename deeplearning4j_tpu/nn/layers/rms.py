@@ -10,8 +10,11 @@ router scores, the KDA state) is float32.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.utils.profiling import scope
@@ -55,15 +58,42 @@ def l2norm(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
 
 
-def rope(x, positions, theta: float):
+def yarn_frequencies(n: int, theta: float, yarn):
+    """The n/2 inverse frequencies of a rotary embedding stretched by YaRN
+    (arXiv:2309.00071) and the factor cos and sin are scaled by.  `yarn` is
+    `(factor, original_max, beta_fast, beta_slow, attention_factor)`: a
+    dimension that turns more than `beta_fast` times within the original
+    context keeps its frequency, one that turns less than `beta_slow` times
+    gets `1 / factor` of it, and those between are blended linearly."""
+    factor, original, beta_fast, beta_slow, attention_factor = yarn
+
+    def turns_at(r):        # the (fractional) dimension that turns r times
+        return n * math.log(original / (2.0 * math.pi * r)) / (2.0 * math.log(theta))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), n - 1)
+    i = np.arange(n // 2)           # NumPy on the host: a constant of the trace
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    plain = float(theta) ** (-2.0 * i / n)
+    inv = (1.0 - ramp) * plain + ramp * plain / factor
+    return jnp.asarray(inv, F32), float(attention_factor)
+
+
+def rope(x, positions, theta: float, yarn=None):
     """Rotate-half rotary embedding over the whole last axis of `x`;
     `positions` broadcasts against x's leading axes (x [..., n], positions
-    [...])."""
+    [...]).  With `yarn` the frequencies and the scale of cos and sin are
+    `yarn_frequencies`'."""
     n = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, n, 2, dtype=F32) / n)
+    if yarn is None:
+        inv, scale = theta ** (-jnp.arange(0, n, 2, dtype=F32) / n), None
+    else:
+        inv, scale = yarn_frequencies(n, theta, yarn)
     ang = positions.astype(F32)[..., None] * inv
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     half = jnp.concatenate([-x[..., n // 2:], x[..., : n // 2]], axis=-1)
     return x * cos + half * sin
 

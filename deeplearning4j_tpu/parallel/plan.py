@@ -76,7 +76,9 @@ STATE_SPLIT_NAMES = frozenset({"k", "v", "h", "c"})
 #: an MLA layer's `kr`, and its latent, which is also called `c` but has a
 #: position axis ([B, max_S, rank]; an LSTM's is [B, H]): one latent serves
 #: all heads, so it cannot be split by head, and the plan has no expert or
-#: data axis for decode state to lie along yet
+#: data axis for decode state to lie along yet.  A grouped-heads layer's `k`
+#: and `v` ([B, G, cells, h], four axes) stay whole too: their trailing axis
+#: is one head's width, and nothing splits the G axis yet
 
 
 def parse_mesh_spec(spec: str) -> Dict[str, int]:
@@ -298,7 +300,7 @@ class ShardPlan:
         m = self.model_size
         nd = len(shape)
         if (m <= 1 or nd < 2 or name not in STATE_SPLIT_NAMES
-                or shape[-1] % m or (name == "c" and nd == 3)):
+                or shape[-1] % m or (name == "c" and nd == 3) or nd > 3):
             return P()
         return P(*((None,) * (nd - 1) + (self.model_axis,)))
 

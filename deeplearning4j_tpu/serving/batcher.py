@@ -47,7 +47,7 @@ import itertools
 import queue
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import Counter, OrderedDict, deque
 from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -771,7 +771,8 @@ class ContinuousBatcher:
         self.continuous = bool(continuous)
         self._auto_start = auto_start
         self._layer_types = decode_mod.check_generative(net.conf)
-        # a state a layer type defines itself (KDA's matrix, MLA's latents)
+        # a state a layer type defines itself (KDA's matrix, MLA's latents,
+        # a window layer's ring beside a full layer's table)
         # lives in the dense slot table only
         dense_only = decode_mod.dense_only(net.conf)
         asked = [name for name, on in (
@@ -781,11 +782,15 @@ class ContinuousBatcher:
         if dense_only and asked:
             raise ValueError(
                 f"{asked} cannot serve layer types {dense_only} yet: their "
-                f"state is a recurrent matrix or a latent cache a slot, which "
-                f"the paged pool has no pages for, a cached prefix row is "
-                f"right for only at the prompt's end, and a verify chunk "
-                f"cannot roll back")
+                f"state is a recurrent matrix, a latent cache or a ring of a "
+                f"window's positions a slot, which the paged pool has no "
+                f"pages for, a cached prefix row is right for only at the "
+                f"prompt's end, and a verify chunk cannot roll back")
         self._has_experts = decode_mod.has_experts(net.conf)
+        # {(cells a row holds, cells of them a step reads): layers} of the
+        # layers that count their state in cells a position
+        self._kv_cells = Counter(
+            decode_mod.kv_cells(net.conf, self.max_seq))
         # silent positional-table overrun fix: `token_embed` gathers
         # P[pos] with no bound check, and jit CLAMPS out-of-range
         # gathers — a stream decoding past the learned table would read
@@ -917,6 +922,9 @@ class ContinuousBatcher:
         # what the expert layers counted, summed over steps and layers
         self._expert_picks = 0
         self._experts_hit = 0
+        # K/V cells the steps needed, and cells their whole-state reads covered
+        self._kv_live = 0
+        self._kv_spanned = 0
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> "ContinuousBatcher":
@@ -1474,6 +1482,8 @@ class ContinuousBatcher:
                 self._lazy_alloc(1)
                 if not any(s is not None for s in self._slots):
                     return
+            self._note_kv(sp, self._pos, np.asarray(
+                [st is not None for st in self._slots], np.int32), 1)
             # counts: a stack with expert layers returns their [picks, hit]
             tok2, keys2, *counts, self._state = ic.decode(
                 self.net.conf, self.net.params, self._state,
@@ -1538,6 +1548,33 @@ class ContinuousBatcher:
         with self._cv:
             self._expert_picks += picks
             self._experts_hit += hit
+
+    def _note_kv(self, sp: span, pos, adv, steps: int) -> None:
+        """The K/V cells of one dispatch of `steps` table steps in which row
+        r, at position `pos[r]`, advances `adv[r]` tokens (0: a free or a
+        finished row), for a stack with layers that count their state in
+        cells (`kv_cells`; nothing otherwise).  `kv_cells_live`: what the
+        steps have to read, `min(position + 1, cells held)` a live row,
+        layer and step, from the slots' positions.  `kv_cells_spanned`: what
+        the layers say their reads cover (`kv_cells_read`), a row of the
+        table, layer and step.  Added to the open `decode` span and to the
+        totals of `stats()`."""
+        if not self._kv_cells:
+            return
+        p, a = np.asarray(pos, np.int64), np.asarray(adv, np.int64)
+        live = spanned = 0
+        for (held, read), n in self._kv_cells.items():
+            # p + 1 .. p + a, each clipped to `held`: `rising` of them lie
+            # under it
+            rising = np.clip(held - p, 0, a)
+            live += n * int((rising * p + rising * (rising + 1) // 2
+                             + (a - rising) * held).sum())
+            spanned += n * steps * self.n_slots * read
+        sp.set(kv_cells_live=sp.attrs.get("kv_cells_live", 0) + live,
+               kv_cells_spanned=sp.attrs.get("kv_cells_spanned", 0) + spanned)
+        with self._cv:
+            self._kv_live += live
+            self._kv_spanned += spanned
 
     def _note_block(self, k: int, wall: float, wait: float, emitted: int,
                     now: float) -> None:
@@ -1771,12 +1808,13 @@ class ContinuousBatcher:
                 pos.copy(), keys, self._temps.copy(), rem.copy(), k,
                 page_table=self._pages())
         adv = np.minimum(rem, k).astype(np.int32)
+        pos_before = pos.copy()
         pos += adv
         rem -= adv
         self._ramp = min(self._ramp * 2, self.k_max)
         return {"k": k, "streams": streams, "toks": toks, "tok": tok2,
-                "keys": keys2, "adv": adv, "pos_after": pos.copy(),
-                "counts": counts}
+                "keys": keys2, "adv": adv, "pos_before": pos_before,
+                "pos_after": pos.copy(), "counts": counts}
 
     def _readback_block(self, blk, t_mark: float, sp: span) -> float:
         """Read back ONE in-flight block — a single device_get for the
@@ -1790,6 +1828,7 @@ class ContinuousBatcher:
             toks, tok_last, keys_last, counts = jax.device_get(
                 (blk["toks"], blk["tok"], blk["keys"], blk["counts"]))
         self._note_experts(sp, counts, blk["k"])
+        self._note_kv(sp, blk["pos_before"], blk["adv"], blk["k"])
         now = time.monotonic()
         emitted = 0
         with span("decode.deliver"):
@@ -1905,6 +1944,10 @@ class ContinuousBatcher:
             with self._cv:
                 out["expert_picks_total"] = self._expert_picks
                 out["experts_hit_total"] = self._experts_hit
+        if self._kv_cells:
+            with self._cv:
+                out["kv_cells_live_total"] = self._kv_live
+                out["kv_cells_spanned_total"] = self._kv_spanned
         if self.paged:
             with self._cv:
                 live_tokens = sum(

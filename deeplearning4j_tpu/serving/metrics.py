@@ -78,6 +78,8 @@ FAMILIES = {
     "dl4j_serving_queue_wait_seconds_total": ("counter", ()),
     "dl4j_serving_expert_picks_total": ("counter", ()),
     "dl4j_serving_experts_hit_total": ("counter", ()),
+    "dl4j_serving_kv_cells_live_total": ("counter", ()),
+    "dl4j_serving_kv_cells_spanned_total": ("counter", ()),
     "dl4j_router_ready": ("gauge", ()),
     "dl4j_router_inflight": ("gauge", ()),
     "dl4j_router_replicas_healthy": ("gauge", ()),
@@ -411,6 +413,17 @@ def replica_metrics(stats: dict, page: Optional[PrometheusText] = None,
                       "summed over steps and expert layers: the expert "
                       "weights a step had to read.",
                       gen["experts_hit_total"], lbl())
+        if "kv_cells_live_total" in gen:    # layers that count K/V cells
+            p.counter("dl4j_serving_kv_cells_live_total",
+                      "K/V cells decode steps had to read: min(position + "
+                      "1, table or window length), summed over live rows, "
+                      "layers and steps.",
+                      gen["kv_cells_live_total"], lbl())
+            p.counter("dl4j_serving_kv_cells_spanned_total",
+                      "K/V cells the layers say their decode reads "
+                      "covered (whole-state reads: every cell of every "
+                      "slot), summed over layers and steps.",
+                      gen["kv_cells_spanned_total"], lbl())
     return p.render() if own_page else ""
 
 

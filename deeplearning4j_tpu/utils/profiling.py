@@ -185,8 +185,12 @@ def scope(name: str):
 
 
 def layer_scope(index: int, conf):
-    """The scope of layer `index` of a stack: `L<i>.<layer_type>`."""
-    return scope(f"L{index}.{str(conf.layer_type)}")
+    """The scope of layer `index` of a stack: `L<i>.<layer_type>`, or where
+    the layer's typed settings name their own kind (`scope_kind`: a window
+    layer beside a full one of one type), `L<i>.<that>`."""
+    kind = (getattr(getattr(conf, "layer_spec", None), "scope_kind", None)
+            or str(conf.layer_type))
+    return scope(f"L{index}.{kind}")
 
 
 # -- profiler sessions --------------------------------------------------------

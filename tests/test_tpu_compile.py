@@ -212,3 +212,73 @@ def test_mla_absorbed_decode_compiles(one_chip):
         MLALayer, conf, jax.ShapeDtypeStruct((64, 2560), jnp.float32),
         jax.eval_shape(lambda: MLALayer.init_state(conf, 64, 2048)),
         jax.ShapeDtypeStruct((64,), jnp.int32))
+
+
+# -- the layer type of PR 32 at Mellum2's widths and the cell's shapes: plain
+# XLA again; what only the chip's compiler can say is whether the ring and
+# the table are read where they lie (K/V heads before positions: no copy of
+# a whole table a step) and whether a block of 1,024 queries fits
+
+def _mellum_conf(layer_type, spec):
+    return NeuralNetConfiguration(layer_type=layer_type, n_in=2304, n_out=2304,
+                                  dtype="bfloat16", compute_dtype="bfloat16",
+                                  layer_spec=spec)
+
+
+def _gqa_conf(kind):
+    from deeplearning4j_tpu.nn.conf import GQASpec
+
+    yarn = (16.0, 8192.0, 32.0, 1.0, 1.2772588722239782)
+    return _mellum_conf(LayerType.GQA, GQASpec(
+        n_heads=32, n_kv_heads=4, head_dim=128, rope_theta=500000.0, qk_norm=True,
+        window=1024 if kind == "window" else 0, yarn=None if kind == "window" else yarn))
+
+
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_gqa_decode_reads_its_state_where_it_lies(one_chip, kind):
+    import re
+
+    from deeplearning4j_tpu.nn.layers.gqa import GQALayer
+
+    conf = _gqa_conf(kind)
+    state = jax.eval_shape(lambda: GQALayer.init_state(conf, 64, 9216))
+    cells = 1024 if kind == "window" else 9216
+    assert state["k"].shape == (64, 4, cells, 128)
+    shaped = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = shaped(jax.eval_shape(lambda k: GQALayer.init(k, conf), jax.random.PRNGKey(0)))
+    # the state donated, as the decode programs of the cache have it
+    text = jax.jit(lambda p, x, s, q: GQALayer.decode_step(p, conf, x, s, q),
+                   donate_argnums=(2,)).lower(
+        params, shaped(jax.ShapeDtypeStruct((64, 2304), jnp.float32)), shaped(state),
+        shaped(jax.ShapeDtypeStruct((64,), jnp.int32))).compile().as_text()
+    # written with the heads' axis inside the scatter's window, the compiler
+    # copied each table into a position-major layout and back, every step
+    assert not re.search(rf"= bf16\[64,4,{cells},128\]\S* (copy|transpose)\(", text)
+
+
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_gqa_prefill_of_8192_compiles_in_blocks(one_chip, kind):
+    from deeplearning4j_tpu.nn.layers.gqa import GQALayer
+
+    conf = _gqa_conf(kind)
+    text = _compile_layer(
+        lambda p, x, s, n: GQALayer.prefill(p, conf, x, s, n), one_chip, GQALayer,
+        conf, jax.ShapeDtypeStruct((1, 8192, 2304), jnp.float32),
+        jax.eval_shape(lambda: GQALayer.init_state(conf, 1, 9216)),
+        jax.ShapeDtypeStruct((1,), jnp.int32))
+    # no block's scores span the whole prompt twice over: [.., 8192, 8192] never
+    assert "8192,8192]" not in text
+
+
+def test_expert_layer_that_holds_every_expert_runs_one_branch(one_chip):
+    from deeplearning4j_tpu.nn.conf import MoESpec
+    from deeplearning4j_tpu.nn.layers.experts import MoELayer
+
+    conf = _mellum_conf(LayerType.MOE, MoESpec(
+        n_routed=64, n_held=64, hidden=896, shared_hidden=0, top_k=8,
+        score="softmax", router_bias=False))
+    text = _compile_layer(lambda p, x: MoELayer.apply(p, conf, x), one_chip,
+                          MoELayer, conf,
+                          jax.ShapeDtypeStruct((64, 2304), jnp.float32))
+    assert text.count("ragged_dot_tiling") >= 2 and " conditional(" not in text
