@@ -156,6 +156,13 @@ def has_experts(conf: MultiLayerConfiguration) -> bool:
                for c in conf.confs)
 
 
+def experts_batched_layers(conf: MultiLayerConfiguration, rows: int) -> int:
+    """Of the layers that count expert picks, how many compute them in the
+    batched form (`product_form`) in a decode step of `rows` rows."""
+    return sum(impl.product_form(c, rows) == "batched"
+               for _, c, impl in _hidden(conf) if hasattr(impl, "counted_step"))
+
+
 def kv_cells(conf: MultiLayerConfiguration, max_seq: int) -> list:
     """For every layer whose class counts its state in cells a position
     (`kv_cells`): how many a row holds at `max_seq`, and how many of them

@@ -14,14 +14,22 @@ n_held)`, adds its shared expert where it has one, and leaves out what the
 absent experts would add: on one chip of an expert-parallel group this is
 that chip's part of the layer, without the exchange.
 
-Dropless: the picks are sorted by expert and every one of a held expert is
-computed by `jax.lax.ragged_dot` over the sorted rows; there is no capacity
-and no token is dropped, at 1 row or at 1024.  The picks of absent experts
-sort to the end and fall outside every group.  Where this layer holds a
-fraction of the experts, its picks usually fit a fraction of the rows: when
-they fit the first 3/8 the products run over those alone, otherwise over
-all of them (`jax.lax.cond`; the result is the same either way).  A layer
-that holds every expert has nothing to save there and builds no such choice.
+Dropless: every pick of a held expert is computed, at 1 row or at 1024;
+there is no capacity and no token is dropped.  The product has two forms,
+and `experts_form` chooses between them from the call's static shapes.
+Sorted: the picks are sorted by expert and computed by `jax.lax.ragged_dot`
+over the sorted rows; the picks of absent experts sort to the end and fall
+outside every group.  Where this layer holds a fraction of the experts, its
+picks usually fit a fraction of the rows: when they fit the first 3/8 the
+products run over those alone, otherwise over all of them (`jax.lax.cond`;
+the result is the same either way); a layer that holds every expert has
+nothing to save there and builds no such choice.  Batched: every held expert
+over all the rows as one batched product, the picks selected after it by a
+dense matrix of the routing weights; taken where a call of so many rows is
+expected to hit nearly every expert anyway and the rows are few (a decode
+step of 64 rows over 64 experts of which a row picks 8), because the chip's
+grouped kernel charges every group a tile of 512 rows there.  The scope is
+`experts` for the sorted form and `experts_batched` for the other.
 
 Neither keeps a decode state (`base.StatelessDecode`).  `MoELayer.apply`
 also returns two counts of the call, `[picks that landed on held experts,
@@ -77,9 +85,38 @@ def route(scores, bias, spec):
     return ids.astype(jnp.int32), weights
 
 
-def held_experts(params, spec, cd, u, ids, weights):
-    """What the held experts give for rows u [R, n]: (y [R, n] float32,
-    counts [2] int32).  Every pick of a held expert is computed."""
+#: The rule's two constants, from one sweep of both forms alone on a TPU v5e
+#: (PERF.md 6, PR 33; ms of device time, sorted / batched, bfloat16).
+#: `HIT_SHARE_FLOOR`: the batched form reads every held expert, the sorted one
+#: those that were hit.  128 held of 512 routed x [2560, 2 x 768], top 8: 64 rows
+#: (expected hit share 63.5 %) 1.72 / 2.01, 96 rows (77.9 %) 1.88 / 2.01, 128
+#: rows (86.7 %) 2.38 / 2.02: the forms cross between 0.78 and 0.87, and from
+#: 0.9 on the batched form reads next to nothing that the sorted one would skip.
+#: `BATCHED_ROWS_MOST`: the batched form computes every row against every held
+#: expert, 2 x rows FLOPs a weight of 2 bytes.  64 of 64 x [2304, 2 x 896]
+#: (793 MB, 0.97 ms at the memory's 819 GB/s): 8 to 128 rows 1.05 to 1.07 (the
+#: FLOPs hide behind the read of the weights), 192 rows 1.16, 256 1.30, 512 2.88;
+#: the sorted form 2.18 at 8 rows, 4.22 at 64, 4.86 at 512.
+HIT_SHARE_FLOOR = 0.9
+BATCHED_ROWS_MOST = 128
+
+
+def experts_form(spec, rows: int) -> str:
+    """`"batched"` or `"sorted"`: the form of the held experts' product for a
+    call of `rows` rows, from static shapes alone.  Batched where a call of
+    so many rows is expected to hit nearly every expert anyway (uniform
+    picks: an expert is missed by all rows with probability (1 - top_k /
+    n_routed) ** rows) and the rows are few enough for the batched FLOPs to
+    hide behind the read of the weights."""
+    hit_share = 1.0 - (1.0 - spec.top_k / spec.n_routed) ** rows
+    if hit_share >= HIT_SHARE_FLOOR and rows <= BATCHED_ROWS_MOST:
+        return "batched"
+    return "sorted"
+
+
+def _sorted_experts(params, spec, cd, u, ids, weights):
+    """The sorted form: the picks sorted by expert, `jax.lax.ragged_dot`
+    over the sorted rows."""
     r, n = u.shape
     picks = r * spec.top_k
     with scope("dispatch"):
@@ -116,6 +153,40 @@ def held_experts(params, spec, cd, u, ids, weights):
     else:
         y = over(picks)
     return y, jnp.stack([here, jnp.sum(sizes > 0)]).astype(jnp.int32)
+
+
+def _batched_experts(params, spec, cd, u, ids, weights):
+    """The batched form: every held expert over all the rows as one batched
+    product, the picks selected after it by a dense [R, n_held] matrix of
+    the routing weights."""
+    with scope("dispatch"):
+        # [R, top_k, n_held]: a pick of an absent expert matches no column
+        at = (ids - spec.first_held)[..., None] == jnp.arange(
+            spec.n_held, dtype=ids.dtype)
+        picked = jnp.any(at, axis=1)                        # [R, n_held]
+        dense = jnp.sum(jnp.where(at, weights[..., None], 0.0), axis=1)
+    with scope("experts_batched"):
+        h = jnp.einsum("rn,enf->erf", u.astype(cd), params["Wgu"].astype(cd),
+                       preferred_element_type=F32)
+        f = h.shape[-1] // 2
+        a = (jax.nn.silu(h[..., :f]) * h[..., f:]).astype(cd)
+        y = jnp.einsum("erf,efn->ern", a, params["Wd"].astype(cd),
+                       preferred_element_type=F32)
+    with scope("combine"):
+        # an unpicked expert's output is dropped, not multiplied by 0
+        y = jnp.sum(jnp.where(picked.T[..., None], y * dense.T[..., None], 0.0),
+                    axis=0)
+    counts = jnp.stack([jnp.sum(picked), jnp.sum(jnp.any(picked, axis=0))])
+    return y, counts.astype(jnp.int32)
+
+
+def held_experts(params, spec, cd, u, ids, weights):
+    """What the held experts give for rows u [R, n]: (y [R, n] float32,
+    counts [2] int32).  Every pick of a held expert is computed, in the
+    form that `experts_form` gives for this many rows."""
+    if experts_form(spec, u.shape[0]) == "batched":
+        return _batched_experts(params, spec, cd, u, ids, weights)
+    return _sorted_experts(params, spec, cd, u, ids, weights)
 
 
 #: `MoESpec.score` -> what turns the router's logits into scores
@@ -161,6 +232,11 @@ class MoELayer(StatelessDecode):
     @staticmethod
     def forward(params, conf, x, key=None, training=False):
         return MoELayer.apply(params, conf, x)[0]
+
+    @staticmethod
+    def product_form(conf, rows: int) -> str:
+        """The form of the experts' product in a call of `rows` rows."""
+        return experts_form(conf.layer_spec, rows)
 
     @staticmethod
     def counted_step(params, conf, x, state, pos, page_table=None):

@@ -787,6 +787,9 @@ class ContinuousBatcher:
                 f"pages for, a cached prefix row is right for only at the "
                 f"prompt's end, and a verify chunk cannot roll back")
         self._has_experts = decode_mod.has_experts(net.conf)
+        # static a program: a decode step is one call of `n_slots` rows
+        self._experts_batched = decode_mod.experts_batched_layers(
+            net.conf, self.n_slots)
         # {(cells a row holds, cells of them a step reads): layers} of the
         # layers that count their state in cells a position
         self._kv_cells = Counter(
@@ -1538,13 +1541,16 @@ class ContinuousBatcher:
         dispatch made, `[[picks that landed on held experts, distinct held
         experts hit]]` summed over the layers (and empty for a stack
         without them): added to the open `decode` span's `picks_here`,
-        `experts_hit` and `steps`, and to the totals of `stats()`."""
+        `experts_hit` and `steps`, and to the totals of `stats()`.  The span
+        also says how many of the layers took the batched form of their
+        product, `experts_batched_layers`."""
         if not counts:
             return
         picks, hit = (int(n) for n in counts[0])
         sp.set(picks_here=sp.attrs.get("picks_here", 0) + picks,
                experts_hit=sp.attrs.get("experts_hit", 0) + hit,
-               steps=sp.attrs.get("steps", 0) + steps)
+               steps=sp.attrs.get("steps", 0) + steps,
+               experts_batched_layers=self._experts_batched)
         with self._cv:
             self._expert_picks += picks
             self._experts_hit += hit
@@ -1944,6 +1950,7 @@ class ContinuousBatcher:
             with self._cv:
                 out["expert_picks_total"] = self._expert_picks
                 out["experts_hit_total"] = self._experts_hit
+            out["experts_batched_layers"] = self._experts_batched
         if self._kv_cells:
             with self._cv:
                 out["kv_cells_live_total"] = self._kv_live

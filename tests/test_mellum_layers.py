@@ -149,7 +149,9 @@ def test_a_layer_that_holds_every_expert_builds_no_choice_of_rows(f32):
 
     i = f32.layer("moe")
     conf = f32.conf.conf(i)
-    x = jnp.zeros((128, f32.sizes["d"]), jnp.float32)
+    # rows past `experts.BATCHED_ROWS_MOST`: the sorted form, as an admission's
+    x = jnp.zeros((136, f32.sizes["d"]), jnp.float32)
+    assert MoELayer.product_form(conf, 136) == "sorted"
     all_held = str(jax.make_jaxpr(lambda p, v: MoELayer.apply(p, conf, v))(f32.params[i], x))
     assert "cond[" not in all_held and "ragged_dot" in all_held
     half = conf.replace(layer_spec=dataclasses.replace(

@@ -119,6 +119,41 @@ def test_fused_blocks_serve_the_same_tokens_and_count_the_same_cells(f32, served
     assert blocks["kv_cells_spanned_total"] % (2 * (3 * 8 + MAX_SEQ)) == 0
 
 
+def test_enough_slots_take_the_batched_form_and_serve_the_same_tokens(f32, served):
+    """8 experts, top 2: 16 rows are expected to hit 99 % of them, so the
+    decode step of 16 slots computes its expert layers in the batched form
+    (the 2 slots of `served`: 44 %, the sorted form).  The streams are the
+    reference's greedy ones all the same, token for token."""
+    from deeplearning4j_tpu.nn.layers.experts import MoELayer
+
+    layers = f32.kinds.count("moe")
+    moe = f32.conf.conf(f32.layer("moe"))
+    assert [MoELayer.product_form(moe, rows) for rows in (2, 4, 16)] == [
+        "sorted", "sorted", "batched"]
+    assert decode.experts_batched_layers(f32.conf, 16) == layers
+    net = MultiLayerNetwork(f32.conf)
+    net.params = f32.params
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, f32.sizes["vocab"], n).astype(np.int32) for n in (5, 8, 3)]
+    profiling.clear()
+    batcher = ContinuousBatcher(net, n_slots=16, max_seq=32, prompt_buckets=(8,),
+                                steps_per_dispatch=1).start()
+    streams = [batcher.submit(p, max_new_tokens=10) for p in prompts]
+    tokens = [list(s.tokens(timeout=120)) for s in streams]
+    stats = batcher.stats()
+    batcher.stop()
+    for prompt, got in zip(prompts, tokens):
+        assert len(got) == 10
+        assert_greedy(f32, prompt, got)
+    steps = [s for s in profiling.spans() if s.name == "decode" and "picks_here" in s.attrs]
+    assert steps and all(s.attrs["experts_batched_layers"] == layers for s in steps)
+    assert stats["experts_batched_layers"] == layers
+    # the two slots of `served` stay with the sorted form
+    assert served[2]["experts_batched_layers"] == 0
+    assert all(s.attrs["experts_batched_layers"] == 0 for s in served[3]
+               if s.name == "decode" and "picks_here" in s.attrs)
+
+
 # ---------------------------------------------------------------- refusals
 
 @pytest.mark.parametrize("option", [

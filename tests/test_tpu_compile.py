@@ -159,12 +159,17 @@ def _ling3_conf(layer_type, spec):
                                   layer_spec=spec)
 
 
-def _compile_layer(fn, one_chip, impl, conf, *args):
+def _compiled_layer(fn, one_chip, impl, conf, *args):
     """`fn(params, *args)` compiled with `impl.init`'s shapes for params."""
     shaped = lambda tree: jax.tree_util.tree_map(  # noqa: E731
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
     params = shaped(jax.eval_shape(lambda k: impl.init(k, conf), jax.random.PRNGKey(0)))
-    return jax.jit(fn).lower(params, *[shaped(a) for a in args]).compile().as_text()
+    return jax.jit(fn).lower(params, *[shaped(a) for a in args]).compile()
+
+
+def _compile_layer(fn, one_chip, impl, conf, *args):
+    """The same, as the program's text."""
+    return _compiled_layer(fn, one_chip, impl, conf, *args).as_text()
 
 
 @pytest.mark.parametrize("rows", [64, 1024], ids=["decode-64", "prefill-1024"])
@@ -179,7 +184,9 @@ def test_expert_layer_is_a_grouped_product_on_the_chip(one_chip, rows):
                           MoELayer, conf,
                           jax.ShapeDtypeStruct((rows, 2560), jnp.float32))
     # XLA's own grouped kernel, in both branches: over 3/8 of the picks and
-    # over all of them; never a product over every expert for every row
+    # over all of them; never a product over every expert for every row (64
+    # rows are expected to hit 63.5 % of the experts: the sorted form)
+    assert MoELayer.product_form(conf, rows) == "sorted"
     assert text.count("ragged_dot_tiling") >= 4 and "conditional" in text
 
 
@@ -271,14 +278,28 @@ def test_gqa_prefill_of_8192_compiles_in_blocks(one_chip, kind):
     assert "8192,8192]" not in text
 
 
-def test_expert_layer_that_holds_every_expert_runs_one_branch(one_chip):
+@pytest.mark.parametrize("rows", [64, 1024], ids=["decode-64", "prefill-1024"])
+def test_expert_layer_that_holds_every_expert_runs_one_branch(one_chip, rows):
     from deeplearning4j_tpu.nn.conf import MoESpec
     from deeplearning4j_tpu.nn.layers.experts import MoELayer
 
     conf = _mellum_conf(LayerType.MOE, MoESpec(
         n_routed=64, n_held=64, hidden=896, shared_hidden=0, top_k=8,
         score="softmax", router_bias=False))
-    text = _compile_layer(lambda p, x: MoELayer.apply(p, conf, x), one_chip,
-                          MoELayer, conf,
-                          jax.ShapeDtypeStruct((64, 2304), jnp.float32))
-    assert text.count("ragged_dot_tiling") >= 2 and " conditional(" not in text
+    compiled = _compiled_layer(lambda p, x: MoELayer.apply(p, conf, x), one_chip,
+                               MoELayer, conf,
+                               jax.ShapeDtypeStruct((rows, 2304), jnp.float32))
+    text = compiled.as_text()
+    assert " conditional(" not in text
+    if rows == 64:
+        # the cell's decode step, 8 picks a row over 64 experts: every expert
+        # over all 64 rows as one batched product (PR 33), no grouped kernel
+        # paying a 512-row tile a group, and the only sort is the router's
+        # top-k; the float32 intermediates (29 + 38 MB) are the temporaries
+        assert MoELayer.product_form(conf, rows) == "batched"
+        assert "ragged" not in text
+        assert all("/router/" in line for line in text.splitlines() if " sort(" in line)
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * (1 << 30)
+    else:
+        assert MoELayer.product_form(conf, rows) == "sorted"
+        assert text.count("ragged_dot_tiling") >= 2
