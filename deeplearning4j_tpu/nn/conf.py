@@ -131,7 +131,22 @@ class KDASpec:
 class MLASpec:
     """LayerType.MLA (arXiv:2405.04434): keys and values from one cached
     latent of `kv_lora_rank` and one rotary key of `qk_rope_head_dim` shared
-    by all heads; no low-rank query."""
+    by all heads.  The options, each off at its default (and left out of
+    the conf's JSON there, `OMIT_AT_DEFAULT`: a conf from before them
+    serialises as it did):
+    `q_lora_rank` R > 0: the query comes through a latent of its own, `cq =
+    RMSNorm(Wqa u)` in R^R, `q = Wqb cq`; 0 is `q = Wq u`.
+    `lora_rescale`: `cq` times `sqrt(n_in / q_lora_rank)` and the cached
+    latent times `sqrt(n_in / kv_lora_rank)`.
+    `window` W > 0: a token sees itself and the W - 1 before it, and the
+    decode state is a ring of W latents written at `pos % W`; 0 is full
+    causal attention over a table of `max_seq` latents.
+    `gate`: every head's output times `sigmoid(Wg u)_h` before `Wo`.
+    `index_topk` K > 0 (with `index_n_heads` heads of `index_head_dim`, and
+    a low-rank query): a learned indexer scores every cached position for
+    the token, `sum_j w_j relu(qi_j . ki_s)`, and attention is over the K
+    positions of largest score alone (all of them while there are at most
+    K); its keys `ki` are a third table of the decode state."""
 
     n_heads: int
     kv_lora_rank: int
@@ -140,6 +155,26 @@ class MLASpec:
     v_head_dim: int
     rope_theta: float = 10000.0
     eps: float = 1e-6
+    q_lora_rank: int = 0
+    lora_rescale: bool = False
+    window: int = 0
+    gate: bool = False
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+
+    OMIT_AT_DEFAULT = ("q_lora_rank", "lora_rescale", "window", "gate",
+                       "index_n_heads", "index_head_dim", "index_topk")
+
+    @property
+    def scope_kind(self) -> Optional[str]:
+        """What `layer_scope` calls the layer where a model has the type in
+        two geometries (`mla_window` beside `mla_full`, told by the
+        low-rank query both have); None, the layer type's own name, for
+        the plain layer."""
+        if self.window:
+            return "mla_window"
+        return "mla_full" if self.q_lora_rank else None
 
 
 @dataclass(frozen=True)

@@ -27,7 +27,9 @@ State layout: one dict per layer, in layer order, as a tuple —
   LSTM/GRAVES_LSTM  {"h": [B, H] f32, "c": [B, H] f32}
   ATTENTION         {"k": [B, max_S, n] compute_dtype, "v": same}
   KDA               {"S": [B, H, dk, dv] f32, "conv": [B, K-1, 3 H dk]}
-  MLA               {"c": [B, max_S, rank], "kr": [B, max_S, rope]}
+  MLA               {"c": [B, max_S, rank], "kr": [.., rope]}, or side by
+                    side {"ckr": [B, max_S or window, rank + rope]} and
+                    with an indexer also {"ki": [B, max_S, index dim]}
   GQA               {"k": [B, G, max_S or window, h] compute_dtype, "v": same}
   everything else   {}
 each made by its layer class's `init_state`; `CARRY` on the class says
@@ -66,6 +68,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deeplearning4j_tpu.nn.conf import LayerType, MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers import get_layer
@@ -164,11 +167,24 @@ def experts_batched_layers(conf: MultiLayerConfiguration, rows: int) -> int:
 
 
 def kv_cells(conf: MultiLayerConfiguration, max_seq: int) -> list:
-    """For every layer whose class counts its state in cells a position
-    (`kv_cells`): how many a row holds at `max_seq`, and how many of them
-    its decode step reads (`kv_cells_read`)."""
-    return [(impl.kv_cells(c, max_seq), impl.kv_cells_read(c, max_seq))
-            for _, c, impl in _hidden(conf) if hasattr(impl, "kv_cells")]
+    """For every table of every layer whose class counts its state in cells
+    a position (`kv_cells`, one number a layer or one a table): the most
+    cells a step needs of a row at `max_seq`, and how many its decode step
+    reads (`kv_cells_read`)."""
+    out = []
+    for _, c, impl in _hidden(conf):
+        if hasattr(impl, "kv_cells"):
+            out += zip(np.atleast_1d(impl.kv_cells(c, max_seq)).tolist(),
+                       np.atleast_1d(impl.kv_cells_read(c, max_seq)).tolist())
+    return out
+
+
+def selected_cells(conf: MultiLayerConfiguration, max_seq: int) -> list:
+    """For every layer whose decode step picks the cached positions it
+    attends to (`selects`), how many it picks for a row at `max_seq`."""
+    picks = [impl.selects(c, max_seq) for _, c, impl in _hidden(conf)
+             if hasattr(impl, "selects")]
+    return [k for k in picks if k]
 
 
 def _refuse_dense_only(conf, what: str) -> None:

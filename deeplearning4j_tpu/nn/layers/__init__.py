@@ -30,11 +30,17 @@ What only some types can do, a class declares by having it:
             each of them where it is a carry ({} otherwise), to roll back to
     counted_step(params, conf, x, state, pos)   -> (hidden, state, counts)
             a step that counts its expert picks ([2] int32)
-    kv_cells(conf, max_seq), kv_cells_read(conf, max_seq)   -> int
-            its state holds one cell a position, so many a row (a table's
-            length, a ring's), and `decode_step` reads so many of them for
-            every row of the table: the batcher counts the cells a step
-            needs beside those the layer says its read covers
+    kv_cells(conf, max_seq), kv_cells_read(conf, max_seq)   -> int, or one
+            a table of its state
+            its state holds one cell a position: the most cells of a row
+            that a step needs (a table's length, a ring's, the positions a
+            step picks), and `decode_step` reads so many for every row of
+            the slot table: the batcher counts the cells a step needs
+            beside those the layer says its read covers
+    selects(conf, max_seq)                      -> int
+            its decode step picks so many of a row's cached positions and
+            attends to those alone (0: to all): the batcher counts the
+            positions cached beside those picked
 A type with a state but without `init_paged_state` and `verify_chunk` lives
 in the dense slot table only (`nn.decode.dense_only`).  A type with no state
 gets all of it from `base.StatelessDecode`.  A new kind of state is a layer

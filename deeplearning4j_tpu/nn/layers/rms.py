@@ -48,6 +48,14 @@ def rms_norm(x, g, eps: float):
                               + eps) * g.astype(F32))
 
 
+def layer_norm(x, g, b, eps: float):
+    """LayerNorm with a weight and a bias, in float32."""
+    x = x.astype(F32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    return (x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                              + eps) * g.astype(F32) + b.astype(F32))
+
+
 def pre_norm(params, x, eps: float):
     """The block's input norm, under the scope `ln`."""
     with scope("ln"):
@@ -96,6 +104,13 @@ def rope(x, positions, theta: float, yarn=None):
         cos, sin = cos * scale, sin * scale
     half = jnp.concatenate([-x[..., n // 2:], x[..., : n // 2]], axis=-1)
     return x * cos + half * sin
+
+
+def rope_first(x, positions, theta: float, n: int):
+    """`rope` over the first `n` dimensions of the last axis, the rest as
+    they are."""
+    return jnp.concatenate([rope(x[..., :n], positions, theta), x[..., n:]],
+                           axis=-1)
 
 
 def swiglu(u, w_gate_up, w_down, cd):

@@ -73,8 +73,9 @@ ROW_SPLIT_NAMES = frozenset({"Wo", "W2"})
 #: [pages,page,n]) split by head; recurrent carries split their hidden
 STATE_SPLIT_NAMES = frozenset({"k", "v", "h", "c"})
 #: Every other leaf is whole on every chip: a KDA layer's `S` and `conv`,
-#: an MLA layer's `kr`, and its latent, which is also called `c` but has a
-#: position axis ([B, max_S, rank]; an LSTM's is [B, H]): one latent serves
+#: an MLA layer's `kr` and `ki` (its indexer's keys), and its latent, which
+#: is also called `c` but has a position axis ([B, max_S or a window, rank];
+#: an LSTM's is [B, H]): one latent serves
 #: all heads, so it cannot be split by head, and the plan has no expert or
 #: data axis for decode state to lie along yet.  A grouped-heads layer's `k`
 #: and `v` ([B, G, cells, h], four axes) stay whole too: their trailing axis

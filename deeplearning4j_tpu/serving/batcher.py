@@ -794,6 +794,9 @@ class ContinuousBatcher:
         # layers that count their state in cells a position
         self._kv_cells = Counter(
             decode_mod.kv_cells(net.conf, self.max_seq))
+        # {positions a layer's step picks among a row's cached ones: layers}
+        self._dsa_picks = Counter(
+            decode_mod.selected_cells(net.conf, self.max_seq))
         # silent positional-table overrun fix: `token_embed` gathers
         # P[pos] with no bound check, and jit CLAMPS out-of-range
         # gathers — a stream decoding past the learned table would read
@@ -928,6 +931,8 @@ class ContinuousBatcher:
         # K/V cells the steps needed, and cells their whole-state reads covered
         self._kv_live = 0
         self._kv_spanned = 0
+        self._dsa_live = 0
+        self._dsa_selected = 0
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> "ContinuousBatcher":
@@ -1555,32 +1560,60 @@ class ContinuousBatcher:
             self._expert_picks += picks
             self._experts_hit += hit
 
+    @staticmethod
+    def _cells_needed(pos, adv, most: int) -> int:
+        """Summed over the rows: row r, at position `pos[r]`, advances
+        `adv[r]` tokens (0: a free or a finished row), and each of its steps
+        needs `min(position + 1, most)` cells: p + 1 .. p + a, each clipped
+        to `most`, of which `rising` lie under it."""
+        rising = np.clip(most - pos, 0, adv)
+        return int((rising * pos + rising * (rising + 1) // 2
+                    + (adv - rising) * most).sum())
+
     def _note_kv(self, sp: span, pos, adv, steps: int) -> None:
-        """The K/V cells of one dispatch of `steps` table steps in which row
-        r, at position `pos[r]`, advances `adv[r]` tokens (0: a free or a
-        finished row), for a stack with layers that count their state in
-        cells (`kv_cells`; nothing otherwise).  `kv_cells_live`: what the
-        steps have to read, `min(position + 1, cells held)` a live row,
-        layer and step, from the slots' positions.  `kv_cells_spanned`: what
-        the layers say their reads cover (`kv_cells_read`), a row of the
-        table, layer and step.  Added to the open `decode` span and to the
-        totals of `stats()`."""
+        """The cached cells of one dispatch of `steps` table steps in which
+        row r, at position `pos[r]`, advances `adv[r]` tokens, for a stack
+        with layers that count their state in cells (`kv_cells`, a layer or
+        a table of one; nothing otherwise).  `kv_cells_live`: what the steps
+        have to read, `min(position + 1, the most the table can need)` a
+        live row, table and step, from the slots' positions.
+        `kv_cells_spanned`: what the layers say their reads cover
+        (`kv_cells_read`), a row of the slot table, table and step.  Added
+        to the open `decode` span and to the totals of `stats()`."""
         if not self._kv_cells:
             return
         p, a = np.asarray(pos, np.int64), np.asarray(adv, np.int64)
         live = spanned = 0
-        for (held, read), n in self._kv_cells.items():
-            # p + 1 .. p + a, each clipped to `held`: `rising` of them lie
-            # under it
-            rising = np.clip(held - p, 0, a)
-            live += n * int((rising * p + rising * (rising + 1) // 2
-                             + (a - rising) * held).sum())
+        for (most, read), n in self._kv_cells.items():
+            live += n * self._cells_needed(p, a, most)
             spanned += n * steps * self.n_slots * read
         sp.set(kv_cells_live=sp.attrs.get("kv_cells_live", 0) + live,
                kv_cells_spanned=sp.attrs.get("kv_cells_spanned", 0) + spanned)
         with self._cv:
             self._kv_live += live
             self._kv_spanned += spanned
+        self._note_dsa(sp, p, a)
+
+    def _note_dsa(self, sp: span, pos, adv) -> None:
+        """The same dispatch's selection, for a stack with layers whose step
+        picks the positions it attends to (`selects`; nothing otherwise).
+        `dsa_cells_live`: the positions cached at or before a live row's own
+        (`position + 1`); `dsa_cells_selected`: what was picked of them
+        (`min(position + 1, picks)`); a row, such layer and step.  Both come
+        from the slots' positions and one constant of the conf, so they
+        describe the traffic (how far the rows are past the picks), not the
+        program: what a step reads of its tables is `kv_cells_read`."""
+        if not self._dsa_picks:
+            return
+        cached = selected = 0
+        for picks, n in self._dsa_picks.items():
+            cached += n * self._cells_needed(pos, adv, self.max_seq)
+            selected += n * self._cells_needed(pos, adv, picks)
+        sp.set(dsa_cells_live=sp.attrs.get("dsa_cells_live", 0) + cached,
+               dsa_cells_selected=sp.attrs.get("dsa_cells_selected", 0) + selected)
+        with self._cv:
+            self._dsa_live += cached
+            self._dsa_selected += selected
 
     def _note_block(self, k: int, wall: float, wait: float, emitted: int,
                     now: float) -> None:
@@ -1955,6 +1988,9 @@ class ContinuousBatcher:
             with self._cv:
                 out["kv_cells_live_total"] = self._kv_live
                 out["kv_cells_spanned_total"] = self._kv_spanned
+                if self._dsa_picks:
+                    out["dsa_cells_live_total"] = self._dsa_live
+                    out["dsa_cells_selected_total"] = self._dsa_selected
         if self.paged:
             with self._cv:
                 live_tokens = sum(

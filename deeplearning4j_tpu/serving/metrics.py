@@ -80,6 +80,8 @@ FAMILIES = {
     "dl4j_serving_experts_hit_total": ("counter", ()),
     "dl4j_serving_kv_cells_live_total": ("counter", ()),
     "dl4j_serving_kv_cells_spanned_total": ("counter", ()),
+    "dl4j_serving_dsa_cells_live_total": ("counter", ()),
+    "dl4j_serving_dsa_cells_selected_total": ("counter", ()),
     "dl4j_router_ready": ("gauge", ()),
     "dl4j_router_inflight": ("gauge", ()),
     "dl4j_router_replicas_healthy": ("gauge", ()),
@@ -424,6 +426,16 @@ def replica_metrics(stats: dict, page: Optional[PrometheusText] = None,
                       "covered (whole-state reads: every cell of every "
                       "slot), summed over layers and steps.",
                       gen["kv_cells_spanned_total"], lbl())
+        if "dsa_cells_live_total" in gen:   # layers that pick what they read
+            p.counter("dl4j_serving_dsa_cells_live_total",
+                      "Cached positions at or before a live row's own, "
+                      "summed over live rows, layers with an indexer and "
+                      "decode steps: what the indexer scored.",
+                      gen["dsa_cells_live_total"], lbl())
+            p.counter("dl4j_serving_dsa_cells_selected_total",
+                      "Of those, the positions the indexer's layers "
+                      "attended to: min(position + 1, index_topk).",
+                      gen["dsa_cells_selected_total"], lbl())
     return p.render() if own_page else ""
 
 

@@ -303,3 +303,4 @@ def test_expert_layer_that_holds_every_expert_runs_one_branch(one_chip, rows):
     else:
         assert MoELayer.product_form(conf, rows) == "sorted"
         assert text.count("ragged_dot_tiling") >= 2
+
