@@ -45,6 +45,7 @@ import heapq
 import io
 import itertools
 import queue
+import statistics
 import threading
 import time
 from collections import Counter, OrderedDict, deque
@@ -160,6 +161,21 @@ def _host_sample(logp, key, temperature: float):
     else:
         tok = int(np.argmax(np.asarray(logp, np.float32)))
     return tok, new_key
+
+
+def _prng_key(seed: int) -> np.ndarray:
+    """`jax.random.PRNGKey(seed)` as a host array, made on the host: under
+    the default implementation (threefry2x32) the key is the seed's high and
+    low 32 bits, the high ones kept only where 64-bit types are on.  A
+    `submit` therefore runs nothing on the device and never queues behind
+    the decode step in flight.  Any other implementation keeps the call."""
+    import jax
+
+    if jax.config.jax_default_prng_impl != "threefry2x32":
+        return np.asarray(jax.random.PRNGKey(seed))
+    bits = int(np.int64(seed))      # past 64 bits it raises, as PRNGKey does
+    high = (bits >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.asarray([high, bits & 0xFFFFFFFF], np.uint32)
 
 
 class _Pending:
@@ -633,14 +649,12 @@ class GenerationStream:
 
     def __init__(self, prompt, max_new_tokens: int, temperature: float,
                  rng_seed: int):
-        import jax
-
         self.prompt = np.asarray(prompt, np.int32).reshape(-1)
         self.max_new = int(max_new_tokens)
         self.temperature = float(temperature)
         # per-stream PRNG key, split once per sampled token on-device —
         # the eager sampler's exact key discipline
-        self.key = np.asarray(jax.random.PRNGKey(int(rng_seed)))
+        self.key = _prng_key(int(rng_seed))
         self.error: Optional[BaseException] = None
         self.tokens_emitted = 0
         #: tokens to swallow on readmission after a page-pool
@@ -901,6 +915,14 @@ class ContinuousBatcher:
         # fused block up the warmed ladder, resets to 1 on any
         # admission, release, or preemption
         self._ramp = 1
+        # the K=1 rounds' clock (decode-loop thread only): what the last
+        # eight steps ahead took from readback to readback and their
+        # dispatches on the host, when the step now on the device
+        # started, and the wall and blocked time since the last completion
+        self._periods: Deque[float] = deque(maxlen=8)
+        self._dispatches: Deque[float] = deque(maxlen=8)
+        self._t_started = self._t_mark = 0.0
+        self._waited_s = 0.0
         # -- stats (guarded by _cv's lock) ---------------------------------
         self._t_start = time.monotonic()
         self._tokens_total = 0
@@ -918,6 +940,10 @@ class ContinuousBatcher:
         # minus the time spent blocked in device_get
         self._host_s = 0.0
         self._wall_s = 0.0
+        # K=1 table steps dispatched, and those of them dispatched while
+        # the step before was still in flight
+        self._steps = 0
+        self._steps_ahead = 0
         # summed from the closed `admit` spans (guarded by _cv's lock):
         # what admissions held the loop for, and what their streams waited
         self._rids = itertools.count(1)
@@ -1449,53 +1475,166 @@ class ContinuousBatcher:
             for p, phys in zip(need, got):
                 self._page_table[slot, p] = phys
 
-    def _decode_once(self) -> None:
-        """One table step: fire per-slot fault points (a raise ends THAT
-        stream only), then one compiled decode call over all slots, then
-        emit per-slot tokens and free finished slots.  When speculative
-        decoding is on and every active slot has room for a spec_k
-        chunk, the step is a draft+verify round instead."""
-        with span("decode", k=1) as sp:
-            self._decode_spanned(sp)
+    def _runs_ahead(self) -> bool:
+        """Whether the step after the one just dispatched can be
+        dispatched before this one is read back: its inputs must not
+        depend on the host having seen this step's tokens.  They do while
+        a slot feeds a prompt's rest through the table (the host swaps
+        the sampled token for the next prompt token), under a draft
+        network (a speculative round needs the accepted depth, a lockstep
+        step the host's token), and over the paged pool, which stays
+        synchronous: a step in flight would be writing pages that the
+        next step's growth may preempt and hand to another slot, and no
+        cell runs it."""
+        return not (self.draft_net is not None or self.paged
+                    or any(self._feed))
 
-    def _decode_spanned(self, sp: span) -> None:
-        """`_decode_once` inside its `decode` span; the children are
-        `decode.dispatch` (argument copies and the program's call),
-        `decode.readback` (the one `device_get`) and `decode.deliver` (the
-        per-slot loop)."""
-        import jax
+    def _admissible(self) -> bool:
+        """Whether a pending stream could be admitted now: a slot stands
+        free (all of them in the sequential arm)."""
+        free = self._slots.count(None)
+        return free > 0 and (self.continuous or free == self.n_slots)
 
-        t0 = time.monotonic()
-        for slot, stream in enumerate(self._slots):
+    def _leaves_rounds(self) -> bool:
+        """Whether the K=1 rounds should complete what is in flight and
+        hand back to `_decode_loop`: an admission is due, or fused blocks
+        may take over."""
+        return ((self._admissible() and self._has_pending())
+                or self._block_eligible())
+
+    def _decode_rounds(self) -> None:
+        """K=1 table steps, each in a `decode` span of its own, until
+        nothing is in flight.
+
+        A step has two halves, `_dispatch` (fault points, the rows'
+        schedule, the program's call) and `_complete` (the one
+        `device_get`, delivery, releases).  Where the next step's inputs
+        need the host to have seen this step's tokens (`_runs_ahead`) a
+        span is dispatch then complete, and the rounds end with it: the
+        synchronous step.  Everywhere else the loop stays ONE STEP AHEAD:
+        step n+1 is dispatched while step n runs, its token and key
+        arguments step n's device outputs, so that the device finds its
+        next step queued when it finishes one.  A steady span is then
+        complete(n), `decode.wait`, dispatch(n+2) while n+1 runs; the two
+        spans that start a run hold a dispatch alone, and those that end
+        it complete one step each of what is left in flight.  A span
+        carries what its completed step counted (`live`, `steps`,
+        `picks_here`, `kv_cells_*` ...) and, of its dispatch, `ahead`.
+
+        An admission keeps the wait it had.  While a slot stands free the
+        loop holds dispatch(n+2) back (`_wait_for_need`), so that a
+        submission arriving meanwhile is admitted behind the one step in
+        flight, not behind two: that step is completed, the rounds end
+        and `_admit_pending` runs as it always did, from the host's
+        arrays."""
+        flying: Deque[dict] = deque()
+        self._t_mark, self._waited_s = time.monotonic(), 0.0
+        leaving = False
+        while True:
+            with span("decode", k=1) as sp:
+                if len(flying) == 2 or leaving:
+                    self._complete(flying.popleft(), sp)
+                if flying and not leaving:
+                    self._wait_for_need()
+                    leaving = self._leaves_rounds()
+                if not leaving:
+                    step = self._dispatch(sp, flying)
+                    if step is None:
+                        leaving = True
+                    elif self._runs_ahead():
+                        flying.append(step)
+                    else:       # synchronous: nothing was in flight before it
+                        self._complete(step, sp)
+            if not flying:
+                return
+
+    def _wait_for_need(self) -> None:
+        """With a step in flight and a slot standing free, wait on `_cv`
+        (which `submit` notifies) for a submission, until the device is
+        about to need the next step: the running step's start plus the
+        loop's recent readback-to-readback period, less its recent
+        `decode.dispatch` time and a margin of as much again.  Both are
+        the loop's own observations; before it has any, and with no slot
+        free, it does not wait.  The wait is the `decode.wait` span; the
+        callers take the GIL for the tokens just delivered meanwhile."""
+        if not (self._periods and self._admissible()):
+            return
+        need = (self._t_started + statistics.median(self._periods)
+                - 2.0 * statistics.median(self._dispatches))
+        if need <= time.monotonic():
+            return
+        with span("decode.wait") as waited:
+            with self._cv:
+                while not self._pending:
+                    left = need - time.monotonic()
+                    if left <= 0:
+                        break
+                    self._cv.wait(left)
+        self._waited_s += waited.seconds
+
+    def _dispatch(self, sp: span, flying: Deque[dict]) -> Optional[dict]:
+        """The first half of a table step, inside its `decode` span `sp`:
+        schedule the rows, fire their fault points (a raise ends THAT
+        stream only), then one compiled decode call over all slots.
+        Returns the step's record for `_complete`, or None where no row
+        was left to advance (or a speculative round took the step).
+
+        With a step in flight (`flying`) the rows are scheduled by
+        arithmetic: streams end by count alone, so a row whose budget or
+        whose table edge the step in flight uses up is scheduled as a
+        free slot is, and the token and key arguments are that step's
+        device outputs, passed on without a sync.  With none the host's
+        arrays are the arguments."""
+        prev = flying[-1] if flying else None
+        streams = list(self._slots)
+        pos = self._pos.copy()
+        adv = np.zeros((self.n_slots,), np.int32)
+        for slot, stream in enumerate(streams):
             if stream is None:
                 continue
+            ran = (int(prev["adv"][slot])
+                   if prev is not None and prev["streams"][slot] is stream
+                   else 0)
+            pos[slot] += ran
+            left = stream.max_new - stream.tokens_emitted + stream._replay
+            adv[slot] = left > ran and int(pos[slot]) < self.max_seq
+        for slot in map(int, np.flatnonzero(adv)):
             try:
-                faults.fire("decode.step", slot=slot,
-                            pos=int(self._pos[slot]))
+                faults.fire("decode.step", slot=slot, pos=int(pos[slot]))
             except BaseException as e:  # noqa: BLE001 — isolate the stream
-                self._release_slot(slot, stream, error=e)
-        active = [s for s, st in enumerate(self._slots) if st is not None]
-        sp.set(live=len(active))
-        if not active:
-            return
+                # the K=1 count: what is in flight reaches the stream first
+                while flying:
+                    self._complete(flying.popleft(), sp)
+                if self._slots[slot] is streams[slot]:
+                    self._release_slot(slot, streams[slot], error=e)
+                adv[slot] = 0
+        prev = flying[-1] if flying else None
+        # a span's counts are its completed step's: `live` is this step's
+        # only where the span completed none before it
+        sp.attrs.setdefault("live", int(adv.sum()))
+        if not adv.any():
+            return None
+        active = np.flatnonzero(adv)
         if (self.spec_k
                 and all(not self._feed[s] for s in active)
-                and all(int(self._pos[s]) + self.spec_k <= self.max_seq
+                and all(int(pos[s]) + self.spec_k <= self.max_seq
                         for s in active)):
             self._spec_once()
-            return
+            return None
         ic = self.net.infer_cache
-        with span("decode.dispatch"):
+        with span("decode.dispatch") as dispatched:
             if self.paged:
                 self._lazy_alloc(1)
-                if not any(s is not None for s in self._slots):
-                    return
-            self._note_kv(sp, self._pos, np.asarray(
-                [st is not None for st in self._slots], np.int32), 1)
+                for slot, stream in enumerate(streams):
+                    if self._slots[slot] is not stream:
+                        adv[slot] = 0   # preempted or failed in page growth
+                if not adv.any():
+                    return None
+            tok, keys = ((self._tok.copy(), self._keys.copy())
+                         if prev is None else (prev["tok"], prev["keys"]))
             # counts: a stack with expert layers returns their [picks, hit]
             tok2, keys2, *counts, self._state = ic.decode(
-                self.net.conf, self.net.params, self._state,
-                self._tok.copy(), self._pos.copy(), self._keys.copy(),
+                self.net.conf, self.net.params, self._state, tok, pos, keys,
                 self._temps.copy(), page_table=self._pages())
             if self.draft_net is not None:
                 # non-spec rounds (feeds pending, or a slot near the table
@@ -1507,16 +1646,41 @@ class ContinuousBatcher:
                     dn.conf, dn.params, self._draft_state, self._tok.copy(),
                     self._pos.copy(), np.zeros((self.n_slots, 2), np.uint32),
                     np.zeros((self.n_slots,), np.float32))
-        # ONE batched device->host transfer for the (tokens, keys) pair
-        # instead of two blocking np.asarray round-trips (ISSUE 19)
+        ahead = int(prev is not None)
+        sp.set(ahead=ahead)
+        self._dispatches.append(dispatched.seconds)
+        if not ahead:
+            self._t_started = time.monotonic()  # the device stood idle
+        with self._cv:
+            self._steps += 1
+            self._steps_ahead += ahead
+        return {"streams": streams, "adv": adv, "pos": pos, "tok": tok2,
+                "keys": keys2, "counts": counts, "ahead": ahead}
+
+    def _complete(self, step: dict, sp: span) -> None:
+        """The second half of a table step: ONE batched device->host
+        transfer for the (tokens, keys, counts) triple, then per-slot
+        delivery and the release of finished slots.  A token for a slot
+        whose stream is no longer the one that was dispatched is
+        dropped."""
+        import jax
+
         with span("decode.readback") as readback:
-            tok2, keys2, counts = jax.device_get((tok2, keys2, counts))
-        self._note_experts(sp, counts, 1)
+            tok2, keys2, counts = jax.device_get(
+                (step["tok"], step["keys"], step["counts"]))
         now = time.monotonic()
+        # a step dispatched ahead started when the one before it was read
+        # back, and the step behind this one starts now
+        if step["ahead"]:
+            self._periods.append(now - self._t_started)
+        self._t_started = now
+        sp.set(live=int(step["adv"].sum()))
+        self._note_experts(sp, counts, 1)
+        self._note_kv(sp, step["pos"], step["adv"], 1)
         emitted = 0
         with span("decode.deliver"):
-            for slot, stream in enumerate(self._slots):
-                if stream is None:
+            for slot, stream in enumerate(step["streams"]):
+                if not step["adv"][slot] or self._slots[slot] is not stream:
                     continue
                 if self._feed[slot]:
                     # prompt-feed step (longest-prefix admission): the
@@ -1538,8 +1702,10 @@ class ContinuousBatcher:
                 if (stream.tokens_emitted >= stream.max_new
                         or int(self._pos[slot]) >= self.max_seq):
                     self._release_slot(slot, stream)
-        self._note_block(1, time.monotonic() - t0, readback.seconds, emitted,
-                         now)
+        t_end = time.monotonic()
+        self._note_block(1, t_end - self._t_mark,
+                         self._waited_s + readback.seconds, emitted, now)
+        self._t_mark, self._waited_s = t_end, 0.0
 
     def _note_experts(self, sp: span, counts, steps: int) -> None:
         """What the expert layers counted over the `steps` table steps one
@@ -1750,7 +1916,7 @@ class ContinuousBatcher:
 
     def _block_eligible(self) -> bool:
         """Fused blocks run only while the slot set is stable: K pins to
-        1 (the `_decode_once` path) whenever pending admissions exist,
+        1 (the `_decode_rounds` path) whenever pending admissions exist,
         prompt feeds are mid-flight, or speculative decoding owns the
         step — TTFT, prompt-feed, and replay semantics stay exactly the
         K=1 loop's."""
@@ -1899,15 +2065,17 @@ class ContinuousBatcher:
 
     def _decode_loop(self) -> None:
         """The loop's thread is tiled by three top-level spans: `admit`
-        (one a stream, in `_admit_pending`), `decode` (a table step or a
-        run of fused blocks) and `idle` (waiting for work)."""
+        (one a stream, in `_admit_pending`), `decode` (a K=1 table step
+        of `_decode_rounds`, which come back here with nothing in flight
+        whenever an admission is due, or a run of fused blocks) and `idle`
+        (waiting for work)."""
         while True:
             self._admit_pending()
             if any(s is not None for s in self._slots):
                 if self._block_eligible():
                     self._block_rounds()
                 else:
-                    self._decode_once()
+                    self._decode_rounds()
                 continue
             with self._cv:
                 if self._pending:
@@ -1924,11 +2092,17 @@ class ContinuousBatcher:
         histogram, stream outcomes, and the fresh-compile count.
 
         Host time, by what covers what: `host_overhead_fraction` and
-        `decode_host_seconds_total` cover DECODE STEPS ONLY (each
-        dispatch-to-delivery less its blocked readback, `_note_block`);
-        an admission is in neither.  `admit_seconds_total` is the `admit`
-        spans' sum and `queue_wait_seconds_total` what those streams
-        waited from `submit` to their admission."""
+        `decode_host_seconds_total` cover DECODE STEPS ONLY (the decode
+        rounds' wall time less what the loop spent blocked in
+        `device_get` or waiting for a submission, `_note_block`); an
+        admission is in neither.  With the loop a step ahead that is the
+        host's BUSY share of a step, not time the device waits: it rises
+        as the period falls to the device's step.  `decode_steps_total`
+        counts the K=1 table steps dispatched and
+        `decode_steps_ahead_total` those dispatched while the step before
+        was still in flight.  `admit_seconds_total` is the `admit` spans'
+        sum and `queue_wait_seconds_total` what those streams waited from
+        `submit` to their admission."""
         with self._cv:
             now = time.monotonic()
             recent = sum(c for t, c in self._recent_tokens
@@ -1969,6 +2143,8 @@ class ContinuousBatcher:
                     round(self._host_s / self._wall_s, 4)
                     if self._wall_s > 0 else 0.0),
                 "decode_host_seconds_total": round(self._host_s, 6),
+                "decode_steps_total": self._steps,
+                "decode_steps_ahead_total": self._steps_ahead,
                 "admit_seconds_total": round(self._admit_s, 6),
                 "queue_wait_seconds_total": round(self._queue_wait_s, 6),
                 "decode_block_steps": {

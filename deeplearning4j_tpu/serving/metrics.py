@@ -76,6 +76,8 @@ FAMILIES = {
     "dl4j_serving_decode_host_seconds_total": ("counter", ()),
     "dl4j_serving_admit_seconds_total": ("counter", ()),
     "dl4j_serving_queue_wait_seconds_total": ("counter", ()),
+    "dl4j_serving_decode_steps_total": ("counter", ()),
+    "dl4j_serving_decode_steps_ahead_total": ("counter", ()),
     "dl4j_serving_expert_picks_total": ("counter", ()),
     "dl4j_serving_experts_hit_total": ("counter", ()),
     "dl4j_serving_kv_cells_live_total": ("counter", ()),
@@ -404,6 +406,14 @@ def replica_metrics(stats: dict, page: Optional[PrometheusText] = None,
                   "Seconds admitted streams waited between submit and "
                   "the start of their admission.",
                   gen.get("queue_wait_seconds_total", 0.0), lbl())
+        p.counter("dl4j_serving_decode_steps_total",
+                  "Single-step (K=1) decode table steps dispatched.",
+                  gen.get("decode_steps_total", 0), lbl())
+        p.counter("dl4j_serving_decode_steps_ahead_total",
+                  "Of those, the steps dispatched while the step before "
+                  "was still in flight, so that the device found its next "
+                  "step queued: under a steady table nearly all.",
+                  gen.get("decode_steps_ahead_total", 0), lbl())
         if "expert_picks_total" in gen:     # a model with expert layers
             p.counter("dl4j_serving_expert_picks_total",
                       "Picks of the router that landed on experts this "

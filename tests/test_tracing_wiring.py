@@ -227,12 +227,32 @@ def test_batcher_leaves_one_admit_span_a_stream_with_its_children():
     top = sorted((s for s in record
                   if s.thread == loop_thread and s.parent is None),
                  key=lambda s: s.start_ns)
-    assert {s.name for s in top} == {"admit", "decode", "idle"}
+    # (`idle` only where the loop got to wait before the first submit or
+    # after the last token: a submit runs nothing on the device any more)
+    assert {"admit", "decode"} <= {s.name for s in top} <= {"admit", "decode",
+                                                            "idle"}
     decodes = [s for s in top if s.name == "decode"]
+    order = ["decode.readback", "decode.deliver", "decode.wait",
+             "decode.dispatch"]
     for d in decodes:
         kids = [s.name for s in record if s.parent == d.sid]
-        assert kids == ["decode.dispatch", "decode.readback", "decode.deliver"]
-        assert d.attrs["k"] == 1 and 1 <= d.attrs["live"] <= 2
+        assert set(kids) <= set(order) and kids.count("decode.dispatch") <= 1
+        if "decode.dispatch" in kids:
+            # a step ahead completes the step before the one in flight,
+            # waits while a slot stands free, then dispatches; a span that
+            # found nothing in flight dispatches and, where it may not run
+            # ahead, completes its own step
+            if kids[0] == "decode.readback":
+                assert d.attrs["ahead"] == 1 and kids[-1] == "decode.dispatch"
+            if kids[-1] == "decode.deliver":
+                assert d.attrs["ahead"] == 0 and kids[0] == "decode.dispatch"
+            assert 1 <= d.attrs["live"] <= 2
+        assert kids.count("decode.readback") == kids.count("decode.deliver")
+        assert d.attrs["k"] == 1
+    dispatched = [d for d in decodes if "ahead" in d.attrs]
+    assert stats["decode_steps_total"] == len(dispatched)
+    assert stats["decode_steps_ahead_total"] == sum(
+        d.attrs["ahead"] for d in dispatched) > 0
     # decode steps only: the admissions are in no part of this counter
     assert stats["decode_host_seconds_total"] < \
         sum(d.end_ns - d.start_ns for d in decodes) / 1e9
