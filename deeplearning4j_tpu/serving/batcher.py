@@ -91,6 +91,13 @@ DECODE_BLOCK_STEPS_BOUNDS = (1, 2, 4, 8, 16)
 #: when attached, holds evicted entries too)
 PREFIX_CACHE_ENTRIES = 32
 
+#: why the device had nothing queued, a closed set (`ContinuousBatcher.
+#: _charge`): the next program was an admission's; a decode step launched
+#: from the host's arrays after an admission or an idle wait; a step of a
+#: loop that reads every step back before the next (`_runs_ahead` false, a
+#: fused block, a speculative round); no stream was live and none pending
+STARVED_CAUSES = ("admit", "restart", "sync", "empty")
+
 
 class ServerOverloaded(RuntimeError):
     """The gateway's pending queue is full — fail fast (HTTP 503)."""
@@ -917,12 +924,22 @@ class ContinuousBatcher:
         self._ramp = 1
         # the K=1 rounds' clock (decode-loop thread only): what the last
         # eight steps ahead took from readback to readback and their
-        # dispatches on the host, when the step now on the device
-        # started, and the wall and blocked time since the last completion
+        # dispatches on the host, and the wall and blocked time since the
+        # last completion
         self._periods: Deque[float] = deque(maxlen=8)
         self._dispatches: Deque[float] = deque(maxlen=8)
-        self._t_started = self._t_mark = 0.0
+        self._t_mark = 0.0
         self._waited_s = 0.0
+        # the account of what is on the device (decode-loop thread only,
+        # the one thread that launches programs and reads them back): how
+        # many it has launched and not yet seen finish, and the instant
+        # (`time.monotonic_ns`) the device last turned: ran dry, or took up
+        # the program it is running.  `_starved_ns` adds up, by cause, the
+        # intervals it stood dry; `stats()` reads it without a lock (one
+        # writer, whole integers, keys that never change)
+        self._in_flight = 0
+        self._t_turn = time.monotonic_ns()
+        self._starved_ns = dict.fromkeys(STARVED_CAUSES, 0)
         # -- stats (guarded by _cv's lock) ---------------------------------
         self._t_start = time.monotonic()
         self._tokens_total = 0
@@ -1047,6 +1064,43 @@ class ContinuousBatcher:
     def _free_slots(self) -> List[int]:
         return [i for i, s in enumerate(self._slots) if s is None]
 
+    # -- the account of what is on the device -------------------------------
+    def _launched(self, sp: span, cause: str) -> None:
+        """Called right after the call of a compiled program returns, inside
+        the loop's open top-level span `sp`: one more program is on the
+        device.  If none was, the device has had nothing queued since
+        `_t_turn`, and the interval is charged to `cause` (`_charge`)."""
+        if not self._in_flight:
+            self._charge(sp, cause)
+        self._in_flight += 1
+
+    def _landed(self, now_ns: int) -> None:
+        """Called right after a host read has seen a launched program's
+        outputs, at `now_ns`: that program is off the device, which at this
+        instant either takes up the next one queued or runs dry."""
+        self._in_flight -= 1
+        self._t_turn = now_ns
+
+    def _charge(self, sp: span, cause: str) -> None:
+        """The device stood dry from `_t_turn` until now, for `cause` (one
+        of `STARVED_CAUSES`): `sp` gets the interval (`starved_at`, a list
+        of `(from_ns, until_ns)` on the record's clock), its length added to
+        `starved_ns`, and `starved_cause`; the cause's counter of `stats()`
+        the same nanoseconds.  The device turns now: it takes up what was
+        just launched, or (`empty`) goes on standing dry from here.  Called
+        alone, with nothing in flight, for a program that no host read ever
+        observes (a row's write after an admission's read, the draft's
+        prefill): charged to here and taken as landed at once, so that the
+        count never waits for a read that does not come; its own device
+        time then lies inside the next interval charged, small beside it."""
+        now = time.monotonic_ns()
+        dry = now - self._t_turn
+        sp.attrs.setdefault("starved_at", []).append((self._t_turn, now))
+        sp.set(starved_ns=sp.attrs.get("starved_ns", 0) + dry,
+               starved_cause=cause)
+        self._starved_ns[cause] += dry
+        self._t_turn = now
+
     def _admit_one(self, slot: int, stream: GenerationStream) -> None:
         """Admit `stream` into `slot`.  A dense admission is ONE call of
         one compiled program (`InferCache.prefill_slot`): it takes the
@@ -1107,29 +1161,41 @@ class ContinuousBatcher:
             prompt[0, :n] = stream.prompt
             length = np.asarray([n], np.int32)
             with span("admit.prefill"):     # to the program's host read
-                if self.paged:  # B=1 programs; the pages are written below
-                    fill, fill_logp = ic.prefill, ic.prefill_logp
-                    into = (ic.init_decode_state(conf, 1, self.max_seq),)
-                else:           # one program writes the row into the table
-                    fill, fill_logp = ic.prefill_slot, ic.prefill_logp_slot
-                    into = (self._state, slot)
-                if self.prefix_cache_enabled:
-                    logp, *kept, state = fill_logp(conf, params, *into,
-                                                   prompt, length)
-                    logp = np.asarray(logp, np.float32)[0]
-                    # the filled row: the paged program's state itself,
-                    # handed back beside the table by the slot program
-                    kept = kept[0] if kept else state
-                else:
-                    t0, keys1, state = fill(
-                        conf, params, *into, prompt, length, stream.key[None],
-                        np.asarray([stream.temperature], np.float32))
-                    t0, keys1 = jax.device_get((t0, keys1))
-                    tok0, key1 = int(t0[0]), keys1[0]
-                if self.paged:
-                    row = state
-                else:
-                    self._state = state
+                with span("admit.launch"):  # until the program's call returns
+                    if self.paged:  # B=1 programs; the pages are written below
+                        fill, fill_logp = ic.prefill, ic.prefill_logp
+                        into = (ic.init_decode_state(conf, 1, self.max_seq),)
+                    else:       # one program writes the row into the table
+                        fill, fill_logp = ic.prefill_slot, ic.prefill_logp_slot
+                        into = (self._state, slot)
+                    if self.prefix_cache_enabled:
+                        logp, *kept, state = fill_logp(conf, params, *into,
+                                                       prompt, length)
+                        # the filled row: the paged program's state itself,
+                        # handed back beside the table by the slot program
+                        kept = kept[0] if kept else state
+                    else:
+                        t0, keys1, state = fill(
+                            conf, params, *into, prompt, length,
+                            stream.key[None],
+                            np.asarray([stream.temperature], np.float32))
+                    self._launched(sp, "admit")
+                    if self.paged:
+                        row = state
+                    else:
+                        self._state = state
+                # blocked on the device until the first token (or the
+                # prompt's last logp) is on the host: with nothing else in
+                # flight, the admission program's device time and the transfer
+                with span("admit.readback"):
+                    try:
+                        if self.prefix_cache_enabled:
+                            logp = np.asarray(logp, np.float32)[0]
+                        else:
+                            t0, keys1 = jax.device_get((t0, keys1))
+                            tok0, key1 = int(t0[0]), keys1[0]
+                    finally:    # read or failed, it is off the account
+                        self._landed(time.monotonic_ns())
             if self.prefix_cache_enabled:
                 self._prefix_store(stream.prompt, logp, kept)
                 tok0, key1 = _host_sample(logp, stream.key,
@@ -1142,10 +1208,12 @@ class ContinuousBatcher:
         if row is not None:
             with span("admit.scatter"):
                 self._scatter_row(slot, row, pages)
+                self._charge(sp, "admit")   # launched, and never read back
         if self.draft_net is not None:
             # the draft consumes exactly the m tokens the target row has
             # consumed, so feed rounds advance both in lockstep
             self._draft_admit(slot, stream.prompt[:m])
+            self._charge(sp, "admit")       # so is the draft's prefill
         with span("admit.deliver"):
             self._admit_deliver(slot, stream, n, m, tok0, key1)
 
@@ -1551,7 +1619,8 @@ class ContinuousBatcher:
     def _wait_for_need(self) -> None:
         """With a step in flight and a slot standing free, wait on `_cv`
         (which `submit` notifies) for a submission, until the device is
-        about to need the next step: the running step's start plus the
+        about to need the next step: the running step's start (`_t_turn`:
+        the account's stamp of when the device took it up) plus the
         loop's recent readback-to-readback period, less its recent
         `decode.dispatch` time and a margin of as much again.  Both are
         the loop's own observations; before it has any, and with no slot
@@ -1559,7 +1628,7 @@ class ContinuousBatcher:
         callers take the GIL for the tokens just delivered meanwhile."""
         if not (self._periods and self._admissible()):
             return
-        need = (self._t_started + statistics.median(self._periods)
+        need = (self._t_turn / 1e9 + statistics.median(self._periods)
                 - 2.0 * statistics.median(self._dispatches))
         if need <= time.monotonic():
             return
@@ -1619,7 +1688,7 @@ class ContinuousBatcher:
                 and all(not self._feed[s] for s in active)
                 and all(int(pos[s]) + self.spec_k <= self.max_seq
                         for s in active)):
-            self._spec_once()
+            self._spec_once(sp)
             return None
         ic = self.net.infer_cache
         with span("decode.dispatch") as dispatched:
@@ -1636,11 +1705,14 @@ class ContinuousBatcher:
             tok2, keys2, *counts, self._state = ic.decode(
                 self.net.conf, self.net.params, self._state, tok, pos, keys,
                 self._temps.copy(), page_table=self._pages())
+            # dry only with nothing in flight: the first step of a run
+            self._launched(sp, "restart" if self._runs_ahead() else "sync")
             if self.draft_net is not None:
                 # non-spec rounds (feeds pending, or a slot near the table
                 # edge) still advance the draft's carries over the same
                 # token, so the draft stays in lockstep with what each
-                # slot has consumed
+                # slot has consumed (behind the step just launched, and
+                # never read: the account leaves it out)
                 dn = self.draft_net
                 _, _, self._draft_state = dn.infer_cache.decode(
                     dn.conf, dn.params, self._draft_state, self._tok.copy(),
@@ -1649,8 +1721,6 @@ class ContinuousBatcher:
         ahead = int(prev is not None)
         sp.set(ahead=ahead)
         self._dispatches.append(dispatched.seconds)
-        if not ahead:
-            self._t_started = time.monotonic()  # the device stood idle
         with self._cv:
             self._steps += 1
             self._steps_ahead += ahead
@@ -1668,12 +1738,13 @@ class ContinuousBatcher:
         with span("decode.readback") as readback:
             tok2, keys2, counts = jax.device_get(
                 (step["tok"], step["keys"], step["counts"]))
-        now = time.monotonic()
+        now_ns = time.monotonic_ns()
+        now = now_ns / 1e9      # `time.monotonic()`'s clock
         # a step dispatched ahead started when the one before it was read
-        # back, and the step behind this one starts now
+        # back (`_t_turn`), and the step behind this one starts now
         if step["ahead"]:
-            self._periods.append(now - self._t_started)
-        self._t_started = now
+            self._periods.append((now_ns - self._t_turn) / 1e9)
+        self._landed(now_ns)
         sp.set(live=int(step["adv"].sum()))
         self._note_experts(sp, counts, 1)
         self._note_kv(sp, step["pos"], step["adv"], 1)
@@ -1712,16 +1783,13 @@ class ContinuousBatcher:
         dispatch made, `[[picks that landed on held experts, distinct held
         experts hit]]` summed over the layers (and empty for a stack
         without them): added to the open `decode` span's `picks_here`,
-        `experts_hit` and `steps`, and to the totals of `stats()`.  The span
-        also says how many of the layers took the batched form of their
-        product, `experts_batched_layers`."""
+        `experts_hit` and `steps`, and to the totals of `stats()`."""
         if not counts:
             return
         picks, hit = (int(n) for n in counts[0])
         sp.set(picks_here=sp.attrs.get("picks_here", 0) + picks,
                experts_hit=sp.attrs.get("experts_hit", 0) + hit,
-               steps=sp.attrs.get("steps", 0) + steps,
-               experts_batched_layers=self._experts_batched)
+               steps=sp.attrs.get("steps", 0) + steps)
         with self._cv:
             self._expert_picks += picks
             self._experts_hit += hit
@@ -1806,7 +1874,7 @@ class ContinuousBatcher:
             else:
                 h["inf"] += 1
 
-    def _spec_once(self) -> None:
+    def _spec_once(self, sp: span) -> None:
         """One speculative round: the draft proposes spec_k - 1 tokens
         per slot, ONE verify program chain-samples spec_k target tokens
         against them, and each slot emits its agreeing prefix (>= 1
@@ -1843,8 +1911,10 @@ class ContinuousBatcher:
             nxt, _, out = dn.infer_cache.decode(
                 dn.conf, dn.params, feed, cur,
                 self._pos + np.int32(i - 1), dkeys, dtemps)
+            self._launched(sp, "sync")
             retained.append(out)
             cur = np.asarray(nxt)
+            self._landed(time.monotonic_ns())
             if i < k:
                 toks[:, i] = cur
         if self.paged:
@@ -1855,9 +1925,11 @@ class ContinuousBatcher:
             self.net.conf, self.net.params, self._state, toks,
             self._pos.copy(), self._keys.copy(), self._temps.copy(),
             page_table=self._pages())
-        g = np.asarray(g)
-        keys_all = np.asarray(keys_all)
-        now = time.monotonic()
+        self._launched(sp, "sync")
+        g, keys_all = jax.device_get((g, keys_all))
+        now_ns = time.monotonic_ns()
+        self._landed(now_ns)
+        now = now_ns / 1e9      # `time.monotonic()`'s clock
         e_idx = np.zeros((nb,), np.int32)
         emitted = 0
         accepted: List[int] = []
@@ -1970,7 +2042,7 @@ class ContinuousBatcher:
                 blk = None
                 if int(rem.max(initial=0)) > 0 and not self._has_pending():
                     blk = self._dispatch_block(ic, streams, tok, keys, pos,
-                                               rem)
+                                               rem, sp)
                     if blk is not None:
                         tok, keys = blk["tok"], blk["keys"]
                 if inflight is not None:
@@ -1979,7 +2051,7 @@ class ContinuousBatcher:
                 if blk is None:
                     return
 
-    def _dispatch_block(self, ic, streams, tok, keys, pos, rem):
+    def _dispatch_block(self, ic, streams, tok, keys, pos, rem, sp: span):
         """Dispatch ONE fused K-step block (no sync): fire the per-slot
         fault points for every scheduled position (a raise ends THAT
         stream only, before its rows are dispatched), allocate pages for
@@ -2012,6 +2084,7 @@ class ContinuousBatcher:
                 self.net.conf, self.net.params, self._state, tok,
                 pos.copy(), keys, self._temps.copy(), rem.copy(), k,
                 page_table=self._pages())
+            self._launched(sp, "sync")
         adv = np.minimum(rem, k).astype(np.int32)
         pos_before = pos.copy()
         pos += adv
@@ -2032,9 +2105,11 @@ class ContinuousBatcher:
         with span("decode.readback") as readback:
             toks, tok_last, keys_last, counts = jax.device_get(
                 (blk["toks"], blk["tok"], blk["keys"], blk["counts"]))
+        now_ns = time.monotonic_ns()
+        self._landed(now_ns)
+        now = now_ns / 1e9      # `time.monotonic()`'s clock
         self._note_experts(sp, counts, blk["k"])
         self._note_kv(sp, blk["pos_before"], blk["adv"], blk["k"])
-        now = time.monotonic()
         emitted = 0
         with span("decode.deliver"):
             for s, stream in enumerate(blk["streams"]):
@@ -2069,6 +2144,7 @@ class ContinuousBatcher:
         of `_decode_rounds`, which come back here with nothing in flight
         whenever an admission is due, or a run of fused blocks) and `idle`
         (waiting for work)."""
+        self._t_turn = time.monotonic_ns()      # the account opens dry
         while True:
             self._admit_pending()
             if any(s is not None for s in self._slots):
@@ -2082,8 +2158,12 @@ class ContinuousBatcher:
                     continue
                 if self._stop:
                     return
-                with span("idle"):
+                # nothing to launch: the device's dry time up to the end of
+                # this wait is the traffic's, and whoever ends the wait is
+                # charged from there
+                with span("idle") as sp:
                     self._cv.wait(timeout=0.5)
+                    self._charge(sp, "empty")
 
     # -- observability -------------------------------------------------------
     def stats(self) -> dict:
@@ -2100,9 +2180,19 @@ class ContinuousBatcher:
         as the period falls to the device's step.  `decode_steps_total`
         counts the K=1 table steps dispatched and
         `decode_steps_ahead_total` those dispatched while the step before
-        was still in flight.  `admit_seconds_total` is the `admit` spans'
-        sum and `queue_wait_seconds_total` what those streams waited from
-        `submit` to their admission."""
+        was still in flight.  **What the device WAITS for the host is
+        `device_starved_seconds_total`**, `{cause: seconds}` over
+        `STARVED_CAUSES`: the intervals in which the loop had nothing on
+        the device, each measured from the host read that saw the last
+        program finish to the return of the next program's call, and named
+        by what the loop was doing (`admit`, `restart`, `sync`; `empty` is
+        the traffic's: nothing live, nothing pending).  The two are not
+        each other's complement: busy time behind a step in flight costs
+        nothing, starved time is lost.  The same intervals lie on the
+        `admit`, `decode` and `idle` spans (`starved_ns`, `starved_cause`,
+        `starved_at`).  `admit_seconds_total` is the `admit` spans' sum and
+        `queue_wait_seconds_total` what those streams waited from `submit`
+        to their admission."""
         with self._cv:
             now = time.monotonic()
             recent = sum(c for t, c in self._recent_tokens
@@ -2145,6 +2235,9 @@ class ContinuousBatcher:
                 "decode_host_seconds_total": round(self._host_s, 6),
                 "decode_steps_total": self._steps,
                 "decode_steps_ahead_total": self._steps_ahead,
+                "device_starved_seconds_total": {
+                    cause: round(ns / 1e9, 6)
+                    for cause, ns in self._starved_ns.items()},
                 "admit_seconds_total": round(self._admit_s, 6),
                 "queue_wait_seconds_total": round(self._queue_wait_s, 6),
                 "decode_block_steps": {

@@ -78,6 +78,7 @@ FAMILIES = {
     "dl4j_serving_queue_wait_seconds_total": ("counter", ()),
     "dl4j_serving_decode_steps_total": ("counter", ()),
     "dl4j_serving_decode_steps_ahead_total": ("counter", ()),
+    "dl4j_serving_device_starved_seconds_total": ("counter", ("cause",)),
     "dl4j_serving_expert_picks_total": ("counter", ()),
     "dl4j_serving_experts_hit_total": ("counter", ()),
     "dl4j_serving_kv_cells_live_total": ("counter", ()),
@@ -414,6 +415,19 @@ def replica_metrics(stats: dict, page: Optional[PrometheusText] = None,
                   "was still in flight, so that the device found its next "
                   "step queued: under a steady table nearly all.",
                   gen.get("decode_steps_ahead_total", 0), lbl())
+        for cause, seconds in gen.get("device_starved_seconds_total",
+                                      {}).items():
+            p.counter("dl4j_serving_device_starved_seconds_total",
+                      "Seconds the decode loop had no program on the "
+                      "device, from the host read that saw the last one "
+                      "finish to the return of the next one's call, by what "
+                      "the loop was doing: admit (the next program was an "
+                      "admission's), restart (the first decode step after "
+                      "one, launched from the host's arrays), sync (a step "
+                      "of a loop that reads every step back before the "
+                      "next), empty (no stream live or pending: the "
+                      "traffic's, not the host's).",
+                      seconds, lbl(cause=cause))
         if "expert_picks_total" in gen:     # a model with expert layers
             p.counter("dl4j_serving_expert_picks_total",
                       "Picks of the router that landed on experts this "

@@ -1,4 +1,4 @@
-"""The program's one tracing module: spans, names and counters.
+"""The program's one tracing module: spans and names.
 
 The reference's observability is wall-clock job timing
 (`WorkerActor.java:199-203` "Job took X ms"), iteration listeners
@@ -6,8 +6,10 @@ The reference's observability is wall-clock job timing
 (`StateTracker.increment/count`, `StateTracker.java:54-56`), and the YARN
 `metricsReport(map<string,long>)` RPC (`IterativeReduceService.java:28`).
 
-Here the same surface is three things, and every module of the package
-that times or names anything does it through them:
+Here that surface is two things, and every module of the package that
+times or names anything does it through them (counters live with what
+they count: `ContinuousBatcher.stats()` and `MicroBatcher.stats()`, which
+`serving/metrics.py` exports, and the trainer's own trackers):
 
   spans     `span(name, rid=None, **attrs)` brackets host work.  It opens a
             `jax.profiler.TraceAnnotation` named `dl4j:<name>`, so that
@@ -22,7 +24,6 @@ that times or names anything does it through them:
             are `jax.named_scope`, which puts the layer into the metadata
             of every device operation.  Names and scopes are metadata:
             they join no cache key and change no equation.
-  counters  `MetricsRegistry` / `METRICS` (StateTracker.increment parity).
 
 `Tracer.start/stop/trace` is the operator's way to open a profiler session.
 """
@@ -224,36 +225,3 @@ class Tracer:
             yield self
         finally:
             self.stop()
-
-
-# -- counters ----------------------------------------------------------------
-class MetricsRegistry:
-    """Named counters + gauges (StateTracker.increment / YARN
-    metricsReport parity), thread-safe."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._counters: Dict[str, float] = {}
-        self._gauges: Dict[str, float] = {}
-
-    def increment(self, name: str, by: float = 1.0) -> None:
-        with self._lock:
-            self._counters[name] = self._counters.get(name, 0.0) + by
-
-    def count(self, name: str) -> float:
-        with self._lock:
-            return self._counters.get(name, 0.0)
-
-    def gauge(self, name: str, value: float) -> None:
-        with self._lock:
-            self._gauges[name] = value
-
-    def report(self) -> Dict[str, float]:
-        """metricsReport(map<string,long>) parity — one flat dict."""
-        with self._lock:
-            out = dict(self._counters)
-            out.update(self._gauges)
-            return out
-
-
-METRICS = MetricsRegistry()  # process-global default registry

@@ -143,8 +143,8 @@ def test_decode_spans_carry_the_experts_counts(f32, served):
         assert s.attrs["k"] == 1 and s.attrs["steps"] == 1 and "live" in s.attrs
         assert 0 < s.attrs["experts_hit"] <= min(s.attrs["picks_here"], held * layers)
         assert s.attrs["picks_here"] <= 2 * f32.sizes["top_k"] * layers
-        assert s.attrs["experts_batched_layers"] == 0       # 2 rows: the sorted form
-    assert stats["experts_batched_layers"] == 0
+        assert "experts_batched_layers" not in s.attrs  # static: `stats()` has it
+    assert stats["experts_batched_layers"] == 0     # 2 rows: the sorted form
     assert stats["expert_picks_total"] == sum(s.attrs["picks_here"] for s in steps)
     assert stats["experts_hit_total"] == sum(s.attrs["experts_hit"] for s in steps)
 

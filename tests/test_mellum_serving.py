@@ -146,12 +146,11 @@ def test_enough_slots_take_the_batched_form_and_serve_the_same_tokens(f32, serve
         assert len(got) == 10
         assert_greedy(f32, prompt, got)
     steps = [s for s in profiling.spans() if s.name == "decode" and "picks_here" in s.attrs]
-    assert steps and all(s.attrs["experts_batched_layers"] == layers for s in steps)
+    # static a program: `stats()` says it once, no span repeats it a step
+    assert steps and not any("experts_batched_layers" in s.attrs for s in steps)
     assert stats["experts_batched_layers"] == layers
     # the two slots of `served` stay with the sorted form
     assert served[2]["experts_batched_layers"] == 0
-    assert all(s.attrs["experts_batched_layers"] == 0 for s in served[3]
-               if s.name == "decode" and "picks_here" in s.attrs)
 
 
 # ---------------------------------------------------------------- refusals

@@ -1,4 +1,4 @@
-"""The tracing module: spans and their record, names, counters."""
+"""The tracing module: spans and their record, names."""
 
 import collections
 import threading
@@ -9,8 +9,7 @@ import jax.numpy as jnp
 import pytest
 
 from deeplearning4j_tpu.utils import profiling
-from deeplearning4j_tpu.utils.profiling import (MetricsRegistry, Tracer,
-                                                self_time, span)
+from deeplearning4j_tpu.utils.profiling import Tracer, self_time, span
 
 
 @pytest.fixture(autouse=True)
@@ -18,16 +17,6 @@ def fresh_record():
     profiling.clear()
     yield
     profiling.clear()
-
-
-def test_metrics_registry_report():
-    r = MetricsRegistry()
-    r.increment("jobs")
-    r.increment("jobs", 2)
-    r.gauge("loss", 0.5)
-    rep = r.report()
-    assert rep["jobs"] == 3.0
-    assert rep["loss"] == 0.5
 
 
 def test_span_is_recorded_with_its_name_rid_and_attrs():
