@@ -56,6 +56,7 @@ class LayerType(str, enum.Enum):
     GQA = "gqa"                    # grouped K/V heads, full or windowed
     SWIGLU = "swiglu"              # gated (SiLU) FFN under an RMSNorm
     MOE = "moe"                    # routed experts + one shared expert
+    SHORT_CONV = "shortconv"       # gated short convolution (LFM2's mixer)
 
     def __str__(self) -> str:
         return self.value
@@ -208,6 +209,16 @@ class GQASpec:
 
 
 @dataclass(frozen=True)
+class ShortConvSpec:
+    """LayerType.SHORT_CONV: a gated short convolution in place of
+    attention, `[B; C; x] = W_in u`, `o_t = (C_t * sum_j w_j (B * x)_{t-K+1+j})
+    W_out` with `kernel` K taps a channel, no bias and no activation."""
+
+    kernel: int = 3
+    eps: float = 1e-6
+
+
+@dataclass(frozen=True)
 class SwiGLUSpec:
     """LayerType.SWIGLU: `down(silu(gate x) * up x)` of width `hidden`."""
 
@@ -253,7 +264,7 @@ class HeadSpec:
 
 
 LAYER_SPECS = {c.__name__: c for c in (KDASpec, MLASpec, GQASpec, SwiGLUSpec,
-                                       MoESpec, HeadSpec)}
+                                       MoESpec, HeadSpec, ShortConvSpec)}
 
 
 def _spec_dict(spec) -> Dict[str, Any]:

@@ -71,15 +71,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf import LayerType, MultiLayerConfiguration
-from deeplearning4j_tpu.nn.layers import get_layer
+from deeplearning4j_tpu.nn.layers import GENERATIVE_HIDDEN, get_layer
 from deeplearning4j_tpu.nn.layers.output import OutputLayer
 from deeplearning4j_tpu.utils.profiling import layer_scope, scope
-
-#: hidden layer types the decode path knows how to step one token at a time
-GENERATIVE_HIDDEN = (LayerType.LSTM, LayerType.GRAVES_LSTM,
-                     LayerType.ATTENTION, LayerType.TRANSFORMER_FFN,
-                     LayerType.KDA, LayerType.MLA, LayerType.GQA,
-                     LayerType.SWIGLU, LayerType.MOE)
 
 #: token emitted by `decode_block` for scan steps a row sat frozen
 #: (its `rem` budget exhausted mid-block) — never a valid token id
@@ -176,6 +170,28 @@ def kv_cells(conf: MultiLayerConfiguration, max_seq: int) -> list:
         if hasattr(impl, "kv_cells"):
             out += zip(np.atleast_1d(impl.kv_cells(c, max_seq)).tolist(),
                        np.atleast_1d(impl.kv_cells_read(c, max_seq)).tolist())
+    return out
+
+
+def state_bytes_read(conf: MultiLayerConfiguration, max_seq: int):
+    """What a decode step reads of one row's state, in bytes as the state
+    lies in memory, by kind: `table`, of every layer that counts its state
+    in cells, the cells its step reads times a cell's bytes (`kv_cells_read`
+    x `kv_cell_bytes`), and `carry`, of every layer whose state advances
+    with each token (`CARRY`), the whole of it.  None where a layer keeps a
+    state that says neither: its read cannot be counted."""
+    out = {"table": 0, "carry": 0}
+    for _, c, impl in _hidden(conf):
+        if hasattr(impl, "kv_cell_bytes"):
+            out["table"] += int(np.dot(
+                np.atleast_1d(impl.kv_cells_read(c, max_seq)),
+                np.atleast_1d(impl.kv_cell_bytes(c, max_seq))))
+            continue
+        row = jax.tree_util.tree_leaves(
+            jax.eval_shape(lambda: impl.init_state(c, 1, max_seq)))
+        if row and not impl.CARRY:
+            return None
+        out["carry"] += sum(a.size * a.dtype.itemsize for a in row)
     return out
 
 

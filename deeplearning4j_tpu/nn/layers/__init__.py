@@ -41,16 +41,20 @@ What only some types can do, a class declares by having it:
             its decode step picks so many of a row's cached positions and
             attends to those alone (0: to all): the batcher counts the
             positions cached beside those picked
+    kv_cell_bytes(conf, max_seq)                -> int, or one a table
+            beside `kv_cells`: the bytes of one cell as the state lays it
+            out in memory
 A type with a state but without `init_paged_state` and `verify_chunk` lives
 in the dense slot table only (`nn.decode.dense_only`).  A type with no state
 gets all of it from `base.StatelessDecode`.  A new kind of state is a layer
-file, its `LayerType` in `nn/conf.py` and a line of the registry below.
+file, its `LayerType` in `nn/conf.py`, a line of the registry below and its
+type in `GENERATIVE_HIDDEN`.
 """
 
 from deeplearning4j_tpu.nn.conf import LayerType
 from deeplearning4j_tpu.nn.layers import (base, output, autoencoder, rbm, lstm,
                                           conv, attention, experts, gqa, kda,
-                                          mla)
+                                          mla, shortconv)
 
 _REGISTRY = {
     LayerType.DENSE: base.DenseLayer,
@@ -73,7 +77,15 @@ _REGISTRY = {
     LayerType.GQA: gqa.GQALayer,
     LayerType.SWIGLU: experts.SwiGLULayer,
     LayerType.MOE: experts.MoELayer,
+    LayerType.SHORT_CONV: shortconv.ShortConvLayer,
 }
+
+#: the hidden layer types a generative stack may hold: each class keeps the
+#: protocol above, which `nn/decode.py` walks
+GENERATIVE_HIDDEN = (LayerType.LSTM, LayerType.GRAVES_LSTM,
+                     LayerType.ATTENTION, LayerType.TRANSFORMER_FFN,
+                     LayerType.KDA, LayerType.MLA, LayerType.GQA,
+                     LayerType.SWIGLU, LayerType.MOE, LayerType.SHORT_CONV)
 
 
 def get_layer(layer_type):

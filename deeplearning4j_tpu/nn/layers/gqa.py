@@ -88,6 +88,13 @@ class GQALayer:
         return GQALayer.kv_cells(conf, max_seq)
 
     @staticmethod
+    def kv_cell_bytes(conf, max_seq: int) -> int:
+        """Bytes of one cell as the state lays it out: K and V of every K/V
+        head."""
+        s = conf.layer_spec
+        return 2 * s.n_kv_heads * s.head_dim * jnp.dtype(compute_dtype(conf)).itemsize
+
+    @staticmethod
     def init_state(conf, batch: int, max_seq: int) -> dict:
         s, cd = conf.layer_spec, compute_dtype(conf)
         shape = (batch, s.n_kv_heads, GQALayer.kv_cells(conf, max_seq), s.head_dim)

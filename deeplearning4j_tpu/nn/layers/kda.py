@@ -37,8 +37,9 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.layers.base import compute_dtype
-from deeplearning4j_tpu.nn.layers.rms import (F32, initializer, l2norm, mm,
-                                              pre_norm, rms_norm)
+from deeplearning4j_tpu.nn.layers.rms import (F32, causal_conv, conv_tail,
+                                              initializer, l2norm, mm, pre_norm,
+                                              rms_norm)
 from deeplearning4j_tpu.utils.profiling import scope
 
 CHUNK = 64
@@ -119,18 +120,12 @@ class KDALayer:
         """x [B, T, n], zero-padded past each row's `length` [B]; `state`
         the rows' state before the prompt.  Returns (hidden [B, T, n],
         state after token `length - 1`)."""
-        spec = conf.layer_spec
-        kk = spec.conv_kernel
         t = x.shape[1]
         qkv, g, beta, gate = KDALayer._project(params, conf, x)
         with scope("conv"):
-            seen = jnp.concatenate([state["conv"], qkv], axis=1)   # [B, K-1+T, 3c]
-            w = params["conv"].astype(F32)
-            y = jax.nn.silu(sum(seen[:, j: j + t].astype(F32) * w[j]
-                                for j in range(kk)))
-            # the inputs of tokens length-K+1 .. length-1 sit at seen[length:]
-            conv = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
-                row, n, kk - 1, axis=0))(seen, length)
+            y, seen = causal_conv(state["conv"], qkv, params["conv"])
+            y = jax.nn.silu(y)
+            conv = conv_tail(seen, conf.layer_spec.conv_kernel, length)
         q, k, v = KDALayer._heads(conf, y)
         real = (jnp.arange(t)[None, :] < length[:, None])
         g = jnp.where(real[..., None, None], g, 0.0)
@@ -145,9 +140,8 @@ class KDALayer:
         """One token a row: x [B, n] -> (hidden [B, n], advanced state)."""
         qkv, g, beta, gate = KDALayer._project(params, conf, x)
         with scope("conv"):
-            seen = jnp.concatenate([state["conv"], qkv[:, None]], axis=1)
-            w = params["conv"].astype(F32)
-            y = jax.nn.silu(jnp.sum(seen.astype(F32) * w[None], axis=1))
+            y, seen = causal_conv(state["conv"], qkv, params["conv"])
+            y = jax.nn.silu(y)
         q, k, v = KDALayer._heads(conf, y)
         with scope("state_update"):
             s = jnp.exp(g)[..., None] * state["S"]
@@ -156,7 +150,7 @@ class KDALayer:
         with scope("state_read"):
             o = jnp.sum(q[..., None] * s, axis=-2)
         return (KDALayer._output(params, conf, x, o, gate),
-                {"S": s, "conv": seen[:, 1:]})
+                {"S": s, "conv": conv_tail(seen, conf.layer_spec.conv_kernel)})
 
     @staticmethod
     def forward(params, conf, x, key=None, training=False):

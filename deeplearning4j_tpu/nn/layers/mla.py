@@ -218,6 +218,18 @@ class MLALayer:
         return (max_seq, max_seq) if k else MLALayer.kv_cells(conf, max_seq)
 
     @staticmethod
+    def kv_cell_bytes(conf, max_seq: int) -> tuple:
+        """Of each table of `kv_cells`, the bytes of one cell as the state
+        lays it out: a latent and a rotary key (apart, or side by side in
+        whole 128s), and beside the picked latents an index key."""
+        s = conf.layer_spec
+        item = jnp.dtype(compute_dtype(conf)).itemsize
+        if not (s.window or s.index_topk):
+            return ((s.kv_lora_rank + s.qk_rope_head_dim) * item,)
+        cell = MLALayer._cell_width(conf) * item
+        return (cell, s.index_head_dim * item) if MLALayer.selects(conf, max_seq) else (cell,)
+
+    @staticmethod
     def init_state(conf, batch: int, max_seq: int) -> dict:
         s, cd = conf.layer_spec, compute_dtype(conf)
         cells = MLALayer._cells(conf, max_seq)

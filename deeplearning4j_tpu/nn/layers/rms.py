@@ -1,6 +1,7 @@
 """What the RMSNorm-family layer types share (`kda`, `mla`, `swiglu`, `moe`,
-and the OUTPUT layer under a `HeadSpec`): the norms, rotary positions, and
-the one matrix product they all take.
+`shortconv`, and the OUTPUT layer under a `HeadSpec`): the norms, rotary
+positions, the one matrix product they all take, and the causal convolution
+with a carried tail of `kda` and `shortconv`.
 
 Their precision contract: parameters are kept in `conf.dtype`, matrix
 products take operands in `compute_dtype(conf)` and accumulate in float32,
@@ -111,6 +112,34 @@ def rope_first(x, positions, theta: float, n: int):
     they are."""
     return jnp.concatenate([rope(x[..., :n], positions, theta), x[..., n:]],
                            axis=-1)
+
+
+def causal_conv(tail, x, w):
+    """Depthwise causal convolution of K taps `w` [K, c] whose inputs before
+    the first of `x` are the carried `tail` [B, K-1, c]: y_t = sum_j w_j *
+    x_{t-K+1+j}, summed in float32, over a prompt x [B, T, c] or one token
+    x [B, c].  Returns (y [B, T, c] or [B, c], `seen`: the tail and x one
+    after the other, of which `conv_tail` takes the next tail)."""
+    if x.ndim == tail.ndim - 1:                                 # one token
+        seen = jnp.concatenate([tail, x[:, None]], axis=1)      # [B, K, c]
+        w = w.astype(F32)
+        return jnp.sum(seen.astype(F32) * w[None], axis=1), seen
+    t = x.shape[1]
+    seen = jnp.concatenate([tail, x], axis=1)                   # [B, K-1+T, c]
+    w = w.astype(F32)
+    return sum(seen[:, j: j + t].astype(F32) * w[j]
+               for j in range(w.shape[0])), seen
+
+
+def conv_tail(seen, k: int, length=None):
+    """The K-1 inputs a row carries after `causal_conv`'s `seen`: its last
+    ones after one token (`length` None), and after a prompt padded past
+    each row's `length` [B], those of tokens length-K+1 .. length-1, which
+    sit at seen[length:]."""
+    if length is None:
+        return seen[:, 1:]
+    return jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
+        row, n, k - 1, axis=0))(seen, length)
 
 
 def swiglu(u, w_gate_up, w_down, cd):

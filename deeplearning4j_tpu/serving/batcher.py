@@ -815,6 +815,8 @@ class ContinuousBatcher:
         # layers that count their state in cells a position
         self._kv_cells = Counter(
             decode_mod.kv_cells(net.conf, self.max_seq))
+        # {kind: bytes a decode step reads of one row's state}, or None
+        self._state_bytes = decode_mod.state_bytes_read(net.conf, self.max_seq)
         # {positions a layer's step picks among a row's cached ones: layers}
         self._dsa_picks = Counter(
             decode_mod.selected_cells(net.conf, self.max_seq))
@@ -1815,7 +1817,13 @@ class ContinuousBatcher:
         live row, table and step, from the slots' positions.
         `kv_cells_spanned`: what the layers say their reads cover
         (`kv_cells_read`), a row of the slot table, table and step.  Added
-        to the open `decode` span and to the totals of `stats()`."""
+        to the open `decode` span and to the totals of `stats()`.  Beside
+        them `state_bytes_read`: what the steps read of every row's state,
+        `steps x slots x state_bytes_per_row` (for a stack whose layers say
+        it: `nn.decode.state_bytes_read`)."""
+        if self._state_bytes and sum(self._state_bytes.values()):
+            sp.set(state_bytes_read=sp.attrs.get("state_bytes_read", 0)
+                   + steps * self.n_slots * sum(self._state_bytes.values()))
         if not self._kv_cells:
             return
         p, a = np.asarray(pos, np.int64), np.asarray(adv, np.int64)
@@ -2243,6 +2251,7 @@ class ContinuousBatcher:
                 "admit_seconds_total": round(self._admit_s, 6),
                 "queue_wait_seconds_total": round(self._queue_wait_s, 6),
                 "dsa_kernel_layers": self._dsa_kernel,
+                "state_bytes_per_row": self._state_bytes,
                 "decode_block_steps": {
                     "bounds": list(DECODE_BLOCK_STEPS_BOUNDS),
                     "counts": list(bh["counts"]),

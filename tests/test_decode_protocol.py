@@ -13,7 +13,8 @@ from deeplearning4j_tpu.nn import decode, layers
 from deeplearning4j_tpu.nn.conf import (Activation, GQASpec, KDASpec, LayerType,
                                         LossFunction, MLASpec, MoESpec,
                                         MultiLayerConfiguration,
-                                        NeuralNetConfiguration, SwiGLUSpec)
+                                        NeuralNetConfiguration, ShortConvSpec,
+                                        SwiGLUSpec)
 from deeplearning4j_tpu.nn.layers import get_layer
 from deeplearning4j_tpu.nn.multilayer import init_params
 
@@ -33,6 +34,7 @@ SPECS = {
     LayerType.SWIGLU: dict(layer_spec=SwiGLUSpec(hidden=16)),
     LayerType.MOE: dict(layer_spec=MoESpec(
         n_routed=8, n_held=4, hidden=8, shared_hidden=8, top_k=2, n_group=2)),
+    LayerType.SHORT_CONV: dict(layer_spec=ShortConvSpec(kernel=3)),
 }
 
 
