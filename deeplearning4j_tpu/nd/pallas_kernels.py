@@ -19,6 +19,11 @@ ops where fusion control matters:
                           of the table where they lie, in blocks fetched
                           by DMA, and the picked ones enter one online
                           softmax (no gather of the picked cells).
+- `gqa_decode`          — a decode step's grouped-query attention over a
+                          K/V table: each row walks its cells up to its
+                          position where they lie, in blocks fetched by
+                          DMA, every K/V head at once (no read of the dead
+                          cells past it).
 
 Every entry point auto-falls back to interpreter mode off-TPU so the same
 code path is exercised by the CPU test suite (`interpret=None` -> detect).
@@ -53,6 +58,21 @@ def _interpret(flag: Optional[bool]) -> bool:
     # reach them; the CLI's JSON names the platform, so such a run cannot
     # pass for a chip run.  Cached: this runs at every kernel call site.
     return not is_tpu()
+
+
+def _on_each_chip(call, *args):
+    """`call(*args)`, or where the arguments lie on a mesh of several chips
+    (a program that a `model` axis partitions), the same call inside a
+    `shard_map` in which every chip holds every argument whole and computes
+    the whole result: XLA cannot partition a Mosaic call itself.  The decode
+    kernels' tables lie whole on every chip under such a plan
+    (`parallel.plan.STATE_SPLIT_NAMES`), so none of them moves."""
+    mesh = jax.typeof(args[-1]).sharding.mesh
+    if mesh.size <= 1:
+        return call(*args)
+    whole = jax.sharding.PartitionSpec()
+    return jax.shard_map(call, mesh=mesh, in_specs=whole, out_specs=whole,
+                         check_vma=False)(*args)
 
 
 def _block_table():
@@ -705,9 +725,17 @@ def scatter_add_rows(table, indices, updates,
 
 # ----------------------------------------------- sparse latent attention
 
-#: cells a block of `dsa_decode`: 640 KB of a bfloat16 table of 640-wide
-#: cells, fetched while the block before it is scored
+#: cells a block of `dsa_decode` and `gqa_decode`: 640 KB of a bfloat16
+#: table of 640-wide cells, 512 KB of K (or of V) of 4 heads of 128, fetched
+#: while the block before it is scored
 DSA_BLOCK = 512
+
+
+def walk_block(cells: int) -> int:
+    """The cells a decode kernel fetches at a time from a table of `cells`:
+    `DSA_BLOCK`, or the largest power of two under it that divides `cells`.
+    A row at position p reads its first ceil((p + 1) / block) blocks."""
+    return math.gcd(cells, DSA_BLOCK)
 
 
 def _dsa_decode_kernel(pos_ref, q_ref, mask_ref, table_ref, out_ref, buf, sem,
@@ -790,7 +818,7 @@ def dsa_decode(q, table, picked, pos, scale: float,
     prefix, not `cells`."""
     rows, h, c = q.shape
     cells = table.shape[1]
-    block = math.gcd(cells, DSA_BLOCK)
+    block = walk_block(cells)
     mask = picked.astype(jnp.int8).reshape(rows, cells // block, 1, block)
     precision = (lax.Precision.HIGHEST if jnp.dtype(table.dtype) == jnp.float32
                  else lax.Precision.DEFAULT)
@@ -809,7 +837,7 @@ def dsa_decode(q, table, picked, pos, scale: float,
         scratch_shapes=[pltpu.VMEM((2, 2, block, c), table.dtype),
                         pltpu.SemaphoreType.DMA((2, 2))],
     )
-    return pl.pallas_call(
+    return _on_each_chip(pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((rows, h, c), jnp.float32),
         grid_spec=grid_spec,
@@ -817,4 +845,128 @@ def dsa_decode(q, table, picked, pos, scale: float,
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(interpret),
         name="dl4j_dsa_decode",
-    )(pos.astype(jnp.int32), q.astype(table.dtype), mask, table)
+    ), pos.astype(jnp.int32), q.astype(table.dtype), mask, table)
+
+
+# ------------------------------------------- grouped-query attention (decode)
+
+def _gqa_decode_kernel(pos_ref, q_ref, k_ref, v_ref, out_ref, kbuf, vbuf, sem,
+                       *, block: int, cells_last: bool, precision):
+    """Row b of a decode step: for each K/V head g, the online softmax of q
+    [G, R, h] against the row's first `pos[b] + 1` keys, and the values'
+    weighted sum.
+
+    K and V [rows, G, cells, h] ([rows, G, h, cells] where `cells_last`)
+    stay in HBM; block j of row b, every head's cells j x block onwards,
+    goes to buf[b % 2, j % 2] while block j - 1 is summed, row b + 1's
+    first block is fetched while row b is walked (so the grid runs in
+    order), and no block past a row's position is fetched (a position past
+    the table walks it whole).  Inside the last block the cells past the
+    position weigh exactly 0."""
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    cells = k_ref.shape[3 if cells_last else 2]
+
+    def live_blocks(r):                         # ceil((pos + 1) / block)
+        return (jnp.clip(pos_ref[r], -1, cells - 1) + block) // block
+
+    def copies(r, j, slot):
+        at = pl.ds(pl.multiple_of(j * block, block), block)
+        return [pltpu.make_async_copy(
+            table.at[r, :, :, at] if cells_last else table.at[r, :, at],
+            buf.at[r % 2, slot], sem.at[n, r % 2, slot])
+            for n, (table, buf) in enumerate(((k_ref, kbuf), (v_ref, vbuf)))]
+
+    def start(r, j, slot):
+        for copy in copies(r, j, slot):
+            copy.start()
+
+    live = live_blocks(b)
+
+    @pl.when((b == 0) & (live > 0))
+    def _():
+        start(b, 0, 0)
+
+    after = jnp.minimum(b + 1, rows - 1)
+
+    @pl.when((b + 1 < rows) & (live_blocks(after) > 0))
+    def _():
+        start(after, 0, 0)
+
+    q = q_ref[...]                                           # [G, R, h]
+    g, r, h = q.shape
+    # the products batch over the K/V heads; a block's cells are its rows,
+    # or where `cells_last` its lanes
+    cell_axis = 2 if cells_last else 1
+    head_axis = 1 if cells_last else 2
+
+    def body(j, carry):
+        acc, m, l = carry
+        slot = j % 2
+
+        @pl.when(j + 1 < live)
+        def _():
+            start(b, j + 1, 1 - slot)
+
+        for copy in copies(b, j, slot):
+            copy.wait()
+        keys, values = kbuf[b % 2, slot], vbuf[b % 2, slot]
+        s = lax.dot_general(q, keys, (((2,), (head_axis,)), ((0,), (0,))),
+                            precision=precision,
+                            preferred_element_type=jnp.float32)  # [G, R, block]
+        cell = j * block + lax.broadcasted_iota(jnp.int32, (g, r, block), 2)
+        s = jnp.where(cell <= pos_ref[b], s, _NEG_BIG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc_new = acc * alpha + lax.dot_general(
+            p.astype(values.dtype), values, (((2,), (cell_axis,)), ((0,), (0,))),
+            precision=precision, preferred_element_type=jnp.float32)
+        return acc_new, m_new, l_new
+
+    acc, _, l = lax.fori_loop(
+        0, live, body, (jnp.zeros((g, r, h), jnp.float32),
+                        jnp.full((g, r, 1), _NEG_BIG, jnp.float32),
+                        jnp.zeros((g, r, 1), jnp.float32)))
+    out_ref[...] = acc / jnp.maximum(l, 1e-30)
+
+
+def gqa_decode(q, k, v, pos, cells_last: bool = False,
+               interpret: Optional[bool] = None):
+    """A decode step's grouped-query attention over a table: q [rows, G, R,
+    h] (scaled), k and v [rows, G, cells, h], or [rows, G, h, cells] where
+    `cells_last`, pos [rows] int32 -> sum_{c <= pos} softmax_c(q . k[c])
+    v[c], [rows, G, R, h] float32.  Blocks of `walk_block(cells)` cells are
+    fetched at a time, every K/V head of a row at once, and a row reads its
+    first ceil((pos + 1) / block) blocks alone: its live prefix, not
+    `cells`."""
+    rows, g, r, h = q.shape
+    cells = k.shape[3 if cells_last else 2]
+    block = walk_block(cells)
+    precision = (lax.Precision.HIGHEST if jnp.dtype(k.dtype) == jnp.float32
+                 else lax.Precision.DEFAULT)
+    kernel = functools.partial(_gqa_decode_kernel, block=block,
+                               cells_last=cells_last, precision=precision)
+    tile = (g, h, block) if cells_last else (g, block, h)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(rows,),
+        in_specs=[
+            pl.BlockSpec((None, g, r, h), lambda b, pos_ref: (b, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((None, g, r, h), lambda b, pos_ref: (b, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, 2) + tile, k.dtype),
+                        pltpu.VMEM((2, 2) + tile, v.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2, 2))],
+    )
+    return _on_each_chip(pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((rows, g, r, h), jnp.float32),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(interpret),
+        name="dl4j_gqa_decode",
+    ), pos.astype(jnp.int32), q.astype(k.dtype), k, v)

@@ -163,21 +163,27 @@ def experts_batched_layers(conf: MultiLayerConfiguration, rows: int) -> int:
 def kv_cells(conf: MultiLayerConfiguration, max_seq: int) -> list:
     """For every table of every layer whose class counts its state in cells
     a position (`kv_cells`, one number a layer or one a table): the most
-    cells a step needs of a row at `max_seq`, and how many its decode step
-    reads (`kv_cells_read`)."""
+    cells a step needs of a row at `max_seq`, how many its decode step reads
+    (`kv_cells_read`: for a row that stands anywhere, the most), the block
+    in which it walks a row's cells up to the row's position
+    (`kv_read_block`; 0: it reads them all) and the bytes of a cell
+    (`kv_cell_bytes`)."""
     out = []
     for _, c, impl in _hidden(conf):
         if hasattr(impl, "kv_cells"):
-            out += zip(np.atleast_1d(impl.kv_cells(c, max_seq)).tolist(),
-                       np.atleast_1d(impl.kv_cells_read(c, max_seq)).tolist())
+            out += zip(*(np.atleast_1d(f(c, max_seq)).tolist() for f in (
+                impl.kv_cells, impl.kv_cells_read, impl.kv_read_block,
+                impl.kv_cell_bytes)))
     return out
 
 
 def state_bytes_read(conf: MultiLayerConfiguration, max_seq: int):
     """What a decode step reads of one row's state, in bytes as the state
     lies in memory, by kind: `table`, of every layer that counts its state
-    in cells, the cells its step reads times a cell's bytes (`kv_cells_read`
-    x `kv_cell_bytes`), and `carry`, of every layer whose state advances
+    in cells, the most cells its step reads times a cell's bytes
+    (`kv_cells_read` x `kv_cell_bytes`; where the step walks a row's blocks
+    up to its position, the batcher counts them by the rows' positions),
+    and `carry`, of every layer whose state advances
     with each token (`CARRY`), the whole of it.  None where a layer keeps a
     state that says neither: its read cannot be counted."""
     out = {"table": 0, "carry": 0}
@@ -203,11 +209,11 @@ def selected_cells(conf: MultiLayerConfiguration, max_seq: int) -> list:
     return [k for k in picks if k]
 
 
-def dsa_kernel_layers(conf: MultiLayerConfiguration, max_seq: int) -> int:
-    """Of the layers whose decode step picks, how many attend through the
-    `dl4j_dsa_decode` kernel at `max_seq` (`dsa_kernel`: on the chip)."""
-    return sum(impl.dsa_kernel(c, max_seq) for _, c, impl in _hidden(conf)
-               if hasattr(impl, "dsa_kernel"))
+def kernel_layers(conf: MultiLayerConfiguration, max_seq: int) -> int:
+    """How many tables a decode step at `max_seq` walks through a kernel, a
+    row's blocks up to its position (`kv_read_block` > 0): one a layer that
+    attends through `dl4j_gqa_decode` or `dl4j_dsa_decode`."""
+    return sum(block > 0 for _, _, block, _ in kv_cells(conf, max_seq))
 
 
 def _refuse_dense_only(conf, what: str) -> None:

@@ -30,13 +30,16 @@ What only some types can do, a class declares by having it:
             each of them where it is a carry ({} otherwise), to roll back to
     counted_step(params, conf, x, state, pos)   -> (hidden, state, counts)
             a step that counts its expert picks ([2] int32)
-    kv_cells(conf, max_seq), kv_cells_read(conf, max_seq)   -> int, or one
-            a table of its state
+    kv_cells(conf, max_seq), kv_cells_read(conf, max_seq),
+    kv_read_block(conf, max_seq)                -> int, or one a table of
+            its state
             its state holds one cell a position: the most cells of a row
             that a step needs (a table's length, a ring's, the positions a
-            step picks), and `decode_step` reads so many for every row of
-            the slot table: the batcher counts the cells a step needs
-            beside those the layer says its read covers
+            step picks), and `decode_step` reads at most so many for every
+            row of the slot table, all of them where `kv_read_block` is 0,
+            else the blocks of that many cells up to the row's position:
+            the batcher counts the cells a step needs beside those the
+            layer says its read covers
     selects(conf, max_seq)                      -> int
             its decode step picks so many of a row's cached positions and
             attends to those alone (0: to all): the batcher counts the

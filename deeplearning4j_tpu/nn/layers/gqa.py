@@ -21,7 +21,10 @@ ring, cell c holds position pos - (pos - c) % window, the newest one of its
 residue: inside the window by construction, and written by this row
 whenever it is >= 0, so the ring's valid cells are again c <= pos, and all
 of them once pos >= window - 1.  What a slot's last row left in the other
-cells is masked, never cleared.
+cells is masked, never cleared.  On the chip a table's step reads no cell
+past a row's block: `pallas_kernels.gqa_decode` walks each row's cells up to
+its position where they lie (`decode_kernel`, `kv_read_block`); off it the
+step reads the whole table under the mask, the kernel's oracle.
 
 `prefill` attends a block of `Q_BLOCK` queries at a time, each against the
 keys it may see and no others (all earlier ones for a full layer, at most
@@ -44,6 +47,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.nd import pallas_kernels
+from deeplearning4j_tpu.nd.platform import is_tpu
 from deeplearning4j_tpu.nn.layers.base import compute_dtype
 from deeplearning4j_tpu.nn.layers.rms import (F32, initializer, mm, pre_norm,
                                               precision_of, rms_norm, rope)
@@ -81,11 +86,29 @@ class GQALayer:
 
     @staticmethod
     def kv_cells_read(conf, max_seq: int) -> int:
-        """Of those, the cells `decode_step` reads for a row of the table,
-        wherever the row stands and whether it is live: all, since `seen`
-        masks the state and slices nothing off it.  A read that stops at a
-        row's live cells says so here."""
+        """Of those, the most cells `decode_step` reads for a row of the
+        table, wherever the row stands and whether it is live: all, since
+        `seen` masks the state and slices nothing off it, or the kernel walks
+        a row's live blocks, at most the table (`kv_read_block`)."""
         return GQALayer.kv_cells(conf, max_seq)
+
+    @staticmethod
+    def kv_read_block(conf, max_seq: int) -> int:
+        """The block in which `decode_step` walks a row's cells up to its
+        position (`decode_kernel`), or 0 where it reads them all."""
+        return pallas_kernels.walk_block(max_seq) if GQALayer.decode_kernel(conf, max_seq) else 0
+
+    @staticmethod
+    def decode_kernel(conf, max_seq: int) -> bool:
+        """Does `decode_step` attend through `dl4j_gqa_decode`: a table (a
+        ring is all live from position window - 1 on), on the chip, of whole
+        128s of positions, with heads whose table the kernel reads where it
+        lies (whole 128s wide, or 64 with the positions innermost).  Off the
+        chip the step reads the whole table under `seen`, the kernel's
+        oracle (interpreted, the kernel loses to XLA there)."""
+        s = conf.layer_spec
+        return (not s.window and is_tpu() and max_seq % 128 == 0
+                and (s.head_dim % 128 == 0 or s.head_dim == 64))
 
     @staticmethod
     def kv_cell_bytes(conf, max_seq: int) -> int:
@@ -206,11 +229,20 @@ class GQALayer:
                     new.reshape(b * g, s.head_dim)).reshape(table.shape)
 
             k_all, v_all = put(state["k"], k), put(state["v"], v)
-        with scope("kv_read"):
-            seen = jnp.arange(cells)[None, :] <= pos[:, None]
-            if s.window:
-                seen = seen | (pos[:, None] >= cells)
-        o = GQALayer._attend(conf, q, k_all, v_all, seen[:, None, None, :])
+        if GQALayer.decode_kernel(conf, cells):
+            with scope("gqa_attend"):
+                # the chip lays a table whose heads are not whole 128s wide
+                # out positions innermost: the kernel reads it as it lies
+                last = s.head_dim % 128 != 0
+                kt, vt = ((jnp.swapaxes(t, 2, 3) if last else t) for t in (k_all, v_all))
+                o = pallas_kernels.gqa_decode(q.astype(cd), kt, vt, pos,
+                                              cells_last=last).reshape(b, -1)
+        else:
+            with scope("kv_read"):
+                seen = jnp.arange(cells)[None, :] <= pos[:, None]
+                if s.window:
+                    seen = seen | (pos[:, None] >= cells)
+            o = GQALayer._attend(conf, q, k_all, v_all, seen[:, None, None, :])
         with scope("proj"):
             y = mm(o, params["Wo"], cd)
         return x.astype(F32) + y, {"k": k_all, "v": v_all}

@@ -192,7 +192,7 @@ class MLALayer:
         return k if 0 < k < max_seq else 0
 
     @staticmethod
-    def dsa_kernel(conf, max_seq: int) -> bool:
+    def decode_kernel(conf, max_seq: int) -> bool:
         """Does `decode_step` attend through `dl4j_dsa_decode`: a layer that
         picks, on the chip.  Off it the step reads the whole table under the
         choice's mask, the kernel's oracle (interpreted, the kernel loses to
@@ -216,6 +216,16 @@ class MLALayer:
         kernel walks a row's live blocks, at most the whole table)."""
         k = MLALayer.selects(conf, max_seq)
         return (max_seq, max_seq) if k else MLALayer.kv_cells(conf, max_seq)
+
+    @staticmethod
+    def kv_read_block(conf, max_seq: int) -> tuple:
+        """Of each table, the block in which `decode_step` walks a row's
+        cells up to its position, or 0 where it reads them all: the picked
+        latents' table under `dl4j_dsa_decode`, and no other."""
+        if not MLALayer.selects(conf, max_seq):
+            return (0,)
+        return (pallas_kernels.walk_block(max_seq) if MLALayer.decode_kernel(conf, max_seq)
+                else 0, 0)
 
     @staticmethod
     def kv_cell_bytes(conf, max_seq: int) -> tuple:
@@ -425,7 +435,7 @@ class MLALayer:
             q_lat = jnp.einsum("bhn,rhn->bhr", q_nope.astype(cd), w_k.astype(cd),
                                precision=hi, preferred_element_type=F32)
         scale = math.sqrt(s.qk_nope_head_dim + s.qk_rope_head_dim)
-        if MLALayer.dsa_kernel(conf, cells):
+        if MLALayer.decode_kernel(conf, cells):
             with scope("dsa_attend"):
                 lat = pallas_kernels.dsa_decode(
                     MLALayer._side_by_side(conf, q_lat.astype(cd), q_rope.astype(cd)),

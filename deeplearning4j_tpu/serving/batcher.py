@@ -811,8 +811,9 @@ class ContinuousBatcher:
         # static a program: a decode step is one call of `n_slots` rows
         self._experts_batched = decode_mod.experts_batched_layers(
             net.conf, self.n_slots)
-        # {(cells a row holds, cells of them a step reads): layers} of the
-        # layers that count their state in cells a position
+        # {(cells a row holds, the most of them a step reads, the block it
+        # walks them in or 0, a cell's bytes): tables} of the layers that
+        # count their state in cells a position
         self._kv_cells = Counter(
             decode_mod.kv_cells(net.conf, self.max_seq))
         # {kind: bytes a decode step reads of one row's state}, or None
@@ -820,8 +821,9 @@ class ContinuousBatcher:
         # {positions a layer's step picks among a row's cached ones: layers}
         self._dsa_picks = Counter(
             decode_mod.selected_cells(net.conf, self.max_seq))
-        # static a program: the layers whose step runs `dl4j_dsa_decode`
-        self._dsa_kernel = decode_mod.dsa_kernel_layers(net.conf, self.max_seq)
+        # static a program: the layers whose step walks a table through a
+        # kernel (`dl4j_dsa_decode`, `dl4j_gqa_decode`)
+        self._kernel_layers = decode_mod.kernel_layers(net.conf, self.max_seq)
         # silent positional-table overrun fix: `token_embed` gathers
         # P[pos] with no bound check, and jit CLAMPS out-of-range
         # gathers — a stream decoding past the learned table would read
@@ -1808,6 +1810,16 @@ class ContinuousBatcher:
         return int((rising * pos + rising * (rising + 1) // 2
                     + (adv - rising) * most).sum())
 
+    @staticmethod
+    def _cells_walked(pos, adv, steps: int, block: int, most: int) -> int:
+        """Summed over every row, live or not, and the `steps` steps: the
+        cells of a table of `most` that a read walking blocks of `block` up
+        to the row's position covers, `ceil((q + 1) / block) x block` at the
+        position q handed to the program (`pos[r] + min(i, adv[r])` in step
+        i; a position past the table walks it whole)."""
+        at = pos[:, None] + np.minimum(np.arange(steps), adv[:, None])
+        return int(((np.clip(at, -1, most - 1) + block) // block * block).sum())
+
     def _note_kv(self, sp: span, pos, adv, steps: int) -> None:
         """The cached cells of one dispatch of `steps` table steps in which
         row r, at position `pos[r]`, advances `adv[r]` tokens, for a stack
@@ -1815,22 +1827,27 @@ class ContinuousBatcher:
         a table of one; nothing otherwise).  `kv_cells_live`: what the steps
         have to read, `min(position + 1, the most the table can need)` a
         live row, table and step, from the slots' positions.
-        `kv_cells_spanned`: what the layers say their reads cover
-        (`kv_cells_read`), a row of the slot table, table and step.  Added
-        to the open `decode` span and to the totals of `stats()`.  Beside
-        them `state_bytes_read`: what the steps read of every row's state,
-        `steps x slots x state_bytes_per_row` (for a stack whose layers say
-        it: `nn.decode.state_bytes_read`)."""
+        `kv_cells_spanned`: what the layers' reads cover, a row of the slot
+        table, table and step: `kv_cells_read`, or where a layer walks a
+        row's cells in blocks up to its position (`kv_read_block`) those
+        blocks (`_cells_walked`).  Added to the open `decode` span and to
+        the totals of `stats()`.  Beside them `state_bytes_read`: what the
+        steps read of every row's state, the spanned cells' bytes and every
+        carry whole (for a stack whose layers say it:
+        `nn.decode.state_bytes_read`)."""
+        p, a = np.asarray(pos, np.int64), np.asarray(adv, np.int64)
+        live = spanned = table_bytes = 0
+        for (most, read, block, cell), n in self._kv_cells.items():
+            live += n * self._cells_needed(p, a, most)
+            cells = n * (self._cells_walked(p, a, steps, block, read) if block
+                         else steps * self.n_slots * read)
+            spanned += cells
+            table_bytes += cells * cell
         if self._state_bytes and sum(self._state_bytes.values()):
-            sp.set(state_bytes_read=sp.attrs.get("state_bytes_read", 0)
-                   + steps * self.n_slots * sum(self._state_bytes.values()))
+            sp.set(state_bytes_read=sp.attrs.get("state_bytes_read", 0) + table_bytes
+                   + steps * self.n_slots * self._state_bytes["carry"])
         if not self._kv_cells:
             return
-        p, a = np.asarray(pos, np.int64), np.asarray(adv, np.int64)
-        live = spanned = 0
-        for (most, read), n in self._kv_cells.items():
-            live += n * self._cells_needed(p, a, most)
-            spanned += n * steps * self.n_slots * read
         sp.set(kv_cells_live=sp.attrs.get("kv_cells_live", 0) + live,
                kv_cells_spanned=sp.attrs.get("kv_cells_spanned", 0) + spanned)
         with self._cv:
@@ -1846,7 +1863,7 @@ class ContinuousBatcher:
         (`min(position + 1, picks)`); a row, such layer and step.  Both come
         from the slots' positions and one constant of the conf, so they
         describe the traffic (how far the rows are past the picks), not the
-        program: what a step reads of its tables is `kv_cells_read`."""
+        program: what a step reads of its tables is `kv_cells_spanned`."""
         if not self._dsa_picks:
             return
         cached = selected = 0
@@ -2250,7 +2267,7 @@ class ContinuousBatcher:
                     for cause, ns in self._starved_ns.items()},
                 "admit_seconds_total": round(self._admit_s, 6),
                 "queue_wait_seconds_total": round(self._queue_wait_s, 6),
-                "dsa_kernel_layers": self._dsa_kernel,
+                "kernel_layers": self._kernel_layers,
                 "state_bytes_per_row": self._state_bytes,
                 "decode_block_steps": {
                     "bounds": list(DECODE_BLOCK_STEPS_BOUNDS),

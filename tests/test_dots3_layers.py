@@ -215,7 +215,7 @@ def test_a_decode_step_through_the_kernel_is_the_masked_reads(request, dtype, mo
     monkeypatch.setattr(pallas_kernels, "DSA_BLOCK", 16)
     on, on_state = step()
     # a table of no more cells than the layer picks has no choice to walk
-    assert MLALayer.dsa_kernel(conf, 64) and not MLALayer.dsa_kernel(conf, 8)
+    assert MLALayer.decode_kernel(conf, 64) and not MLALayer.decode_kernel(conf, 8)
     jax.tree_util.tree_map(np.testing.assert_array_equal, on_state, off_state)
     np.testing.assert_allclose(on, off, atol=TIGHT if dtype == "f32" else 2e-3)
 

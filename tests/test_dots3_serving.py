@@ -81,10 +81,13 @@ def test_the_state_is_two_tables_and_an_index_or_a_ring(f32):
     # a full layer needs the picked latents and every index key, and reads
     # both tables whole (the kernel walks a row's live blocks of the first);
     # a ring: its cells
-    assert decode.kv_cells(f32.conf, 4096) == [(8, 4096), (4096, 4096)] * 2 + [(5, 5)]
+    # (off the chip no table is walked in blocks: the fourth number is a
+    # cell's bytes, float32)
+    assert decode.kv_cells(f32.conf, 4096) == [(8, 4096, 0, 512), (4096, 4096, 0, 64)] * 2 + [
+        (5, 5, 0, 512)]
     assert decode.selected_cells(f32.conf, 4096) == [8, 8]
     # a table no longer than what would be picked: read whole, nothing picked
-    assert decode.kv_cells(f32.conf, 4) == [(4, 4)] * 3
+    assert [c[:3] for c in decode.kv_cells(f32.conf, 4)] == [(4, 4, 0)] * 3
     assert decode.selected_cells(f32.conf, 8) == []
 
 
@@ -124,7 +127,12 @@ def test_the_cells_a_layer_says_it_reads_are_its_programs_keys(f32, kind, path,
         assert not (latents if path == "kernel" else walked)
     else:
         assert latents == {read[0]} == {5} and not walked
-    assert decode.dsa_kernel_layers(f32.conf, MAX_SEQ) == (2 if path == "kernel" else 0)
+    assert decode.kernel_layers(f32.conf, MAX_SEQ) == (2 if path == "kernel" else 0)
+    # and the kernel walks the picked latents' table in blocks up to a row's
+    # position, which the batcher counts by the rows' positions
+    from deeplearning4j_tpu.nd.pallas_kernels import walk_block
+    assert MLALayer.kv_read_block(conf, MAX_SEQ) == (
+        (walk_block(MAX_SEQ), 0) if path == "kernel" else (0, 0) if kind == "full" else (0,))
 
 
 def _equations(jaxpr):
@@ -161,7 +169,7 @@ def test_decode_spans_carry_the_cells_and_the_selection(f32, served):
         stats["dsa_cells_live_total"] + stats["dsa_cells_selected_total"]
         + hand_count(PROMPTS, NEW, 5))
     assert 0 < stats["dsa_cells_selected_total"] < stats["dsa_cells_live_total"]
-    assert stats["dsa_kernel_layers"] == 0          # off the chip: the masked read
+    assert stats["kernel_layers"] == 0          # off the chip: the masked read
 
 
 def test_fused_blocks_serve_the_same_tokens_and_count_the_same(f32, served):
@@ -197,7 +205,7 @@ def test_a_stack_that_picks_nothing_counts_no_selection():
 
     ling = Ling("float32")
     cells = decode.kv_cells(ling.conf, 32)
-    assert cells == [(32, 32)] and decode.selected_cells(ling.conf, 32) == []
+    assert [c[:3] for c in cells] == [(32, 32, 0)] and decode.selected_cells(ling.conf, 32) == []
     net = MultiLayerNetwork(ling.conf)
     net.params = ling.params
     profiling.clear()
@@ -207,7 +215,7 @@ def test_a_stack_that_picks_nothing_counts_no_selection():
     batcher.stop()
     assert stats["kv_cells_live_total"] == 4 + 5 and stats["kv_cells_spanned_total"] == 64
     assert not any(k.startswith("dsa_cells") for k in stats)
-    assert stats["dsa_kernel_layers"] == 0
+    assert stats["kernel_layers"] == 0
     assert not any("dsa_cells_live" in s.attrs for s in profiling.spans())
 
 

@@ -120,3 +120,96 @@ def test_a_declared_cell_is_the_states_own_bytes(impl, c):
     if impl is GQALayer:
         s = c.layer_spec
         assert state["k"].shape == (1, s.n_kv_heads, s.window or max_seq, s.head_dim)
+
+
+# ------------------------------------- the walk of a row's live blocks
+
+@pytest.mark.parametrize("head_dim,window,chip,max_seq,engages", [
+    (64, 0, True, 6144, True),          # LFM2's tables, positions innermost
+    (128, 0, True, 9216, True),         # Mellum's full layers
+    (128, 1024, True, 9216, False),     # its rings: all live from position 1,023
+    (96, 0, True, 4096, False),         # a table the kernel would make the chip copy
+    (64, 0, False, 6144, False),        # off the chip: `seen` over the table, the oracle
+    (128, 0, True, 200, False),         # positions not whole 128s
+], ids=["h64-table", "h128-table", "h128-ring", "h96-table", "off-the-chip", "ragged-table"])
+def test_the_kernel_engages_on_tables_it_reads_where_they_lie(monkeypatch, head_dim, window,
+                                                              chip, max_seq, engages):
+    from deeplearning4j_tpu.nn.layers import gqa
+
+    monkeypatch.setattr(gqa, "is_tpu", lambda: chip)
+    c = conf(head_dim=head_dim, window=window)
+    assert GQALayer.decode_kernel(c, max_seq) == engages
+    assert GQALayer.kv_read_block(c, max_seq) == (512 if engages else 0)
+    # what it reads at the most does not change: the whole table, or the ring
+    assert GQALayer.kv_cells_read(c, max_seq) == (min(window, max_seq) if window else max_seq)
+
+
+def test_a_decode_step_through_the_kernel_is_the_masked_read(monkeypatch):
+    """The chip's path, the kernel interpreted: the same outputs and the same
+    state as the step off the chip, for rows at the first cell, inside and at
+    the edge of a block, at the table's last cell and past it (a finished
+    slot at `max_seq`).  Heads of 64, whose table the kernel is handed
+    positions innermost (`test_pallas_kernels.py` holds it to the masked
+    read at heads of 128 too)."""
+    head_dim = 64
+    from deeplearning4j_tpu.nd import pallas_kernels
+    from deeplearning4j_tpu.nn.layers import gqa
+
+    monkeypatch.setattr(pallas_kernels, "DSA_BLOCK", 32)
+    c = conf(head_dim=head_dim)
+    p, max_seq = params(c), 128
+    pos = jnp.asarray([0, 31, 32, 77, 127, 128], jnp.int32)
+    k, v = (rows((6, 4, max_seq, head_dim), seed=s) for s in (5, 6))
+    x = rows((6, 64), seed=7)
+    step = lambda: GQALayer.decode_step(p, c, x, {"k": k, "v": v}, pos)  # noqa: E731
+    want, want_state = step()
+    monkeypatch.setattr(gqa, "is_tpu", lambda: True)
+    assert "pallas_call" in str(jax.make_jaxpr(step)())
+    got, got_state = step()
+    np.testing.assert_allclose(got, want, atol=TIGHT)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(got_state[name], want_state[name])
+
+
+@pytest.mark.parametrize("steps", [1, 3], ids=["one-step", "a-block-of-three"])
+def test_a_walked_table_counts_the_blocks_its_rows_read(monkeypatch, steps):
+    """On the chip's path a full layer's `kv_cells_spanned` and its bytes in
+    `state_bytes_read` are the blocks of 32 cells each slot's step walks at
+    the position handed to the program, live or not (a finished slot at
+    `max_seq` walks all 4); the rings of 8 still count whole.  The tiny
+    Mellum with heads of 64: three window layers, one full."""
+    from mellum_model import Model
+
+    from deeplearning4j_tpu.nd import pallas_kernels
+    from deeplearning4j_tpu.nn import decode
+    from deeplearning4j_tpu.nn.layers import gqa
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+    from deeplearning4j_tpu.serving.batcher import ContinuousBatcher
+    from deeplearning4j_tpu.utils.profiling import span
+
+    monkeypatch.setattr(gqa, "is_tpu", lambda: True)
+    monkeypatch.setattr(pallas_kernels, "DSA_BLOCK", 32)
+    m = Model("float32", head_dim=64)
+    batcher = ContinuousBatcher(MultiLayerNetwork(m.conf), n_slots=5, max_seq=128,
+                                prompt_buckets=(8,))
+    assert batcher.stats()["kernel_layers"] == decode.kernel_layers(m.conf, 128) == 1
+    full, ring = (GQALayer.kv_cell_bytes(m.conf.conf(m.layer(kind)), 128)
+                  for kind in ("full", "window"))
+    if steps == 1:      # slot 4 is free at the table's end
+        pos, adv = [0, 31, 32, 127, 128], [1, 1, 1, 1, 0]
+        walked = 32 + 32 + 64 + 128 + 128
+    else:               # rows that cross a block edge and the table's end mid-block
+        pos, adv = [0, 31, 126, 128, 5], [3, 2, 3, 0, 0]
+        walked = 3 * 32 + (32 + 64 + 64) + 3 * 128 + 3 * 128 + 3 * 32
+    with span("decode") as sp:
+        batcher._note_kv(sp, np.asarray(pos), np.asarray(adv), steps)
+    rings = 3 * steps * 5 * 8
+    assert sp.attrs["kv_cells_spanned"] == walked + rings
+    assert sp.attrs["state_bytes_read"] == walked * full + rings * ring
+    monkeypatch.setattr(gqa, "is_tpu", lambda: False)      # off the chip: the whole table
+    whole = ContinuousBatcher(MultiLayerNetwork(m.conf), n_slots=5, max_seq=128,
+                              prompt_buckets=(8,))
+    with span("decode") as sp:
+        whole._note_kv(sp, np.asarray(pos), np.asarray(adv), steps)
+    assert sp.attrs["kv_cells_spanned"] == steps * 5 * 128 + rings
+    assert whole.stats()["kernel_layers"] == 0

@@ -73,8 +73,10 @@ def test_a_window_layers_state_is_the_window_whatever_max_seq(f32):
                 assert lay["k"].shape == lay["v"].shape == (3, 2, cells, 16), kind
             else:
                 assert lay == {}
-    assert decode.kv_cells(f32.conf, 4096) == [(8, 8)] * 3 + [(4096, 4096)]
-    assert decode.kv_cells(f32.conf, 4) == [(4, 4)] * 4
+    # (off the chip no table is walked in blocks: the fourth number is a
+    # cell's bytes, K and V of two heads of 16 in float32)
+    assert decode.kv_cells(f32.conf, 4096) == [(8, 8, 0, 256)] * 3 + [(4096, 4096, 0, 256)]
+    assert decode.kv_cells(f32.conf, 4) == [(4, 4, 0, 256)] * 4
 
 
 @pytest.mark.parametrize("kind", ["window", "full"])

@@ -221,3 +221,86 @@ def test_dsa_decode_waits_for_every_copy_it_starts(monkeypatch, capsys):
         masked_read(q, table, picked, 0.125)), atol=1e-5)
     assert not interpret_pallas_call.races.races_found
     assert "non-zero count" not in capsys.readouterr().out
+
+
+# ------------------------------------- grouped-query attention (decode)
+
+GQA_ROWS = {    # a call's rows' positions, blocks of 16 (the chip's 512 scaled):
+    # the first cell, either side of a block's edge, the table's last cell
+    # and finished slots past it; then rows anywhere
+    "edges": lambda cells: [0, 15, 16, 17, cells - 1, cells, cells + 3],
+    "row mix": lambda cells: np.random.default_rng(cells).integers(0, cells, 6).tolist(),
+}
+
+
+@pytest.mark.parametrize("rows", list(GQA_ROWS))
+@pytest.mark.parametrize("cells", [64, 288], ids=["4-blocks", "18-blocks"])
+@pytest.mark.parametrize("heads", [(4, 8, 128), (8, 4, 64)], ids=["G4-R8-h128", "G8-R4-h64"])
+def test_gqa_decode_is_the_masked_read(heads, cells, rows, monkeypatch, capsys):
+    """`dl4j_gqa_decode` against the whole table under `seen`, as a grouped
+    layer's decode step reads it off the chip (`GQALayer._attend`), in float32;
+    heads of 64 handed positions innermost, as the chip lays their table out.
+    Every cell past a row's last block is NaN, so a block read past a row's
+    position would poison its output.  Under the TPU interpreter's race
+    detector with copies that land as they start: every copy is waited once
+    and none races a read of its buffer."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    from deeplearning4j_tpu.nn.conf import GQASpec, LayerType, NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.layers.gqa import GQALayer
+
+    monkeypatch.setattr(pallas_kernels, "DSA_BLOCK", 16)
+    g, r, h = heads
+    pos = jnp.asarray(GQA_ROWS[rows](cells), jnp.int32)
+    n = len(pos)
+    key = jax.random.split(jax.random.PRNGKey(14), 3)
+    q = jax.random.normal(key[0], (n, g, r, h), jnp.float32) / np.sqrt(h)
+    k, v = (jax.random.normal(kk, (n, g, cells, h), jnp.float32) for kk in key[1:])
+    walked = (np.clip(np.asarray(pos), -1, cells - 1) // 16 + 1) * 16
+    past = jnp.asarray(np.arange(cells)[None] >= walked[:, None])[:, None, :, None]
+    conf = NeuralNetConfiguration(layer_type=LayerType.GQA, n_in=g * r * h, dtype="float32",
+                                  layer_spec=GQASpec(n_heads=g * r, n_kv_heads=g, head_dim=h))
+    seen = jnp.arange(cells)[None, :] <= pos[:, None]
+    want = GQALayer._attend(conf, q, k, v, seen[:, None, None, :])
+    k, v = (jnp.where(past, jnp.nan, t) for t in (k, v))
+    last = h % 128 != 0
+    if last:
+        k, v = jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)
+    got = pallas_kernels.gqa_decode(q, k, v, pos, cells_last=last,
+                                    interpret=pltpu.InterpretParams(
+                                        detect_races=True, dma_execution_mode="eager"))
+    np.testing.assert_allclose(np.asarray(got).reshape(n, -1), np.asarray(want), atol=1e-5)
+    assert not interpret_pallas_call.races.races_found
+    assert "non-zero count" not in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("kernel", ["gqa", "dsa"])
+def test_a_decode_kernel_on_a_model_axis_is_the_call_on_one_chip(kernel, monkeypatch):
+    """Interpreted inside a program partitioned over a `model` axis of 4 of
+    the virtual devices (the queries split by head, the tables whole, as the
+    serving plan lays them): each chip runs the whole call, and the result
+    is the single-chip call's, bit for bit."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    monkeypatch.setattr(pallas_kernels, "DSA_BLOCK", 16)
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(1, 4), ("batch", "model"))
+    pos = jnp.asarray([0, 15, 16, 40, 63, 64], jnp.int32)
+    key = jax.random.split(jax.random.PRNGKey(15), 3)
+    if kernel == "gqa":
+        q = jax.random.normal(key[0], (6, 4, 2, 128), jnp.float32)
+        k, v = (jax.random.normal(kk, (6, 4, 64, 128), jnp.float32) for kk in key[1:])
+        call = lambda q, k, v, pos: pallas_kernels.gqa_decode(q, k, v, pos)  # noqa: E731
+        args = (q, k, v, pos)
+    else:
+        q = jax.random.normal(key[0], (6, 4, 128), jnp.float32)
+        table = jax.random.normal(key[1], (6, 64, 128), jnp.float32)
+        picked = jnp.arange(64)[None] <= pos[:, None]
+        call = lambda q, t, m, pos: pallas_kernels.dsa_decode(q, t, m, pos, 0.125)  # noqa: E731
+        args = (q, table, picked, pos)
+    shardings = (NamedSharding(mesh, P(None, "model")),) + (NamedSharding(mesh, P()),) * 3
+    placed = [jax.device_put(a, s) for a, s in zip(args, shardings)]
+    got = jax.jit(call)(*placed)
+    assert len(got.sharding.device_set) == 4
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(jax.jit(call)(*args)))

@@ -375,3 +375,156 @@ def test_an_admission_of_4096_fits_beside_64_slots(one_chip):
     table = 64 * (2 * 6144 * 2048 + 8 * 2 * 2048 * 2)
     stream = 3 * 4096 * 2048 * 4        # the layer's input, output and residual
     assert weights + table + temps + stream < 0.9 * 15.75 * (1 << 30)
+
+
+@pytest.mark.parametrize("cell", ["mellum", "lfm2"])
+def test_a_full_layers_decode_walks_its_table_where_it_lies(one_chip, cell, monkeypatch):
+    """The chip's path (the platform patched): a full layer's decode step
+    attends through `dl4j_gqa_decode` at the cell's slots and table, and
+    neither the write of a row's cell nor the kernel's walk copies or
+    transposes the table (Mellum's `[64, 4, 9216, 128]`, laid out as it
+    reads; LFM2's `[64, 8, 6144, 64]`, laid out positions innermost, which the
+    kernel is handed as `[64, 8, 64, 6144]`: a bitcast).  The kernel keeps
+    its blocks in fast memory: the step's temporaries are not a twentieth of
+    the table."""
+    import re
+
+    from deeplearning4j_tpu.nn.layers import gqa
+    from deeplearning4j_tpu.nn.layers.gqa import GQALayer
+
+    monkeypatch.setattr(gqa, "is_tpu", lambda: True)
+    monkeypatch.setattr(pk, "is_tpu", lambda: True)
+    conf, max_seq = (_gqa_conf("full"), 9216) if cell == "mellum" else (_lfm2_gqa(), 6144)
+    s = conf.layer_spec
+    assert GQALayer.decode_kernel(conf, max_seq) and GQALayer.kv_read_block(conf, max_seq) == 512
+    state = jax.eval_shape(lambda: GQALayer.init_state(conf, 64, max_seq))
+    shaped = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = shaped(jax.eval_shape(lambda k: GQALayer.init(k, conf), jax.random.PRNGKey(0)))
+    compiled = jax.jit(lambda p, x, st, q: GQALayer.decode_step(p, conf, x, st, q),
+                       donate_argnums=(2,)).lower(
+        params, shaped(jax.ShapeDtypeStruct((64, conf.n_in), jnp.float32)), shaped(state),
+        shaped(jax.ShapeDtypeStruct((64,), jnp.int32))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "dl4j_gqa_decode" in text
+    table = rf"bf16\[64,{s.n_kv_heads},({max_seq},{s.head_dim}|{s.head_dim},{max_seq})\]"
+    assert not re.search(rf"= {table}\S* (copy|transpose)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05 * state["k"].size * 2
+
+
+def _lfm2_stack(dtype):
+    """LFM2's stack cut to one short convolution and one attention layer,
+    each with its dense FFN, at every published width: what the batcher's
+    decode program does around the table besides the attention layer."""
+    import json
+
+    from benchmark.families.lfm2_moe.program import build_conf
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "lfm2-24b-a2b-pp10.json")) as f:
+        cfg = json.load(f)
+    return build_conf({**cfg, "num_hidden_layers": 2, "layer_types": ["conv", "full_attention"],
+                       "flags": {"param_dtype": dtype, "compute_dtype": dtype}})
+
+
+def _table_copies(text, dtype, cells):
+    """The copies and transposes in `text` of an array of `cells` elements of
+    `dtype` (a table, whatever its shape and layout)."""
+    import math
+    import re
+
+    return sum(math.prod(int(d) for d in dims.split(",")) == cells for dims in re.findall(
+        rf"= {dtype}\[([\d,]+)\]\S* (?:copy|transpose)\(", text))
+
+
+@pytest.mark.parametrize("slots,dtype", [(16, "bfloat16"), (64, "float32")],
+                         ids=["16-slots-bf16", "64-slots-f32"])
+def test_a_whole_decode_program_walks_a_64_wide_table_where_it_lies(one_chip, monkeypatch,
+                                                                     slots, dtype):
+    """The batcher's decode program of a stack with 64-wide heads, its state
+    donated, at another slot count than the cell's and with float32 tables:
+    the kernel's `[slots, 8, 64, 6144]` view of a table is a bitcast, so the
+    program copies a table no more often than the masked read's does (in
+    float32 at 64 slots never; at 16 slots the write of a row's cell copies
+    each table out of the positions-innermost layout and back, on both
+    paths)."""
+    from deeplearning4j_tpu.nn import decode
+    from deeplearning4j_tpu.nn.layers import gqa
+    from deeplearning4j_tpu.nn.multilayer import init_params
+    from deeplearning4j_tpu.optimize.infer_cache import _decode_program
+
+    monkeypatch.setattr(pk, "is_tpu", lambda: True)
+    conf = _lfm2_stack(dtype)
+    shaped = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = shaped(jax.eval_shape(lambda k: init_params(conf, k), jax.random.PRNGKey(0)))
+    state = shaped(jax.eval_shape(lambda: decode.init_state(conf, slots, 6144)))
+    rows = lambda dt, *shape: shaped(jax.ShapeDtypeStruct((slots,) + shape, dt))  # noqa: E731
+
+    def compiled(chip):
+        monkeypatch.setattr(gqa, "is_tpu", lambda: chip)
+        assert decode.kernel_layers(conf, 6144) == chip
+        return jax.jit(_decode_program(conf), donate_argnums=(1,)).lower(
+            params, state, rows(jnp.int32), rows(jnp.int32), rows(jnp.uint32, 2),
+            rows(jnp.float32)).compile()
+
+    walked = compiled(True)
+    assert "dl4j_gqa_decode" in walked.as_text()
+    short, cells = ("bf16" if dtype == "bfloat16" else "f32"), slots * 8 * 6144 * 64
+    copies = _table_copies(walked.as_text(), short, cells)
+    if slots == 64:
+        assert copies == 0
+    else:
+        masked = compiled(False)
+        assert copies == _table_copies(masked.as_text(), short, cells) == 4
+        assert (walked.memory_analysis().temp_size_in_bytes
+                <= masked.memory_analysis().temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("kernel", ["gqa", "dsa"])
+def test_a_decode_kernel_runs_whole_on_each_chip_of_a_model_axis(topo, one_chip, monkeypatch,
+                                                                  kernel):
+    """Under a plan with a `model` axis over the described four chips (the
+    weights split by head, the tables whole on every chip) a decode kernel
+    is no call XLA has to partition: it runs in a `shard_map` in which each
+    chip holds its arguments whole, and no table is gathered.  Mellum's full
+    layer at the cell's slots and table; `dsa_decode` at dots3's latent
+    table."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from deeplearning4j_tpu.nn.layers import gqa
+    from deeplearning4j_tpu.nn.layers.gqa import GQALayer
+    from deeplearning4j_tpu.parallel.plan import ShardPlan
+
+    monkeypatch.setattr(gqa, "is_tpu", lambda: True)
+    monkeypatch.setattr(pk, "is_tpu", lambda: True)
+    plan = ShardPlan(mesh=Mesh(np.asarray(topo.devices).reshape(1, 4), ("batch", "model")))
+    whole = NamedSharding(plan.mesh, P())
+    placed = lambda tree, shardings: jax.tree_util.tree_map(  # noqa: E731
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree, shardings)
+    if kernel == "gqa":
+        conf = _gqa_conf("full")
+        params = jax.eval_shape(lambda k: GQALayer.init(k, conf), jax.random.PRNGKey(0))
+        state = jax.eval_shape(lambda: GQALayer.init_state(conf, 64, 9216))
+        table = r"bf16\[64,4,9216,128\]"
+        compiled = jax.jit(lambda p, x, st, q: GQALayer.decode_step(p, conf, x, st, q),
+                           donate_argnums=(2,)).lower(
+            placed(params, plan.param_shardings(params)),
+            jax.ShapeDtypeStruct((64, 2304), jnp.float32, sharding=whole),
+            placed(state, plan.state_shardings(state)),
+            jax.ShapeDtypeStruct((64,), jnp.int32, sharding=whole)).compile()
+    else:
+        heads = NamedSharding(plan.mesh, P(None, "model", None))
+        table = r"bf16\[64,10240,640\]"
+        compiled = jax.jit(lambda q, t, m, p: pk.dsa_decode(q, t, m, p, 0.07)).lower(
+            jax.ShapeDtypeStruct((64, 128, 640), jnp.bfloat16, sharding=heads),
+            jax.ShapeDtypeStruct((64, 10240, 640), jnp.bfloat16, sharding=whole),
+            jax.ShapeDtypeStruct((64, 10240), jnp.bool_, sharding=whole),
+            jax.ShapeDtypeStruct((64,), jnp.int32, sharding=whole)).compile()
+    text = compiled.as_text()
+    assert f"dl4j_{kernel}_decode" in text
+    assert not re.search(rf"= {table}\S* (all-gather|copy|transpose)", text)
