@@ -57,6 +57,7 @@ class LayerType(str, enum.Enum):
     SWIGLU = "swiglu"              # gated (SiLU) FFN under an RMSNorm
     MOE = "moe"                    # routed experts + one shared expert
     SHORT_CONV = "shortconv"       # gated short convolution (LFM2's mixer)
+    PARALLEL = "parallel"          # attention and experts off one LayerNorm
 
     def __str__(self) -> str:
         return self.value
@@ -190,7 +191,11 @@ class GQASpec:
     attention_factor)`: the rotary frequencies are blended toward
     `1 / factor` of themselves and cos and sin scaled by the last (arXiv:
     2309.00071); None is plain rotary at `rope_theta`.  `qk_norm` puts an
-    RMSNorm over each head's `head_dim` of q and of k before the rotation."""
+    RMSNorm over each head's `head_dim` of q and of k before the rotation.
+    `rotary` pairs the dimensions that turn together: `half` (rotate-half,
+    m with m + head_dim/2), `pairs` (interleaved, 2m with 2m + 1), or
+    `none` (no positions at all); left out of the conf's JSON at its
+    default (`OMIT_AT_DEFAULT`)."""
 
     n_heads: int
     n_kv_heads: int
@@ -200,6 +205,9 @@ class GQASpec:
     yarn: Optional[Tuple[float, ...]] = None
     qk_norm: bool = False
     eps: float = 1e-6
+    rotary: str = "half"
+
+    OMIT_AT_DEFAULT = ("rotary",)
 
     @property
     def scope_kind(self) -> str:
@@ -233,8 +241,13 @@ class MoESpec:
     `topk_group` groups stay, then the `top_k` best experts among them), of
     which this layer holds `n_held` from `first_held` on and computes those
     picks alone; one shared expert of `shared_hidden` is whole here
-    (`shared_hidden` 0: there is none, and no such leaves), and
-    `router_bias` says whether a learned bias enters the choice.  The two
+    (`shared_hidden` 0: there is none, and no such leaves), its output times
+    `shared_scale` (several shared experts averaged are one of their widths
+    summed, scaled by one over their number), and `router_bias` says
+    whether a learned bias enters the choice.  `ranks`: the chips of the
+    expert-parallel group whose rows reach the held experts where this layer
+    is one chip of a deployment (1: this chip's rows alone); the form of the
+    held experts' product counts their picks (`experts.experts_form`).  The
     fields of `OMIT_AT_DEFAULT` are left out of the conf's JSON where they
     hold their defaults: a conf from before them serialises as it did."""
 
@@ -250,31 +263,74 @@ class MoESpec:
     eps: float = 1e-6
     score: str = "sigmoid"
     router_bias: bool = True
+    shared_scale: float = 1.0
+    ranks: int = 1
 
-    OMIT_AT_DEFAULT = ("score", "router_bias")
+    OMIT_AT_DEFAULT = ("score", "router_bias", "shared_scale", "ranks")
 
 
 @dataclass(frozen=True)
 class HeadSpec:
-    """LayerType.OUTPUT as a language model's head: an RMSNorm, then a
-    matrix with no bias whose logits come out in float32 whatever type the
-    weights are kept in."""
+    """LayerType.OUTPUT as a language model's head: a norm (`norm` `rms`, or
+    `layer`: a LayerNorm with a weight and no bias), then a matrix with no
+    bias whose logits come out in float32 whatever type the weights are
+    kept in.  `tied`: the matrix is the EMBEDDING layer's table itself,
+    read in place (the head holds the norm alone).  Both are left out of
+    the conf's JSON at their defaults."""
 
     eps: float = 1e-6
+    norm: str = "rms"
+    tied: bool = False
+
+    OMIT_AT_DEFAULT = ("norm", "tied")
+
+
+@dataclass(frozen=True)
+class ParallelSpec:
+    """LayerType.PARALLEL: attention and an expert FFN side by side off one
+    LayerNorm (a weight, no bias, `eps`), `x + Attn(LN(x)) + MoE(LN(x))`:
+    `attn` is a `GQASpec`, `ffn` a `MoESpec` (their own `eps` unused)."""
+
+    attn: GQASpec
+    ffn: MoESpec
+    eps: float = 1e-5
+
+    @property
+    def scope_kind(self) -> str:
+        """`parallel_window` or `parallel_full`, by the mixer's window."""
+        return "parallel_window" if self.attn.window else "parallel_full"
 
 
 LAYER_SPECS = {c.__name__: c for c in (KDASpec, MLASpec, GQASpec, SwiGLUSpec,
-                                       MoESpec, HeadSpec, ShortConvSpec)}
+                                       MoESpec, HeadSpec, ShortConvSpec,
+                                       ParallelSpec)}
 
 
 def _spec_dict(spec) -> Dict[str, Any]:
-    """A typed spec as JSON: its kind, then its fields, without those of its
-    `OMIT_AT_DEFAULT` that hold their defaults."""
+    """A typed spec as JSON: its kind, then its fields (a spec inside it as
+    one of these), without those of its `OMIT_AT_DEFAULT` that hold their
+    defaults."""
     out = {"kind": type(spec).__name__, **dataclasses.asdict(spec)}
+    for f in dataclasses.fields(spec):
+        value = getattr(spec, f.name)
+        if type(value) in LAYER_SPECS.values():
+            out[f.name] = _spec_dict(value)
     for name in getattr(spec, "OMIT_AT_DEFAULT", ()):
         if out[name] == type(spec).__dataclass_fields__[name].default:
             del out[name]
     return out
+
+
+def _spec_of(d: Dict[str, Any]):
+    """`_spec_dict`'s inverse (JSON has no tuples: a list is one)."""
+    d = dict(d)
+    kind = LAYER_SPECS[d.pop("kind")]
+    for k, v in d.items():
+        if isinstance(v, dict) and "kind" in v:
+            d[k] = _spec_of(v)
+        elif isinstance(v, list):
+            d[k] = tuple(v)
+    return kind(**d)
 
 
 @dataclass(frozen=True)
@@ -425,10 +481,7 @@ class NeuralNetConfiguration:
         if d.get("dist") is not None:
             d["dist"] = Distribution(**d["dist"])
         if d.get("layer_spec") is not None:
-            spec = dict(d["layer_spec"])
-            if spec.get("yarn") is not None:    # JSON has no tuples
-                spec["yarn"] = tuple(spec["yarn"])
-            d["layer_spec"] = LAYER_SPECS[spec.pop("kind")](**spec)
+            d["layer_spec"] = _spec_of(d["layer_spec"])
         for k in ("momentum_after",):
             if k in d and d[k] is not None:
                 d[k] = tuple(tuple(x) for x in d[k])

@@ -300,7 +300,8 @@ def _head_logp(conf: MultiLayerConfiguration, params, x):
     under the layer's scope."""
     last = conf.n_layers - 1
     with layer_scope(last, conf.conf(last)):
-        probs = OutputLayer.forward(params[last], conf.conf(last), x)
+        probs = OutputLayer.forward(OutputLayer.head_params(conf, params),
+                                    conf.conf(last), x)
         return jnp.log(jnp.clip(probs, 1e-9, 1.0))
 
 

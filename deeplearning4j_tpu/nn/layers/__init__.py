@@ -57,7 +57,7 @@ type in `GENERATIVE_HIDDEN`.
 from deeplearning4j_tpu.nn.conf import LayerType
 from deeplearning4j_tpu.nn.layers import (base, output, autoencoder, rbm, lstm,
                                           conv, attention, experts, gqa, kda,
-                                          mla, shortconv)
+                                          mla, parallel, shortconv)
 
 _REGISTRY = {
     LayerType.DENSE: base.DenseLayer,
@@ -81,6 +81,7 @@ _REGISTRY = {
     LayerType.SWIGLU: experts.SwiGLULayer,
     LayerType.MOE: experts.MoELayer,
     LayerType.SHORT_CONV: shortconv.ShortConvLayer,
+    LayerType.PARALLEL: parallel.ParallelLayer,
 }
 
 #: the hidden layer types a generative stack may hold: each class keeps the
@@ -88,7 +89,8 @@ _REGISTRY = {
 GENERATIVE_HIDDEN = (LayerType.LSTM, LayerType.GRAVES_LSTM,
                      LayerType.ATTENTION, LayerType.TRANSFORMER_FFN,
                      LayerType.KDA, LayerType.MLA, LayerType.GQA,
-                     LayerType.SWIGLU, LayerType.MOE, LayerType.SHORT_CONV)
+                     LayerType.SWIGLU, LayerType.MOE, LayerType.SHORT_CONV,
+                     LayerType.PARALLEL)
 
 
 def get_layer(layer_type):

@@ -16,7 +16,8 @@ that chip's part of the layer, without the exchange.
 
 Dropless: every pick of a held expert is computed, at 1 row or at 1024;
 there is no capacity and no token is dropped.  The product has two forms,
-and `experts_form` chooses between them from the call's static shapes.
+and `experts_form` chooses between them from the call's static shapes and
+the ranks whose rows reach the held experts (`MoESpec.ranks`).
 Sorted: the picks are sorted by expert and computed by `jax.lax.ragged_dot`
 over the sorted rows; the picks of absent experts sort to the end and fall
 outside every group.  Where this layer holds a fraction of the experts, its
@@ -35,6 +36,9 @@ Neither keeps a decode state (`base.StatelessDecode`).  `MoELayer.apply`
 also returns two counts of the call, `[picks that landed on held experts,
 distinct held experts hit]`; `MoELayer.counted_step` is the decode step that
 keeps them (`nn.decode.has_experts`), for the programs to hand the batcher.
+`MoELayer.branch` is the expert layer from a normed input to its output,
+with no norm and no residual: what a block that norms once for two branches
+(`parallel.py`) calls.
 """
 
 from __future__ import annotations
@@ -107,8 +111,10 @@ def experts_form(spec, rows: int) -> str:
     so many rows is expected to hit nearly every expert anyway (uniform
     picks: an expert is missed by all rows with probability (1 - top_k /
     n_routed) ** rows) and the rows are few enough for the batched FLOPs to
-    hide behind the read of the weights."""
-    hit_share = 1.0 - (1.0 - spec.top_k / spec.n_routed) ** rows
+    hide behind the read of the weights.  Where the layer is one chip of an
+    expert-parallel group (`spec.ranks`), its held experts serve every
+    rank's rows, so the hit share counts `rows x ranks` of them."""
+    hit_share = 1.0 - (1.0 - spec.top_k / spec.n_routed) ** (rows * spec.ranks)
     if hit_share >= HIT_SHARE_FLOOR and rows <= BATCHED_ROWS_MOST:
         return "batched"
     return "sorted"
@@ -214,8 +220,15 @@ class MoELayer(StatelessDecode):
     @staticmethod
     def apply(params, conf, x):
         """x [.., n] -> (hidden [.., n], counts [2] int32)."""
+        u = pre_norm(params, x, conf.layer_spec.eps)
+        y, counts = MoELayer.branch(params, conf, u)
+        return x.astype(F32) + y, counts
+
+    @staticmethod
+    def branch(params, conf, u):
+        """The normed input u [.., n] -> (the experts' output [.., n]
+        float32, counts [2] int32), no residual."""
         s, cd = conf.layer_spec, compute_dtype(conf)
-        u = pre_norm(params, x, s.eps)
         rows = u.reshape(-1, u.shape[-1])
         with scope("router"):
             scores = _SCORES[s.score](jnp.matmul(
@@ -226,8 +239,11 @@ class MoELayer(StatelessDecode):
         y, counts = held_experts(params, s, cd, rows, ids, weights)
         if s.shared_hidden:
             with scope("shared"):
-                y = y + swiglu(rows, params["sWgu"], params["sWd"], cd)
-        return x.astype(F32) + y.reshape(u.shape), counts
+                shared = swiglu(rows, params["sWgu"], params["sWd"], cd)
+                if s.shared_scale != 1.0:
+                    shared = shared * s.shared_scale
+                y = y + shared
+        return y.reshape(u.shape), counts
 
     @staticmethod
     def forward(params, conf, x, key=None, training=False):

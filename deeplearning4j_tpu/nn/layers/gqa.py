@@ -4,11 +4,14 @@ RMSNorm, and has no bias.
 
 With u = RMSNorm(x): q = Wq u in [H, h], k = Wk u and v = Wv u in [G, h],
 G = n_kv_heads; where `qk_norm`, an RMSNorm over h on each head of q and of
-k; rotate-half rotary positions on q and k (plain, or YaRN's frequencies and
-scale where the spec gives them).  Query head j reads K/V head j // (H // G).
+k; rotary positions on q and k by `rotary` (rotate-half, or interleaved
+pairs; plain, or YaRN's frequencies and scale where the spec gives them;
+`none`: no positions).  Query head j reads K/V head j // (H // G).
 Scores q . k / sqrt(h), float32 softmax over the keys a token may see: every
 earlier position and its own (`window` 0), or its own and the `window - 1`
-before it.  Then Wo.
+before it.  Then Wo.  `branch_prefill` and `branch_step` are the same
+attention from a normed input to Wo's output, with no norm and no residual:
+what a block that norms once for two branches (`parallel.py`) calls.
 
 Decode state, a row a slot, in the compute dtype, K/V heads before positions
 so that a head's cells lie together:
@@ -26,13 +29,14 @@ past a row's block: `pallas_kernels.gqa_decode` walks each row's cells up to
 its position where they lie (`decode_kernel`, `kv_read_block`); off it the
 step reads the whole table under the mask, the kernel's oracle.
 
-`prefill` attends a block of `Q_BLOCK` queries at a time, each against the
-keys it may see and no others (all earlier ones for a full layer, at most
-`window + Q_BLOCK` for a window layer): the scores of a long prompt are
-never whole in memory.  A full layer writes its T keys and values to cells
-0..T-1; padding needs no care there (cells past a row's length are never
-seen by a real token and are overwritten before they are read).  A window
-layer gathers into the ring, for every cell, the newest real position of
+`prefill` attends a block of `q_block(n_heads)` queries at a time, each
+against the keys it may see and no others (all earlier ones for a full
+layer, at most `window` more for a window layer): the scores of a long
+prompt are never whole in memory, and a block's rows of scores, heads times
+queries, are `SCORE_ROWS` at most.  A full layer writes its T keys and
+values to cells 0..T-1; padding needs no care there (cells past a row's
+length are never seen by a real token and are overwritten before they are
+read).  A window layer gathers into the ring, for every cell, the newest real position of
 its residue, `length - 1 - (length - 1 - c) % window`: the prompt's last
 `min(length, window)` real positions, and no padding.
 
@@ -54,9 +58,15 @@ from deeplearning4j_tpu.nn.layers.rms import (F32, initializer, mm, pre_norm,
                                               precision_of, rms_norm, rope)
 from deeplearning4j_tpu.utils.profiling import scope
 
-#: queries a block of `prefill`; at 32 heads a block's float32 scores
-#: against 8192 keys are 1 GiB
-Q_BLOCK = 1024
+#: float32 rows of scores a block of `prefill` holds, heads x queries: at
+#: 32 heads a block is 1,024 queries, whose scores against 8,192 keys are
+#: 1 GiB; at 128 heads 256
+SCORE_ROWS = 32 * 1024
+
+
+def q_block(n_heads: int) -> int:
+    """Queries a block of `prefill`, from the heads alone."""
+    return SCORE_ROWS // n_heads
 
 
 class GQALayer:
@@ -124,25 +134,27 @@ class GQALayer:
         return {"k": jnp.zeros(shape, cd), "v": jnp.zeros(shape, cd)}
 
     @staticmethod
-    def _project(params, conf, x, positions):
-        """x [..., n] at `positions` [...] -> q [..., G, H/G, h] in float32
-        (rotated, scaled by 1/sqrt(h)) and, in the cache's type, k and v
-        [..., G, h] (k rotated)."""
+    def _project(params, conf, u, positions):
+        """The normed input u [..., n] at `positions` [...] -> q [..., G,
+        H/G, h] in float32 (rotated, scaled by 1/sqrt(h)) and, in the
+        cache's type, k and v [..., G, h] (k rotated)."""
         s, cd = conf.layer_spec, compute_dtype(conf)
         g = s.n_kv_heads
-        u = pre_norm(params, x, s.eps)
         with scope("qkv"):
-            q = mm(u, params["Wq"], cd).reshape(x.shape[:-1] + (s.n_heads, -1))
-            k = mm(u, params["Wk"], cd).reshape(x.shape[:-1] + (g, -1))
-            v = mm(u, params["Wv"], cd).reshape(x.shape[:-1] + (g, -1))
+            q = mm(u, params["Wq"], cd).reshape(u.shape[:-1] + (s.n_heads, -1))
+            k = mm(u, params["Wk"], cd).reshape(u.shape[:-1] + (g, -1))
+            v = mm(u, params["Wv"], cd).reshape(u.shape[:-1] + (g, -1))
             if s.qk_norm:
                 q = rms_norm(q, params["q_norm"], s.eps)
                 k = rms_norm(k, params["k_norm"], s.eps)
-        with scope("rope"):
-            at = positions[..., None]
-            q = rope(q, at, s.rope_theta, s.yarn) / math.sqrt(s.head_dim)
-            k = rope(k, at, s.rope_theta, s.yarn)
-        q = q.reshape(x.shape[:-1] + (g, s.n_heads // g, s.head_dim))
+        if s.rotary == "none":
+            q = q / math.sqrt(s.head_dim)
+        else:
+            with scope("rope"):
+                at, pairs = positions[..., None], s.rotary == "pairs"
+                q = rope(q, at, s.rope_theta, s.yarn, pairs) / math.sqrt(s.head_dim)
+                k = rope(k, at, s.rope_theta, s.yarn, pairs)
+        q = q.reshape(u.shape[:-1] + (g, s.n_heads // g, s.head_dim))
         return q, k.astype(cd), v.astype(cd)
 
     @staticmethod
@@ -168,11 +180,20 @@ class GQALayer:
 
     @staticmethod
     def prefill(params, conf, x, state, length):
-        s, cd = conf.layer_spec, compute_dtype(conf)
         b, t, _ = x.shape
+        at = jnp.broadcast_to(jnp.arange(t), (b, t))
+        u = pre_norm(params, x, conf.layer_spec.eps)
+        y, state = GQALayer.branch_prefill(params, conf, u, at, state, length)
+        return x.astype(F32) + y, state
+
+    @staticmethod
+    def branch_prefill(params, conf, u, positions, state, length):
+        """`prefill` from the normed prompt u [B, T, n] at `positions`
+        [B, T]: (Wo's output [B, T, n] float32, state), no residual."""
+        s, cd = conf.layer_spec, compute_dtype(conf)
+        b, t, _ = u.shape
         w = s.window
-        q, k, v = GQALayer._project(
-            params, conf, x, jnp.broadcast_to(jnp.arange(t), (b, t)))
+        q, k, v = GQALayer._project(params, conf, u, positions)
         q = jnp.moveaxis(q, 1, 3)                               # [B, G, R, T, h]
         k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)     # [B, G, T, h]
         with scope("kv_write"):
@@ -191,9 +212,9 @@ class GQALayer:
                 state = {
                     "k": jax.lax.dynamic_update_slice(state["k"], k, (0, 0, 0, 0)),
                     "v": jax.lax.dynamic_update_slice(state["v"], v, (0, 0, 0, 0))}
-        out = []
-        for start in range(0, t, Q_BLOCK):
-            stop = min(start + Q_BLOCK, t)
+        out, block = [], q_block(s.n_heads)
+        for start in range(0, t, block):
+            stop = min(start + block, t)
             lo = max(0, start - w) if w else 0
             qp = jnp.arange(start, stop)[:, None]
             kp = jnp.arange(lo, stop)[None, :]
@@ -205,15 +226,22 @@ class GQALayer:
                 v[:, :, lo:stop], seen))
         o = out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
         with scope("proj"):
-            y = mm(o, params["Wo"], cd)
-        return x.astype(F32) + y, state
+            return mm(o, params["Wo"], cd), state
 
     @staticmethod
     def decode_step(params, conf, x, state, pos):
+        u = pre_norm(params, x, conf.layer_spec.eps)
+        y, state = GQALayer.branch_step(params, conf, u, state, pos)
+        return x.astype(F32) + y, state
+
+    @staticmethod
+    def branch_step(params, conf, u, state, pos):
+        """`decode_step` from the normed token u [B, n]: (Wo's output [B, n]
+        float32, state), no residual."""
         s, cd = conf.layer_spec, compute_dtype(conf)
-        b = x.shape[0]
+        b = u.shape[0]
         cells = state["k"].shape[2]
-        q, k, v = GQALayer._project(params, conf, x, pos)
+        q, k, v = GQALayer._project(params, conf, u, pos)
         with scope("kv_write"):
             # one cell a (row, K/V head): scattered with both indices on the
             # leading axes, the table is written where it lies (with the
@@ -244,8 +272,7 @@ class GQALayer:
                     seen = seen | (pos[:, None] >= cells)
             o = GQALayer._attend(conf, q, k_all, v_all, seen[:, None, None, :])
         with scope("proj"):
-            y = mm(o, params["Wo"], cd)
-        return x.astype(F32) + y, {"k": k_all, "v": v_all}
+            return mm(o, params["Wo"], cd), {"k": k_all, "v": v_all}
 
     @staticmethod
     def forward(params, conf, x, key=None, training=False):

@@ -14,15 +14,20 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nd import losses as L
 from deeplearning4j_tpu.nd.ops import activate
 from deeplearning4j_tpu.nn.layers.base import DenseLayer, compute_dtype
-from deeplearning4j_tpu.nn.layers.rms import mm, rms_norm
+from deeplearning4j_tpu.nn.layers.rms import (F32, layer_norm, mm, precision_of,
+                                              rms_norm)
 from deeplearning4j_tpu.utils.profiling import scope
 
 
 class OutputLayer(DenseLayer):
     @staticmethod
     def init(key, conf):
+        spec = conf.layer_spec
+        if spec is not None and spec.tied:
+            # the matrix is the embedding's table: the head holds its norm
+            return {"norm": jnp.ones((conf.n_in,), jnp.dtype(conf.dtype))}
         params = DenseLayer.init(key, conf)
-        if conf.layer_spec is None:
+        if spec is None:
             return params
         # a language model's head (`HeadSpec`): its norm, a matrix, no bias
         return {"W": params["W"],
@@ -37,12 +42,41 @@ class OutputLayer(DenseLayer):
 
     @staticmethod
     def _lm_head(params, conf, x):
-        """Under a `HeadSpec`: RMSNorm, then logits in float32 from weights
-        of any type, then softmax."""
+        """Under a `HeadSpec`: its norm, then logits in float32 from weights
+        of any type (a tied head's are the table `E` [vocab, n] that
+        `head_params` hands it, read as it lies: x . E^T), then softmax."""
+        spec, cd = conf.layer_spec, compute_dtype(conf)
         with scope("ln"):
-            x = rms_norm(x, params["norm"], conf.layer_spec.eps)
+            if spec.norm == "layer":
+                x = layer_norm(x, params["norm"], None, spec.eps)
+            else:
+                x = rms_norm(x, params["norm"], spec.eps)
         with scope("head"):
-            return activate("softmax", mm(x, params["W"], compute_dtype(conf)))
+            if spec.tied:
+                logits = jax.lax.dot_general(
+                    x.astype(cd), params["E"].astype(cd),
+                    (((x.ndim - 1,), (1,)), ((), ())),
+                    precision=precision_of(cd), preferred_element_type=F32)
+            else:
+                logits = mm(x, params["W"], cd)
+            return activate("softmax", logits)
+
+    @staticmethod
+    def head_params(conf, params) -> dict:
+        """The head's parameters as `forward` takes them, from the whole
+        stack's `params`: its own, and under a tied `HeadSpec` the first
+        layer's (the EMBEDDING's) table beside them as `E`, the one array
+        and no copy."""
+        last = conf.n_layers - 1
+        if not getattr(conf.conf(last).layer_spec, "tied", False):
+            return params[last]
+        from deeplearning4j_tpu.nn.conf import LayerType
+
+        if LayerType(str(conf.conf(0).layer_type)) != LayerType.EMBEDDING:
+            raise ValueError("a tied head reads the first layer's table, and "
+                             f"the first layer is {conf.conf(0).layer_type}, "
+                             "not an EMBEDDING")
+        return {**params[last], "E": params[0]["W"]}
 
     @staticmethod
     def _head(params, conf, x, key, training):
@@ -73,10 +107,13 @@ class OutputLayer(DenseLayer):
     def loss(params, conf, x, labels, key=None, training=False):
         out = OutputLayer.forward(params, conf, x, key, training)
         with scope("loss"):
-            l2n = jnp.sum(params["W"].astype(jnp.float32) ** 2)
+            # a tied head's table is the embedding's, whose term the stack
+            # counts with that layer
+            l2n = (jnp.sum(params["W"].astype(jnp.float32) ** 2)
+                   if "W" in params else 0.0)
             l2 = conf.l2 if conf.use_regularization else 0.0
             s = L.score(labels, conf.loss_function, out, l2, l2n)
-            if conf.use_regularization and conf.l1:
+            if conf.use_regularization and conf.l1 and "W" in params:
                 s = s + conf.l1 * jnp.sum(
                     jnp.abs(params["W"].astype(jnp.float32)))
         return s

@@ -1,7 +1,7 @@
-"""What the RMSNorm-family layer types share (`kda`, `mla`, `swiglu`, `moe`,
-`shortconv`, and the OUTPUT layer under a `HeadSpec`): the norms, rotary
-positions, the one matrix product they all take, and the causal convolution
-with a carried tail of `kda` and `shortconv`.
+"""What the RMSNorm-family layer types share (`kda`, `mla`, `gqa`, `swiglu`,
+`moe`, `shortconv`, `parallel`, and the OUTPUT layer under a `HeadSpec`): the
+norms, rotary positions, the one matrix product they all take, and the causal
+convolution with a carried tail of `kda` and `shortconv`.
 
 Their precision contract: parameters are kept in `conf.dtype`, matrix
 products take operands in `compute_dtype(conf)` and accumulate in float32,
@@ -50,11 +50,12 @@ def rms_norm(x, g, eps: float):
 
 
 def layer_norm(x, g, b, eps: float):
-    """LayerNorm with a weight and a bias, in float32."""
+    """LayerNorm with a weight and a bias (`b` None: none), in float32."""
     x = x.astype(F32)
     x = x - jnp.mean(x, axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-                              + eps) * g.astype(F32) + b.astype(F32))
+    y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                          + eps) * g.astype(F32)
+    return y if b is None else y + b.astype(F32)
 
 
 def pre_norm(params, x, eps: float):
@@ -88,23 +89,32 @@ def yarn_frequencies(n: int, theta: float, yarn):
     return jnp.asarray(inv, F32), float(attention_factor)
 
 
-def rope(x, positions, theta: float, yarn=None):
-    """Rotate-half rotary embedding over the whole last axis of `x`;
-    `positions` broadcasts against x's leading axes (x [..., n], positions
-    [...]).  With `yarn` the frequencies and the scale of cos and sin are
-    `yarn_frequencies`'."""
+def rope(x, positions, theta: float, yarn=None, pairs: bool = False):
+    """Rotary embedding over the whole last axis of `x`; `positions`
+    broadcasts against x's leading axes (x [..., n], positions [...]).
+    Rotate-half (dimension m turns with m + n/2 by angle m), or with `pairs`
+    interleaved (dimensions 2m and 2m + 1 turn together by angle m, GPT-J's
+    pairing); angle m is `position * theta^(-2m/n)`.  With `yarn` the
+    frequencies and the scale of cos and sin are `yarn_frequencies`'."""
     n = x.shape[-1]
     if yarn is None:
         inv, scale = theta ** (-jnp.arange(0, n, 2, dtype=F32) / n), None
     else:
         inv, scale = yarn_frequencies(n, theta, yarn)
     ang = positions.astype(F32)[..., None] * inv
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+    if pairs:
+        cos = jnp.repeat(jnp.cos(ang), 2, axis=-1)
+        sin = jnp.repeat(jnp.sin(ang), 2, axis=-1)
+    else:
+        cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
+        sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
     if scale is not None:
         cos, sin = cos * scale, sin * scale
-    half = jnp.concatenate([-x[..., n // 2:], x[..., : n // 2]], axis=-1)
-    return x * cos + half * sin
+    if pairs:
+        turned = jnp.stack([-x[..., 1::2], x[..., 0::2]], axis=-1).reshape(x.shape)
+    else:
+        turned = jnp.concatenate([-x[..., n // 2:], x[..., : n // 2]], axis=-1)
+    return x * cos + turned * sin
 
 
 def rope_first(x, positions, theta: float, n: int):

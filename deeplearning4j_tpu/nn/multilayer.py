@@ -93,11 +93,15 @@ def feed_forward(conf: MultiLayerConfiguration, params, x, key=None,
     acts = []
     keys = (jax.random.split(key, max(1, n)) if key is not None
             else [None] * max(1, n))
+    from deeplearning4j_tpu.nn.layers.output import OutputLayer
+
     for i in range(n):
         c = conf.conf(i)
         x = apply_preprocessor(conf.preprocessor(i), x)
-        x = _layer_forward(get_layer(c.layer_type), c, params[i], x,
-                           keys[i], training, i)
+        p = (OutputLayer.head_params(conf, params) if i == conf.n_layers - 1
+             else params[i])
+        x = _layer_forward(get_layer(c.layer_type), c, p, x, keys[i],
+                           training, i)
         acts.append(x)
     return acts
 
@@ -123,8 +127,8 @@ def network_loss(conf: MultiLayerConfiguration, params, x, labels, key=None,
     out_conf = conf.conf(n - 1)
     h = apply_preprocessor(conf.preprocessor(n - 1), h)
     with profiling.layer_scope(n - 1, out_conf):
-        loss = OutputLayer.loss(params[n - 1], out_conf, h, labels,
-                                keys[n - 1], training)
+        loss = OutputLayer.loss(OutputLayer.head_params(conf, params), out_conf,
+                                h, labels, keys[n - 1], training)
     if out_conf.use_regularization and out_conf.l2:
         for i in range(n - 1):
             if "W" in params[i]:
@@ -176,8 +180,9 @@ def network_rowwise_loss(conf: MultiLayerConfiguration, params, x, labels,
     out_conf = conf.conf(n - 1)
     h = apply_preprocessor(conf.preprocessor(n - 1), h)
     with profiling.layer_scope(n - 1, out_conf):
-        rows = OutputLayer.rowwise_loss(params[n - 1], out_conf, h, labels,
-                                        keys[n - 1], training)
+        rows = OutputLayer.rowwise_loss(OutputLayer.head_params(conf, params),
+                                        out_conf, h, labels, keys[n - 1],
+                                        training)
     if return_bn_stats:
         return rows, tuple(stats)
     return rows

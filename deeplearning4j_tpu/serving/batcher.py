@@ -816,6 +816,13 @@ class ContinuousBatcher:
         # count their state in cells a position
         self._kv_cells = Counter(
             decode_mod.kv_cells(net.conf, self.max_seq))
+        # of those, the rings: a table whose step reads fewer cells than a
+        # row has positions holds a window's, `{cells: tables}` (an indexed
+        # table NEEDS fewer, its picks, but is read whole)
+        self._rings = Counter()
+        for (_, read, _, _), n in self._kv_cells.items():
+            if read < self.max_seq:
+                self._rings[read] += n
         # {kind: bytes a decode step reads of one row's state}, or None
         self._state_bytes = decode_mod.state_bytes_read(net.conf, self.max_seq)
         # {positions a layer's step picks among a row's cached ones: layers}
@@ -980,6 +987,8 @@ class ContinuousBatcher:
         # K/V cells the steps needed, and cells their whole-state reads covered
         self._kv_live = 0
         self._kv_spanned = 0
+        self._ring_live = 0
+        self._ring_spanned = 0
         self._dsa_live = 0
         self._dsa_selected = 0
 
@@ -1834,7 +1843,10 @@ class ContinuousBatcher:
         the totals of `stats()`.  Beside them `state_bytes_read`: what the
         steps read of every row's state, the spanned cells' bytes and every
         carry whole (for a stack whose layers say it:
-        `nn.decode.state_bytes_read`)."""
+        `nn.decode.state_bytes_read`); and for the rings among the tables
+        (a window's, fewer cells than `max_seq`), read whole a step,
+        `ring_cells_live` (`min(position + 1, window)` a live row, ring and
+        step) and `ring_cells_spanned` (`steps x slots x window` a ring)."""
         p, a = np.asarray(pos, np.int64), np.asarray(adv, np.int64)
         live = spanned = table_bytes = 0
         for (most, read, block, cell), n in self._kv_cells.items():
@@ -1854,6 +1866,15 @@ class ContinuousBatcher:
             self._kv_live += live
             self._kv_spanned += spanned
         self._note_dsa(sp, p, a)
+        if not self._rings:
+            return
+        live = sum(n * self._cells_needed(p, a, w) for w, n in self._rings.items())
+        spanned = sum(n * steps * self.n_slots * w for w, n in self._rings.items())
+        sp.set(ring_cells_live=sp.attrs.get("ring_cells_live", 0) + live,
+               ring_cells_spanned=sp.attrs.get("ring_cells_spanned", 0) + spanned)
+        with self._cv:
+            self._ring_live += live
+            self._ring_spanned += spanned
 
     def _note_dsa(self, sp: span, pos, adv) -> None:
         """The same dispatch's selection, for a stack with layers whose step
@@ -2289,6 +2310,9 @@ class ContinuousBatcher:
                 if self._dsa_picks:
                     out["dsa_cells_live_total"] = self._dsa_live
                     out["dsa_cells_selected_total"] = self._dsa_selected
+                if self._rings:
+                    out["ring_cells_live_total"] = self._ring_live
+                    out["ring_cells_spanned_total"] = self._ring_spanned
         if self.paged:
             with self._cv:
                 live_tokens = sum(

@@ -13,8 +13,8 @@ from deeplearning4j_tpu.nn import decode, layers
 from deeplearning4j_tpu.nn.conf import (Activation, GQASpec, KDASpec, LayerType,
                                         LossFunction, MLASpec, MoESpec,
                                         MultiLayerConfiguration,
-                                        NeuralNetConfiguration, ShortConvSpec,
-                                        SwiGLUSpec)
+                                        NeuralNetConfiguration, ParallelSpec,
+                                        ShortConvSpec, SwiGLUSpec)
 from deeplearning4j_tpu.nn.layers import get_layer
 from deeplearning4j_tpu.nn.multilayer import init_params
 
@@ -35,6 +35,12 @@ SPECS = {
     LayerType.MOE: dict(layer_spec=MoESpec(
         n_routed=8, n_held=4, hidden=8, shared_hidden=8, top_k=2, n_group=2)),
     LayerType.SHORT_CONV: dict(layer_spec=ShortConvSpec(kernel=3)),
+    # a ring of 4 cells with interleaved rotary beside experts and two
+    # shared ones averaged, off one LayerNorm
+    LayerType.PARALLEL: dict(layer_spec=ParallelSpec(
+        attn=GQASpec(n_heads=4, n_kv_heads=2, head_dim=4, window=4, rotary="pairs"),
+        ffn=MoESpec(n_routed=8, n_held=4, hidden=8, shared_hidden=16, top_k=2,
+                    router_bias=False, shared_scale=0.5))),
 }
 
 

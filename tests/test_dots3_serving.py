@@ -170,6 +170,10 @@ def test_decode_spans_carry_the_cells_and_the_selection(f32, served):
         + hand_count(PROMPTS, NEW, 5))
     assert 0 < stats["dsa_cells_selected_total"] < stats["dsa_cells_live_total"]
     assert stats["kernel_layers"] == 0          # off the chip: the masked read
+    # the rings: the window layer's 5 cells alone; the indexed tables need
+    # their 8 picks but are read whole, so are none
+    assert stats["ring_cells_live_total"] == hand_count(PROMPTS, NEW, 5)
+    assert stats["ring_cells_spanned_total"] == 2 * 5 * len(steps)
 
 
 def test_fused_blocks_serve_the_same_tokens_and_count_the_same(f32, served):

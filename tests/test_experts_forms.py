@@ -4,6 +4,8 @@ as one batched product) hold each other equal at toy widths in float32, and
 the rule that chooses between them from static shapes gives what PERF.md's
 table says for every program a cell runs."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -83,6 +85,12 @@ HIT_EDGE = 18
     ("the first row count at the hit share's floor", MELLUM, HIT_EDGE, "batched"),
     ("the last under it", MELLUM, HIT_EDGE - 1, "sorted"),
     ("a quarter held: the routed experts count, not the held", spec_of(16, 4, 4, 8), 16, "batched"),
+    ("Command A+ decode, 32 slots on this chip alone: 87.3 % expected",
+     spec_of(128, 16, 8), 32, "sorted"),
+    ("Command A+ decode, 32 slots on each of 8 ranks: 32 x 8 rows' picks",
+     dataclasses.replace(spec_of(128, 16, 8), ranks=8), 32, "batched"),
+    ("Command A+ admission, bucket 4,096, on each of 8 ranks",
+     dataclasses.replace(spec_of(128, 16, 8), ranks=8), 4096, "sorted"),
 ])
 def test_the_rule_of_the_form(what, spec, rows, form):
     assert experts.experts_form(spec, rows) == form, what

@@ -26,7 +26,7 @@ YARN = (16.0, 8192.0, 32.0, 1.0, 1.2772588722239782)
 def small_blocks(monkeypatch):
     """Blocks of 8 queries, so that a sequence of 24 is three blocks and a
     window layer's block meets a band of at most 16 keys."""
-    monkeypatch.setattr(gqa, "Q_BLOCK", 8)
+    monkeypatch.setattr(gqa, "q_block", lambda n_heads: 8)
 
 
 @pytest.mark.parametrize("kind", ["window", "full"])
@@ -48,10 +48,10 @@ def test_blocks_of_queries_change_nothing(f32, monkeypatch):
     for kind in ("window", "full"):
         i = f32.layer(kind)
         whole = GQALayer.forward(f32.params[i], f32.conf.conf(i), x)
-        monkeypatch.setattr(gqa, "Q_BLOCK", 8)
+        monkeypatch.setattr(gqa, "q_block", lambda n_heads: 8)
         np.testing.assert_allclose(GQALayer.forward(f32.params[i], f32.conf.conf(i), x),
                                    whole, atol=TIGHT)
-        monkeypatch.setattr(gqa, "Q_BLOCK", 1024)
+        monkeypatch.undo()
 
 
 def test_yarn_frequencies_and_factor_are_the_formulas():

@@ -528,3 +528,58 @@ def test_a_decode_kernel_runs_whole_on_each_chip_of_a_model_axis(topo, one_chip,
     text = compiled.as_text()
     assert f"dl4j_{kernel}_decode" in text
     assert not re.search(rf"= {table}\S* (all-gather|copy|transpose)", text)
+
+
+def test_a_decode_kernel_of_16_query_heads_a_kv_head_compiles(one_chip):
+    """`dl4j_gqa_decode` at Command A+'s full layer: 128 query heads over 8
+    K/V heads of 128 (a group of 16), 32 rows of a table of 10,240 cells
+    walked in blocks of 512, the blocks in fast memory."""
+    text = _compile(lambda q, k, v, p: pk.gqa_decode(q, k, v, p), one_chip,
+                    ((32, 8, 16, 128), jnp.bfloat16), ((32, 8, 10240, 128), jnp.bfloat16),
+                    ((32, 8, 10240, 128), jnp.bfloat16), ((32,), jnp.int32))
+    assert "dl4j_gqa_decode" in text and pk.walk_block(10240) == 512
+
+
+def _commanda_stack():
+    """Command A+'s stack cut to one window layer and its full layer, at
+    every published width and this rank's 16 experts and 32,768 ids."""
+    import json
+
+    from benchmark.families.cohere2_moe.program import build_conf
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "command-a-plus-ep8.json")) as f:
+        cfg = json.load(f)
+    return build_conf({**cfg, "num_hidden_layers": 2,
+                       "layer_types": ["sliding_attention", "full_attention"]})
+
+
+def test_the_parallel_blocks_decode_program_at_32_slots(one_chip, monkeypatch):
+    """The batcher's decode program of the parallel stack at the cell's 32
+    slots and 10,240 positions, its state donated, on the chip's path: the
+    full layer walks its table through `dl4j_gqa_decode`, neither the ring
+    (`[32, 8, 4096, 128]`) nor the table (`[32, 8, 10240, 128]`) is copied,
+    and the step's temporaries are under a tenth of a GiB."""
+    from deeplearning4j_tpu.nn import decode
+    from deeplearning4j_tpu.nn.layers import gqa
+    from deeplearning4j_tpu.nn.multilayer import init_params
+    from deeplearning4j_tpu.optimize.infer_cache import _decode_program
+
+    monkeypatch.setattr(pk, "is_tpu", lambda: True)
+    monkeypatch.setattr(gqa, "is_tpu", lambda: True)
+    conf = _commanda_stack()
+    assert decode.kernel_layers(conf, 10240) == 1
+    shaped = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = shaped(jax.eval_shape(lambda k: init_params(conf, k), jax.random.PRNGKey(0)))
+    state = shaped(jax.eval_shape(lambda: decode.init_state(conf, 32, 10240)))
+    assert [lay["k"].shape for lay in state if lay] == [(32, 8, 4096, 128), (32, 8, 10240, 128)]
+    rows = lambda dt, *shape: shaped(jax.ShapeDtypeStruct((32,) + shape, dt))  # noqa: E731
+    compiled = jax.jit(_decode_program(conf), donate_argnums=(1,)).lower(
+        params, state, rows(jnp.int32), rows(jnp.int32), rows(jnp.uint32, 2),
+        rows(jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "dl4j_gqa_decode" in text
+    for cells in (32 * 8 * 4096 * 128, 32 * 8 * 10240 * 128):
+        assert _table_copies(text, "bf16", cells) == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * (1 << 30)
