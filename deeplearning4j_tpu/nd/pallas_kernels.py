@@ -730,12 +730,24 @@ def scatter_add_rows(table, indices, updates,
 #: while the block before it is scored
 DSA_BLOCK = 512
 
+#: bytes of a block of one table (K, V, or the latents) at the most: 1 MiB
+#: of K of 8 heads of 128 in bfloat16.  Double buffered for two rows, K and
+#: V take 8 MiB of the 16 MiB of fast memory a kernel may hold
+#: (`VMEM_LIMIT_BYTES`); blocks of 512 cells of 16 such heads would take all
+#: of it
+WALK_TILE_BYTES = 1 << 20
 
-def walk_block(cells: int) -> int:
-    """The cells a decode kernel fetches at a time from a table of `cells`:
-    `DSA_BLOCK`, or the largest power of two under it that divides `cells`.
-    A row at position p reads its first ceil((p + 1) / block) blocks."""
-    return math.gcd(cells, DSA_BLOCK)
+
+def walk_block(cells: int, cell_bytes: int) -> int:
+    """The cells a decode kernel fetches at a time from a table of `cells`
+    whose cells take `cell_bytes` each: `DSA_BLOCK`, or the largest power
+    of two under it that divides `cells` and keeps a block within
+    `WALK_TILE_BYTES`.  A row at position p reads its first
+    ceil((p + 1) / block) blocks."""
+    block = math.gcd(cells, DSA_BLOCK)
+    while block > 1 and block * cell_bytes > WALK_TILE_BYTES:
+        block //= 2
+    return block
 
 
 def _dsa_decode_kernel(pos_ref, q_ref, mask_ref, table_ref, out_ref, buf, sem,
@@ -812,13 +824,12 @@ def dsa_decode(q, table, picked, pos, scale: float,
     """sum_s softmax_s(q . table[s] * scale over the picked s) table[s], a
     head at a time: q [rows, H, C], table [rows, cells, C], picked [rows,
     cells] bool (no cell past a row's `pos` picked), pos [rows] int32 ->
-    [rows, H, C] float32.  Blocks of `DSA_BLOCK` cells (or of the largest
-    power of two under it that divides `cells`) are fetched at a time, and a
-    row reads its first ceil((pos + 1) / block) blocks alone: its live
-    prefix, not `cells`."""
+    [rows, H, C] float32.  Blocks of `walk_block` cells are fetched at a
+    time, and a row reads its first ceil((pos + 1) / block) blocks alone:
+    its live prefix, not `cells`."""
     rows, h, c = q.shape
     cells = table.shape[1]
-    block = walk_block(cells)
+    block = walk_block(cells, c * jnp.dtype(table.dtype).itemsize)
     mask = picked.astype(jnp.int8).reshape(rows, cells // block, 1, block)
     precision = (lax.Precision.HIGHEST if jnp.dtype(table.dtype) == jnp.float32
                  else lax.Precision.DEFAULT)
@@ -936,13 +947,13 @@ def gqa_decode(q, k, v, pos, cells_last: bool = False,
     """A decode step's grouped-query attention over a table: q [rows, G, R,
     h] (scaled), k and v [rows, G, cells, h], or [rows, G, h, cells] where
     `cells_last`, pos [rows] int32 -> sum_{c <= pos} softmax_c(q . k[c])
-    v[c], [rows, G, R, h] float32.  Blocks of `walk_block(cells)` cells are
+    v[c], [rows, G, R, h] float32.  Blocks of `walk_block` cells are
     fetched at a time, every K/V head of a row at once, and a row reads its
     first ceil((pos + 1) / block) blocks alone: its live prefix, not
     `cells`."""
     rows, g, r, h = q.shape
     cells = k.shape[3 if cells_last else 2]
-    block = walk_block(cells)
+    block = walk_block(cells, g * h * jnp.dtype(k.dtype).itemsize)
     precision = (lax.Precision.HIGHEST if jnp.dtype(k.dtype) == jnp.float32
                  else lax.Precision.DEFAULT)
     kernel = functools.partial(_gqa_decode_kernel, block=block,

@@ -5,7 +5,7 @@ two compiled programs instead of re-running the whole prefix every token:
 
   prefill      run the prompt once through the normal sequence forward,
                recording attention K/V rows into pre-allocated
-               [B, max_S, n] caches (LSTM carries (h, c) the same way),
+               [B, H, max_S, hd] caches (LSTM carries (h, c) the same way),
                and return the next-token log-probs at each row's last
                real prompt position.
   decode_step  advance every row by ONE token against the recorded
@@ -25,7 +25,7 @@ and never asks a layer's type to know what its state is or how to step it.
 
 State layout: one dict per layer, in layer order, as a tuple —
   LSTM/GRAVES_LSTM  {"h": [B, H] f32, "c": [B, H] f32}
-  ATTENTION         {"k": [B, max_S, n] compute_dtype, "v": same}
+  ATTENTION         {"k": [B, H, max_S, hd] compute_dtype, "v": same}
   KDA               {"S": [B, H, dk, dv] f32, "conv": [B, K-1, 3 H dk]}
   MLA               {"c": [B, max_S, rank], "kr": [.., rope]}, or side by
                     side {"ckr": [B, max_S or window, rank + rope]} and
@@ -43,7 +43,7 @@ argument whose leaves keep their shapes/dtypes across steps, so the
 compiled step can alias its cache buffers in place.
 
 Paged variant (ISSUE 16): `init_paged_state` replaces each ATTENTION
-layer's dense [B, max_S, n] table with a shared physical page pool
+layer's dense [B, H, max_S, hd] table with a shared physical page pool
   ATTENTION         {"k": [n_pages, page_size, n], "v": same}
 addressed through a per-call `page_table` [B, pages_per_slot] int32 of
 physical page ids — cache memory scales with LIVE pages, not

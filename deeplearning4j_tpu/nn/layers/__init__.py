@@ -47,6 +47,9 @@ What only some types can do, a class declares by having it:
     kv_cell_bytes(conf, max_seq)                -> int, or one a table
             beside `kv_cells`: the bytes of one cell as the state lays it
             out in memory
+    SPLIT_HEADS   its `k` and `v` tables lie [B, H, max_S, hd], and a plan
+            with a `model` axis splits them on the heads' axis
+            (`parallel.plan.ShardPlan.state_pspecs`)
 A type with a state but without `init_paged_state` and `verify_chunk` lives
 in the dense slot table only (`nn.decode.dense_only`).  A type with no state
 gets all of it from `base.StatelessDecode`.  A new kind of state is a layer

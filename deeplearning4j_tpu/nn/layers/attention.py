@@ -11,11 +11,14 @@ any other layer.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nd import random as ndr
 from deeplearning4j_tpu.nd.platform import is_tpu
+from deeplearning4j_tpu.nn.layers import gqa
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.nn.layers.base import (StatelessDecode,
                                                 compute_dtype, mixed_matmul)
@@ -135,10 +138,48 @@ class MultiHeadAttentionLayer:
 
     # -- decode protocol (`nn/layers/__init__.py`): a K/V table, or pages ----
     CARRY = False       # a finished row rewrites one cell with what it holds
+    SPLIT_HEADS = True  # a `model` axis splits the table's heads (`parallel.plan`)
+
+    @staticmethod
+    def kv_cells(conf, max_seq: int) -> int:
+        """Positions a row's table holds: one cell a position."""
+        return max_seq
+
+    @staticmethod
+    def kv_cells_read(conf, max_seq: int) -> int:
+        """The most cells `decode_step` reads of a row: the table, masked
+        whole, or walked up to the row's position (`kv_read_block`)."""
+        return max_seq
+
+    @staticmethod
+    def kv_read_block(conf, max_seq: int) -> int:
+        """The block in which `decode_step` walks a row's cells up to its
+        position (`decode_kernel`), or 0 where it reads them all."""
+        h = conf.n_heads
+        return (gqa.walk_block(max_seq, h, conf.n_in // h, compute_dtype(conf))
+                if MultiHeadAttentionLayer.decode_kernel(conf, max_seq) else 0)
+
+    @staticmethod
+    def decode_kernel(conf, max_seq: int) -> bool:
+        """Does `decode_step` attend to the table through `dl4j_gqa_decode`,
+        as a grouped-query layer with a K/V head a query head does
+        (`gqa.walks_table`).  Under a plan with a `model` axis, whose table
+        lies split by head, the step takes the masked read instead."""
+        return gqa.walks_table(max_seq, conf.n_in // conf.n_heads)
+
+    @staticmethod
+    def kv_cell_bytes(conf, max_seq: int) -> int:
+        """Bytes of one cell as the table lays it out: K and V of every
+        head."""
+        return 2 * conf.n_in * jnp.dtype(compute_dtype(conf)).itemsize
 
     @staticmethod
     def init_state(conf, batch: int, max_seq: int) -> dict:
-        return _kv_zeros(conf, (batch, max_seq, conf.n_in))
+        """K and V [B, H, max_S, hd]: heads before positions, so that a
+        head's cells lie together, as a grouped-query layer lays its table
+        out (`gqa.py`)."""
+        h = conf.n_heads
+        return _kv_zeros(conf, (batch, h, max_seq, conf.n_in // h))
 
     @staticmethod
     def init_paged_state(conf, batch: int, n_pages: int, page_size: int) -> dict:
@@ -152,7 +193,8 @@ class MultiHeadAttentionLayer:
         forward over the whole prompt and record the projected K/V rows
         into the pre-allocated caches.
 
-        x: [B, T, n]; state: K and V [B, max_S, n] (T <= max_S).  Returns
+        x: [B, T, n]; state: K and V [B, H, max_S, hd] (T <= max_S), the
+        prompt's T positions written heads before positions.  Returns
         (hidden [B, T, n], state).  Bucket padding beyond each row's true
         prompt `length` writes junk K/V at positions >= length, which is
         harmless: the causal mask hides them from every prompt position,
@@ -164,14 +206,14 @@ class MultiHeadAttentionLayer:
         hd = n // h
         cd = compute_dtype(conf)
         q, k, v = _qkv(params, conf, x, cd)
-        with scope("kv_write"):
-            state = {"k": jax.lax.dynamic_update_slice(
-                         state["k"], k.astype(state["k"].dtype), (0, 0, 0)),
-                     "v": jax.lax.dynamic_update_slice(
-                         state["v"], v.astype(state["v"].dtype), (0, 0, 0))}
         q = q.reshape(b, s, h, hd)
         k = k.reshape(b, s, h, hd)
         v = v.reshape(b, s, h, hd)
+        with scope("kv_write"):
+            state = {name: jax.lax.dynamic_update_slice(
+                         state[name], jnp.swapaxes(new, 1, 2).astype(state[name].dtype),
+                         (0, 0, 0, 0))
+                     for name, new in (("k", k), ("v", v))}
         o = MultiHeadAttentionLayer._attend(conf, q, k, v)
         o = _proj(params, conf, o.reshape(b, s, n).astype(x.dtype))
         return x + o, state
@@ -182,18 +224,21 @@ class MultiHeadAttentionLayer:
 
         x: [B, n] (current token's hidden row); pos: [B] int32, the
         sequence position each row is writing.  Dense state: K and V
-        [B, max_S, n], the new row scattered at `pos`.  With a `page_table`
-        [B, pages_per_slot] int32 of physical page ids the state is the
-        shared pool [n_pages, page_size, n]: the new row is scattered at
-        (page_table[b, pos // ps], pos % ps) and the row's pages are
-        gathered back into one [B, pages_per_slot * ps, n] view.  Either
-        way scores are [B, H, ctx] — one sequence-scaled axis, never
-        [S, S] — and key positions > pos get the same additive -1e30 mask
-        as `nd.attention.full_attention`, so a greedy decode reproduces the
-        eager full-forward trajectory exactly in f32.  Unallocated table
-        entries point at the host's scratch page, whose junk sits behind
-        the mask (exp(-1e30 + .) underflows to exactly 0.0), so paged and
-        dense trajectories are token-identical.
+        [B, H, max_S, hd], the new row's heads scattered at `pos`.  With a
+        `page_table` [B, pages_per_slot] int32 of physical page ids the
+        state is the shared pool [n_pages, page_size, n]: the new row is
+        scattered at (page_table[b, pos // ps], pos % ps) and the row's
+        pages are gathered back into one [B, H, pages_per_slot * ps, hd]
+        view.  Either way scores are [B, H, ctx] — one sequence-scaled
+        axis, never [S, S] — and key positions > pos get the same additive
+        -1e30 mask as `nd.attention.full_attention`, so a greedy decode
+        reproduces the eager full-forward trajectory exactly in f32.
+        Unallocated table entries point at the host's scratch page, whose
+        junk sits behind the mask (exp(-1e30 + .) underflows to exactly
+        0.0), so paged and dense trajectories are token-identical.  On the
+        chip a dense table on one chip is not read whole:
+        `dl4j_gqa_decode` walks each row's blocks up to its position
+        (`decode_kernel`), scoring and normalising in float32.
         """
         b, n = x.shape
         h = conf.n_heads
@@ -202,18 +247,24 @@ class MultiHeadAttentionLayer:
         q, k, v = _qkv(params, conf, x, cd)
         with scope("kv_write"):
             state = _kv_write(state, k, v, jnp.arange(b), pos, page_table)
+        if (page_table is None and _on_one_chip(state["k"]) and
+                MultiHeadAttentionLayer.decode_kernel(conf, state["k"].shape[2])):
+            with scope("attend"):
+                qs = q.astype(jnp.float32).reshape(b, h, 1, hd) / math.sqrt(hd)
+                o = _walk_table(qs, state["k"], state["v"], pos)
+            return x + _proj(params, conf, o.reshape(b, n).astype(x.dtype)), state
         with scope("kv_read"):
             qh = q.reshape(b, h, hd)
             kh, vh = _kv_read(state, page_table, b, h, hd, cd)
         with scope("scores"):
-            s = jnp.einsum("bhd,bkhd->bhk", qh, kh) / jnp.sqrt(
+            s = jnp.einsum("bhd,bhkd->bhk", qh, kh) / jnp.sqrt(
                 jnp.asarray(hd, qh.dtype))
-            kpos = jnp.arange(kh.shape[1])[None, :]
+            kpos = jnp.arange(kh.shape[2])[None, :]
             mask = jnp.where(kpos <= pos[:, None], 0.0,
                              -1e30).astype(s.dtype)
             p = jax.nn.softmax(s + mask[:, None, :], axis=-1)
         with scope("attend"):
-            o = jnp.einsum("bhk,bkhd->bhd", p, vh)
+            o = jnp.einsum("bhk,bhkd->bhd", p, vh)
         o = _proj(params, conf, o.reshape(b, n).astype(x.dtype))
         return x + o, state
 
@@ -243,14 +294,14 @@ class MultiHeadAttentionLayer:
             qh = q.reshape(b, kk, h, hd)
             kh, vh = _kv_read(state, page_table, b, h, hd, cd)
         with scope("scores"):
-            s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / jnp.sqrt(
+            s = jnp.einsum("bqhd,bhkd->bhqk", qh, kh) / jnp.sqrt(
                 jnp.asarray(hd, qh.dtype))
-            kpos = jnp.arange(kh.shape[1])[None, None, :]
+            kpos = jnp.arange(kh.shape[2])[None, None, :]
             mask = jnp.where(kpos <= idx[:, :, None], 0.0,
                              -1e30).astype(s.dtype)
             p = jax.nn.softmax(s + mask[:, None, :, :], axis=-1)
         with scope("attend"):
-            o = jnp.einsum("bhqk,bkhd->bqhd", p, vh)
+            o = jnp.einsum("bhqk,bhkd->bqhd", p, vh)
         o = _proj(params, conf, o.reshape(b, kk, n).astype(x.dtype))
         return x + o, state, {}
 
@@ -261,26 +312,52 @@ def _kv_zeros(conf, shape) -> dict:
 
 
 def _kv_write(state, k, v, rows, idx, page_table):
-    """The new rows k, v at positions `idx` of rows `rows`: into the rows'
-    own tables, or through `page_table` into the pool's (page, offset)."""
+    """The new rows k, v ([B, n], or [B, K, n] at positions `idx` [B, K])
+    at positions `idx` of rows `rows`: into the rows' own tables, a cell a
+    (row, head), or through `page_table` into the pool's (page, offset)."""
     kc, vc = state["k"], state["v"]
-    if page_table is None:
-        at = (rows, idx)
-    else:
+    if page_table is not None:
         ps = kc.shape[1]
         at = (page_table[rows, idx // ps], idx % ps)
-    return {"k": kc.at[at].set(k.astype(kc.dtype)),
-            "v": vc.at[at].set(v.astype(vc.dtype))}
+        return {"k": kc.at[at].set(k.astype(kc.dtype)),
+                "v": vc.at[at].set(v.astype(vc.dtype))}
+    # scattered with both indices on the leading axes, the table is written
+    # where it lies (with the heads' axis inside the window the TPU's
+    # compiler copies the whole table into a position-major layout and
+    # back, a step)
+    b, h, cells, hd = kc.shape
+    heads = jnp.arange(b * h).reshape((b * h,) + (1,) * (idx.ndim - 1))
+    cell = jnp.repeat(idx, h, axis=0)
+
+    def put(table, new):
+        new = new.reshape(new.shape[:-1] + (h, hd))
+        if new.ndim == 4:                                   # [B, H, K, hd]
+            new = jnp.swapaxes(new, 1, 2)
+        flat = table.reshape(b * h, cells, hd)
+        return flat.at[heads, cell].set(
+            new.reshape((b * h,) + new.shape[2:]).astype(table.dtype)).reshape(table.shape)
+
+    return {"k": put(kc, k), "v": put(vc, v)}
 
 
 def _kv_read(state, page_table, b, h, hd, cd):
-    """Every row's keys and values as [B, ctx, H, hd] in `cd`: its table
+    """Every row's keys and values as [B, H, ctx, hd] in `cd`: its table
     (ctx = max_S), or its pages gathered (ctx = pages_per_slot * ps)."""
     if page_table is None:
-        return tuple(state[name].astype(cd).reshape(b, -1, h, hd)
-                     for name in ("k", "v"))
-    return tuple(state[name][page_table].reshape(b, -1, h, hd).astype(cd)
-                 for name in ("k", "v"))
+        return state["k"].astype(cd), state["v"].astype(cd)
+    return tuple(jnp.swapaxes(state[name][page_table].reshape(b, -1, h, hd),
+                              1, 2).astype(cd) for name in ("k", "v"))
+
+
+#: `gqa.walk_table` as one function: a decode step's layers call it with the
+#: same shapes, so it is traced and lowered once, not once a layer
+_walk_table = jax.jit(gqa.walk_table)
+
+
+def _on_one_chip(table) -> bool:
+    """Does `table` lie on one chip, not on a mesh (where a plan's `model`
+    axis splits it by head)?"""
+    return jax.typeof(table).sharding.mesh.size <= 1
 
 
 def _layer_norm(x, g, b, eps: float = 1e-5):

@@ -69,6 +69,35 @@ def q_block(n_heads: int) -> int:
     return SCORE_ROWS // n_heads
 
 
+def walks_table(cells: int, head_dim: int) -> bool:
+    """Does a decode step attend to a table of `cells` positions, K/V heads
+    before positions, through `dl4j_gqa_decode`: on the chip, of whole 128s
+    of positions, with heads whose table the kernel reads where it lies
+    (whole 128s wide, or 64 with the positions innermost).  Off the chip
+    the step reads the whole table under a mask, the kernel's oracle
+    (interpreted, the kernel loses to XLA there).  The rule of both
+    attention layers that keep such a table (this one, `attention.py`)."""
+    return is_tpu() and cells % 128 == 0 and (head_dim % 128 == 0 or head_dim == 64)
+
+
+def walk_block(cells: int, kv_heads: int, head_dim: int, dtype) -> int:
+    """The block in which `walk_table` reads a table of `cells` positions
+    of `kv_heads` heads `head_dim` wide in `dtype`: `pallas_kernels.walk_block`
+    of its K (or V) cells, each `kv_heads x head_dim` wide."""
+    return pallas_kernels.walk_block(cells, kv_heads * head_dim * jnp.dtype(dtype).itemsize)
+
+
+def walk_table(q, k, v, pos):
+    """`pallas_kernels.gqa_decode` of q [B, G, R, h] (scaled; cast to the
+    table's type) over the tables k and v [B, G, cells, h] up to each row's
+    `pos`: [B, G, R, h] float32.  The chip lays a table whose heads are not
+    whole 128s wide out positions innermost: the kernel reads it as it
+    lies."""
+    last = k.shape[-1] % 128 != 0
+    kt, vt = ((jnp.swapaxes(t, 2, 3) if last else t) for t in (k, v))
+    return pallas_kernels.gqa_decode(q.astype(k.dtype), kt, vt, pos, cells_last=last)
+
+
 class GQALayer:
     CARRY = False       # a finished row rewrites one cell with what it holds
 
@@ -106,19 +135,17 @@ class GQALayer:
     def kv_read_block(conf, max_seq: int) -> int:
         """The block in which `decode_step` walks a row's cells up to its
         position (`decode_kernel`), or 0 where it reads them all."""
-        return pallas_kernels.walk_block(max_seq) if GQALayer.decode_kernel(conf, max_seq) else 0
+        s = conf.layer_spec
+        return (walk_block(max_seq, s.n_kv_heads, s.head_dim, compute_dtype(conf))
+                if GQALayer.decode_kernel(conf, max_seq) else 0)
 
     @staticmethod
     def decode_kernel(conf, max_seq: int) -> bool:
         """Does `decode_step` attend through `dl4j_gqa_decode`: a table (a
-        ring is all live from position window - 1 on), on the chip, of whole
-        128s of positions, with heads whose table the kernel reads where it
-        lies (whole 128s wide, or 64 with the positions innermost).  Off the
-        chip the step reads the whole table under `seen`, the kernel's
-        oracle (interpreted, the kernel loses to XLA there)."""
+        ring is all live from position window - 1 on) that `walks_table`
+        admits."""
         s = conf.layer_spec
-        return (not s.window and is_tpu() and max_seq % 128 == 0
-                and (s.head_dim % 128 == 0 or s.head_dim == 64))
+        return not s.window and walks_table(max_seq, s.head_dim)
 
     @staticmethod
     def kv_cell_bytes(conf, max_seq: int) -> int:
@@ -259,12 +286,7 @@ class GQALayer:
             k_all, v_all = put(state["k"], k), put(state["v"], v)
         if GQALayer.decode_kernel(conf, cells):
             with scope("gqa_attend"):
-                # the chip lays a table whose heads are not whole 128s wide
-                # out positions innermost: the kernel reads it as it lies
-                last = s.head_dim % 128 != 0
-                kt, vt = ((jnp.swapaxes(t, 2, 3) if last else t) for t in (k_all, v_all))
-                o = pallas_kernels.gqa_decode(q.astype(cd), kt, vt, pos,
-                                              cells_last=last).reshape(b, -1)
+                o = walk_table(q, k_all, v_all, pos).reshape(b, -1)
         else:
             with scope("kv_read"):
                 seen = jnp.arange(cells)[None, :] <= pos[:, None]

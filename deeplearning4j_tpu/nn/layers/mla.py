@@ -224,8 +224,9 @@ class MLALayer:
         latents' table under `dl4j_dsa_decode`, and no other."""
         if not MLALayer.selects(conf, max_seq):
             return (0,)
-        return (pallas_kernels.walk_block(max_seq) if MLALayer.decode_kernel(conf, max_seq)
-                else 0, 0)
+        cell = MLALayer._cell_width(conf) * jnp.dtype(compute_dtype(conf)).itemsize
+        return (pallas_kernels.walk_block(max_seq, cell)
+                if MLALayer.decode_kernel(conf, max_seq) else 0, 0)
 
     @staticmethod
     def kv_cell_bytes(conf, max_seq: int) -> tuple:

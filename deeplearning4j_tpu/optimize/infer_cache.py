@@ -412,11 +412,11 @@ class InferCache(CompiledProgramCache):
         shardings = None
         if plan.has_model_axis:
             rep = plan.replicated()
-            shardings = ((plan.state_shardings(head) if head_is_row
+            shardings = ((plan.state_shardings(head, conf) if head_is_row
                           else plan.param_shardings(head),
-                          plan.state_shardings(state))
+                          plan.state_shardings(state, conf))
                          + (rep,) * len(rest))
-        fn = self._get(key, self._tp_build(lambda: program(conf, policy)),
+        fn = self._get(key, self._tp_build(conf, lambda: program(conf, policy)),
                        (head, state) + rest, donate=self._decode_donate(),
                        shardings=shardings)
         if compile_only:
@@ -424,13 +424,13 @@ class InferCache(CompiledProgramCache):
         with self._lock:
             self.stats.steps += 1
         if shardings is not None:
-            head = (self._place_decode_state(head) if head_is_row
+            head = (self._place_decode_state(conf, head) if head_is_row
                     else self._place_params(head))
-            state = self._place_decode_state(state)
+            state = self._place_decode_state(conf, state)
             rest = tuple(jax.device_put(a, rep) for a in rest)
         return fn(head, state, *rest)
 
-    def _tp_build(self, build):
+    def _tp_build(self, conf, build):
         """Wrap a decode-family program builder for a tensor-parallel
         plan: the returned program pins its (donated, state-last)
         output state to the plan's per-leaf specs with
@@ -452,21 +452,21 @@ class InferCache(CompiledProgramCache):
                 st = jax.tree_util.tree_map(
                     lambda a, s: jax.lax.with_sharding_constraint(
                         a, jax.sharding.NamedSharding(mesh, s)),
-                    st, plan.state_pspecs(st))
+                    st, plan.state_pspecs(st, conf))
                 return tuple(rest) + (st,)
 
             return program
 
         return wrapped
 
-    def _place_decode_state(self, state):
-        """Plan placement for a fresh decode state (no-op without a
-        `model` axis)."""
+    def _place_decode_state(self, conf, state):
+        """Plan placement for a fresh decode state of `conf`'s stack
+        (no-op without a `model` axis)."""
         plan = self.plan
         if not plan.has_model_axis:
             return state
         return jax.tree_util.tree_map(jax.device_put, state,
-                                      plan.state_shardings(state))
+                                      plan.state_shardings(state, conf))
 
     def init_decode_state(self, conf, batch: int, max_seq: int):
         """Fresh decode state shaped for the active policy's programs,
@@ -474,9 +474,8 @@ class InferCache(CompiledProgramCache):
         feature dims so the cache itself can exceed one chip's HBM)."""
         from deeplearning4j_tpu.nn import decode as decode_mod
 
-        return self._place_decode_state(
-            decode_mod.init_state(_policy_conf(conf, self._policy),
-                                  batch, max_seq))
+        return self._place_decode_state(conf, decode_mod.init_state(
+            _policy_conf(conf, self._policy), batch, max_seq))
 
     def decode(self, conf, params, state, tok, pos, keys, temps,
                page_table=None, compile_only: bool = False):
@@ -509,7 +508,7 @@ class InferCache(CompiledProgramCache):
         page pool's feature dim shards over a `model` axis by head)."""
         from deeplearning4j_tpu.nn import decode as decode_mod
 
-        return self._place_decode_state(decode_mod.init_paged_state(
+        return self._place_decode_state(conf, decode_mod.init_paged_state(
             _policy_conf(conf, self._policy), batch, n_pages, page_size))
 
     def decode_multi(self, conf, params, state, tok, pos, keys, temps,

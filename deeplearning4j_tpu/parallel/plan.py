@@ -38,8 +38,10 @@ Axis semantics:
           projections row-split (`P('model', None)`, jit inserts the
           all-reduce), embedding splits its d_model columns, the vocab
           projection splits whichever dim divides, and the decode K/V
-          tables (dense AND paged) split their feature dim by head —
-          the layout that lets params + KV cache exceed one chip's HBM.
+          tables (dense AND paged) split by head: the feature dim of a
+          [.., n] table, the heads' axis of a layer's [B, H, max_S, hd]
+          one (`SPLIT_HEADS`) — the layout that lets params + KV cache
+          exceed one chip's HBM.
 
 Any spec is a *layout hint*, never a semantics change: GSPMD reshards
 as needed, so an indivisible leaf simply replicates.
@@ -69,8 +71,10 @@ SINGLE = "single"
 ROW_SPLIT_NAMES = frozenset({"Wo", "W2"})
 
 #: decode-state leaf names whose trailing (feature/hidden) dim splits
-#: over `model`: attention K/V tables (dense [B,S,n] and paged
-#: [pages,page,n]) split by head; recurrent carries split their hidden
+#: over `model`: attention K/V pools ([pages, page, n]) split by head;
+#: recurrent carries split their hidden.  A layer whose class says
+#: `SPLIT_HEADS` (multi-head attention's dense [B, H, max_S, hd] table)
+#: splits its `k` and `v` on the heads' axis instead
 STATE_SPLIT_NAMES = frozenset({"k", "v", "h", "c"})
 #: Every other leaf is whole on every chip: a KDA layer's `S` and `conv`,
 #: an MLA layer's `kr` and `ki` (its indexer's keys), and its latent, which
@@ -78,8 +82,8 @@ STATE_SPLIT_NAMES = frozenset({"k", "v", "h", "c"})
 #: an LSTM's is [B, H]): one latent serves
 #: all heads, so it cannot be split by head, and the plan has no expert or
 #: data axis for decode state to lie along yet.  A grouped-heads layer's `k`
-#: and `v` ([B, G, cells, h], four axes) stay whole too: their trailing axis
-#: is one head's width, and nothing splits the G axis yet
+#: and `v` ([B, G, cells, h], four axes, no `SPLIT_HEADS`) stay whole too:
+#: its decode kernel runs whole on every chip (`pallas_kernels._on_each_chip`)
 
 
 def parse_mesh_spec(spec: str) -> Dict[str, int]:
@@ -154,6 +158,17 @@ def plan_mesh(shape: Optional[Dict[str, int]] = None, devices=None) -> Mesh:
         raise ValueError(f"mesh {shape} needs {total} devices, have {n}")
     dev = np.asarray(devices[:total]).reshape([shape[a] for a in axes])
     return Mesh(dev, axis_names=tuple(axes))
+
+
+def _split_heads_layers(conf) -> frozenset:
+    """The indices of the layers of `conf` (None: none) whose class says
+    `SPLIT_HEADS`: a decode state's entry i is layer i's."""
+    if conf is None:
+        return frozenset()
+    from deeplearning4j_tpu.nn.layers import get_layer
+
+    return frozenset(i for i in range(conf.n_layers) if getattr(
+        get_layer(conf.conf(i).layer_type), "SPLIT_HEADS", False))
 
 
 def _leaf_name(path) -> str:
@@ -297,33 +312,42 @@ class ShardPlan:
             lambda s: NamedSharding(mesh, s), self.param_pspecs(params),
             is_leaf=lambda x: isinstance(x, P))
 
-    def _state_spec(self, name: str, shape: Tuple[int, ...]) -> P:
+    def _state_spec(self, name: str, shape: Tuple[int, ...],
+                    heads: bool = False) -> P:
         m = self.model_size
         nd = len(shape)
-        if (m <= 1 or nd < 2 or name not in STATE_SPLIT_NAMES
-                or shape[-1] % m or (name == "c" and nd == 3) or nd > 3):
+        if m <= 1 or nd < 2 or name not in STATE_SPLIT_NAMES:
+            return P()
+        if heads and nd == 4:
+            return (P(None, self.model_axis, None, None) if shape[1] % m == 0
+                    else P())
+        if shape[-1] % m or (name == "c" and nd == 3) or nd > 3:
             return P()
         return P(*((None,) * (nd - 1) + (self.model_axis,)))
 
-    def state_pspecs(self, state):
+    def state_pspecs(self, state, conf=None):
         """Per-leaf PartitionSpecs for a decode-state tree: K/V tables
         (dense and paged) and recurrent carries split their trailing
         feature dim over `model` when divisible — the sharded KV slot
-        table that lets a generation cache exceed one chip's HBM."""
+        table that lets a generation cache exceed one chip's HBM.  Given
+        the stack's `conf`, the four-axis `k` and `v` of a layer whose
+        class says `SPLIT_HEADS` split their heads' axis (1)."""
         if not self.has_model_axis:
             return jax.tree_util.tree_map(lambda _: P(), state)
+        heads = _split_heads_layers(conf)
         flat, treedef = jax.tree_util.tree_flatten_with_path(state)
         specs = [self._state_spec(_leaf_name(path),
-                                  tuple(getattr(leaf, "shape", ()) or ()))
+                                  tuple(getattr(leaf, "shape", ()) or ()),
+                                  getattr(path[0], "idx", None) in heads)
                  for path, leaf in flat]
         return jax.tree_util.tree_unflatten(treedef, specs)
 
-    def state_shardings(self, state):
+    def state_shardings(self, state, conf=None):
         if self.mesh is None:
             return None
         mesh = self.mesh
         return jax.tree_util.tree_map(
-            lambda s: NamedSharding(mesh, s), self.state_pspecs(state),
+            lambda s: NamedSharding(mesh, s), self.state_pspecs(state, conf),
             is_leaf=lambda x: isinstance(x, P))
 
     # -- training ------------------------------------------------------------

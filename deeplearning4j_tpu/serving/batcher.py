@@ -1295,6 +1295,8 @@ class ContinuousBatcher:
             self._state = self.net.infer_cache.write_row(
                 self.net.conf, self._state, row, slot)
             return
+        import jax.numpy as jnp
+
         ps = self.page_size
         new_state = []
         for i, lay in enumerate(self._state):
@@ -1305,8 +1307,11 @@ class ContinuousBatcher:
                     {"h": lay["h"].at[slot].set(row[i]["h"][0]),
                      "c": lay["c"].at[slot].set(row[i]["c"][0])})
             else:
+                # the row's dense [H, max_S, hd] table as the pool's
+                # [max_S, n] positions
                 k, v = lay["k"], lay["v"]
-                rk, rv = row[i]["k"][0], row[i]["v"][0]
+                rk, rv = (jnp.swapaxes(row[i][name][0], 0, 1).reshape(
+                    -1, lay[name].shape[-1]) for name in ("k", "v"))
                 for j, phys in enumerate(pages):
                     blk_k = rk[j * ps: (j + 1) * ps]
                     blk_v = rv[j * ps: (j + 1) * ps]

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.models.zoo import char_lstm, char_transformer
-from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork, network_output
 from deeplearning4j_tpu.reliability import faults
 
 VOCAB = 13
@@ -56,6 +56,25 @@ def _compiled_tokens(net, prompt, n_new, temperature=0.0, rng_seed=0,
         got.append(int(tok[0]))
         pos = pos + 1
     return got
+
+
+def _eager_transformer_tokens(net, prompt, n_new):
+    """Greedy reference by full eager re-forward over the sequence so far —
+    no cache at all, so agreement means the cached path IS the model.  The
+    ids are padded to one width (a causal model's outputs do not see what
+    follows), so that the eager ops compile for one shape and not for
+    `n_new`."""
+    seq, toks = list(prompt), []
+    width = len(prompt) + n_new
+    for _ in range(n_new):
+        ids = np.zeros((1, width), np.int32)
+        ids[0, :len(seq)] = seq
+        probs = network_output(net.conf, net.params, jnp.asarray(ids))
+        probs = probs.reshape(width, -1)
+        toks.append(int(jnp.argmax(
+            jnp.log(jnp.clip(probs[len(seq) - 1], 1e-9, 1.0)))))
+        seq.append(toks[-1])
+    return toks
 
 
 def _drain(streams, timeout=60.0):

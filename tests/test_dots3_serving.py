@@ -131,8 +131,10 @@ def test_the_cells_a_layer_says_it_reads_are_its_programs_keys(f32, kind, path,
     # and the kernel walks the picked latents' table in blocks up to a row's
     # position, which the batcher counts by the rows' positions
     from deeplearning4j_tpu.nd.pallas_kernels import walk_block
+    cell = MLALayer._cell_width(conf) * 4                   # float32
     assert MLALayer.kv_read_block(conf, MAX_SEQ) == (
-        (walk_block(MAX_SEQ), 0) if path == "kernel" else (0, 0) if kind == "full" else (0,))
+        (walk_block(MAX_SEQ, cell), 0) if path == "kernel"
+        else (0, 0) if kind == "full" else (0,))
 
 
 def _equations(jaxpr):

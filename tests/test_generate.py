@@ -31,12 +31,11 @@ from deeplearning4j_tpu.models.zoo import char_lstm, char_transformer, mlp
 from deeplearning4j_tpu.nn import decode as decode_mod
 from deeplearning4j_tpu.nn.conf import LayerType
 from deeplearning4j_tpu.nn.layers import get_layer
-from deeplearning4j_tpu.nn.multilayer import network_output
 from deeplearning4j_tpu.reliability import faults
 from deeplearning4j_tpu.serving.batcher import (ContinuousBatcher,
                                                 ServerOverloaded)
 from generate_helpers import (VOCAB, _clean_faults, _compiled_tokens,   # noqa: F401
-                              lstm_net, transformer_net)
+                              _eager_transformer_tokens, lstm_net, transformer_net)
 
 
 def _eager_lstm_tokens(net, prompt, n_new, temperature=0.0, rng_seed=0):
@@ -72,25 +71,6 @@ def _eager_lstm_tokens(net, prompt, n_new, temperature=0.0, rng_seed=0):
             t = int(jax.random.categorical(sub, logp[0] / temperature))
         toks.append(t)
         logp, hs, cs = step(jnp.asarray(eye[t][None]), hs, cs)
-    return toks
-
-
-def _eager_transformer_tokens(net, prompt, n_new):
-    """Greedy reference by full eager re-forward over the sequence so far —
-    no cache at all, so agreement means the cached path IS the model.  The
-    ids are padded to one width (a causal model's outputs do not see what
-    follows), so that the eager ops compile for one shape and not for
-    `n_new`."""
-    seq, toks = list(prompt), []
-    width = len(prompt) + n_new
-    for _ in range(n_new):
-        ids = np.zeros((1, width), np.int32)
-        ids[0, :len(seq)] = seq
-        probs = network_output(net.conf, net.params, jnp.asarray(ids))
-        probs = probs.reshape(width, VOCAB)
-        toks.append(int(jnp.argmax(
-            jnp.log(jnp.clip(probs[len(seq) - 1], 1e-9, 1.0)))))
-        seq.append(toks[-1])
     return toks
 
 
@@ -146,7 +126,7 @@ def test_check_generative_accepts_and_rejects():
 def test_init_state_shapes_and_embedding_bound(transformer_net):
     state = decode_mod.init_state(transformer_net.conf, 3, 16)
     k = state[1]["k"]  # layer 0 is the embedding
-    assert k.shape == (3, 16, 16)
+    assert k.shape == (3, 2, 16, 8)  # [B, H, max_S, hd]: heads before positions
     with pytest.raises(ValueError):
         # max_seq beyond the learned positional table would index junk
         decode_mod.init_state(transformer_net.conf, 1, 64)

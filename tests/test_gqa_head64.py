@@ -12,6 +12,7 @@ import pytest
 from benchmark.families.lfm2_moe import reference as ref
 from deeplearning4j_tpu.nn.conf import (GQASpec, LayerType, MLASpec,
                                         NeuralNetConfiguration)
+from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttentionLayer
 from deeplearning4j_tpu.nn.layers.gqa import GQALayer
 from deeplearning4j_tpu.nn.layers.mla import MLALayer
 
@@ -104,13 +105,16 @@ def mla(**options):
     (MLALayer, mla(window=5)),                  # side by side in whole 128s
     (MLALayer, mla(q_lora_rank=16, index_n_heads=2, index_head_dim=8,
                    index_topk=4)),              # and the indexer's keys
+    (MultiHeadAttentionLayer, NeuralNetConfiguration(   # GPT-2's [rows, H, cells, hd]
+        layer_type=LayerType.ATTENTION, n_in=32, n_out=32, n_heads=4,
+        dtype="bfloat16", compute_dtype="bfloat16")),
 ], ids=["gqa64", "gqa64-ring", "gqa128", "gqa96-ring", "mla", "mla-ring",
-        "mla-indexed"])
+        "mla-indexed", "mha"])
 def test_a_declared_cell_is_the_states_own_bytes(impl, c):
     """What `decode.state_bytes_read` counts of a table, `kv_cells_read` x
     `kv_cell_bytes`, is every byte of a row's state where the step reads it
     whole, as every such layer's does; a GQA state keeps [rows, G, cells, h]
-    at every head width."""
+    at every head width, and GPT-2's [rows, H, cells, hd]."""
     max_seq = 12
     state = jax.eval_shape(lambda: impl.init_state(c, 1, max_seq))
     held = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(state))
@@ -120,6 +124,8 @@ def test_a_declared_cell_is_the_states_own_bytes(impl, c):
     if impl is GQALayer:
         s = c.layer_spec
         assert state["k"].shape == (1, s.n_kv_heads, s.window or max_seq, s.head_dim)
+    if impl is MultiHeadAttentionLayer:
+        assert state["k"].shape == (1, c.n_heads, max_seq, c.n_in // c.n_heads)
 
 
 # ------------------------------------- the walk of a row's live blocks

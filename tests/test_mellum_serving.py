@@ -235,10 +235,9 @@ def test_the_counters_are_exported(served):
 
 
 def test_a_stack_without_such_layers_counts_no_cells():
-    from deeplearning4j_tpu.models.zoo import char_transformer
+    from deeplearning4j_tpu.models.zoo import char_lstm
 
-    net = MultiLayerNetwork(char_transformer(32, d_model=16, n_blocks=1, n_heads=2,
-                                             max_seq_len=16)).init()
+    net = MultiLayerNetwork(char_lstm(32, hidden=16)).init()
     profiling.clear()
     batcher = ContinuousBatcher(net, n_slots=1, max_seq=16, prompt_buckets=(8,)).start()
     list(batcher.submit(np.arange(3, dtype=np.int32), max_new_tokens=3).tokens(timeout=120))
@@ -284,16 +283,25 @@ def test_the_plan_keeps_the_new_leaves_whole(f32):
             assert name in ("k", "v") and spec[name] == P()
             seen += 1
     assert seen == 8
-    table = {"k": jax.ShapeDtypeStruct((4, 16, 8), jnp.float32)}    # GPT-2's [B, S, n]
-    assert plan.state_pspecs((table,))[0]["k"] == P(None, None, "model")
+    # GPT-2's dense table [B, H, S, hd] splits its heads; its pool [pages, page, n] its width
+    from deeplearning4j_tpu.models.zoo import char_transformer
+    gpt2 = char_transformer(64, d_model=32, n_blocks=1, n_heads=2, max_seq_len=16)
+    dense = plan.state_pspecs(jax.eval_shape(lambda: decode.init_state(gpt2, 4, 16)), gpt2)
+    pool = plan.state_pspecs(jax.eval_shape(lambda: decode.init_paged_state(gpt2, 4, 8, 4)), gpt2)
+    assert dense[1]["k"] == dense[1]["v"] == P(None, "model", None, None)
+    assert pool[1]["k"] == pool[1]["v"] == P(None, None, "model")
 
 
 # Lowered at the parent commit (6a2c6f5) with this file's own recipe: the
 # tiny Ling-3.0 stack's decode step and prefill, and a tiny GPT-2 block's.
 # Equal texts: the change reached no program of the confs that were there.
+# GPT-2's decode step and prefill were lowered again once its table lay
+# heads before positions; its training program (forward and gradient) kept
+# its text through that change.
 PARENT_TEXTS = {
     "ling3:decode": "0343380428ba6d8a", "ling3:prefill": "39a4806081d65b43",
-    "gpt2:decode": "5501944fbe9980c9", "gpt2:prefill": "f09c30c3315d46bf"}
+    "gpt2:decode": "c95f7a27288c5dcf", "gpt2:prefill": "f62be4a2392db374",
+    "gpt2:train": "ab8a7496a645d9f5"}
 
 
 def lowered_texts() -> dict:
@@ -316,6 +324,13 @@ def lowered_texts() -> dict:
             lambda p, s, t, n: decode.prefill(conf, p, s, t, n)).lower(
                 params, table(1), jax.ShapeDtypeStruct((1, 8), jnp.int32),
                 jax.ShapeDtypeStruct((1,), jnp.int32)).as_text()
+    from deeplearning4j_tpu.nn.multilayer import network_output
+
+    gpt2 = confs["gpt2"]
+    params = jax.eval_shape(lambda k: init_params(gpt2, k), jax.random.PRNGKey(0))
+    out["gpt2:train"] = jax.jit(lambda p, x: jax.grad(
+        lambda p: network_output(gpt2, p, x, training=True).sum())(p)).lower(
+            params, jax.ShapeDtypeStruct((2, 16), jnp.int32)).as_text()
     return {k: hashlib.sha256(v.encode()).hexdigest()[:16] for k, v in out.items()}
 
 

@@ -235,7 +235,8 @@ GQA_ROWS = {    # a call's rows' positions, blocks of 16 (the chip's 512 scaled)
 
 @pytest.mark.parametrize("rows", list(GQA_ROWS))
 @pytest.mark.parametrize("cells", [64, 288], ids=["4-blocks", "18-blocks"])
-@pytest.mark.parametrize("heads", [(4, 8, 128), (8, 4, 64)], ids=["G4-R8-h128", "G8-R4-h64"])
+@pytest.mark.parametrize("heads", [(4, 8, 128), (8, 4, 64), (16, 1, 128)],
+                         ids=["G4-R8-h128", "G8-R4-h64", "G16-R1-h128"])
 def test_gqa_decode_is_the_masked_read(heads, cells, rows, monkeypatch, capsys):
     """`dl4j_gqa_decode` against the whole table under `seen`, as a grouped
     layer's decode step reads it off the chip (`GQALayer._attend`), in float32;

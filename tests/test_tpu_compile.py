@@ -537,7 +537,7 @@ def test_a_decode_kernel_of_16_query_heads_a_kv_head_compiles(one_chip):
     text = _compile(lambda q, k, v, p: pk.gqa_decode(q, k, v, p), one_chip,
                     ((32, 8, 16, 128), jnp.bfloat16), ((32, 8, 10240, 128), jnp.bfloat16),
                     ((32, 8, 10240, 128), jnp.bfloat16), ((32,), jnp.int32))
-    assert "dl4j_gqa_decode" in text and pk.walk_block(10240) == 512
+    assert "dl4j_gqa_decode" in text and pk.walk_block(10240, 8 * 128 * 2) == 512
 
 
 def _commanda_stack():
@@ -583,3 +583,60 @@ def test_the_parallel_blocks_decode_program_at_32_slots(one_chip, monkeypatch):
     for cells in (32 * 8 * 4096 * 128, 32 * 8 * 10240 * 128):
         assert _table_copies(text, "bf16", cells) == 0
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * (1 << 30)
+
+
+def _gpt2_block():
+    """GPT-2 1.3B cut to one block, at every published width: 16 heads of
+    128, d 2,048, 50,257 ids."""
+    import json
+
+    from benchmark.families.gpt2.program import build_conf
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "cerebras-gpt-1.3b.json")) as f:
+        cfg = json.load(f)
+    return build_conf({**cfg, "n_layer": 1})
+
+
+def test_gpt2s_decode_walks_its_table_where_it_lies(one_chip, monkeypatch):
+    """GPT-2's decode at the cell's 8 slots and 2,048 positions, on the
+    chip's path: the attention layer's step, its state donated, attends
+    through `dl4j_gqa_decode` in blocks of 256 (a block of 512 of 16 heads
+    of 128 would take all the fast memory a kernel may hold), writes a row's
+    cell where it lies, and holds no temporary a twentieth of a table; and
+    the batcher's whole decode program copies and transposes neither
+    `[8, 16, 2048, 128]` table."""
+    from deeplearning4j_tpu.nn import decode
+    from deeplearning4j_tpu.nn.layers import gqa
+    from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttentionLayer as MHA
+    from deeplearning4j_tpu.nn.multilayer import init_params
+    from deeplearning4j_tpu.optimize.infer_cache import _decode_program
+
+    monkeypatch.setattr(pk, "is_tpu", lambda: True)
+    monkeypatch.setattr(gqa, "is_tpu", lambda: True)
+    conf = _gpt2_block()
+    c = conf.conf(1)
+    assert MHA.decode_kernel(c, 2048) and MHA.kv_read_block(c, 2048) == 256
+    assert decode.kernel_layers(conf, 2048) == 1
+    shaped = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    state = shaped(jax.eval_shape(lambda: MHA.init_state(c, 8, 2048)))
+    table = 8 * 16 * 2048 * 128
+    assert state["k"].shape == (8, 16, 2048, 128)
+    params = shaped(jax.eval_shape(lambda k: MHA.init(k, c), jax.random.PRNGKey(0)))
+    step = jax.jit(lambda p, x, st, q: MHA.decode_step(p, c, x, st, q),
+                   donate_argnums=(2,)).lower(
+        params, shaped(jax.ShapeDtypeStruct((8, 2048), jnp.float32)), state,
+        shaped(jax.ShapeDtypeStruct((8,), jnp.int32))).compile()
+    assert "dl4j_gqa_decode" in step.as_text()
+    assert _table_copies(step.as_text(), "bf16", table) == 0
+    memory = step.memory_analysis()
+    assert memory.alias_size_in_bytes == 2 * table * 2
+    assert memory.temp_size_in_bytes < 0.05 * table * 2
+    params = shaped(jax.eval_shape(lambda k: init_params(conf, k), jax.random.PRNGKey(0)))
+    rows = lambda dt, *shape: shaped(jax.ShapeDtypeStruct((8,) + shape, dt))  # noqa: E731
+    program = jax.jit(_decode_program(conf), donate_argnums=(1,)).lower(
+        params, shaped(jax.eval_shape(lambda: decode.init_state(conf, 8, 2048))),
+        rows(jnp.int32), rows(jnp.int32), rows(jnp.uint32, 2), rows(jnp.float32)).compile()
+    assert "dl4j_gqa_decode" in program.as_text()
+    assert _table_copies(program.as_text(), "bf16", table) == 0
