@@ -72,6 +72,7 @@ import numpy as np
 
 from deeplearning4j_tpu.nn.conf import LayerType, MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers import GENERATIVE_HIDDEN, get_layer
+from deeplearning4j_tpu.nn.layers.base import compute_dtype
 from deeplearning4j_tpu.nn.layers.output import OutputLayer
 from deeplearning4j_tpu.utils.profiling import layer_scope, scope
 
@@ -214,6 +215,41 @@ def kernel_layers(conf: MultiLayerConfiguration, max_seq: int) -> int:
     row's blocks up to its position (`kv_read_block` > 0): one a layer that
     attends through `dl4j_gqa_decode` or `dl4j_dsa_decode`."""
     return sum(block > 0 for _, _, block, _ in kv_cells(conf, max_seq))
+
+
+def operand_tree(conf: MultiLayerConfiguration, params):
+    """`params` as a generation program may take them: every leaf that its
+    layer's class declares an operand (`OPERANDS`) held in the layer's
+    compute dtype where it is stored wider, so that a program reads the
+    weights its products multiply and not the masters they are cast from
+    (the products are the same).  Returns (tree, the narrowed leaves in
+    layer order).  Every other leaf is the same object, and where nothing
+    is wider the tree is `params` itself."""
+    out, narrowed = list(params), []
+    for i, layer in enumerate(params):
+        c = conf.conf(i)
+        cd = compute_dtype(c)
+        for path in getattr(get_layer(c.layer_type), "OPERANDS", ()):
+            *outer, name = (path,) if isinstance(path, str) else path
+            node = out[i]
+            for key in outer:
+                node = node.get(key, {})
+            leaf = node.get(name)
+            dtype = getattr(leaf, "dtype", None)
+            if (dtype is None or not jnp.issubdtype(dtype, jnp.floating)
+                    or jnp.dtype(dtype).itemsize <= cd.itemsize):
+                continue
+            narrow = leaf.astype(cd)
+            narrowed.append(narrow)
+            out[i] = _replaced(out[i], list(outer) + [name], narrow)
+    return (params, []) if not narrowed else (type(params)(out), narrowed)
+
+
+def _replaced(tree: dict, path: list, leaf) -> dict:
+    """A copy of the nested dict `tree` with `leaf` at `path`, sharing every
+    other entry."""
+    key, *rest = path
+    return {**tree, key: _replaced(tree[key], rest, leaf) if rest else leaf}
 
 
 def _refuse_dense_only(conf, what: str) -> None:

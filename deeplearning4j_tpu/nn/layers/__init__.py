@@ -50,6 +50,11 @@ What only some types can do, a class declares by having it:
     SPLIT_HEADS   its `k` and `v` tables lie [B, H, max_S, hd], and a plan
             with a `model` axis splits them on the heads' axis
             (`parallel.plan.ShardPlan.state_pspecs`)
+    OPERANDS  the names of its leaves (a tuple of names for a leaf inside a
+            branch's dict) that its programs read only as a weight product's
+            operand in `compute_dtype(conf)`: a generation program may be
+            handed them narrowed to it once (`nn.decode.operand_tree`) and
+            gives the same result.  A type that says nothing has none
 A type with a state but without `init_paged_state` and `verify_chunk` lives
 in the dense slot table only (`nn.decode.dense_only`).  A type with no state
 gets all of it from `base.StatelessDecode`.  A new kind of state is a layer

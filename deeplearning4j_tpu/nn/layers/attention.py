@@ -34,6 +34,8 @@ def _dtype(conf):
 class MultiHeadAttentionLayer:
     """Pre-LN multi-head self-attention with residual connection."""
 
+    OPERANDS = ("Wqkv", "Wo")   # read only as weight products' operands
+
     @staticmethod
     def init(key, conf):
         d = _dtype(conf)
@@ -371,14 +373,15 @@ def _qkv(params, conf, x, cd):
     """Pre-LN, then the fused Q/K/V projection in `cd` (scopes `ln`, `qkv`)."""
     xn = _layer_norm(x, params["ln_g"], params["ln_b"])
     with scope("qkv"):
-        qkv = mixed_matmul(xn, params["Wqkv"], conf) + params["bqkv"]
+        qkv = (mixed_matmul(xn, params["Wqkv"], conf, params["bqkv"].dtype)
+               + params["bqkv"])
         return jnp.split(qkv.astype(cd), 3, axis=-1)
 
 
 def _proj(params, conf, o):
     """The output projection of the attended rows (scope `proj`)."""
     with scope("proj"):
-        return mixed_matmul(o, params["Wo"], conf) + params["bo"]
+        return mixed_matmul(o, params["Wo"], conf, params["bo"].dtype) + params["bo"]
 
 
 class TransformerFFNLayer(StatelessDecode):
@@ -388,6 +391,8 @@ class TransformerFFNLayer(StatelessDecode):
     MultiHeadAttentionLayer to form [attention, ffn] blocks in a
     MultiLayerConfiguration stack.  Keeps no decode state.
     """
+
+    OPERANDS = ("W1", "W2")
 
     @staticmethod
     def init(key, conf):
@@ -413,9 +418,9 @@ class TransformerFFNLayer(StatelessDecode):
     def forward(params, conf, x, key=None, training=False):
         xn = _layer_norm(x, params["ln_g"], params["ln_b"])
         with scope("ffn"):
-            h = jax.nn.gelu(
-                mixed_matmul(xn, params["W1"], conf) + params["b1"])
-            o = mixed_matmul(h, params["W2"], conf) + params["b2"]
+            h = jax.nn.gelu(mixed_matmul(xn, params["W1"], conf, params["b1"].dtype)
+                            + params["b1"])
+            o = mixed_matmul(h, params["W2"], conf, params["b2"].dtype) + params["b2"]
         if training and conf.dropout > 0.0 and key is not None:
             o = o * ndr.dropout_mask(key, 1.0 - conf.dropout, o.shape, o.dtype)
         return x + o

@@ -28,12 +28,16 @@ def compute_dtype(conf):
     return jnp.dtype(cd) if cd else jnp.dtype(conf.dtype)
 
 
-def mixed_matmul(x, W, conf):
+def mixed_matmul(x, W, conf, master=None):
     """x @ W with operands in conf.compute_dtype — bf16 feeds the MXU at
-    full rate while params stay f32 (output cast back to the param dtype;
-    TPU bf16 matmuls accumulate in f32 on the MXU)."""
+    full rate while params stay f32 (TPU bf16 matmuls accumulate in f32 on
+    the MXU).  The product comes back in the masters' dtype: W's own, or,
+    where W is an operand held narrow beside wider masters
+    (`nn.decode.operand_tree`), the wider `master` dtype the caller names
+    (its bias's), so that every sum after it is the master tree's."""
     cd = compute_dtype(conf)
-    return (x.astype(cd) @ W.astype(cd)).astype(W.dtype)
+    out = W.dtype if master is None else jnp.promote_types(W.dtype, master)
+    return (x.astype(cd) @ W.astype(cd)).astype(out)
 
 
 def rows_broadcast(v, n_rows, dtype=None):
@@ -84,6 +88,10 @@ class StatelessDecode:
 class DenseLayer:
     """f(x.W + b) with optional dropout/dropconnect."""
 
+    #: the leaves a generation program reads only as a weight product's
+    #: operand, which `nn.decode.operand_tree` may hold in the compute dtype
+    OPERANDS = ("W",)
+
     @staticmethod
     def init(key, conf):
         kw, _ = jax.random.split(key)
@@ -99,7 +107,7 @@ class DenseLayer:
         W = params["W"]
         if training and conf.drop_connect and key is not None:
             W = W * ndr.dropout_mask(key, 0.5, W.shape, W.dtype)
-        z = mixed_matmul(x, W, conf)
+        z = mixed_matmul(x, W, conf, params["b"].dtype)
         if z.ndim == 2:  # gemm-broadcast the bias: pad-invariant bias grad
             return z + rows_broadcast(params["b"], z.shape[0], z.dtype)
         return z + params["b"]
@@ -275,6 +283,8 @@ class EmbeddingLayer:
 
     With conf.max_seq_len > 0 a learned positional table is added over the
     sequence axis (transformer-LM input embedding)."""
+
+    OPERANDS = ()       # `W` and `P` are gathered, not multiplied
 
     @staticmethod
     def init(key, conf):

@@ -53,6 +53,8 @@ from deeplearning4j_tpu.utils.profiling import scope
 
 
 class SwiGLULayer(StatelessDecode):
+    OPERANDS = ("Wgu", "Wd")
+
     @staticmethod
     def init(key, conf):
         s = conf.layer_spec
@@ -200,6 +202,10 @@ _SCORES = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}
 
 
 class MoELayer(StatelessDecode):
+    #: the experts' and the shared expert's matrices; the router's `Wr` is
+    #: multiplied in float32
+    OPERANDS = ("Wgu", "Wd", "sWgu", "sWd")
+
     @staticmethod
     def init(key, conf):
         s = conf.layer_spec

@@ -100,6 +100,7 @@ def walk_table(q, k, v, pos):
 
 class GQALayer:
     CARRY = False       # a finished row rewrites one cell with what it holds
+    OPERANDS = ("Wq", "Wk", "Wv", "Wo")   # read only as products' operands
 
     @staticmethod
     def init(key, conf):

@@ -53,6 +53,7 @@ def _widths(conf):
 
 class KDALayer:
     CARRY = True        # a finished row's state must not advance
+    OPERANDS = ("Wqkv", "Wa", "Wbg", "Wo")
 
     @staticmethod
     def init(key, conf):

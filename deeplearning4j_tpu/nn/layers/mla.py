@@ -125,6 +125,8 @@ def select_top(scores, valid, k: int):
 
 class MLALayer:
     CARRY = False       # a finished row rewrites one cell with what it holds
+    OPERANDS = ("Wq", "Wqa", "Wqb", "Wkva", "Wkvb", "Wiq", "Wik", "Wiw", "Wg",
+                "Wo")
 
     @staticmethod
     def init(key, conf):

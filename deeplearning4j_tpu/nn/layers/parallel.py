@@ -48,6 +48,9 @@ def _norm(params, conf, x):
 
 class ParallelLayer:
     CARRY = False       # the attention's table or ring
+    #: each branch's operands, by their path in the layer's params
+    OPERANDS = (tuple(("attn", name) for name in GQALayer.OPERANDS)
+                + tuple(("ffn", name) for name in MoELayer.OPERANDS))
 
     @staticmethod
     def init(key, conf):

@@ -31,6 +31,7 @@ from deeplearning4j_tpu.utils.profiling import scope
 
 class ShortConvLayer:
     CARRY = True        # a finished row's tail must not advance
+    OPERANDS = ("in_proj", "out_proj")
 
     @staticmethod
     def init(key, conf):

@@ -335,7 +335,7 @@ class MultiLayerNetwork:
         if seed is None:
             seed = conf.confs[0].seed if conf.confs else 123
         self._key = jax.random.PRNGKey(seed)
-        self.params: Optional[tuple] = None
+        self._params: Optional[tuple] = None
         self.listeners: List = []
         self._bn_ema_fn = None
         # compiled train-step cache: one AOT-compiled solver program per
@@ -369,6 +369,16 @@ class MultiLayerNetwork:
         # has no labels, so the delta is measured here, once, and the
         # batcher/server/router surface it read-only
         self._serve_precision_report: dict = {"policy": "f32"}
+
+    @property
+    def params(self) -> Optional[tuple]:
+        return self._params
+
+    @params.setter
+    def params(self, params: Optional[tuple]) -> None:
+        # a replaced or dropped tree takes what serving derived from it along
+        self._params = params
+        self.infer_cache.forget_params()
 
     # -- lifecycle ---------------------------------------------------------
     def _next_key(self):

@@ -41,6 +41,19 @@ the dominant non-compute overhead of accelerator inference.
                  are cast once on the host (memoized per tree); int8
                  serves the fixed quantized snapshot installed with the
                  policy and dequantizes to bf16 in-graph.
+  operands      a generation program (the decode family, `_run_decode`)
+                 is handed the params with every leaf its layer declares
+                 a weight product's operand (`OPERANDS`) held in the
+                 compute dtype where it is stored wider
+                 (`nn.decode.operand_tree`): the same products from half
+                 the bytes of float32 masters.  Cast once a params tree
+                 (memoized on its identity, dropped with the net's
+                 params: `forget_params`) beside the masters, which
+                 `output`, `feed_forward` and `loss` keep taking;
+                 the narrowed leaves' signature joins the program's key,
+                 so a master tree's program is never handed an operand
+                 tree.  Where nothing is wider the tree and the key are
+                 the masters' own.
   no donation   unlike the train cache, inference programs NEVER donate
                  their params buffer: the same params serve every call.
   observability `cache.stats` (hits / misses / steps / compile seconds)
@@ -106,6 +119,14 @@ class InferCache(CompiledProgramCache):
         # memoized bf16 cast of the last-served params tree (same
         # identity discipline as _placed_params)
         self._policy_params: Tuple = (None, None)
+        # (params, conf, operand tree, its key) of the last tree a
+        # generation program was handed (`_operand_params`, same identity
+        # discipline), and what `stats()` of the batcher reports of it:
+        # the trees cast so far, and the bytes of the served tree's
+        # narrowed leaves
+        self._operands: Tuple = (None,) * 4
+        self.operand_casts = 0
+        self.operand_bytes_narrowed = 0
 
     def _donate_argnums(self) -> Tuple[int, ...]:
         # serve-path params are reused by every subsequent call (and by
@@ -196,8 +217,16 @@ class InferCache(CompiledProgramCache):
         with self._lock:
             self._policy = policy
             self._qparams = qparams if policy == "int8" else None
-            self._policy_params = (None, None)
-            self._placed_params = (None, None)
+            self.forget_params()
+
+    def forget_params(self) -> None:
+        """Drop what the cache derived from the last params tree (its
+        policy cast, its placement, its operand tree): the net calls it
+        when its params are replaced or dropped, so no copy outlives
+        them."""
+        with self._lock:
+            self._policy_params = self._placed_params = (None, None)
+            self._operands = (None,) * 4
 
     @property
     def policy(self) -> str:
@@ -230,6 +259,28 @@ class InferCache(CompiledProgramCache):
             with self._lock:
                 self._policy_params = (params, cast)
         return cast
+
+    def _operand_params(self, conf, params) -> Tuple:
+        """(tree, key) of a generation program's params: `params` (as the
+        policy serves them) with their declared operands narrowed to the
+        compute dtype (`nn.decode.operand_tree`), and the signature of the
+        narrowed leaves, which the program's key takes (empty, and the
+        tree `params` itself, where nothing is wider).  Cast once a tree,
+        memoized on its identity as `_serve_params` is: a new tree (after
+        `fit`) is cast again."""
+        with self._lock:
+            held, held_conf, tree, key = self._operands
+        if held is params and held_conf is conf:
+            return tree, key
+        from deeplearning4j_tpu.nn.decode import operand_tree
+
+        tree, narrowed = operand_tree(_policy_conf(conf, self._policy), params)
+        key = tuple((tuple(a.shape), str(a.dtype)) for a in narrowed)
+        with self._lock:
+            self._operands = (params, conf, tree, key)
+            self.operand_bytes_narrowed = sum(a.nbytes for a in narrowed)
+            self.operand_casts += bool(narrowed)
+        return tree, key
 
     def programs_summary(self):
         """Resident compiled programs as (entry, bucket, sharding,
@@ -390,24 +441,27 @@ class InferCache(CompiledProgramCache):
 
         `program(conf, policy)` builds the function to compile.  Its
         arguments are `(head, state) + rest`: `head` the params as the
-        policy serves them (for `write_row` the row: `head_is_row`),
+        policy serves them, their declared operands narrowed
+        (`_operand_params`; for `write_row` the row: `head_is_row`),
         `state` the decode state or slot table — argument 1, donated
         off-CPU, and last among the outputs — and `rest` the small host
         arguments.  The key is (entry, fingerprint, the signature of
-        `keyed` (default: `rest`) and then of the state's leaves, the
-        decode tag) + the policy suffix.  Under a plan with a `model` axis
-        the head and the state are sharded leaf by leaf per the plan and
-        the rest replicated, at compile time and at the call (params
-        memoized; a no-op for the state in the steady loop, whose output
-        the program pins to the same specs); without one, generation stays
-        a single-chip program and nothing is placed.  compile_only=True
-        (warmup) compiles, or restores from disk, and returns None."""
+        `keyed` (default: `rest`), then of the state's leaves and then of
+        the narrowed operands, the decode tag) + the policy suffix.  Under
+        a plan with a `model` axis the head and the state are sharded leaf
+        by leaf per the plan and the rest replicated, at compile time and
+        at the call (params memoized; a no-op for the state in the steady
+        loop, whose output the program pins to the same specs); without
+        one, generation stays a single-chip program and nothing is placed.
+        compile_only=True (warmup) compiles, or restores from disk, and
+        returns None."""
         plan, policy = self.plan, self._policy
+        operands = ()
         if not head_is_row:
-            head = self._serve_params(head)
+            head, operands = self._operand_params(conf, self._serve_params(head))
         key = (entry, self._fingerprint(conf),
                arg_signature(*(rest if keyed is None else keyed),
-                             *jax.tree_util.tree_leaves(state)),
+                             *jax.tree_util.tree_leaves(state)) + operands,
                self._decode_tag()) + self._policy_suffix()
         shardings = None
         if plan.has_model_axis:

@@ -2295,6 +2295,11 @@ class ContinuousBatcher:
                 "queue_wait_seconds_total": round(self._queue_wait_s, 6),
                 "kernel_layers": self._kernel_layers,
                 "state_bytes_per_row": self._state_bytes,
+                # the served tree's weight operands held in the compute
+                # dtype beside their masters (`InferCache._operand_params`),
+                # and the trees cast so far: one a params tree
+                "operand_bytes_narrowed": self.net.infer_cache.operand_bytes_narrowed,
+                "operand_casts_total": self.net.infer_cache.operand_casts,
                 "decode_block_steps": {
                     "bounds": list(DECODE_BLOCK_STEPS_BOUNDS),
                     "counts": list(bh["counts"]),

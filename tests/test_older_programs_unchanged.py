@@ -4,7 +4,9 @@ into a residual layer and a branch it calls, the prefill's block of queries
 from a rule on the heads (`gqa.q_block`), the rotary variants, the head's
 norm and tie and the ring counts reach none of them.  Each family's decode
 step (4 slots) and prefill (a bucket of 64) at its rehearsal's sizes on the
-CPU, lowered and never compiled."""
+CPU, lowered and never compiled.  Handed their tree through the operand rule
+(`nn.decode.operand_tree`) they lower to the same texts: their weights are
+bfloat16 already, and the rule casts none."""
 
 import hashlib
 
@@ -30,6 +32,8 @@ def lowered_texts(workload: str) -> dict:
     cfg = run.load_cell(workload, rehearse=True).cfg
     conf = families.of(cfg).program.build_conf(cfg)
     params = jax.eval_shape(lambda k: init_params(conf, k), jax.random.PRNGKey(0))
+    tree, narrowed = decode.operand_tree(conf, params)
+    assert tree is params and narrowed == []
     table = lambda rows: jax.eval_shape(lambda: decode.init_state(conf, rows, 96))  # noqa: E731
     ids = jax.ShapeDtypeStruct((4,), jnp.int32)
     texts = {"decode": jax.jit(lambda p, s, t, q: decode.decode_step(conf, p, s, t, q)).lower(

@@ -640,3 +640,82 @@ def test_gpt2s_decode_walks_its_table_where_it_lies(one_chip, monkeypatch):
         rows(jnp.int32), rows(jnp.int32), rows(jnp.uint32, 2), rows(jnp.float32)).compile()
     assert "dl4j_gqa_decode" in program.as_text()
     assert _table_copies(program.as_text(), "bf16", table) == 0
+
+
+def _weight_converts(text, shapes) -> int:
+    """The converts in `text` whose result is a bfloat16 array of one of
+    `shapes`: a weight leaf cast inside the program."""
+    import re
+
+    dims = "|".join(",".join(map(str, s)) for s in shapes)
+    return len(re.findall(rf"= bf16\[(?:{dims})\]\S* convert\(", text))
+
+
+def test_gpt2s_decode_reads_its_weight_operands_in_bfloat16(one_chip, monkeypatch):
+    """GPT-2 1.3B's decode program at every published width (one block of 24)
+    on the chip's path, compiled from the float32 masters and from the operand
+    tree (`nn.decode.operand_tree`): its arguments fall by the narrowed
+    operands' bytes (306.5 MB here, 2.62 GB at the cell's 24 blocks), and the
+    casts of the weight leaves that the masters' program makes (the head's and
+    each block's four) are gone from it."""
+    from deeplearning4j_tpu.nn import decode
+    from deeplearning4j_tpu.nn.layers import gqa
+    from deeplearning4j_tpu.nn.multilayer import init_params
+    from deeplearning4j_tpu.optimize.infer_cache import _decode_program
+
+    monkeypatch.setattr(pk, "is_tpu", lambda: True)
+    monkeypatch.setattr(gqa, "is_tpu", lambda: True)
+    conf = _gpt2_block()
+    shaped = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    masters = shaped(jax.eval_shape(lambda k: init_params(conf, k), jax.random.PRNGKey(0)))
+    operands, narrowed = shaped(jax.eval_shape(lambda p: decode.operand_tree(conf, p), masters))
+    shapes = {a.shape for a in narrowed}
+    assert shapes == {(2048, 6144), (2048, 2048), (2048, 8192), (8192, 2048), (2048, 50257)}
+    assert all(a.dtype == jnp.bfloat16 for a in narrowed)
+    state = shaped(jax.eval_shape(lambda: decode.init_state(conf, 8, 2048)))
+    rows = lambda dt, *shape: shaped(jax.ShapeDtypeStruct((8,) + shape, dt))  # noqa: E731
+
+    def compiled(params):
+        return jax.jit(_decode_program(conf), donate_argnums=(1,)).lower(
+            params, state, rows(jnp.int32), rows(jnp.int32), rows(jnp.uint32, 2),
+            rows(jnp.float32)).compile()
+
+    before, after = compiled(masters), compiled(operands)
+    fell = (before.memory_analysis().argument_size_in_bytes
+            - after.memory_analysis().argument_size_in_bytes)
+    narrow_bytes = sum(a.size * 2 for a in narrowed)
+    assert narrow_bytes == 306_515_968
+    assert abs(fell - narrow_bytes) < 0.001 * narrow_bytes
+    assert _weight_converts(before.as_text(), shapes) >= len(narrowed)
+    assert _weight_converts(after.as_text(), shapes) == 0
+    assert "dl4j_gqa_decode" in after.as_text()
+
+
+def test_the_training_steps_text_is_the_parents():
+    """`train-590m-2k`'s data-parallel step at its real widths and batch,
+    lowered (not compiled): the text it had before generation programs took
+    narrowed operands (commit 97a9b27), since `mixed_matmul` returns a float32
+    product from float32 weights as it did."""
+    import hashlib
+
+    from benchmark import families, run
+    from deeplearning4j_tpu.nn.multilayer import init_params
+    from deeplearning4j_tpu.optimize.updater import init_updater
+    from deeplearning4j_tpu.parallel.data_parallel import TrainState, make_dp_train_step
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+
+    cell = run.load_cell("train-590m-2k", rehearse=False)
+    conf = families.of(cell.cfg).program.build_conf(cell.cfg)
+
+    def state(key):
+        params = init_params(conf, key)
+        return TrainState(params, init_updater(params), jnp.asarray(0, jnp.int32))
+
+    rows, seq = cell.mix["rows"], cell.mix["seq"]
+    text = make_dp_train_step(conf, make_mesh({"dp": 1})).lower(
+        jax.eval_shape(state, jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((rows, seq), jnp.int32),
+        jax.ShapeDtypeStruct((rows * seq,), jnp.int32),
+        jax.eval_shape(lambda: jax.random.PRNGKey(0))).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "37239e68ca97f6d8"
