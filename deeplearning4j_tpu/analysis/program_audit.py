@@ -22,16 +22,14 @@ invariants:
   undonated-step         a train-step program compiled without donating
                          its params buffer where donation is available
                          (double-buffers every parameter in HBM)
-  undonated-kv-cache     a decode/prefill/verify/decode-multi[K]
-                         program compiled without donating its
-                         decode-state buffers where donation is
-                         available — the KV cache is the largest live
-                         buffer in a generation server, and an
-                         undonated one is double-buffered every single
-                         token (or every K-token block)
+  undonated-kv-cache     a decode/prefill/verify program compiled
+                         without donating its decode-state buffers
+                         where donation is available — the KV cache is
+                         the largest live buffer in a generation
+                         server, and an undonated one is
+                         double-buffered every single token
   undonated-kv-pages     the paged variant of the same rule: a
-                         decode-paged/verify-paged/
-                         decode-multi-paged[K] program compiled
+                         decode-paged/verify-paged program compiled
                          without donating the shared physical page
                          pool — the pool IS the server's KV memory,
                          so an undonated one doubles the whole
@@ -387,14 +385,10 @@ def audit_cache(cache, *, expect_donation: Optional[bool] = None,
                 "undonated-step", "error", f"program:{where}",
                 "train-step program compiled without donating its params "
                 "buffer — every parameter is double-buffered in HBM"))
-        # K is folded into the entry name ("decode-multi[4]"), so the
-        # fused kinds match by prefix; the bracket keeps "decode-multi["
-        # from swallowing "decode-multi-paged[..." entries
         if (rec["kind"] == "infer-cache" and rec["key"]
-                and (rec["key"][0] in ("decode", "prefill", "verify",
-                                       "prefill-logp", "prefill-slot",
-                                       "prefill-logp-slot", "write-row")
-                     or rec["key"][0].startswith("decode-multi["))
+                and rec["key"][0] in ("decode", "prefill", "verify",
+                                      "prefill-logp", "prefill-slot",
+                                      "prefill-logp-slot", "write-row")
                 and not rec["donate_argnums"]
                 and _donation_expected(expect_donation)):
             findings.append(Finding(
@@ -403,8 +397,7 @@ def audit_cache(cache, *, expect_donation: Optional[bool] = None,
                 f"decode-state buffers — the KV cache is double-buffered "
                 f"in HBM on every token"))
         if (rec["kind"] == "infer-cache" and rec["key"]
-                and (rec["key"][0] in ("decode-paged", "verify-paged")
-                     or rec["key"][0].startswith("decode-multi-paged["))
+                and rec["key"][0] in ("decode-paged", "verify-paged")
                 and not rec["donate_argnums"]
                 and _donation_expected(expect_donation)):
             findings.append(Finding(
@@ -470,12 +463,10 @@ def audit_zoo_models(small: bool = True, rows: int = 4,
             # fixed audit geometry, NOT a serving default: the
             # auditor pins tiny shapes so every variant compiles
             net.warmup_generate(slots=2, max_seq=8,  # lint: allow(hardcoded-tunable)
-                                prompt_buckets=(4,),
-                                steps_per_dispatch=4)  # lint: allow(hardcoded-tunable)
+                                prompt_buckets=(4,))
             net.warmup_generate(slots=2, max_seq=8,  # lint: allow(hardcoded-tunable)
                                 prompt_buckets=(4,),
-                                page_size=4, prefix_cache=True,  # lint: allow(hardcoded-tunable)
-                                steps_per_dispatch=4)  # lint: allow(hardcoded-tunable)
+                                page_size=4, prefix_cache=True)  # lint: allow(hardcoded-tunable)
             draft = MultiLayerNetwork(
                 zoo.char_lstm(conf.conf(-1).n_out, hidden=8, n_layers=1),
                 seed=0).init()
@@ -558,26 +549,6 @@ def audit_decode_structure(S: int = 1024) -> List[Finding]:
 
     findings += audit_fn(step, (net.params, pstate, tok, pos, page_table),
                          where=f"decode-step-paged:S={S}",
-                         seq_threshold=S)
-
-    # the K-step fused block must keep the same score-shape story AT
-    # EVERY scan step (the scan body is traced once, so one trace
-    # covers all K), stay free of host callbacks (the whole point is K
-    # device-resident tokens per host round-trip), and keep sampling
-    # in-program — trace the exact builders the infer cache compiles
-    from deeplearning4j_tpu.optimize.infer_cache import _decode_multi_program
-
-    keys = jnp.zeros((1, 2), jnp.uint32)
-    temps = jnp.zeros((1,), jnp.float32)
-    rem = jnp.full((1,), 4, jnp.int32)
-    block = _decode_multi_program(conf, "f32", 4)
-    findings += audit_fn(block,
-                         (net.params, state, tok, pos, keys, temps, rem),
-                         where=f"decode-multi[4]:S={S}", seq_threshold=S)
-    findings += audit_fn(block,
-                         (net.params, pstate, tok, pos, keys, temps, rem,
-                          page_table),
-                         where=f"decode-multi-paged[4]:S={S}",
                          seq_threshold=S)
     return findings
 

@@ -149,8 +149,7 @@ _TUNABLE_CONST_NAMES = {"DEFAULT_TARGET_ROWS", "_BLOCK_TABLE",
 _TUNABLE_KWARGS = {"max_delay_ms", "block_q", "block_k", "block_q_bwd",
                    "block_k_bwd", "buffer_batches", "n_slots", "slots",
                    "gen_slots", "page_size", "gen_page_size",
-                   "target_rows", "prefetch_depth", "steps_per_dispatch",
-                   "gen_steps_per_dispatch"}
+                   "target_rows", "prefetch_depth"}
 
 
 def _rule_hardcoded_tunable(tree: ast.AST, relpath: str) -> List[Finding]:

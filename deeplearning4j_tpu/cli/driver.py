@@ -585,8 +585,7 @@ def _warm_generate(net, args, draft=None) -> dict:
         n_pages=getattr(args, "gen_pages", 0),
         prefix_cache=getattr(args, "gen_prefix_cache", False),
         draft_net=draft,
-        spec_k=getattr(args, "gen_spec_k", 0),
-        steps_per_dispatch=getattr(args, "gen_steps_per_dispatch", None))
+        spec_k=getattr(args, "gen_spec_k", 0))
     summary.pop("infer_cache", None)  # _build_server reports cache stats
     return summary
 
@@ -621,9 +620,7 @@ def cmd_generate(args) -> int:
                         prefix_cache=getattr(args, "gen_prefix_cache",
                                              False),
                         draft_net=draft,
-                        spec_k=getattr(args, "gen_spec_k", 0),
-                        steps_per_dispatch=getattr(
-                            args, "gen_steps_per_dispatch", None))
+                        spec_k=getattr(args, "gen_spec_k", 0))
     warmed_misses = net.infer_cache.stats.misses
     batcher = ContinuousBatcher(net, n_slots=1,  # lint: allow(hardcoded-tunable)
                                 max_seq=args.gen_max_seq,
@@ -634,9 +631,7 @@ def cmd_generate(args) -> int:
                                                      "gen_prefix_cache",
                                                      False),
                                 draft_net=draft,
-                                spec_k=getattr(args, "gen_spec_k", 0),
-                                steps_per_dispatch=getattr(
-                                    args, "gen_steps_per_dispatch", None))
+                                spec_k=getattr(args, "gen_spec_k", 0))
     try:
         t0 = time.perf_counter()
         stream = batcher.submit(prompt,
@@ -717,9 +712,7 @@ def _build_server(args):
                        gen_prefix_match=getattr(args, "gen_prefix_match",
                                                 "exact"),
                        gen_draft=gen_draft,
-                       gen_spec_k=getattr(args, "gen_spec_k", 0),
-                       gen_steps_per_dispatch=getattr(
-                           args, "gen_steps_per_dispatch", None))
+                       gen_spec_k=getattr(args, "gen_spec_k", 0))
     summary = {"url": server.url, "warmed": warmed,
                "fresh_compiles": net.infer_cache.stats.misses,
                "batching": not args.no_batching,
@@ -1211,15 +1204,6 @@ def _add_generate_flags(p: argparse.ArgumentParser) -> None:
                         "tokens, ONE verify step accepts the agreeing "
                         "prefix (greedy output token-identical to "
                         "non-speculative decode)")
-    p.add_argument("--gen-steps-per-dispatch", dest="gen_steps_per_dispatch",
-                   type=int, default=None,
-                   help="max decode steps fused per device dispatch "
-                        "(K); the batcher ramps 1 -> K while the slot "
-                        "set is stable and drops to 1 on admissions "
-                        "or preemptions; tokens are identical for any "
-                        "K; default: the decode.steps_per_dispatch "
-                        "tunable (1, or the tuned table); incompatible "
-                        "with --gen-spec-k")
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -1381,11 +1365,6 @@ def build_parser() -> argparse.ArgumentParser:
     g.add_argument("--spec-k", dest="gen_spec_k", type=int, default=0,
                    help="speculative chunk size (>= 2; draft proposes "
                         "spec_k - 1 tokens per verify step)")
-    g.add_argument("--steps-per-dispatch", dest="gen_steps_per_dispatch",
-                   type=int, default=None,
-                   help="max decode steps fused per device dispatch "
-                        "(token-identical output for any K; "
-                        "incompatible with --spec-k)")
     g.add_argument("--mesh", nargs="?", const="all", default=None,
                    metavar="SPEC",
                    help="decode on a device mesh (bare flag = 1-D batch "

@@ -33,9 +33,9 @@ State layout: one dict per layer, in layer order, as a tuple —
   GQA               {"k": [B, G, max_S or window, h] compute_dtype, "v": same}
   everything else   {}
 each made by its layer class's `init_state`; `CARRY` on the class says
-whether the state advances with every token (so that `decode_block` must
-hold a finished row's still) or is a table written at `pos`.  `zero_row`
-and `write_row` treat every kind alike, leaf by leaf along axis 0.  A type
+whether the state advances with every token (a step reads it whole) or is
+a table written at `pos`.  `zero_row` and `write_row` treat every kind
+alike, leaf by leaf along axis 0.  A type
 whose class has no `init_paged_state` and `verify_chunk` lives in the dense
 table only: the paged pool and the verify chunk refuse it (`dense_only`).
 The tuple-of-dicts shape makes the whole state one donatable jit
@@ -47,8 +47,8 @@ layer's dense [B, H, max_S, hd] table with a shared physical page pool
   ATTENTION         {"k": [n_pages, page_size, n], "v": same}
 addressed through a per-call `page_table` [B, pages_per_slot] int32 of
 physical page ids — cache memory scales with LIVE pages, not
-slots x max_seq.  `decode_step`, `decode_block` and `verify_chunk` take the
-table as `page_table=None` and hand it to the layers; how attention writes
+slots x max_seq.  `decode_step` and `verify_chunk` take the table as
+`page_table=None` and hand it to the layers; how attention writes
 and reads through it, token-identical to the dense step, is told at
 `layers/attention.py:decode_step`.  The host (serving/batcher.py) owns
 the free list and keeps physical page 0 as a scratch page every
@@ -62,9 +62,16 @@ what greedy acceptance compares draft tokens against.  Rows re-walk a
 mis-speculated suffix by simply rewriting those positions next call —
 the cache never needs a rollback because `decode_step`/`verify_chunk`
 always overwrite position `pos` before attending to it.
+
+`StepCounts` is what the serving loop knows of the layers' reads: built once
+for a slot table, it turns one completed step's row positions (and the
+expert layers' counts) into the cells, picks and bytes that step read, and
+running totals of those into the loop's counters.
 """
 
 from __future__ import annotations
+
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -75,11 +82,6 @@ from deeplearning4j_tpu.nn.layers import GENERATIVE_HIDDEN, get_layer
 from deeplearning4j_tpu.nn.layers.base import compute_dtype
 from deeplearning4j_tpu.nn.layers.output import OutputLayer
 from deeplearning4j_tpu.utils.profiling import layer_scope, scope
-
-#: token emitted by `decode_block` for scan steps a row sat frozen
-#: (its `rem` budget exhausted mid-block) — never a valid token id
-BLOCK_SENTINEL = -1
-
 
 def check_generative(conf: MultiLayerConfiguration):
     """Validate that `conf` is a decodable generative stack and return
@@ -183,7 +185,7 @@ def state_bytes_read(conf: MultiLayerConfiguration, max_seq: int):
     lies in memory, by kind: `table`, of every layer that counts its state
     in cells, the most cells its step reads times a cell's bytes
     (`kv_cells_read` x `kv_cell_bytes`; where the step walks a row's blocks
-    up to its position, the batcher counts them by the rows' positions),
+    up to its position, `StepCounts` counts them by the rows' positions),
     and `carry`, of every layer whose state advances
     with each token (`CARRY`), the whole of it.  None where a layer keeps a
     state that says neither: its read cannot be counted."""
@@ -215,6 +217,134 @@ def kernel_layers(conf: MultiLayerConfiguration, max_seq: int) -> int:
     row's blocks up to its position (`kv_read_block` > 0): one a layer that
     attends through `dl4j_gqa_decode` or `dl4j_dsa_decode`."""
     return sum(block > 0 for _, _, block, _ in kv_cells(conf, max_seq))
+
+
+def _cells_needed(pos, adv, most: int) -> int:
+    """Summed over the rows that advance (`adv` 1, not 0), at their positions
+    `pos`: `min(position + 1, most)` cells."""
+    return int((adv * np.minimum(pos + 1, most)).sum())
+
+
+def _cells_walked(pos, block: int, most: int) -> int:
+    """Summed over every row, live or not: the cells of a table of `most`
+    that a read walking blocks of `block` up to the row's position covers,
+    `ceil((position + 1) / block) x block` (a position past the table walks
+    it whole)."""
+    return int(((np.clip(pos, -1, most - 1) + block) // block * block).sum())
+
+
+#: the `stats()` key under which the serving loop reports each count's total
+_TOTALS = {"picks_here": "expert_picks_total", "experts_hit": "experts_hit_total",
+           "kv_cells_live": "kv_cells_live_total",
+           "kv_cells_spanned": "kv_cells_spanned_total",
+           "dsa_cells_live": "dsa_cells_live_total",
+           "dsa_cells_selected": "dsa_cells_selected_total",
+           "ring_cells_live": "ring_cells_live_total",
+           "ring_cells_spanned": "ring_cells_spanned_total"}
+
+
+class StepCounts:
+    """What a decode step of `conf` over a slot table of `n_slots` rows and
+    `max_seq` positions reads, counted from the rows' positions: the layers'
+    descriptors above, asked once.  `step` turns one completed step into the
+    counts its `decode` span carries; `totals` turns the counts summed over
+    steps into the serving loop's `stats()` keys.  The loop names no layer
+    kind: a stack without a kind of state has none of its counts."""
+
+    def __init__(self, conf: MultiLayerConfiguration, n_slots: int,
+                 max_seq: int):
+        self.n_slots, self.max_seq = int(n_slots), int(max_seq)
+        self.has_experts = has_experts(conf)
+        # static a program: a decode step is one call of `n_slots` rows
+        self.experts_batched_layers = experts_batched_layers(conf, self.n_slots)
+        # {(cells a row holds, the most of them a step reads, the block it
+        # walks them in or 0, a cell's bytes): tables} of the layers that
+        # count their state in cells a position
+        self.kv_cells = Counter(kv_cells(conf, self.max_seq))
+        # of those, the rings: a table whose step reads fewer cells than a
+        # row has positions holds a window's, `{cells: tables}` (an indexed
+        # table NEEDS fewer, its picks, but is read whole)
+        self.rings = Counter()
+        for (_, read, _, _), n in self.kv_cells.items():
+            if read < self.max_seq:
+                self.rings[read] += n
+        # {kind: bytes a decode step reads of one row's state}, or None
+        self.state_bytes = state_bytes_read(conf, self.max_seq)
+        # {positions a layer's step picks among a row's cached ones: layers}
+        self.dsa_picks = Counter(selected_cells(conf, self.max_seq))
+        # static a program: the tables a step walks through a kernel
+        self.kernel_layers = kernel_layers(conf, self.max_seq)
+        # the counts of `step` that `totals` reports, by what the stack has
+        self._totalled = (
+            ("picks_here", "experts_hit") * self.has_experts
+            + ("kv_cells_live", "kv_cells_spanned") * bool(self.kv_cells)
+            + ("dsa_cells_live", "dsa_cells_selected") * bool(self.kv_cells and self.dsa_picks)
+            + ("ring_cells_live", "ring_cells_spanned") * bool(self.rings))
+
+    def step(self, pos, adv, counts=None) -> dict:
+        """The counts of one completed step in which row r, at position
+        `pos[r]`, advanced `adv[r]` (1) or not (0: a free or a finished row),
+        and the expert layers counted `counts` ([picks that landed on held
+        experts, distinct held experts hit] summed over the layers; None for
+        a stack without them), as `decode` span attributes:
+
+        `picks_here`, `experts_hit` and `steps` (1), where there are counts.
+        `kv_cells_live`: what the step has to read, `min(position + 1, the
+        most the table can need)` a live row and table.  `kv_cells_spanned`:
+        what the layers' reads cover, a row of the slot table and table:
+        `kv_cells_read`, or where a layer walks a row's cells in blocks up to
+        its position (`kv_read_block`) those blocks.  `state_bytes_read`:
+        what the step read of every row's state, the spanned cells' bytes and
+        every carry whole.  `dsa_cells_live` and `dsa_cells_selected`, for
+        layers that pick the positions they attend to: the positions cached
+        at or before a live row's own and `min(position + 1, picks)` of them,
+        a row and such layer; they describe the traffic (how far the rows
+        are past the picks), not the read.  `ring_cells_live` and
+        `ring_cells_spanned`, for the rings among the tables (read whole a
+        step): `min(position + 1, window)` a live row and ring, and `slots x
+        window` a ring.  A count the stack cannot have is left out."""
+        out = {}
+        if counts is not None:
+            picks, hit = (int(n) for n in counts)
+            out.update(picks_here=picks, experts_hit=hit, steps=1)
+        p, a = np.asarray(pos, np.int64), np.asarray(adv, np.int64)
+        live = spanned = table_bytes = 0
+        for (most, read, block, cell), n in self.kv_cells.items():
+            live += n * _cells_needed(p, a, most)
+            cells = n * (_cells_walked(p, block, read) if block
+                         else self.n_slots * read)
+            spanned += cells
+            table_bytes += cells * cell
+        if self.state_bytes and sum(self.state_bytes.values()):
+            out["state_bytes_read"] = (table_bytes + self.n_slots
+                                       * self.state_bytes["carry"])
+        if not self.kv_cells:
+            return out
+        out.update(kv_cells_live=live, kv_cells_spanned=spanned)
+        if self.dsa_picks:
+            out["dsa_cells_live"] = sum(
+                n * _cells_needed(p, a, self.max_seq) for n in self.dsa_picks.values())
+            out["dsa_cells_selected"] = sum(
+                n * _cells_needed(p, a, k) for k, n in self.dsa_picks.items())
+        if self.rings:
+            out["ring_cells_live"] = sum(
+                n * _cells_needed(p, a, w) for w, n in self.rings.items())
+            out["ring_cells_spanned"] = sum(
+                n * self.n_slots * w for w, n in self.rings.items())
+        return out
+
+    def totals(self, sums) -> dict:
+        """The serving loop's `stats()` keys from `sums`, `step`'s counts
+        summed over steps: the running totals of the counts this stack has
+        (`expert_picks_total` ... `ring_cells_spanned_total`, 0 before any
+        step), `experts_batched_layers` beside the experts', and
+        `kernel_layers` and `state_bytes_per_row` always."""
+        out = {_TOTALS[name]: sums.get(name, 0) for name in self._totalled}
+        if self.has_experts:
+            out["experts_batched_layers"] = self.experts_batched_layers
+        out.update(kernel_layers=self.kernel_layers,
+                   state_bytes_per_row=self.state_bytes)
+        return out
 
 
 def operand_tree(conf: MultiLayerConfiguration, params):
@@ -376,69 +506,6 @@ def decode_step(conf: MultiLayerConfiguration, params, state, tok, pos,
     [B, pages_per_slot] int32 over a paged state does."""
     logp, state, _ = step(conf, params, state, tok, pos, page_table)
     return logp, state
-
-
-def decode_block(conf: MultiLayerConfiguration, params, state, tok, pos,
-                 keys, temps, rem, k: int, sample, page_table=None):
-    """Fused multi-step decode (ISSUE 19): advance every row up to `k`
-    tokens in ONE program — a `lax.scan` whose body is exactly
-    `decode_step` (over the paged state when `page_table` is given)
-    followed by the injected `sample(logp, keys, temps) -> (tok, keys)`
-    on-device sampler.  One host dispatch per K tokens instead of per
-    token; the token trajectory is bitwise-identical to K sequential
-    one-step calls for any K.
-
-    rem [B] int32 is each row's remaining token budget.  A row whose
-    budget hits 0 mid-block FREEZES: its tok/pos/key and recurrent
-    carries stop advancing (cheap [B]-shaped `where`s — no full-cache
-    select), and its scan outputs turn into `BLOCK_SENTINEL`.  Its K/V
-    cache needs no mask at all: with tok and pos frozen, the step
-    rewrites the SAME cache cell with bitwise-identical values
-    (deterministic math over identical inputs), so "stops mutating"
-    holds value-for-value, and for released paged rows the host's
-    page table already points every write at the inert scratch page.
-
-    The key-split discipline matches the one-step path exactly: the
-    sampler runs over the full batch every scan step, but a frozen
-    row's advanced key is discarded, so its key splits precisely once
-    per token it actually emitted — the same count K=1 decoding burns.
-
-    Returns (toks [k, B] int32 scan outputs, tok [B] (last real token
-    per row), keys [B, 2], state) — state LAST, the donation/TP
-    contract every decode-family program shares.  Where the stack has
-    layers that count (`has_experts`), their counts summed over the k steps,
-    [2] int32, come before the state."""
-    carried = [i for i, _, impl in _hidden(conf) if impl.CARRY]
-
-    def hold(active, new, old):
-        """`new` for the rows still going, `old` for the finished ones."""
-        return jnp.where(active.reshape((-1,) + (1,) * (new.ndim - 1)),
-                         new, old)
-
-    def body(carry, _):
-        st, t, p, ks, r = carry
-        active = r > 0
-        logp, st2, counts = step(conf, params, st, t, p, page_table)
-        t2, ks2 = sample(logp, ks, temps)
-        frozen = list(st2)
-        for i in carried:
-            frozen[i] = {name: hold(active, st2[i][name], st[i][name])
-                         for name in st2[i]}
-        out = jnp.where(active, t2, jnp.int32(BLOCK_SENTINEL))
-        t3 = jnp.where(active, t2, t)
-        ks3 = jnp.where(active[:, None], ks2, ks)
-        p3 = jnp.where(active, p + 1, p)
-        r3 = jnp.where(active, r - 1, r)
-        return (tuple(frozen), t3, p3, ks3, r3), (
-            out if counts is None else (out, counts))
-
-    carry, outs = jax.lax.scan(
-        body, (state, tok, pos, keys, rem), xs=None, length=int(k))
-    state, tok, _, keys, _ = carry
-    if has_experts(conf):
-        toks, counts = outs
-        return toks, tok, keys, jnp.sum(counts, axis=0), state
-    return outs, tok, keys, state
 
 
 def verify_chunk(conf: MultiLayerConfiguration, params, state, toks, pos,

@@ -17,9 +17,8 @@ walks the layers and calls, never asking which type it has before it:
                                                    with the rows on axis 0
     prefill(params, conf, x, state, length)     -> (hidden [B, T, n], state)
     decode_step(params, conf, x, state, pos)    -> (hidden [B, n], state)
-    CARRY   does the state advance with every token (a recurrent carry: a
-            fused K-step block must hold a finished row's still), or is it
-            a table written at `pos` (rewriting a cell changes nothing)?
+    CARRY   does the state advance with every token (a recurrent carry,
+            which a step reads whole), or is it a table written at `pos`?
 What only some types can do, a class declares by having it:
     init_paged_state(conf, batch, n_pages, page_size) -> state
             its state can live in the page pool; `decode_step`,
@@ -38,12 +37,12 @@ What only some types can do, a class declares by having it:
             step picks), and `decode_step` reads at most so many for every
             row of the slot table, all of them where `kv_read_block` is 0,
             else the blocks of that many cells up to the row's position:
-            the batcher counts the cells a step needs beside those the
-            layer says its read covers
+            `nn.decode.StepCounts` counts the cells a step needs beside
+            those the layer says its read covers
     selects(conf, max_seq)                      -> int
             its decode step picks so many of a row's cached positions and
-            attends to those alone (0: to all): the batcher counts the
-            positions cached beside those picked
+            attends to those alone (0: to all): `nn.decode.StepCounts`
+            counts the positions cached beside those picked
     kv_cell_bytes(conf, max_seq)                -> int, or one a table
             beside `kv_cells`: the bytes of one cell as the state lays it
             out in memory

@@ -139,7 +139,7 @@ class MultiHeadAttentionLayer:
         return o
 
     # -- decode protocol (`nn/layers/__init__.py`): a K/V table, or pages ----
-    CARRY = False       # a finished row rewrites one cell with what it holds
+    CARRY = False       # a table: a step writes the cell at `pos`
     SPLIT_HEADS = True  # a `model` axis splits the table's heads (`parallel.plan`)
 
     @staticmethod

@@ -99,7 +99,7 @@ def walk_table(q, k, v, pos):
 
 
 class GQALayer:
-    CARRY = False       # a finished row rewrites one cell with what it holds
+    CARRY = False       # a table: a step writes the cell at `pos`
     OPERANDS = ("Wq", "Wk", "Wv", "Wo")   # read only as products' operands
 
     @staticmethod

@@ -15,7 +15,7 @@ Decode state, a row a slot: {"S": [B, H, dk, dv] float32, "conv": [B, K-1,
 3 H dk] compute dtype}, the last K-1 inputs of the convolution.  It is a
 carry: a prompt padded to its bucket leaves both as they were at `length -
 1` (padded positions get g = 0 and beta = 0, under which the recurrence is
-the identity), and `nn.decode.decode_block` keeps a finished row's.
+the identity), and a decode step reads and writes it whole.
 
 `prefill` runs the recurrence in chunks of `CHUNK` tokens.  Inside a chunk,
 with G_t the running sum of g from the chunk's start and S_0 the state
@@ -52,7 +52,7 @@ def _widths(conf):
 
 
 class KDALayer:
-    CARRY = True        # a finished row's state must not advance
+    CARRY = True        # advances with every token, read whole a step
     OPERANDS = ("Wqkv", "Wa", "Wbg", "Wo")
 
     @staticmethod

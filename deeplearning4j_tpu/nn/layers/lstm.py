@@ -129,7 +129,7 @@ class LSTMLayer:
         return h, c
 
     # -- decode protocol (`nn/layers/__init__.py`): the carry (h, c) ----------
-    CARRY = True        # a finished row's carry must not advance
+    CARRY = True        # advances with every token, read whole a step
 
     @staticmethod
     def init_state(conf, batch: int, max_seq: int) -> dict:

@@ -124,7 +124,7 @@ def select_top(scores, valid, k: int):
 
 
 class MLALayer:
-    CARRY = False       # a finished row rewrites one cell with what it holds
+    CARRY = False       # a table: a step writes the cell at `pos`
     OPERANDS = ("Wq", "Wqa", "Wqb", "Wkva", "Wkvb", "Wiq", "Wik", "Wiw", "Wg",
                 "Wo")
 

@@ -13,8 +13,8 @@ see the same inputs), and the convolution's sums and the gates are float32.
 
 Decode state, a row a slot: {"conv": [B, K-1, d]} in the compute dtype, the
 last K-1 values of z.  It is a carry: a prompt padded to its bucket leaves it
-as it stood after token `length - 1`, and `nn.decode.decode_block` keeps a
-finished row's.  It holds no position, so neither `init_paged_state` nor
+as it stood after token `length - 1`, and a decode step reads and writes it
+whole.  It holds no position, so neither `init_paged_state` nor
 `verify_chunk`: the state lives in the dense slot table only.
 """
 
@@ -30,7 +30,7 @@ from deeplearning4j_tpu.utils.profiling import scope
 
 
 class ShortConvLayer:
-    CARRY = True        # a finished row's tail must not advance
+    CARRY = True        # advances with every token, read whole a step
     OPERANDS = ("in_proj", "out_proj")
 
     @staticmethod

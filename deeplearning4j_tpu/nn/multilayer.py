@@ -571,8 +571,7 @@ class MultiLayerNetwork:
                         prompt_buckets: Sequence[int] = (8,),
                         page_size: Optional[int] = None, n_pages: int = 0,
                         prefix_cache: bool = False, draft_net=None,
-                        spec_k: int = 0,
-                        steps_per_dispatch: Optional[int] = None):
+                        spec_k: int = 0):
         """Precompile the autoregressive generation programs (ISSUE 14)
         ahead of traffic: ONE decode step over the `slots`-wide table
         plus one admission program per prompt bucket, `prefill_slot`:
@@ -604,20 +603,11 @@ class MultiLayerNetwork:
                     if slots is None else slots)
         page_size = (tunables.resolve("decode.page_size")
                      if page_size is None else page_size)
-        if steps_per_dispatch is None:
-            steps_per_dispatch = tunables.resolve("decode.steps_per_dispatch")
-        k_max = int(steps_per_dispatch)
-        if draft_net is not None and k_max > 1:
-            # ContinuousBatcher pins speculative decoding to K=1; a
-            # tunable-resolved K>1 silently yields there, so warm what
-            # the batcher will actually run
-            k_max = 1
         ic = self.infer_cache
         tok = jnp.zeros((slots,), jnp.int32)
         pos = jnp.zeros((slots,), jnp.int32)
         keys = jnp.zeros((slots, 2), jnp.uint32)
         temps = jnp.zeros((slots,), jnp.float32)
-        rem = jnp.zeros((slots,), jnp.int32)
         page_size = int(page_size)
         page_table = None
         if page_size > 0:
@@ -632,15 +622,6 @@ class MultiLayerNetwork:
             state = ic.init_decode_state(self.conf, slots, max_seq)
         ic.decode(self.conf, self.params, state, tok, pos, keys, temps,
                   page_table=page_table, compile_only=True)
-        # the adaptive-K loop dispatches every ladder K up to k_max
-        # while ramping — k=1 included (a ramp reset dispatches the
-        # fused block at K=1, not the classic step) — so warm the
-        # whole ladder
-        if k_max > 1:
-            for k in tunables.decode_k_ladder(k_max):
-                ic.decode_multi(self.conf, self.params, state, tok, pos,
-                                keys, temps, rem, k, page_table=page_table,
-                                compile_only=True)
         if draft_net is not None:
             if int(spec_k) < 2:
                 raise ValueError("draft_net requires spec_k >= 2")
@@ -691,7 +672,6 @@ class MultiLayerNetwork:
             "page_size": page_size,
             "prefix_cache": bool(prefix_cache),
             "spec_k": int(spec_k) if draft_net is not None else 0,
-            "steps_per_dispatch": k_max,
             "infer_cache": ic.stats.as_dict(),
         }
 
@@ -707,8 +687,7 @@ class MultiLayerNetwork:
               gen_max_pending: int = 64, gen_page_size: Optional[int] = None,
               gen_pages: int = 0, gen_prefix_cache: bool = False,
               gen_prefix_match: str = "exact", gen_draft=None,
-              gen_spec_k: int = 0,
-              gen_steps_per_dispatch: Optional[int] = None):
+              gen_spec_k: int = 0):
         """Start the micro-batching HTTP gateway over this network
         (`serving.ModelServer`): POST /v1/predict coalesces concurrent
         requests into one bucketed infer-cache call per flush, GET
@@ -743,9 +722,7 @@ class MultiLayerNetwork:
                            gen_prefix_cache=gen_prefix_cache,
                            gen_prefix_match=gen_prefix_match,
                            gen_draft=gen_draft,
-                           gen_spec_k=gen_spec_k,
-                           gen_steps_per_dispatch=gen_steps_per_dispatch
-                           ).start()
+                           gen_spec_k=gen_spec_k).start()
 
     # -- inference ---------------------------------------------------------
     def _serve_cached(self, x) -> bool:

@@ -538,11 +538,11 @@ class InferCache(CompiledProgramCache):
         [B] f32 (<= 0 rows decode greedily).  Returns (next_tok [B]
         int32, advanced keys, new state); the state argument is donated
         off-CPU.  A stack with expert layers (`nn.decode.has_experts`)
-        returns their counts of the step, [2] int32, before the state (as
-        `decode_multi` does, summed over its steps).  Under a 1-D (or no)
-        mesh generation is single-chip and the key carries the SINGLE tag
-        exactly as before; a plan with a `model` axis re-keys the program
-        by its sharding tag and shards params + KV state per the plan.
+        returns their counts of the step, [2] int32, before the state.
+        Under a 1-D (or no) mesh generation is single-chip and the key
+        carries the SINGLE tag exactly as before; a plan with a `model`
+        axis re-keys the program by its sharding tag and shards params +
+        KV state per the plan.
 
         Over a paged state (ISSUE 16, `init_paged_decode_state`),
         `page_table` [B, pages_per_slot] int32 is a tiny per-call host
@@ -564,31 +564,6 @@ class InferCache(CompiledProgramCache):
 
         return self._place_decode_state(conf, decode_mod.init_paged_state(
             _policy_conf(conf, self._policy), batch, n_pages, page_size))
-
-    def decode_multi(self, conf, params, state, tok, pos, keys, temps,
-                     rem, k: int, page_table=None,
-                     compile_only: bool = False):
-        """Fused K-step decode (ISSUE 19): ONE program advances every
-        row up to `k` tokens — `lax.scan` over the decode step with
-        in-program sampling, bitwise the trajectory `k` sequential
-        `decode` calls produce.  rem [B] int32 is each row's remaining
-        token budget; rows exhausting it mid-block freeze and emit
-        `nn.decode.BLOCK_SENTINEL`.  Returns (toks [k, B] int32,
-        tok_last [B], keys [B, 2], new state).  K is folded into the
-        key's ENTRY name ("decode-multi[k]") so the (entry, sig, tag,
-        policy) key layout every summary/audit consumer parses is
-        unchanged.  Same donation/sharding contract as `decode`.
-
-        Over a paged state the entry is "decode-multi-paged[k]": the
-        `page_table` rides the whole block, so the host must have
-        allocated pages for all `k` positions up front."""
-        return self._run_decode(
-            ("decode-multi[%d]" if page_table is None
-             else "decode-multi-paged[%d]") % int(k),
-            lambda c, policy: _decode_multi_program(c, policy, k), conf,
-            params, state,
-            _with_pages((tok, pos, keys, temps, rem), page_table),
-            compile_only=compile_only)
 
     def verify(self, conf, params, state, toks, pos, keys, temps,
                page_table=None, compile_only: bool = False):
@@ -823,24 +798,6 @@ def _decode_program(conf, policy: str = "f32") -> Callable:
         if counts is None:
             return tok2, keys2, state
         return tok2, keys2, counts, state   # a stack with expert layers
-
-    return program
-
-
-def _decode_multi_program(conf, policy: str = "f32", k: int = 1) -> Callable:
-    from deeplearning4j_tpu.nn import decode as decode_mod
-
-    pconf = _policy_conf(conf, policy)
-
-    def sample(logp, keys, temps):
-        if policy != "f32":
-            logp = logp.astype(jnp.float32)
-        return _sample_tokens(logp, keys, temps)
-
-    def program(params, state, tok, pos, keys, temps, rem, page_table=None):
-        return decode_mod.decode_block(
-            pconf, _policy_args(params, policy), state, tok, pos, keys,
-            temps, rem, k, sample, page_table)
 
     return program
 

@@ -137,33 +137,11 @@ _register(
     "KV-cache page size in tokens; 0 = contiguous [slots, max_seq] "
     "table (today's default).")
 _register(
-    "decode.steps_per_dispatch", "serving/batcher", 1,
-    (1, 2, 4, 8, 16),
-    None,
-    "Fused decode block size K: tokens generated per host dispatch "
-    "(lax.scan over the decode step). 1 = one program per token "
-    "(today's default); >1 amortizes the host loop over K tokens.")
-_register(
     "data.prefetch_depth", "datasets/iterator", 2,
     (1, 2, 4, 8),
     None,
     "PrefetchIterator buffer depth (batches staged ahead of the "
     "training step).")
-
-
-def decode_k_ladder(k_max: int) -> Tuple[int, ...]:
-    """Ascending block sizes the adaptive-K decode loop may dispatch for
-    a ceiling of `k_max`: every power of two below it, plus `k_max`
-    itself.  Warmup compiles exactly this ladder, so a warmed batcher
-    ramping 1 -> 2 -> 4 -> ... -> k_max never fresh-compiles."""
-    k_max = max(1, int(k_max))
-    ladder = []
-    v = 1
-    while v < k_max:
-        ladder.append(v)
-        v *= 2
-    ladder.append(k_max)
-    return tuple(ladder)
 
 
 class TunedTable:

@@ -249,46 +249,6 @@ def _tune_decode(net, search, max_seq):
     search.pick("decode", "decode.slots", cands, incumbent, run,
                 throughput=lambda slots: float(slots))
     tunables.note_fresh()
-    _tune_decode_steps(net, search, max_seq)
-
-
-def _tune_decode_steps(net, search, max_seq):
-    """K sweep through the fused decode block: tokens/s at each
-    steps-per-dispatch picks `decode.steps_per_dispatch` (each dispatch
-    advances every slot K tokens and costs ONE host round-trip, so
-    bigger K wins until per-step device time dominates the amortised
-    host overhead)."""
-    import jax
-    import jax.numpy as jnp
-
-    ic = net.infer_cache
-    slots = 2
-    tun = tunables.REGISTRY["decode.steps_per_dispatch"]
-    incumbent = tun.default
-    cands = sorted(k for k in set(tun.space) | {incumbent}
-                   if k <= max_seq)
-
-    def run(k):
-        state = ic.init_decode_state(net.conf, slots, max_seq)
-        tok = jnp.zeros((slots,), jnp.int32)
-        pos = jnp.zeros((slots,), jnp.int32)
-        keys = jnp.zeros((slots, 2), jnp.uint32)
-        temps = jnp.zeros((slots,), jnp.float32)
-        steps = 0
-        while steps + k <= max_seq:
-            rem = jnp.full((slots,), k, jnp.int32)
-            _, tok, keys, state = ic.decode_multi(
-                net.conf, net.params, state, tok, pos, keys, temps,
-                rem, k)
-            pos = pos + k
-            steps += k
-        jax.device_get(tok)
-
-    # every candidate decodes (about) the same token count, so the
-    # tokens-per-run numerator is the actual work done, not K itself
-    search.pick("decode", "decode.steps_per_dispatch", cands, incumbent,
-                run, throughput=lambda k: float(slots * (max_seq // k) * k))
-    tunables.note_fresh()
 
 
 def tune_model(net, groups: Sequence[str] = ("attention", "serve",

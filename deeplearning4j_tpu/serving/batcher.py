@@ -83,10 +83,6 @@ LATENCY_BUCKETS_S = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
 #: step (`le` upper bounds; a round always accepts >= 1)
 ACCEPTED_TOKENS_BOUNDS = (1, 2, 3, 4, 6, 8, 12, 16)
 
-#: histogram bucket bounds for decode-block size K (tokens per host
-#: dispatch) — the `decode.steps_per_dispatch` tunable's search space
-DECODE_BLOCK_STEPS_BOUNDS = (1, 2, 4, 8, 16)
-
 #: in-memory prefix-cache entries kept per batcher (LRU; the disk store,
 #: when attached, holds evicted entries too)
 PREFIX_CACHE_ENTRIES = 32
@@ -95,7 +91,7 @@ PREFIX_CACHE_ENTRIES = 32
 #: _charge`): the next program was an admission's; a decode step launched
 #: from the host's arrays after an admission or an idle wait; a step of a
 #: loop that reads every step back before the next (`_runs_ahead` false, a
-#: fused block, a speculative round); no stream was live and none pending
+#: speculative round); no stream was live and none pending
 STARVED_CAUSES = ("admit", "restart", "sync", "empty")
 
 
@@ -771,8 +767,7 @@ class ContinuousBatcher:
                  auto_start: bool = True, page_size: Optional[int] = None,
                  n_pages: int = 0, prefix_cache: bool = False,
                  prefix_match: str = "exact", draft_net=None,
-                 spec_k: int = 0,
-                 steps_per_dispatch: Optional[int] = None):
+                 spec_k: int = 0):
         from deeplearning4j_tpu.nn import decode as decode_mod
         from deeplearning4j_tpu.nn.conf import LayerType
 
@@ -807,30 +802,10 @@ class ContinuousBatcher:
                 f"window's positions a slot, which the paged pool has no "
                 f"pages for, a cached prefix row is right for only at the "
                 f"prompt's end, and a verify chunk cannot roll back")
-        self._has_experts = decode_mod.has_experts(net.conf)
-        # static a program: a decode step is one call of `n_slots` rows
-        self._experts_batched = decode_mod.experts_batched_layers(
-            net.conf, self.n_slots)
-        # {(cells a row holds, the most of them a step reads, the block it
-        # walks them in or 0, a cell's bytes): tables} of the layers that
-        # count their state in cells a position
-        self._kv_cells = Counter(
-            decode_mod.kv_cells(net.conf, self.max_seq))
-        # of those, the rings: a table whose step reads fewer cells than a
-        # row has positions holds a window's, `{cells: tables}` (an indexed
-        # table NEEDS fewer, its picks, but is read whole)
-        self._rings = Counter()
-        for (_, read, _, _), n in self._kv_cells.items():
-            if read < self.max_seq:
-                self._rings[read] += n
-        # {kind: bytes a decode step reads of one row's state}, or None
-        self._state_bytes = decode_mod.state_bytes_read(net.conf, self.max_seq)
-        # {positions a layer's step picks among a row's cached ones: layers}
-        self._dsa_picks = Counter(
-            decode_mod.selected_cells(net.conf, self.max_seq))
-        # static a program: the layers whose step walks a table through a
-        # kernel (`dl4j_dsa_decode`, `dl4j_gqa_decode`)
-        self._kernel_layers = decode_mod.kernel_layers(net.conf, self.max_seq)
+        # what a completed step read of the layers' state, by the rows'
+        # positions: the counts of its `decode` span and of `stats()`
+        self._counts = decode_mod.StepCounts(net.conf, self.n_slots,
+                                             self.max_seq)
         # silent positional-table overrun fix: `token_embed` gathers
         # P[pos] with no bound check, and jit CLAMPS out-of-range
         # gathers — a stream decoding past the learned table would read
@@ -895,25 +870,6 @@ class ContinuousBatcher:
                     f"max_seq={self.max_seq} exceeds the DRAFT model's "
                     f"positional table (max_seq_len={dbound})")
         self._draft_state = None  # device tree, B = n_slots (spec only)
-        # -- fused multi-step decode (ISSUE 19) ----------------------------
-        explicit_k = steps_per_dispatch is not None
-        if steps_per_dispatch is None:
-            steps_per_dispatch = tunables.resolve("decode.steps_per_dispatch")
-        k_max = int(steps_per_dispatch)
-        if k_max < 1:
-            raise ValueError(
-                f"steps_per_dispatch must be >= 1, got {k_max}")
-        if self.spec_k and k_max > 1:
-            if explicit_k:
-                raise ValueError(
-                    "speculative decoding is pinned to "
-                    "steps_per_dispatch=1: draft/verify rounds already "
-                    "advance multiple positions per dispatch and roll "
-                    "draft carries back per round; drop spec_k or "
-                    "steps_per_dispatch")
-            k_max = 1  # a tuned table's K>1 silently yields to spec
-        self.k_max = k_max
-        self._k_ladder = tunables.decode_k_ladder(k_max)
         self._cv = threading.Condition()
         self._pending: Deque[GenerationStream] = deque()
         self._stop = False
@@ -931,11 +887,7 @@ class ContinuousBatcher:
         self._spec_rounds = 0
         self._accept_hist = {"counts": [0] * len(ACCEPTED_TOKENS_BOUNDS),
                              "inf": 0, "sum": 0.0, "count": 0}
-        # adaptive-K ramp (decode-loop thread only): doubles per stable
-        # fused block up the warmed ladder, resets to 1 on any
-        # admission, release, or preemption
-        self._ramp = 1
-        # the K=1 rounds' clock (decode-loop thread only): what the last
+        # the rounds' clock (decode-loop thread only): what the last
         # eight steps ahead took from readback to readback and their
         # dispatches on the host, and the wall and blocked time since the
         # last completion
@@ -965,12 +917,12 @@ class ContinuousBatcher:
         self._ttfts: Deque[float] = deque(maxlen=4096)
         self._ttft_hist = {"counts": [0] * len(LATENCY_BUCKETS_S),
                            "inf": 0, "sum": 0.0, "count": 0}
-        # host-overhead accounting per dispatched block (guarded by
-        # _cv's lock): wall = dispatch-to-readback span, host = wall
+        # host-overhead accounting per completed step (guarded by
+        # _cv's lock): wall = readback-to-readback span, host = wall
         # minus the time spent blocked in device_get
         self._host_s = 0.0
         self._wall_s = 0.0
-        # K=1 table steps dispatched, and those of them dispatched while
+        # table steps dispatched, and those of them dispatched while
         # the step before was still in flight
         self._steps = 0
         self._steps_ahead = 0
@@ -979,18 +931,8 @@ class ContinuousBatcher:
         self._rids = itertools.count(1)
         self._admit_s = 0.0
         self._queue_wait_s = 0.0
-        self._blk_hist = {"counts": [0] * len(DECODE_BLOCK_STEPS_BOUNDS),
-                            "inf": 0, "sum": 0.0, "count": 0}
-        # what the expert layers counted, summed over steps and layers
-        self._expert_picks = 0
-        self._experts_hit = 0
-        # K/V cells the steps needed, and cells their whole-state reads covered
-        self._kv_live = 0
-        self._kv_spanned = 0
-        self._ring_live = 0
-        self._ring_spanned = 0
-        self._dsa_live = 0
-        self._dsa_selected = 0
+        # `self._counts.step`'s counts summed over the completed steps
+        self._step_sums: Counter = Counter()
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> "ContinuousBatcher":
@@ -1238,7 +1180,6 @@ class ContinuousBatcher:
         arguments, the first token to the caller, the counters."""
         self._slots[slot] = stream
         self._temps[slot] = stream.temperature
-        self._ramp = 1  # slot set changed: fused blocks re-ramp from K=1
         now = time.monotonic()
         delivered = False
         if tok0 is not None:
@@ -1457,7 +1398,6 @@ class ContinuousBatcher:
         self._slots[slot] = None
         self._temps[slot] = 0.0
         self._feed[slot] = []
-        self._ramp = 1  # slot set changed: fused blocks re-ramp from K=1
         if self.paged:
             # release the slot's pages and point its table rows at the
             # scratch page so later junk writes stay inert
@@ -1486,7 +1426,6 @@ class ContinuousBatcher:
         self._slots[slot] = None
         self._temps[slot] = 0.0
         self._feed[slot] = []
-        self._ramp = 1  # slot set changed: fused blocks re-ramp from K=1
         if self.paged:
             self._pool.free(self._page_table[slot])
             self._page_table[slot, :] = 0
@@ -1525,29 +1464,20 @@ class ContinuousBatcher:
         or None over a dense table, which selects the dense program."""
         return self._page_table.copy() if self.paged else None
 
-    def _lazy_alloc(self, k: int, pos=None, steps=None) -> None:
+    def _lazy_alloc(self, k: int) -> None:
         """Ensure every active slot has physical pages for its next `k`
         positions, allocating from the pool as streams cross page
         boundaries.  Genuine exhaustion past the admission gate
         (overcommit pressure) preempts the ONE stream that could not
         grow — requeued for recompute, never failed; an armed
         `decode.page_alloc` fault ends that stream with the injected
-        error.  Either way the table keeps decoding.
-
-        The pipelined block loop passes its own scheduled `pos` array
-        (device positions lag the host's scheduling arithmetic there)
-        and a per-slot `steps` array — slots scheduled 0 steps this
-        block (budget already exhausted, release pending readback) must
-        not allocate pages they will never write."""
+        error.  Either way the table keeps decoding."""
         ps = self.page_size
         for slot, stream in enumerate(self._slots):
             if stream is None:
                 continue
-            kk = k if steps is None else int(steps[slot])
-            if kk <= 0:
-                continue
-            p0 = int(self._pos[slot] if pos is None else pos[slot])
-            need = [p for p in range(p0 // ps, (p0 + kk - 1) // ps + 1)
+            p0 = int(self._pos[slot])
+            need = [p for p in range(p0 // ps, (p0 + k - 1) // ps + 1)
                     if p < self.pages_per_slot
                     and self._page_table[slot, p] == 0]
             if not need:
@@ -1583,16 +1513,18 @@ class ContinuousBatcher:
         free = self._slots.count(None)
         return free > 0 and (self.continuous or free == self.n_slots)
 
+    def _has_pending(self) -> bool:
+        with self._cv:
+            return bool(self._pending)
+
     def _leaves_rounds(self) -> bool:
-        """Whether the K=1 rounds should complete what is in flight and
-        hand back to `_decode_loop`: an admission is due, or fused blocks
-        may take over."""
-        return ((self._admissible() and self._has_pending())
-                or self._block_eligible())
+        """Whether the rounds should complete what is in flight and hand
+        back to `_decode_loop`: an admission is due."""
+        return self._admissible() and self._has_pending()
 
     def _decode_rounds(self) -> None:
-        """K=1 table steps, each in a `decode` span of its own, until
-        nothing is in flight.
+        """Table steps, each in a `decode` span of its own, until nothing
+        is in flight.
 
         A step has two halves, `_dispatch` (fault points, the rows'
         schedule, the program's call) and `_complete` (the one
@@ -1766,8 +1698,12 @@ class ContinuousBatcher:
             self._periods.append((now_ns - self._t_turn) / 1e9)
         self._landed(now_ns)
         sp.set(live=int(step["adv"].sum()))
-        self._note_experts(sp, counts, 1)
-        self._note_kv(sp, step["pos"], step["adv"], 1)
+        # added, not set: a span that completes two steps carries their sum
+        counted = self._counts.step(step["pos"], step["adv"], *counts)
+        sp.set(**{name: sp.attrs.get(name, 0) + n
+                  for name, n in counted.items()})
+        with self._cv:
+            self._step_sums.update(counted)
         emitted = 0
         with span("decode.deliver"):
             for slot, stream in enumerate(step["streams"]):
@@ -1794,120 +1730,15 @@ class ContinuousBatcher:
                         or int(self._pos[slot]) >= self.max_seq):
                     self._release_slot(slot, stream)
         t_end = time.monotonic()
-        self._note_block(1, t_end - self._t_mark,
+        self._note_block(t_end - self._t_mark,
                          self._waited_s + readback.seconds, emitted, now)
         self._t_mark, self._waited_s = t_end, 0.0
 
-    def _note_experts(self, sp: span, counts, steps: int) -> None:
-        """What the expert layers counted over the `steps` table steps one
-        dispatch made, `[[picks that landed on held experts, distinct held
-        experts hit]]` summed over the layers (and empty for a stack
-        without them): added to the open `decode` span's `picks_here`,
-        `experts_hit` and `steps`, and to the totals of `stats()`."""
-        if not counts:
-            return
-        picks, hit = (int(n) for n in counts[0])
-        sp.set(picks_here=sp.attrs.get("picks_here", 0) + picks,
-               experts_hit=sp.attrs.get("experts_hit", 0) + hit,
-               steps=sp.attrs.get("steps", 0) + steps)
-        with self._cv:
-            self._expert_picks += picks
-            self._experts_hit += hit
-
-    @staticmethod
-    def _cells_needed(pos, adv, most: int) -> int:
-        """Summed over the rows: row r, at position `pos[r]`, advances
-        `adv[r]` tokens (0: a free or a finished row), and each of its steps
-        needs `min(position + 1, most)` cells: p + 1 .. p + a, each clipped
-        to `most`, of which `rising` lie under it."""
-        rising = np.clip(most - pos, 0, adv)
-        return int((rising * pos + rising * (rising + 1) // 2
-                    + (adv - rising) * most).sum())
-
-    @staticmethod
-    def _cells_walked(pos, adv, steps: int, block: int, most: int) -> int:
-        """Summed over every row, live or not, and the `steps` steps: the
-        cells of a table of `most` that a read walking blocks of `block` up
-        to the row's position covers, `ceil((q + 1) / block) x block` at the
-        position q handed to the program (`pos[r] + min(i, adv[r])` in step
-        i; a position past the table walks it whole)."""
-        at = pos[:, None] + np.minimum(np.arange(steps), adv[:, None])
-        return int(((np.clip(at, -1, most - 1) + block) // block * block).sum())
-
-    def _note_kv(self, sp: span, pos, adv, steps: int) -> None:
-        """The cached cells of one dispatch of `steps` table steps in which
-        row r, at position `pos[r]`, advances `adv[r]` tokens, for a stack
-        with layers that count their state in cells (`kv_cells`, a layer or
-        a table of one; nothing otherwise).  `kv_cells_live`: what the steps
-        have to read, `min(position + 1, the most the table can need)` a
-        live row, table and step, from the slots' positions.
-        `kv_cells_spanned`: what the layers' reads cover, a row of the slot
-        table, table and step: `kv_cells_read`, or where a layer walks a
-        row's cells in blocks up to its position (`kv_read_block`) those
-        blocks (`_cells_walked`).  Added to the open `decode` span and to
-        the totals of `stats()`.  Beside them `state_bytes_read`: what the
-        steps read of every row's state, the spanned cells' bytes and every
-        carry whole (for a stack whose layers say it:
-        `nn.decode.state_bytes_read`); and for the rings among the tables
-        (a window's, fewer cells than `max_seq`), read whole a step,
-        `ring_cells_live` (`min(position + 1, window)` a live row, ring and
-        step) and `ring_cells_spanned` (`steps x slots x window` a ring)."""
-        p, a = np.asarray(pos, np.int64), np.asarray(adv, np.int64)
-        live = spanned = table_bytes = 0
-        for (most, read, block, cell), n in self._kv_cells.items():
-            live += n * self._cells_needed(p, a, most)
-            cells = n * (self._cells_walked(p, a, steps, block, read) if block
-                         else steps * self.n_slots * read)
-            spanned += cells
-            table_bytes += cells * cell
-        if self._state_bytes and sum(self._state_bytes.values()):
-            sp.set(state_bytes_read=sp.attrs.get("state_bytes_read", 0) + table_bytes
-                   + steps * self.n_slots * self._state_bytes["carry"])
-        if not self._kv_cells:
-            return
-        sp.set(kv_cells_live=sp.attrs.get("kv_cells_live", 0) + live,
-               kv_cells_spanned=sp.attrs.get("kv_cells_spanned", 0) + spanned)
-        with self._cv:
-            self._kv_live += live
-            self._kv_spanned += spanned
-        self._note_dsa(sp, p, a)
-        if not self._rings:
-            return
-        live = sum(n * self._cells_needed(p, a, w) for w, n in self._rings.items())
-        spanned = sum(n * steps * self.n_slots * w for w, n in self._rings.items())
-        sp.set(ring_cells_live=sp.attrs.get("ring_cells_live", 0) + live,
-               ring_cells_spanned=sp.attrs.get("ring_cells_spanned", 0) + spanned)
-        with self._cv:
-            self._ring_live += live
-            self._ring_spanned += spanned
-
-    def _note_dsa(self, sp: span, pos, adv) -> None:
-        """The same dispatch's selection, for a stack with layers whose step
-        picks the positions it attends to (`selects`; nothing otherwise).
-        `dsa_cells_live`: the positions cached at or before a live row's own
-        (`position + 1`); `dsa_cells_selected`: what was picked of them
-        (`min(position + 1, picks)`); a row, such layer and step.  Both come
-        from the slots' positions and one constant of the conf, so they
-        describe the traffic (how far the rows are past the picks), not the
-        program: what a step reads of its tables is `kv_cells_spanned`."""
-        if not self._dsa_picks:
-            return
-        cached = selected = 0
-        for picks, n in self._dsa_picks.items():
-            cached += n * self._cells_needed(pos, adv, self.max_seq)
-            selected += n * self._cells_needed(pos, adv, picks)
-        sp.set(dsa_cells_live=sp.attrs.get("dsa_cells_live", 0) + cached,
-               dsa_cells_selected=sp.attrs.get("dsa_cells_selected", 0) + selected)
-        with self._cv:
-            self._dsa_live += cached
-            self._dsa_selected += selected
-
-    def _note_block(self, k: int, wall: float, wait: float, emitted: int,
+    def _note_block(self, wall: float, wait: float, emitted: int,
                     now: float) -> None:
-        """Per-dispatch bookkeeping shared by the K=1 step and the fused
-        block loop: token totals + trailing rate window, the block-size
-        histogram, and the host-overhead split (host = wall minus the
-        time spent blocked in device_get)."""
+        """Per-step bookkeeping: token totals + trailing rate window and
+        the host-overhead split (host = wall minus the time spent blocked
+        in device_get or waiting for a submission)."""
         host = max(wall - wait, 0.0)
         with self._cv:
             self._tokens_total += emitted
@@ -1917,15 +1748,6 @@ class ContinuousBatcher:
                 self._recent_tokens.popleft()
             self._host_s += host
             self._wall_s += wall
-            h = self._blk_hist
-            h["sum"] += k
-            h["count"] += 1
-            for i, bound in enumerate(DECODE_BLOCK_STEPS_BOUNDS):
-                if k <= bound:
-                    h["counts"][i] += 1
-                    break
-            else:
-                h["inf"] += 1
 
     def _spec_once(self, sp: span) -> None:
         """One speculative round: the draft proposes spec_k - 1 tokens
@@ -2034,177 +1856,16 @@ class ContinuousBatcher:
                 else:
                     h["inf"] += 1
 
-    # -- fused multi-step decode (ISSUE 19) ----------------------------------
-    def _has_pending(self) -> bool:
-        with self._cv:
-            return bool(self._pending)
-
-    def _block_eligible(self) -> bool:
-        """Fused blocks run only while the slot set is stable: K pins to
-        1 (the `_decode_rounds` path) whenever pending admissions exist,
-        prompt feeds are mid-flight, or speculative decoding owns the
-        step — TTFT, prompt-feed, and replay semantics stay exactly the
-        K=1 loop's."""
-        if self.k_max <= 1 or self.spec_k:
-            return False
-        if any(self._feed):
-            return False
-        return not self._has_pending()
-
-    def _next_k(self, max_rem: int) -> int:
-        """Largest warmed-ladder K within the ramp and the longest
-        remaining per-slot budget.  The ramp doubles per stable
-        dispatched block (1 -> 2 -> ... -> k_max) and resets to 1 on
-        any admission, release, or preemption."""
-        k = 1
-        for v in self._k_ladder:
-            if v <= self._ramp and v <= max_rem:
-                k = v
-        return k
-
-    def _block_rounds(self) -> None:
-        """Pipelined fused-block decode: dispatch block N+1 BEFORE
-        reading back block N, so the host's per-block work (delivery,
-        bookkeeping) overlaps the device's compute, and fetch each
-        block's whole [K, slots] token array in ONE device->host
-        transfer.  Per-slot progress is tracked with deterministic
-        scheduling arithmetic — block N+1's token/key arguments are
-        block N's DEVICE outputs, chained without a sync — so no
-        readback is needed to keep dispatching.  The loop returns to
-        the outer admission path the moment pending streams exist
-        (bounded by the one in-flight block)."""
-        import jax
-
-        ic = self.net.infer_cache
-        nb = self.n_slots
-        streams = list(self._slots)
-        pos = self._pos.copy()
-        rem = np.zeros((nb,), np.int32)
-        for s, stream in enumerate(streams):
-            if stream is not None:
-                budget = (stream.max_new - stream.tokens_emitted
-                          + stream._replay)
-                rem[s] = max(0, min(budget, self.max_seq - int(pos[s])))
-        tok, keys = self._tok.copy(), self._keys.copy()
-        inflight = None
-        t_mark = time.monotonic()
-        live = sum(1 for st in streams if st is not None)
-        # one `decode` span over the pipelined rounds
-        with span("decode", k=self.k_max, live=live) as sp:
-            while True:
-                blk = None
-                if int(rem.max(initial=0)) > 0 and not self._has_pending():
-                    blk = self._dispatch_block(ic, streams, tok, keys, pos,
-                                               rem, sp)
-                    if blk is not None:
-                        tok, keys = blk["tok"], blk["keys"]
-                if inflight is not None:
-                    t_mark = self._readback_block(inflight, t_mark, sp)
-                inflight = blk
-                if blk is None:
-                    return
-
-    def _dispatch_block(self, ic, streams, tok, keys, pos, rem, sp: span):
-        """Dispatch ONE fused K-step block (no sync): fire the per-slot
-        fault points for every scheduled position (a raise ends THAT
-        stream only, before its rows are dispatched), allocate pages for
-        the whole block, then launch the decode-multi program.  Updates
-        the caller's scheduled pos/rem in place; returns the in-flight
-        block record, or None when nothing remained to dispatch."""
-        k = self._next_k(int(rem.max(initial=0)))
-        for s, stream in enumerate(streams):
-            if (stream is None or rem[s] <= 0
-                    or self._slots[s] is not stream):
-                continue
-            try:
-                for j in range(min(k, int(rem[s]))):
-                    faults.fire("decode.step", slot=s, pos=int(pos[s]) + j,
-                                block=k)
-            except BaseException as e:  # noqa: BLE001 — isolate the stream
-                self._release_slot(s, stream, error=e)
-                rem[s] = 0
-        if int(rem.max(initial=0)) <= 0:
-            return None
-        if self.paged:
-            self._lazy_alloc(k, pos=pos, steps=np.minimum(rem, k))
-            for s, stream in enumerate(streams):
-                if stream is not None and self._slots[s] is not stream:
-                    rem[s] = 0  # preempted/failed during page growth
-            if int(rem.max(initial=0)) <= 0:
-                return None
-        with span("decode.dispatch"):
-            toks, tok2, keys2, *counts, self._state = ic.decode_multi(
-                self.net.conf, self.net.params, self._state, tok,
-                pos.copy(), keys, self._temps.copy(), rem.copy(), k,
-                page_table=self._pages())
-            self._launched(sp, "sync")
-        adv = np.minimum(rem, k).astype(np.int32)
-        pos_before = pos.copy()
-        pos += adv
-        rem -= adv
-        self._ramp = min(self._ramp * 2, self.k_max)
-        return {"k": k, "streams": streams, "toks": toks, "tok": tok2,
-                "keys": keys2, "adv": adv, "pos_before": pos_before,
-                "pos_after": pos.copy(), "counts": counts}
-
-    def _readback_block(self, blk, t_mark: float, sp: span) -> float:
-        """Read back ONE in-flight block — a single device_get for the
-        ([K, slots] tokens, last token, keys) triple — then the host
-        side: per-stream delivery (replay-aware), TTFT, releases, and
-        host-overhead accounting.  `sp` is the rounds' open `decode` span.
-        Returns the new wall-clock mark."""
-        import jax
-
-        with span("decode.readback") as readback:
-            toks, tok_last, keys_last, counts = jax.device_get(
-                (blk["toks"], blk["tok"], blk["keys"], blk["counts"]))
-        now_ns = time.monotonic_ns()
-        self._landed(now_ns)
-        now = now_ns / 1e9      # `time.monotonic()`'s clock
-        self._note_experts(sp, counts, blk["k"])
-        self._note_kv(sp, blk["pos_before"], blk["adv"], blk["k"])
-        emitted = 0
-        with span("decode.deliver"):
-            for s, stream in enumerate(blk["streams"]):
-                if stream is None or int(blk["adv"][s]) <= 0:
-                    continue
-                if self._slots[s] is not stream:
-                    continue  # released or preempted since dispatch
-                first = stream.tokens_emitted == 0
-                sent_first = False
-                for j in range(int(blk["adv"][s])):
-                    if stream._deliver(int(toks[j, s]), now):
-                        emitted += 1
-                        if first and not sent_first:
-                            sent_first = True
-                self._tok[s] = tok_last[s]
-                self._keys[s] = keys_last[s]
-                self._pos[s] = blk["pos_after"][s]
-                if sent_first:
-                    with self._cv:
-                        self._record_ttft_locked(stream)
-                if (stream.tokens_emitted >= stream.max_new
-                        or int(self._pos[s]) >= self.max_seq):
-                    self._release_slot(s, stream)
-        t_end = time.monotonic()
-        self._note_block(blk["k"], t_end - t_mark, readback.seconds, emitted,
-                         now)
-        return t_end
-
     def _decode_loop(self) -> None:
         """The loop's thread is tiled by three top-level spans: `admit`
-        (one a stream, in `_admit_pending`), `decode` (a K=1 table step
-        of `_decode_rounds`, which come back here with nothing in flight
-        whenever an admission is due, or a run of fused blocks) and `idle`
-        (waiting for work)."""
+        (one a stream, in `_admit_pending`), `decode` (a table step of
+        `_decode_rounds`, which come back here with nothing in flight
+        whenever an admission is due) and `idle` (waiting for work)."""
         self._t_turn = time.monotonic_ns()      # the account opens dry
         while True:
             self._admit_pending()
             if any(s is not None for s in self._slots):
-                if self._block_eligible():
-                    self._block_rounds()
-                else:
-                    self._decode_rounds()
+                self._decode_rounds()
                 continue
             with self._cv:
                 if self._pending:
@@ -2231,7 +1892,7 @@ class ContinuousBatcher:
         admission is in neither.  With the loop a step ahead that is the
         host's BUSY share of a step, not time the device waits: it rises
         as the period falls to the device's step.  `decode_steps_total`
-        counts the K=1 table steps dispatched and
+        counts the table steps dispatched and
         `decode_steps_ahead_total` those dispatched while the step before
         was still in flight.  **What the device WAITS for the host is
         `device_starved_seconds_total`**, `{cause: seconds}` over
@@ -2252,7 +1913,6 @@ class ContinuousBatcher:
                          if now - t <= RATE_WINDOW_S)
             ttfts = sorted(self._ttfts)
             h = self._ttft_hist
-            bh = self._blk_hist
             active = self._active
             out = {
                 "slots": {"width": self.n_slots, "active": active,
@@ -2281,7 +1941,6 @@ class ContinuousBatcher:
                     "sum": h["sum"],
                     "count": h["count"],
                 },
-                "steps_per_dispatch": self.k_max,
                 "host_overhead_fraction": (
                     round(self._host_s / self._wall_s, 4)
                     if self._wall_s > 0 else 0.0),
@@ -2293,36 +1952,14 @@ class ContinuousBatcher:
                     for cause, ns in self._starved_ns.items()},
                 "admit_seconds_total": round(self._admit_s, 6),
                 "queue_wait_seconds_total": round(self._queue_wait_s, 6),
-                "kernel_layers": self._kernel_layers,
-                "state_bytes_per_row": self._state_bytes,
                 # the served tree's weight operands held in the compute
                 # dtype beside their masters (`InferCache._operand_params`),
                 # and the trees cast so far: one a params tree
                 "operand_bytes_narrowed": self.net.infer_cache.operand_bytes_narrowed,
                 "operand_casts_total": self.net.infer_cache.operand_casts,
-                "decode_block_steps": {
-                    "bounds": list(DECODE_BLOCK_STEPS_BOUNDS),
-                    "counts": list(bh["counts"]),
-                    "inf": bh["inf"],
-                    "sum": bh["sum"],
-                    "count": bh["count"],
-                },
             }
-        if self._has_experts:
-            with self._cv:
-                out["expert_picks_total"] = self._expert_picks
-                out["experts_hit_total"] = self._experts_hit
-            out["experts_batched_layers"] = self._experts_batched
-        if self._kv_cells:
-            with self._cv:
-                out["kv_cells_live_total"] = self._kv_live
-                out["kv_cells_spanned_total"] = self._kv_spanned
-                if self._dsa_picks:
-                    out["dsa_cells_live_total"] = self._dsa_live
-                    out["dsa_cells_selected_total"] = self._dsa_selected
-                if self._rings:
-                    out["ring_cells_live_total"] = self._ring_live
-                    out["ring_cells_spanned_total"] = self._ring_spanned
+            # what the steps read of the layers' state, summed
+            out.update(self._counts.totals(self._step_sums))
         if self.paged:
             with self._cv:
                 live_tokens = sum(

@@ -72,7 +72,6 @@ FAMILIES = {
     "dl4j_serving_prefix_cache_hits_total": ("counter", ()),
     "dl4j_serving_prefix_cache_misses_total": ("counter", ()),
     "dl4j_serving_accepted_tokens_per_step": ("histogram", ()),
-    "dl4j_serving_decode_block_steps": ("histogram", ()),
     "dl4j_serving_decode_host_seconds_total": ("counter", ()),
     "dl4j_serving_admit_seconds_total": ("counter", ()),
     "dl4j_serving_queue_wait_seconds_total": ("counter", ()),
@@ -383,19 +382,11 @@ def replica_metrics(stats: dict, page: Optional[PrometheusText] = None,
                         "(draft proposals plus the guaranteed target "
                         "token).", h["bounds"], h["counts"], h["inf"],
                         h["sum"], h["count"], lbl())
-        h = gen.get("decode_block_steps")
-        if h and h.get("count"):
-            p.histogram("dl4j_serving_decode_block_steps",
-                        "Decode steps fused per device dispatch (the "
-                        "adaptive-K fused decode block; 1 = classic "
-                        "step-at-a-time decode).", h["bounds"],
-                        h["counts"], h["inf"], h["sum"], h["count"],
-                        lbl())
         p.counter("dl4j_serving_decode_host_seconds_total",
                   "Host-side seconds of the decode loop spent outside "
                   "the device-readback wait (dispatch, scheduling, "
                   "token delivery); with wall time this gives the "
-                  "host-overhead fraction fused dispatch amortises.",
+                  "host-overhead fraction.",
                   gen.get("decode_host_seconds_total", 0.0), lbl())
         p.counter("dl4j_serving_admit_seconds_total",
                   "Seconds the decode loop spent admitting streams "

@@ -272,8 +272,7 @@ class ModelServer:
                  gen_page_size: Optional[int] = None, gen_pages: int = 0,
                  gen_prefix_cache: bool = False,
                  gen_prefix_match: str = "exact",
-                 gen_draft=None, gen_spec_k: int = 0,
-                 gen_steps_per_dispatch: Optional[int] = None):
+                 gen_draft=None, gen_spec_k: int = 0):
         self.net = net
         self.batching = bool(batching)
         self.request_timeout_s = float(request_timeout_s)
@@ -296,8 +295,7 @@ class ModelServer:
                               prefix_cache=gen_prefix_cache,
                               prefix_match=gen_prefix_match,
                               draft_net=gen_draft,
-                              spec_k=gen_spec_k,
-                              steps_per_dispatch=gen_steps_per_dispatch)
+                              spec_k=gen_spec_k)
             if generate else None)
         handler = type("Handler", (_ServeHandler,), {"model_server": self})
         self.server = ThreadingHTTPServer((host, port), handler)

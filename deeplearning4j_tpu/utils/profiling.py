@@ -161,7 +161,7 @@ def self_time(record: List[Span]) -> Dict[int, int]:
 # -- names ------------------------------------------------------------------
 def program_name(entry) -> str:
     """`dl4j_<entry>` with everything but letters, digits and `_` turned
-    to `_`: "decode-multi[4]" -> "dl4j_decode_multi_4"."""
+    to `_`: "prefill-logp-slot" -> "dl4j_prefill_logp_slot"."""
     return "dl4j_" + re.sub(r"[^0-9A-Za-z_]+", "_", str(entry)).strip("_")
 
 

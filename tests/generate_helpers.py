@@ -77,6 +77,26 @@ def _eager_transformer_tokens(net, prompt, n_new):
     return toks
 
 
+#: a count on the `decode` spans and the `stats()` total that sums it
+TOTALS = {"picks_here": "expert_picks_total", "experts_hit": "experts_hit_total",
+          "kv_cells_live": "kv_cells_live_total",
+          "kv_cells_spanned": "kv_cells_spanned_total",
+          "dsa_cells_live": "dsa_cells_live_total",
+          "dsa_cells_selected": "dsa_cells_selected_total",
+          "ring_cells_live": "ring_cells_live_total",
+          "ring_cells_spanned": "ring_cells_spanned_total"}
+
+
+def spans_sum_to_totals(spans, stats):
+    """Assert that every count the batcher totals in `stats` is the sum of
+    that count over the `decode` spans; returns the counts' names."""
+    have = [name for name, total in TOTALS.items() if total in stats]
+    decodes = [s for s in spans if s.name == "decode"]
+    for name in have:
+        assert stats[TOTALS[name]] == sum(s.attrs.get(name, 0) for s in decodes), name
+    return have
+
+
 def _drain(streams, timeout=60.0):
     return [list(s.tokens(timeout=timeout)) for s in streams]
 

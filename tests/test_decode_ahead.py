@@ -29,15 +29,22 @@ from deeplearning4j_tpu.serving.metrics import FAMILIES, replica_metrics
 from deeplearning4j_tpu.utils import profiling
 from deeplearning4j_tpu.utils.profiling import Span
 
-#: (prompt, max_new, rng_seed) a stream; two slots, so the third and fourth
-#: streams are admitted into a slot released the step before
+#: (temperature, page size, (prompt, max_new, rng_seed) a stream); two
+#: slots, so the third and fourth streams are admitted into a slot released
+#: the step before
+TWO = [([1, 2, 3], 10, 0), ([4, 5], 10, 1)]
 CASES = {
-    "greedy": (0.0, [([1, 2, 3], 12, 0), ([4, 5], 12, 1), ([6], 12, 2)]),
-    "sampled": (0.8, [([1, 2, 3], 12, 0), ([4, 5], 12, 1), ([6], 12, 2)]),
-    "staggered": (0.8, [([1, 2], 3, 0), ([3], 7, 1), ([4, 5, 6], 12, 2),
-                        ([7], 5, 3), ([8, 9], 1, 4)]),
+    "greedy": (0.0, 0, [([1, 2, 3], 12, 0), ([4, 5], 12, 1), ([6], 12, 2)]),
+    "sampled": (0.8, 0, [([1, 2, 3], 12, 0), ([4, 5], 12, 1), ([6], 12, 2)]),
+    "staggered": (0.8, 0, [([1, 2], 3, 0), ([3], 7, 1), ([4, 5, 6], 12, 2),
+                           ([7], 5, 3), ([8, 9], 1, 4)]),
     # max_new is cut to the table's edge: max_seq 16 less the prompt
-    "table_edge": (0.0, [([1, 2, 3], 40, 0), ([4, 5], 6, 1), ([6], 40, 2)]),
+    "table_edge": (0.0, 0, [([1, 2, 3], 40, 0), ([4, 5], 6, 1), ([6], 40, 2)]),
+    "two_greedy": (0.0, 0, TWO),
+    "two_sampled": (0.8, 0, TWO),
+    # over the paged pool the loop stays synchronous
+    "paged_greedy": (0.0, 4, TWO),
+    "paged_sampled": (0.8, 4, TWO),
 }
 
 
@@ -52,18 +59,56 @@ def _dispatched(record):
 def test_streams_are_the_synchronous_loops_token_for_token(request, model,
                                                            case):
     net = request.getfixturevalue(f"{model}_net")
-    temperature, asked = CASES[case]
+    temperature, page_size, asked = CASES[case]
     refs = [_compiled_tokens(net, p, min(n, 16 - len(p)),
                              temperature=temperature, rng_seed=seed)
             for p, n, seed in asked]
-    cb = ContinuousBatcher(net, n_slots=2, max_seq=16, prompt_buckets=(8,))
+    cb = ContinuousBatcher(net, n_slots=2, max_seq=16, prompt_buckets=(8,),
+                           page_size=page_size)
     try:
         streams = [cb.submit(p, max_new_tokens=n, temperature=temperature,
                              rng_seed=seed) for p, n, seed in asked]
         assert _drain(streams) == refs
         st = cb.stats()
         assert st["streams"]["completed"] == len(asked)
-        assert 0 < st["decode_steps_ahead_total"] < st["decode_steps_total"]
+        ahead = st["decode_steps_ahead_total"]
+        assert (ahead == 0 if page_size else 0 < ahead) \
+            and ahead < st["decode_steps_total"]
+    finally:
+        cb.stop()
+
+
+def test_two_budgets_the_short_row_ends_while_a_step_is_in_flight(lstm_net):
+    """Two streams, 3 tokens and 12: the step that the short row's budget
+    ends in is already in flight behind the one before, and is dispatched for
+    the long row alone; both streams are their synchronous loops'."""
+    refs = [_compiled_tokens(lstm_net, [1, 2], 3, rng_seed=0),
+            _compiled_tokens(lstm_net, [3, 4], 12, rng_seed=1)]
+    profiling.clear()
+    cb = ContinuousBatcher(lstm_net, n_slots=2, max_seq=16,
+                           prompt_buckets=(8,))
+    try:
+        a = cb.submit([1, 2], max_new_tokens=3, rng_seed=0)
+        b = cb.submit([3, 4], max_new_tokens=12, rng_seed=1)
+        assert _drain([a, b]) == refs
+    finally:
+        cb.stop()
+    lives = [d.attrs["live"] for d in _dispatched(profiling.spans())]
+    assert 2 in lives and lives[-1] == 1
+
+
+def test_the_steps_report_the_hosts_share(lstm_net):
+    """The host's busy share of the steps, and its seconds, reach
+    `stats()`."""
+    cb = ContinuousBatcher(lstm_net, n_slots=2, max_seq=32,
+                           prompt_buckets=(8,))
+    try:
+        streams = [cb.submit([1, 2], max_new_tokens=28, rng_seed=i)
+                   for i in range(2)]
+        assert [len(t) for t in _drain(streams)] == [28, 28]
+        st = cb.stats()
+        assert 0.0 < st["host_overhead_fraction"] <= 1.0
+        assert st["decode_host_seconds_total"] > 0.0
     finally:
         cb.stop()
 

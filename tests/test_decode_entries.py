@@ -1,4 +1,4 @@
-"""The contract the eleven decode-family entries of `InferCache` share, one
+"""The contract the nine decode-family entries of `InferCache` share, one
 case an entry: the key's layout and entry name (what `optimize/persist.py`
 files a program under and `programs_summary` parses), the program's name
 `jit_dl4j_<entry>` (what the benchmark's readers look for in a trace), the
@@ -14,7 +14,7 @@ from deeplearning4j_tpu.optimize.infer_cache import InferCache
 from deeplearning4j_tpu.optimize.step_cache import arg_signature
 from deeplearning4j_tpu.utils.profiling import program_name
 
-SLOTS, MAX_SEQ, PAGE, K = 2, 8, 4, 2
+SLOTS, MAX_SEQ, PAGE = 2, 8, 4
 
 
 @pytest.fixture(scope="module")
@@ -27,7 +27,7 @@ def net():
 def calls(ic, conf, params):
     """entry name -> (the call, the arguments its key names before the
     state's leaves, the state or table it donates)."""
-    tok = pos = rem = jnp.zeros((SLOTS,), jnp.int32)
+    tok = pos = jnp.zeros((SLOTS,), jnp.int32)
     keys = jnp.zeros((SLOTS, 2), jnp.uint32)
     temps = jnp.zeros((SLOTS,), jnp.float32)
     toks = jnp.zeros((SLOTS, 3), jnp.int32)
@@ -44,13 +44,6 @@ def calls(ic, conf, params):
         "decode-paged": (lambda **kw: ic.decode(
             conf, params, pool, tok, pos, keys, temps, page_table=pages, **kw),
             (tok, pos, keys, temps, pages), pool),
-        f"decode-multi[{K}]": (lambda **kw: ic.decode_multi(
-            conf, params, table, tok, pos, keys, temps, rem, K, **kw),
-            (tok, pos, keys, temps, rem), table),
-        f"decode-multi-paged[{K}]": (lambda **kw: ic.decode_multi(
-            conf, params, pool, tok, pos, keys, temps, rem, K,
-            page_table=pages, **kw),
-            (tok, pos, keys, temps, rem, pages), pool),
         "verify": (lambda **kw: ic.verify(
             conf, params, table, toks, pos, keys, temps, **kw),
             (toks, pos, keys, temps), table),
@@ -73,8 +66,7 @@ def calls(ic, conf, params):
     }
 
 
-ENTRIES = ("decode", "decode-paged", f"decode-multi[{K}]",
-           f"decode-multi-paged[{K}]", "verify", "verify-paged", "prefill",
+ENTRIES = ("decode", "decode-paged", "verify", "verify-paged", "prefill",
            "prefill-logp", "prefill-slot", "prefill-logp-slot", "write-row")
 
 

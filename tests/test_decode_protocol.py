@@ -56,39 +56,10 @@ def stack(kind):
                      loss_function=LossFunction.MCXENT)))
 
 
-def greedy(logp, keys, temps):
-    return jnp.argmax(logp, axis=-1).astype(jnp.int32), keys
-
-
-def row_of(state, r):
-    return jax.tree_util.tree_map(lambda a: np.asarray(a[r]), state)
-
-
-def same(a, b) -> bool:
-    return all(np.array_equal(x, y) for x, y in zip(
-        jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))
-
-
-def cell0(leaf):
-    """Position 0's cell of one row's table: [positions, n], or with the K/V
-    heads before the positions [G, positions, h]."""
-    return leaf[:, :1] if leaf.ndim == 3 else leaf[:1]
-
-
-def block_then_step(conf, params):
-    """Two rows from a zero state: a block of three steps in which row 1's
-    budget is one token, and one plain step.  Returns (the block's tokens,
-    row 1 after the block, row 1 after the step)."""
-    tok, pos = jnp.asarray([1, 2], jnp.int32), jnp.zeros((2,), jnp.int32)
-    keys = jnp.zeros((2, 2), jnp.uint32)
-    # traced anew at every call: a case changes a class's CARRY in between
-    toks, _, _, *_, after = jax.jit(lambda p, s: decode.decode_block(
-        conf, p, s, tok, pos, keys, jnp.zeros((2,)),
-        jnp.asarray([3, 1], jnp.int32), 3, greedy))(
-            params, decode.init_state(conf, 2, MAX_SEQ))
-    _, stepped = jax.jit(lambda p, s: decode.decode_step(conf, p, s, tok, pos))(
-        params, decode.init_state(conf, 2, MAX_SEQ))
-    return np.asarray(toks), row_of(after, 1), row_of(stepped, 1)
+def row_bytes(conf) -> int:
+    """The bytes of one row's state, every layer's."""
+    return sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(
+        decode.init_state(conf, 1, MAX_SEQ)))
 
 
 @pytest.mark.parametrize("kind", decode.GENERATIVE_HIDDEN, ids=str)
@@ -120,21 +91,17 @@ def test_a_layer_type_keeps_the_protocol(kind, monkeypatch):
         assert np.array_equal(a, b)
     assert sorted(table[0]) == sorted(impl.init_state(c, 3, MAX_SEQ))
 
-    # a finished row under `decode_block` keeps its state iff CARRY
-    net_params = init_params(conf, jax.random.PRNGKey(5))
-    toks, held, stepped = block_then_step(conf, net_params)
-    assert toks[0, 1] >= 0 and list(toks[1:, 1]) == [decode.BLOCK_SENTINEL] * 2
+    # what a step reads of a row: a carry whole iff CARRY, a table through
+    # its cells, and a state that says neither cannot be counted
+    read = decode.state_bytes_read(conf, MAX_SEQ)
     if impl.CARRY:
-        assert held[0] and same(held, stepped)
+        assert read == {"table": 0, "carry": row_bytes(conf)} and read["carry"]
         monkeypatch.setattr(impl, "CARRY", False)       # and only for that
-        assert not same(block_then_step(conf, net_params)[1], stepped)
-    elif held[0]:
-        # a table: the row goes on writing the cell at its frozen position
-        assert not same(held, stepped)
-        assert all(np.array_equal(cell0(a), cell0(b)) for a, b in zip(
-            jax.tree_util.tree_leaves(held), jax.tree_util.tree_leaves(stepped)))
+        assert decode.state_bytes_read(conf, MAX_SEQ) is None
+    elif hasattr(impl, "kv_cell_bytes"):
+        assert read["carry"] == 0 and 0 < read["table"] <= row_bytes(conf)
     else:
-        assert held[0] == {} and stepped[0] == {}
+        assert table[0] == {} and read == {"table": 0, "carry": 0}
 
 
 class RunningMean:
@@ -174,7 +141,7 @@ class RunningMean:
 def test_a_new_kind_of_state_is_a_layer_file_and_a_registry_line(monkeypatch):
     """Registered under a type the walkers already admit, a class they have
     never seen goes through `init_state`, `prefill`, `decode_step` and
-    `decode_block`; nothing of `nn/decode.py` names it."""
+    `state_bytes_read`; nothing of `nn/decode.py` names it."""
     kind = LayerType.TRANSFORMER_FFN
     monkeypatch.setitem(layers._REGISTRY, kind, RunningMean)
     conf = stack(kind)
@@ -199,9 +166,8 @@ def test_a_new_kind_of_state_is_a_layer_file_and_a_registry_line(monkeypatch):
         conf, params, state, ids[jnp.arange(2), length], length)
     np.testing.assert_allclose(logp, want[jnp.arange(2), length], atol=1e-5)
 
-    toks, held, stepped = block_then_step(conf, params)
-    assert list(toks[1:, 1]) == [decode.BLOCK_SENTINEL] * 2
-    assert same(held, stepped) and float(held[0]["seen"][0]) == 1.0
+    # a carry: a step reads its N + 1 floats a row whole
+    assert decode.state_bytes_read(conf, MAX_SEQ) == {"table": 0, "carry": (N + 1) * 4}
 
     # what the class does not declare, the walkers refuse by its type's name
     assert decode.dense_only(conf) == ["transformer_ffn"]

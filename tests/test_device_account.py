@@ -210,17 +210,16 @@ def test_admissions_back_to_back_are_both_charged_to_admit(lstm_net, ledger):
     check_account(record, cb, ledger)
 
 
-@pytest.mark.parametrize("mode", ["paged", "speculative", "feed", "blocks"])
+@pytest.mark.parametrize("mode", ["paged", "speculative", "feed"])
 def test_a_loop_that_reads_every_step_back_is_charged_to_sync(lstm_net,
                                                               ledger, mode):
-    """Over the paged pool, in speculative rounds, while a slot feeds a
-    prompt's rest and in fused blocks the device runs dry between a read and
+    """Over the paged pool, in speculative rounds and while a slot feeds a
+    prompt's rest the device runs dry between a read and
     the next launch, and that is the loop's doing: cause `sync` on every
     such step, `restart` on none but the first step that may run ahead."""
     kwargs = {"paged": dict(page_size=4),
               "speculative": dict(draft_net=_draft_net(), spec_k=3),
-              "feed": dict(prefix_cache=True, prefix_match="longest"),
-              "blocks": dict(steps_per_dispatch=4)}[mode]
+              "feed": dict(prefix_cache=True, prefix_match="longest")}[mode]
     cb = fresh(lstm_net, max_seq=16, **kwargs)
     prompt = [1, 2, 3, 4, 5, 6]
     try:

@@ -23,6 +23,7 @@ from deeplearning4j_tpu.nn.layers.mla import MLALayer
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.serving.batcher import ContinuousBatcher
 from deeplearning4j_tpu.utils import profiling
+from generate_helpers import spans_sum_to_totals
 from dots3_model import Model, f32      # noqa: F401  (f32: a fixture)
 
 MAX_SEQ = 64
@@ -42,14 +43,14 @@ def assert_greedy(m: Model, prompt, tokens):
 @pytest.fixture(scope="module")
 def served(f32):
     """Five requests through two slots (so slots are reused, once after the
-    longest request), K=1."""
+    longest request)."""
     net = MultiLayerNetwork(f32.conf)
     net.params = f32.params
     rng = np.random.default_rng(7)
     prompts = [rng.integers(0, f32.sizes["vocab"], n).astype(np.int32) for n in PROMPTS]
     profiling.clear()
     batcher = ContinuousBatcher(net, n_slots=2, max_seq=MAX_SEQ,
-                                prompt_buckets=(8, 16, 32), steps_per_dispatch=1).start()
+                                prompt_buckets=(8, 16, 32)).start()
     streams = [batcher.submit(p, max_new_tokens=n) for p, n in zip(prompts, NEW)]
     tokens = [list(s.tokens(timeout=120)) for s in streams]
     stats = batcher.stats()
@@ -178,20 +179,16 @@ def test_decode_spans_carry_the_cells_and_the_selection(f32, served):
     assert stats["ring_cells_spanned_total"] == 2 * 5 * len(steps)
 
 
-def test_fused_blocks_serve_the_same_tokens_and_count_the_same(f32, served):
+def test_more_requests_than_slots_are_the_references_and_the_spans_sum_up(f32, served):
+    """Five requests through two slots: every stream is the reference's greedy
+    one, and the counts on the `decode` spans sum to the totals of `stats()`."""
     prompts, tokens, stats, spans, _ = served
-    net = MultiLayerNetwork(f32.conf)
-    net.params = f32.params
-    profiling.clear()
-    batcher = ContinuousBatcher(net, n_slots=2, max_seq=MAX_SEQ,
-                                prompt_buckets=(8, 16, 32), steps_per_dispatch=4).start()
-    streams = [batcher.submit(p, max_new_tokens=n) for p, n in zip(prompts[:2], NEW[:2])]
-    got = [list(s.tokens(timeout=120)) for s in streams]
-    blocks = batcher.stats()
-    batcher.stop()
-    assert got == tokens[:2]
-    assert blocks["dsa_cells_live_total"] == 2 * hand_count(PROMPTS[:2], NEW[:2], MAX_SEQ)
-    assert blocks["dsa_cells_selected_total"] == 2 * hand_count(PROMPTS[:2], NEW[:2], 8)
+    assert len(prompts) > stats["slots"]["width"]
+    for prompt, got in zip(prompts, tokens):
+        assert_greedy(f32, prompt, got)
+    assert spans_sum_to_totals(spans, stats) == [
+        "picks_here", "experts_hit", "kv_cells_live", "kv_cells_spanned",
+        "dsa_cells_live", "dsa_cells_selected", "ring_cells_live", "ring_cells_spanned"]
 
 
 def test_the_counters_are_exported(served):

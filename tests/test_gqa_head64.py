@@ -177,8 +177,19 @@ def test_a_decode_step_through_the_kernel_is_the_masked_read(monkeypatch):
         np.testing.assert_array_equal(got_state[name], want_state[name])
 
 
-@pytest.mark.parametrize("steps", [1, 3], ids=["one-step", "a-block-of-three"])
-def test_a_walked_table_counts_the_blocks_its_rows_read(monkeypatch, steps):
+#: (positions, advances) of five slots, and the cells the full layer's walks
+#: in blocks of 32 cover
+WALKS = {
+    # slot 4 is free at the table's end
+    "one-step": ([0, 31, 32, 127, 128], [1, 1, 1, 1, 0], 32 + 32 + 64 + 128 + 128),
+    # rows either side of a block's edge, and one at the table's last position
+    "across-a-block-edge": ([63, 64, 95, 96, 127], [1, 1, 0, 1, 1],
+                            64 + 96 + 96 + 128 + 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALKS))
+def test_a_walked_table_counts_the_blocks_its_rows_read(monkeypatch, case):
     """On the chip's path a full layer's `kv_cells_spanned` and its bytes in
     `state_bytes_read` are the blocks of 32 cells each slot's step walks at
     the position handed to the program, live or not (a finished slot at
@@ -189,33 +200,20 @@ def test_a_walked_table_counts_the_blocks_its_rows_read(monkeypatch, steps):
     from deeplearning4j_tpu.nd import pallas_kernels
     from deeplearning4j_tpu.nn import decode
     from deeplearning4j_tpu.nn.layers import gqa
-    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-    from deeplearning4j_tpu.serving.batcher import ContinuousBatcher
-    from deeplearning4j_tpu.utils.profiling import span
 
     monkeypatch.setattr(gqa, "is_tpu", lambda: True)
     monkeypatch.setattr(pallas_kernels, "DSA_BLOCK", 32)
     m = Model("float32", head_dim=64)
-    batcher = ContinuousBatcher(MultiLayerNetwork(m.conf), n_slots=5, max_seq=128,
-                                prompt_buckets=(8,))
-    assert batcher.stats()["kernel_layers"] == decode.kernel_layers(m.conf, 128) == 1
+    counts = decode.StepCounts(m.conf, 5, 128)
+    assert counts.totals({})["kernel_layers"] == decode.kernel_layers(m.conf, 128) == 1
     full, ring = (GQALayer.kv_cell_bytes(m.conf.conf(m.layer(kind)), 128)
                   for kind in ("full", "window"))
-    if steps == 1:      # slot 4 is free at the table's end
-        pos, adv = [0, 31, 32, 127, 128], [1, 1, 1, 1, 0]
-        walked = 32 + 32 + 64 + 128 + 128
-    else:               # rows that cross a block edge and the table's end mid-block
-        pos, adv = [0, 31, 126, 128, 5], [3, 2, 3, 0, 0]
-        walked = 3 * 32 + (32 + 64 + 64) + 3 * 128 + 3 * 128 + 3 * 32
-    with span("decode") as sp:
-        batcher._note_kv(sp, np.asarray(pos), np.asarray(adv), steps)
-    rings = 3 * steps * 5 * 8
-    assert sp.attrs["kv_cells_spanned"] == walked + rings
-    assert sp.attrs["state_bytes_read"] == walked * full + rings * ring
+    pos, adv, walked = WALKS[case]
+    got = counts.step(np.asarray(pos), np.asarray(adv))
+    rings = 3 * 5 * 8
+    assert got["kv_cells_spanned"] == walked + rings
+    assert got["state_bytes_read"] == walked * full + rings * ring
     monkeypatch.setattr(gqa, "is_tpu", lambda: False)      # off the chip: the whole table
-    whole = ContinuousBatcher(MultiLayerNetwork(m.conf), n_slots=5, max_seq=128,
-                              prompt_buckets=(8,))
-    with span("decode") as sp:
-        whole._note_kv(sp, np.asarray(pos), np.asarray(adv), steps)
-    assert sp.attrs["kv_cells_spanned"] == steps * 5 * 128 + rings
-    assert whole.stats()["kernel_layers"] == 0
+    whole = decode.StepCounts(m.conf, 5, 128)
+    assert whole.step(np.asarray(pos), np.asarray(adv))["kv_cells_spanned"] == 5 * 128 + rings
+    assert whole.totals({})["kernel_layers"] == 0

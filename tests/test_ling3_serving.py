@@ -1,6 +1,6 @@
 """Ling-3.0-flash's language model through `ContinuousBatcher`, at tiny
 widths on the CPU: greedy streams against the plain reference of
-`benchmark/families/ling3`, fused blocks, the options that cannot hold its
+`benchmark/families/ling3`, the options that cannot hold its
 state, its scopes, spans and counters, and what its typed settings left as it
 was.  The layers are in `test_ling3_layers.py` and `test_ling3_experts.py`,
 the slot table in `test_ling3_slot_table.py`."""
@@ -17,6 +17,7 @@ from deeplearning4j_tpu.nn.conf import MultiLayerConfiguration, NeuralNetConfigu
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.serving.batcher import ContinuousBatcher
 from deeplearning4j_tpu.utils import profiling
+from generate_helpers import spans_sum_to_totals
 from ling3_model import Model, f32      # noqa: F401  (f32: a fixture)
 
 
@@ -36,15 +37,14 @@ def reference_greedy(m: Model, prompt, n_new: int, width: int = 24):
 
 @pytest.fixture(scope="module")
 def served(f32):
-    """Five requests through two slots (so slots are reused), K=1."""
+    """Five requests through two slots (so slots are reused)."""
     net = MultiLayerNetwork(f32.conf)
     net.params = f32.params
     rng = np.random.default_rng(7)
     prompts = [rng.integers(0, f32.sizes["vocab"], n).astype(np.int32)
                for n in (5, 16, 9, 3, 12)]
     profiling.clear()
-    batcher = ContinuousBatcher(net, n_slots=2, max_seq=48, prompt_buckets=(8, 16),
-                                steps_per_dispatch=1).start()
+    batcher = ContinuousBatcher(net, n_slots=2, max_seq=48, prompt_buckets=(8, 16)).start()
     streams = [batcher.submit(p, max_new_tokens=6) for p in prompts]
     tokens = [list(s.tokens(timeout=120)) for s in streams]
     stats = batcher.stats()
@@ -60,22 +60,20 @@ def test_a_greedy_stream_is_the_references(f32, served, request_no):
     assert tokens[request_no] == reference_greedy(f32, prompts[request_no], 6)
 
 
-def test_fused_blocks_serve_the_same_tokens(f32, served):
-    prompts, tokens, *_ = served
-    net = MultiLayerNetwork(f32.conf)
-    net.params = f32.params
-    profiling.clear()
-    batcher = ContinuousBatcher(net, n_slots=2, max_seq=48, prompt_buckets=(8, 16),
-                                steps_per_dispatch=4).start()
-    streams = [batcher.submit(p, max_new_tokens=6) for p in prompts[:2]]
-    got = [list(s.tokens(timeout=120)) for s in streams]
-    stats = batcher.stats()
-    batcher.stop()
-    assert got == tokens[:2]
-    blocks = [s for s in profiling.spans() if s.name == "decode" and "steps" in s.attrs]
-    assert blocks and all(s.attrs["picks_here"] >= s.attrs["experts_hit"] > 0
-                          for s in blocks)
-    assert stats["expert_picks_total"] == sum(s.attrs["picks_here"] for s in blocks)
+def test_more_requests_than_slots_are_the_references_and_the_spans_sum_up(f32, served):
+    """Five requests through two slots: every stream is the reference's greedy
+    one (a teacher-forced pass over prompt and answer, padded to one width),
+    and the counts on the `decode` spans sum to the totals of `stats()`."""
+    prompts, tokens, stats, spans, _ = served
+    assert len(prompts) > stats["slots"]["width"]
+    for prompt, got in zip(prompts, tokens):
+        ids = np.zeros((1, 24), np.int32)
+        ids[0, :len(prompt) + len(got)] = np.concatenate([prompt, got])
+        best = np.argmax(f32.logp(ids)[0], axis=-1)
+        assert got == list(best[len(prompt) - 1: len(prompt) - 1 + len(got)])
+    assert spans_sum_to_totals(spans, stats) == [
+        "picks_here", "experts_hit", "kv_cells_live", "kv_cells_spanned"]
+    assert stats["expert_picks_total"] >= stats["experts_hit_total"] > 0
 
 
 # ---------------------------------------------------------------- (f) refusals

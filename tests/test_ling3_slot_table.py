@@ -2,7 +2,7 @@
 prefill and the slot table against the plain reference of
 `benchmark/families/ling3`: the program holds the reference's numbers, an
 admission and the table's decode steps are the full forward pass in float32
-and in bfloat16, and a frozen row of a fused block keeps its state."""
+and in bfloat16."""
 
 import jax
 import jax.numpy as jnp
@@ -79,30 +79,3 @@ def test_prefill_then_decode_through_the_table_is_the_full_forward_pass(
         for j, logp in enumerate(got[r][:-1]):      # the last has no successor
             np.testing.assert_allclose(logp, want[r, length - 1 + j],
                                        atol=tolerance, rtol=0)
-
-def test_a_frozen_row_in_decode_block_does_not_advance(f32):
-    conf, params = f32.conf, f32.params
-    state = decode.init_state(conf, 2, 32)
-    prompt = np.asarray([[5, 9, 2, 7], [1, 3, 0, 0]], np.int32)
-    _, state = jax.jit(lambda p, s, t, n: decode.prefill(conf, p, s, t, n))(
-        params, state, prompt, np.asarray([4, 2], np.int32))
-
-    def greedy(logp, keys, temps):
-        return jnp.argmax(logp, axis=-1).astype(jnp.int32), keys
-
-    tok, pos = np.asarray([11, 4], np.int32), np.asarray([4, 2], np.int32)
-    keys, temps = np.zeros((2, 2), np.uint32), np.zeros((2,), np.float32)
-    toks, _, _, counts, after = jax.jit(
-        lambda p, s, *row: decode.decode_block(conf, p, s, *row, 3, greedy))(
-            params, state, tok, pos, keys, temps, np.asarray([3, 0], np.int32))
-    assert (np.asarray(toks)[:, 1] == decode.BLOCK_SENTINEL).all()
-    assert (np.asarray(toks)[:, 0] != decode.BLOCK_SENTINEL).all()
-    assert counts.shape == (2,)
-    for before, now, c in zip(state, after, conf.confs):
-        if str(c.layer_type) != "kda":
-            continue
-        for leaf in ("S", "conv"):      # row 1 frozen, row 0 moved on
-            np.testing.assert_array_equal(np.asarray(now[leaf][1]),
-                                          np.asarray(before[leaf][1]))
-            assert not np.array_equal(np.asarray(now[leaf][0]),
-                                      np.asarray(before[leaf][0]))

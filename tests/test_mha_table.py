@@ -19,7 +19,6 @@ from deeplearning4j_tpu.nn.layers import gqa
 from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttentionLayer as MHA
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.serving.batcher import ContinuousBatcher
-from deeplearning4j_tpu.utils.profiling import span
 from generate_helpers import (VOCAB, _clean_faults, _draft_net, _drain,  # noqa: F401
                               _eager_transformer_tokens, transformer_net)
 
@@ -101,9 +100,10 @@ def test_a_step_through_the_kernel_is_the_masked_read(monkeypatch):
 
 def test_the_batcher_counts_the_blocks_a_table_is_walked_in(monkeypatch):
     """On the chip's path GPT-2's table is one of `kernel_layers`, and its
-    `kv_cells_spanned` and `state_bytes_read` are the blocks of 32 cells each
-    slot's step walks at the position handed to the program (a free slot at
-    `max_seq` walks all 4); off it the table counts whole."""
+    `kv_cells_spanned` and `state_bytes_read` in the batcher's `StepCounts`
+    are the blocks of 32 cells each slot's step walks at the position handed
+    to the program (a free slot at `max_seq` walks all 4); off it the table
+    counts whole."""
     monkeypatch.setattr(pallas_kernels, "DSA_BLOCK", 32)
     net = walked_net()
     cell = MHA.kv_cell_bytes(net.conf.conf(1), 128)
@@ -111,12 +111,10 @@ def test_the_batcher_counts_the_blocks_a_table_is_walked_in(monkeypatch):
     counted = {}
     for chip in (True, False):
         monkeypatch.setattr(gqa, "is_tpu", lambda: chip)
-        batcher = ContinuousBatcher(net, n_slots=4, max_seq=128, prompt_buckets=(8,),
-                                    auto_start=False)
-        with span("decode") as sp:
-            batcher._note_kv(sp, pos, adv, 1)
-        counted[chip] = (batcher.stats()["kernel_layers"], sp.attrs["kv_cells_spanned"],
-                         sp.attrs["state_bytes_read"], sp.attrs["kv_cells_live"])
+        counts = decode.StepCounts(net.conf, 4, 128)
+        step = counts.step(pos, adv)
+        counted[chip] = (counts.totals({})["kernel_layers"], step["kv_cells_spanned"],
+                         step["state_bytes_read"], step["kv_cells_live"])
     walked = 32 + 32 + 64 + 128
     assert counted[True] == (1, walked, walked * cell, 1 + 32 + 33)
     assert counted[False] == (0, 4 * 128, 4 * 128 * cell, 1 + 32 + 33)

@@ -213,8 +213,7 @@ def test_a_rings_live_cells_are_counted_on_the_spans_and_in_stats(model):
     net = MultiLayerNetwork(conf)
     net.params = params
     profiling.clear()
-    batcher = ContinuousBatcher(net, n_slots=1, max_seq=24, prompt_buckets=(8,),
-                                steps_per_dispatch=1).start()
+    batcher = ContinuousBatcher(net, n_slots=1, max_seq=24, prompt_buckets=(8,)).start()
     try:
         tokens = list(batcher.submit(np.arange(5, dtype=np.int32) + 3,
                                      max_new_tokens=10).tokens(timeout=120))

@@ -112,9 +112,9 @@ def test_an_exception_inside_a_span_closes_it_and_passes():
 
 def test_program_name_from_the_entry_kind():
     assert profiling.program_name("decode") == "dl4j_decode"
-    assert profiling.program_name("decode-multi[4]") == "dl4j_decode_multi_4"
-    assert profiling.program_name("decode-multi-paged[16]") == \
-        "dl4j_decode_multi_paged_16"
+    assert profiling.program_name("decode-paged") == "dl4j_decode_paged"
+    assert profiling.program_name("prefill-logp-slot") == "dl4j_prefill_logp_slot"
+    assert profiling.program_name("entry[16]") == "dl4j_entry_16"
     assert profiling.program_name("prefill-logp") == "dl4j_prefill_logp"
 
 

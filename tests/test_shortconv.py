@@ -128,24 +128,23 @@ def test_the_tail_goes_through_the_walkers_that_never_name_it():
         assert "SHORT_CONV" not in text and "shortconv" not in text.lower(), module
 
 
-def test_a_finished_rows_tail_stays_as_it_was():
-    """Under a fused block a row whose budget ran out keeps its tail: the
-    tail after one token is what one plain step leaves."""
+def test_an_admission_replaces_a_finished_rows_tail():
+    """The table steps every row, a finished one too, so a free slot's tail
+    moves on past its stream's end; the next admission's write puts back
+    the whole row, and its tail is what a fresh prefill leaves."""
     net_conf = stack()
     p = init_params(net_conf, jax.random.PRNGKey(6))
     tok, pos = jnp.asarray([1, 2], jnp.int32), jnp.zeros((2,), jnp.int32)
-
-    def greedy(logp, keys, temps):
-        return jnp.argmax(logp, axis=-1).astype(jnp.int32), keys
-
-    toks, *_, held = decode.decode_block(
-        net_conf, p, decode.init_state(net_conf, 2, 8), tok, pos,
-        jnp.zeros((2, 2), jnp.uint32), jnp.zeros((2,)), jnp.asarray([3, 1], jnp.int32),
-        3, greedy)
-    _, stepped = decode.decode_step(net_conf, p, decode.init_state(net_conf, 2, 8), tok, pos)
-    assert list(np.asarray(toks)[1:, 1]) == [decode.BLOCK_SENTINEL] * 2
-    np.testing.assert_array_equal(held[0]["conv"][1], stepped[0]["conv"][1])
-    assert not np.array_equal(held[0]["conv"][0], stepped[0]["conv"][0])
+    table = decode.init_state(net_conf, 2, 8)
+    for _ in range(3):
+        _, table = decode.decode_step(net_conf, p, table, tok, pos)
+        pos = pos + 1
+    prompt, length = jnp.asarray([[3, 1, 4, 0]], jnp.int32), jnp.asarray([3], jnp.int32)
+    _, fresh = decode.prefill(net_conf, p, decode.init_state(net_conf, 1, 8), prompt, length)
+    assert not np.array_equal(table[0]["conv"][1], fresh[0]["conv"][0])
+    table = decode.write_row(table, fresh, jnp.int32(1))
+    np.testing.assert_array_equal(table[0]["conv"][1], fresh[0]["conv"][0])
+    assert not np.array_equal(table[0]["conv"][0], fresh[0]["conv"][0])
 
 
 # ------------------------------------- the convolution KDA shares, bit for bit

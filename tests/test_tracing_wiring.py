@@ -55,18 +55,19 @@ def fresh_record():
 # -- names ---------------------------------------------------------------------
 def test_every_infer_program_has_its_own_dl4j_name_and_its_old_key():
     net = _net()
+    net.warmup_generate(slots=2, max_seq=16, prompt_buckets=(4,))
     net.warmup_generate(slots=2, max_seq=16, prompt_buckets=(4,),
-                        steps_per_dispatch=4)
+                        page_size=4, prefix_cache=True)
     ic = net.infer_cache
     ic.output(net.conf, net.params, np.ones((8, 16), np.int32),
               compile_only=True)
     names = {key: _module_name(fn) for key, fn in ic._programs.items()}
-    assert len(set(names.values())) == len(names) >= 6
+    assert len(set(names.values())) == len(names) >= 5
     for key, name in names.items():
         assert name == "jit_" + profiling.program_name(key[0])
         assert "dl4j" not in repr(key)
     assert {"jit_dl4j_decode", "jit_dl4j_prefill_slot", "jit_dl4j_output",
-            "jit_dl4j_decode_multi_4"} <= set(names.values())
+            "jit_dl4j_decode_paged", "jit_dl4j_prefill_logp"} <= set(names.values())
     # the key of the pre-name schema, built by hand
     xp = jnp.zeros((ic._serve_bucket(8), 16), jnp.int32)
     assert ("output", ic._fingerprint(net.conf), arg_signature(xp),
@@ -156,13 +157,11 @@ def test_train_step_carries_every_layer_scope_and_the_same_equations(
         assert str(jax.make_jaxpr(bare)(*args)) == scoped
 
 
-@pytest.mark.parametrize("entry", ["decode", "prefill-slot",
-                                   "decode-multi[2]"])
+@pytest.mark.parametrize("entry", ["decode", "prefill-slot"])
 def test_decode_programs_carry_every_layer_scope_and_the_same_equations(
         entry, monkeypatch):
     net = _net()
-    net.warmup_generate(slots=2, max_seq=16, prompt_buckets=(4,),
-                        steps_per_dispatch=2)
+    net.warmup_generate(slots=2, max_seq=16, prompt_buckets=(4,))
     rec = next(r for r in net.infer_cache.audit_records()
                if r["key"][0] == entry)
     text = jax.jit(rec["build"]()).lower(*rec["abstract"]).as_text(
@@ -256,26 +255,6 @@ def test_batcher_leaves_one_admit_span_a_stream_with_its_children():
     # decode steps only: the admissions are in no part of this counter
     assert stats["decode_host_seconds_total"] < \
         sum(d.end_ns - d.start_ns for d in decodes) / 1e9
-
-
-def test_fused_blocks_run_inside_one_decode_span_with_the_same_children():
-    net = _net()
-    net.warmup_generate(slots=2, max_seq=32, prompt_buckets=(4,),
-                        steps_per_dispatch=4)
-    batcher = ContinuousBatcher(net, n_slots=2, max_seq=32,
-                                prompt_buckets=(4,),
-                                steps_per_dispatch=4).start()
-    profiling.clear()
-    assert len(batcher.generate(np.arange(1, 4), max_new_tokens=20)) == 20
-    batcher.stop()
-    record = profiling.spans()
-    fused = [s for s in record if s.name == "decode" and s.attrs["k"] == 4]
-    assert fused and all(s.parent is None for s in fused)
-    inside = [s.name for s in record if s.parent in {d.sid for d in fused}]
-    assert set(inside) == {"decode.dispatch", "decode.readback",
-                           "decode.deliver"}
-    # more than one block a span: the rounds are pipelined inside it
-    assert inside.count("decode.dispatch") > len(fused)
 
 
 def test_admission_counters_reach_the_prometheus_page():
