@@ -58,6 +58,7 @@ class LayerType(str, enum.Enum):
     MOE = "moe"                    # routed experts + one shared expert
     SHORT_CONV = "shortconv"       # gated short convolution (LFM2's mixer)
     PARALLEL = "parallel"          # attention and experts off one LayerNorm
+    GDN = "gdn"                    # gated delta rule, a decay a value head
 
     def __str__(self) -> str:
         return self.value
@@ -130,6 +131,24 @@ class KDASpec:
 
 
 @dataclass(frozen=True)
+class GDNSpec:
+    """LayerType.GDN (Gated DeltaNet, arXiv:2412.06464, as Qwen3-Next runs
+    it): `n_v_heads` value heads of `v_dim`, each with a state of `k_dim` x
+    `v_dim`, read and written by `n_k_heads` query/key heads of `k_dim`
+    (key head j serves value heads j r .. j r + r - 1, r = n_v_heads /
+    n_k_heads); a depthwise causal convolution of `conv_kernel` taps on q, k
+    and v; a decay `exp(g)` of one number a value head; the output normed a
+    value head and gated by SiLU of a projection of its own."""
+
+    n_k_heads: int
+    n_v_heads: int
+    k_dim: int
+    v_dim: int
+    conv_kernel: int = 4
+    eps: float = 1e-6
+
+
+@dataclass(frozen=True)
 class MLASpec:
     """LayerType.MLA (arXiv:2405.04434): keys and values from one cached
     latent of `kv_lora_rank` and one rotary key of `qk_rope_head_dim` shared
@@ -194,8 +213,12 @@ class GQASpec:
     RMSNorm over each head's `head_dim` of q and of k before the rotation.
     `rotary` pairs the dimensions that turn together: `half` (rotate-half,
     m with m + head_dim/2), `pairs` (interleaved, 2m with 2m + 1), or
-    `none` (no positions at all); left out of the conf's JSON at its
-    default (`OMIT_AT_DEFAULT`)."""
+    `none` (no positions at all).  `rotary_dim` R > 0 turns the first R
+    dimensions of a head alone (rotate-half within them; 0: all of them).
+    `out_gate`: the query projection gives each head a query and a gate of
+    `head_dim` side by side, and the attention's output is multiplied by
+    the gate's sigmoid, element by element, before Wo.  The last three are
+    left out of the conf's JSON at their defaults (`OMIT_AT_DEFAULT`)."""
 
     n_heads: int
     n_kv_heads: int
@@ -206,8 +229,10 @@ class GQASpec:
     qk_norm: bool = False
     eps: float = 1e-6
     rotary: str = "half"
+    rotary_dim: int = 0
+    out_gate: bool = False
 
-    OMIT_AT_DEFAULT = ("rotary",)
+    OMIT_AT_DEFAULT = ("rotary", "rotary_dim", "out_gate")
 
     @property
     def scope_kind(self) -> str:
@@ -247,7 +272,9 @@ class MoESpec:
     whether a learned bias enters the choice.  `ranks`: the chips of the
     expert-parallel group whose rows reach the held experts where this layer
     is one chip of a deployment (1: this chip's rows alone); the form of the
-    held experts' product counts their picks (`experts.experts_form`).  The
+    held experts' product counts their picks (`experts.experts_form`).
+    `shared_gate`: the shared expert's output is scaled, a token at a time,
+    by `sigmoid(u . w_sg)` of a learned vector (after `shared_scale`).  The
     fields of `OMIT_AT_DEFAULT` are left out of the conf's JSON where they
     hold their defaults: a conf from before them serialises as it did."""
 
@@ -265,8 +292,10 @@ class MoESpec:
     router_bias: bool = True
     shared_scale: float = 1.0
     ranks: int = 1
+    shared_gate: bool = False
 
-    OMIT_AT_DEFAULT = ("score", "router_bias", "shared_scale", "ranks")
+    OMIT_AT_DEFAULT = ("score", "router_bias", "shared_scale", "ranks",
+                       "shared_gate")
 
 
 @dataclass(frozen=True)
@@ -303,7 +332,7 @@ class ParallelSpec:
 
 LAYER_SPECS = {c.__name__: c for c in (KDASpec, MLASpec, GQASpec, SwiGLUSpec,
                                        MoESpec, HeadSpec, ShortConvSpec,
-                                       ParallelSpec)}
+                                       ParallelSpec, GDNSpec)}
 
 
 def _spec_dict(spec) -> Dict[str, Any]:

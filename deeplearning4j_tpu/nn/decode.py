@@ -295,11 +295,13 @@ class StepCounts:
         `kv_cells_read`, or where a layer walks a row's cells in blocks up to
         its position (`kv_read_block`) those blocks.  `state_bytes_read`:
         what the step read of every row's state, the spanned cells' bytes and
-        every carry whole.  `dsa_cells_live` and `dsa_cells_selected`, for
-        layers that pick the positions they attend to: the positions cached
-        at or before a live row's own and `min(position + 1, picks)` of them,
-        a row and such layer; they describe the traffic (how far the rows
-        are past the picks), not the read.  `ring_cells_live` and
+        every carry whole; `carry_bytes_read`, where the stack has a carry,
+        the carries' part of it alone.  `dsa_cells_live` and
+        `dsa_cells_selected`, for layers that pick the positions they attend
+        to: the positions cached at or before a live row's own and
+        `min(position + 1, picks)` of them, a row and such layer; they
+        describe the traffic (how far the rows are past the picks), not the
+        read.  `ring_cells_live` and
         `ring_cells_spanned`, for the rings among the tables (read whole a
         step): `min(position + 1, window)` a live row and ring, and `slots x
         window` a ring.  A count the stack cannot have is left out."""
@@ -318,6 +320,8 @@ class StepCounts:
         if self.state_bytes and sum(self.state_bytes.values()):
             out["state_bytes_read"] = (table_bytes + self.n_slots
                                        * self.state_bytes["carry"])
+            if self.state_bytes["carry"]:
+                out["carry_bytes_read"] = self.n_slots * self.state_bytes["carry"]
         if not self.kv_cells:
             return out
         out.update(kv_cells_live=live, kv_cells_spanned=spanned)

@@ -63,8 +63,8 @@ type in `GENERATIVE_HIDDEN`.
 
 from deeplearning4j_tpu.nn.conf import LayerType
 from deeplearning4j_tpu.nn.layers import (base, output, autoencoder, rbm, lstm,
-                                          conv, attention, experts, gqa, kda,
-                                          mla, parallel, shortconv)
+                                          conv, attention, experts, gdn, gqa,
+                                          kda, mla, parallel, shortconv)
 
 _REGISTRY = {
     LayerType.DENSE: base.DenseLayer,
@@ -89,6 +89,7 @@ _REGISTRY = {
     LayerType.MOE: experts.MoELayer,
     LayerType.SHORT_CONV: shortconv.ShortConvLayer,
     LayerType.PARALLEL: parallel.ParallelLayer,
+    LayerType.GDN: gdn.GDNLayer,
 }
 
 #: the hidden layer types a generative stack may hold: each class keeps the
@@ -97,7 +98,7 @@ GENERATIVE_HIDDEN = (LayerType.LSTM, LayerType.GRAVES_LSTM,
                      LayerType.ATTENTION, LayerType.TRANSFORMER_FFN,
                      LayerType.KDA, LayerType.MLA, LayerType.GQA,
                      LayerType.SWIGLU, LayerType.MOE, LayerType.SHORT_CONV,
-                     LayerType.PARALLEL)
+                     LayerType.PARALLEL, LayerType.GDN)
 
 
 def get_layer(layer_type):

@@ -10,9 +10,10 @@ with one group it is a plain top-k; a learned bias, where the spec has one,
 enters the choice and not the weights), and weighs the picks by their
 scores, normalised over all `top_k` and scaled.  The layer then computes the
 picks that landed on its own `n_held` experts, `[first_held, first_held +
-n_held)`, adds its shared expert where it has one, and leaves out what the
-absent experts would add: on one chip of an expert-parallel group this is
-that chip's part of the layer, without the exchange.
+n_held)`, adds its shared expert where it has one (scaled by a constant, and
+where the spec says so by a learned sigmoid gate a token), and leaves out
+what the absent experts would add: on one chip of an expert-parallel group
+this is that chip's part of the layer, without the exchange.
 
 Dropless: every pick of a held expert is computed, at 1 row or at 1024;
 there is no capacity and no token is dropped.  The product has two forms,
@@ -202,15 +203,15 @@ _SCORES = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}
 
 
 class MoELayer(StatelessDecode):
-    #: the experts' and the shared expert's matrices; the router's `Wr` is
-    #: multiplied in float32
+    #: the experts' and the shared expert's matrices; the router's `Wr` and
+    #: the shared expert's gate `Wsg` are multiplied in float32
     OPERANDS = ("Wgu", "Wd", "sWgu", "sWd")
 
     @staticmethod
     def init(key, conf):
         s = conf.layer_spec
         d, n = jnp.dtype(conf.dtype), conf.n_in
-        ks = jax.random.split(key, 5)
+        ks = jax.random.split(key, 6 if s.shared_gate else 5)
         w = initializer(conf)
         out = {"ln": jnp.ones((n,), d),
                "Wr": w(ks[0], (n, s.n_routed)),
@@ -221,6 +222,8 @@ class MoELayer(StatelessDecode):
         if s.shared_hidden:
             out.update(sWgu=w(ks[3], (n, 2 * s.shared_hidden)),
                        sWd=w(ks[4], (s.shared_hidden, n)))
+        if s.shared_gate:
+            out["Wsg"] = w(ks[5], (n, 1))
         return out
 
     @staticmethod
@@ -248,6 +251,10 @@ class MoELayer(StatelessDecode):
                 shared = swiglu(rows, params["sWgu"], params["sWd"], cd)
                 if s.shared_scale != 1.0:
                     shared = shared * s.shared_scale
+                if s.shared_gate:
+                    shared = shared * jax.nn.sigmoid(jnp.matmul(
+                        rows, params["Wsg"].astype(F32),
+                        precision=jax.lax.Precision.HIGHEST))
                 y = y + shared
         return y.reshape(u.shape), counts
 

@@ -6,12 +6,15 @@ With u = RMSNorm(x): q = Wq u in [H, h], k = Wk u and v = Wv u in [G, h],
 G = n_kv_heads; where `qk_norm`, an RMSNorm over h on each head of q and of
 k; rotary positions on q and k by `rotary` (rotate-half, or interleaved
 pairs; plain, or YaRN's frequencies and scale where the spec gives them;
-`none`: no positions).  Query head j reads K/V head j // (H // G).
+`none`: no positions), over the first `rotary_dim` dimensions of a head
+where the spec gives them.  Query head j reads K/V head j // (H // G).
 Scores q . k / sqrt(h), float32 softmax over the keys a token may see: every
 earlier position and its own (`window` 0), or its own and the `window - 1`
-before it.  Then Wo.  `branch_prefill` and `branch_step` are the same
-attention from a normed input to Wo's output, with no norm and no residual:
-what a block that norms once for two branches (`parallel.py`) calls.
+before it.  Where `out_gate`, Wq u gives each head [q, gate] in [2h], and
+the heads' output is multiplied by sigmoid(gate) element by element.  Then
+Wo.  `branch_prefill` and `branch_step` are the same attention from a normed
+input to Wo's output, with no norm and no residual: what a block that norms
+once for two branches (`parallel.py`) calls.
 
 Decode state, a row a slot, in the compute dtype, K/V heads before positions
 so that a head's cells lie together:
@@ -55,7 +58,8 @@ from deeplearning4j_tpu.nd import pallas_kernels
 from deeplearning4j_tpu.nd.platform import is_tpu
 from deeplearning4j_tpu.nn.layers.base import compute_dtype
 from deeplearning4j_tpu.nn.layers.rms import (F32, initializer, mm, pre_norm,
-                                              precision_of, rms_norm, rope)
+                                              precision_of, rms_norm, rope,
+                                              rope_first)
 from deeplearning4j_tpu.utils.profiling import scope
 
 #: float32 rows of scores a block of `prefill` holds, heads x queries: at
@@ -109,7 +113,7 @@ class GQALayer:
         ks = jax.random.split(key, 4)
         w = initializer(conf)
         out = {"ln": jnp.ones((n,), d),
-               "Wq": w(ks[0], (n, s.n_heads * s.head_dim)),
+               "Wq": w(ks[0], (n, s.n_heads * s.head_dim * (2 if s.out_gate else 1))),
                "Wk": w(ks[1], (n, s.n_kv_heads * s.head_dim)),
                "Wv": w(ks[2], (n, s.n_kv_heads * s.head_dim)),
                "Wo": w(ks[3], (s.n_heads * s.head_dim, n))}
@@ -164,12 +168,16 @@ class GQALayer:
     @staticmethod
     def _project(params, conf, u, positions):
         """The normed input u [..., n] at `positions` [...] -> q [..., G,
-        H/G, h] in float32 (rotated, scaled by 1/sqrt(h)) and, in the
-        cache's type, k and v [..., G, h] (k rotated)."""
+        H/G, h] in float32 (rotated, scaled by 1/sqrt(h)), in the cache's
+        type k and v [..., G, h] (k rotated), and the output's gate [..., H
+        h] float32 (None without `out_gate`)."""
         s, cd = conf.layer_spec, compute_dtype(conf)
-        g = s.n_kv_heads
+        g, gate = s.n_kv_heads, None
         with scope("qkv"):
             q = mm(u, params["Wq"], cd).reshape(u.shape[:-1] + (s.n_heads, -1))
+            if s.out_gate:
+                q, gate = q[..., :s.head_dim], q[..., s.head_dim:]
+                gate = gate.reshape(u.shape[:-1] + (-1,))
             k = mm(u, params["Wk"], cd).reshape(u.shape[:-1] + (g, -1))
             v = mm(u, params["Wv"], cd).reshape(u.shape[:-1] + (g, -1))
             if s.qk_norm:
@@ -177,13 +185,27 @@ class GQALayer:
                 k = rms_norm(k, params["k_norm"], s.eps)
         if s.rotary == "none":
             q = q / math.sqrt(s.head_dim)
+        elif s.rotary_dim:
+            with scope("rope"):
+                at = positions[..., None]
+                q = rope_first(q, at, s.rope_theta, s.rotary_dim) / math.sqrt(s.head_dim)
+                k = rope_first(k, at, s.rope_theta, s.rotary_dim)
         else:
             with scope("rope"):
                 at, pairs = positions[..., None], s.rotary == "pairs"
                 q = rope(q, at, s.rope_theta, s.yarn, pairs) / math.sqrt(s.head_dim)
                 k = rope(k, at, s.rope_theta, s.yarn, pairs)
         q = q.reshape(u.shape[:-1] + (g, s.n_heads // g, s.head_dim))
-        return q, k.astype(cd), v.astype(cd)
+        return q, k.astype(cd), v.astype(cd), gate
+
+    @staticmethod
+    def _gated(o, gate):
+        """The heads' output [..., H h] times sigmoid(gate), where there is
+        one."""
+        if gate is None:
+            return o
+        with scope("gate"):
+            return o * jax.nn.sigmoid(gate)
 
     @staticmethod
     def _attend(conf, q, k, v, seen):
@@ -221,7 +243,7 @@ class GQALayer:
         s, cd = conf.layer_spec, compute_dtype(conf)
         b, t, _ = u.shape
         w = s.window
-        q, k, v = GQALayer._project(params, conf, u, positions)
+        q, k, v, gate = GQALayer._project(params, conf, u, positions)
         q = jnp.moveaxis(q, 1, 3)                               # [B, G, R, T, h]
         k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)     # [B, G, T, h]
         with scope("kv_write"):
@@ -253,6 +275,7 @@ class GQALayer:
                 conf, q[:, :, :, start:stop], k[:, :, lo:stop],
                 v[:, :, lo:stop], seen))
         o = out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+        o = GQALayer._gated(o, gate)
         with scope("proj"):
             return mm(o, params["Wo"], cd), state
 
@@ -269,7 +292,7 @@ class GQALayer:
         s, cd = conf.layer_spec, compute_dtype(conf)
         b = u.shape[0]
         cells = state["k"].shape[2]
-        q, k, v = GQALayer._project(params, conf, u, pos)
+        q, k, v, gate = GQALayer._project(params, conf, u, pos)
         with scope("kv_write"):
             # one cell a (row, K/V head): scattered with both indices on the
             # leading axes, the table is written where it lies (with the
@@ -294,6 +317,7 @@ class GQALayer:
                 if s.window:
                     seen = seen | (pos[:, None] >= cells)
             o = GQALayer._attend(conf, q, k_all, v_all, seen[:, None, None, :])
+        o = GQALayer._gated(o, gate)
         with scope("proj"):
             return mm(o, params["Wo"], cd), {"k": k_all, "v": v_all}
 

@@ -144,12 +144,7 @@ class KDALayer:
             y, seen = causal_conv(state["conv"], qkv, params["conv"])
             y = jax.nn.silu(y)
         q, k, v = KDALayer._heads(conf, y)
-        with scope("state_update"):
-            s = jnp.exp(g)[..., None] * state["S"]
-            ks = jnp.sum(k[..., None] * s, axis=-2)
-            s = s + (beta[..., None] * k)[..., None] * (v - ks)[..., None, :]
-        with scope("state_read"):
-            o = jnp.sum(q[..., None] * s, axis=-2)
+        o, s = delta_step(q, k, v, g, beta, state["S"])
         return (KDALayer._output(params, conf, x, o, gate),
                 {"S": s, "conv": conv_tail(seen, conf.layer_spec.conv_kernel)})
 
@@ -163,13 +158,31 @@ class KDALayer:
         return out
 
 
-def chunked_delta_rule(q, k, v, g, beta, state, chunk: int = CHUNK):
-    """The recurrence of the module's docstring over q, k, v, g [B, T, H, dk]
-    and beta [B, T, H] from `state` [B, H, dk, dv], `chunk` tokens at a
-    time.  Returns (o [B, T, H, dv], the state after token T - 1).  A
-    position with g = 0 and beta = 0 leaves the state as it was."""
+def delta_step(q, k, v, g, beta, state):
+    """One token of the recurrence of the module's docstring: q, k [B, H,
+    dk], v [B, H, dv], the log decay g [B, H, dk] (a number a channel) or
+    [B, H, 1] (one a head), beta [B, H], from `state` [B, H, dk, dv].
+    Returns (o [B, H, dv], the state after the token)."""
+    with scope("state_update"):
+        s = jnp.exp(g)[..., None] * state
+        ks = jnp.sum(k[..., None] * s, axis=-2)
+        s = s + (beta[..., None] * k)[..., None] * (v - ks)[..., None, :]
+    with scope("state_read"):
+        o = jnp.sum(q[..., None] * s, axis=-2)
+    return o, s
+
+
+def chunked_delta_rule(q, k, v, g, beta, state, chunk: int = 0):
+    """The recurrence of the module's docstring over q, k, v [B, T, H, dk],
+    the log decay g [B, T, H, dk] (a number a channel) or [B, T, H, 1] (one
+    a head), and beta [B, T, H] from `state` [B, H, dk, dv], `chunk` tokens
+    at a time (0: `CHUNK`).  Returns (o [B, T, H, dv], the state after
+    token T - 1).  A position with g = 0 and beta = 0 leaves the state as it
+    was.  With one decay a head, a chunk's decays are one [c, c] matrix a
+    head and M and A are products: (K K^T) * e^{G_t - G_s} and (Q K^T) *
+    e^{G_t - G_s}."""
     b, t, h, dk = q.shape
-    c = min(chunk, t)
+    c = min(chunk or CHUNK, t)
     pad = -t % c
     if pad:
         q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -189,8 +202,13 @@ def chunked_delta_rule(q, k, v, g, beta, state, chunk: int = CHUNK):
         big = jnp.cumsum(gc, axis=2)                     # G_t
         diff = big[:, :, :, None, :] - big[:, :, None, :, :]
         decay = jnp.exp(jnp.where(lower[:, :, None], diff, -jnp.inf))
-        m = jnp.sum(kc[:, :, :, None, :] * kc[:, :, None, :, :] * decay, axis=-1)
-        a = jnp.sum(qc[:, :, :, None, :] * kc[:, :, None, :, :] * decay, axis=-1)
+        if gc.shape[-1] == 1:                            # one decay a head
+            decay = decay[..., 0]
+            m = jnp.einsum("bhti,bhsi->bhts", kc, kc, precision=_HI) * decay
+            a = jnp.einsum("bhti,bhsi->bhts", qc, kc, precision=_HI) * decay
+        else:
+            m = jnp.sum(kc[:, :, :, None, :] * kc[:, :, None, :, :] * decay, axis=-1)
+            a = jnp.sum(qc[:, :, :, None, :] * kc[:, :, None, :, :] * decay, axis=-1)
         grown = jnp.exp(big)
         rhs = bc[..., None] * (vc - jnp.einsum(
             "bhci,bhij->bhcj", kc * grown, s0, precision=_HI))

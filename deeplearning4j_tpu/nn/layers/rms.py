@@ -1,12 +1,13 @@
-"""What the RMSNorm-family layer types share (`kda`, `mla`, `gqa`, `swiglu`,
-`moe`, `shortconv`, `parallel`, and the OUTPUT layer under a `HeadSpec`): the
-norms, rotary positions, the one matrix product they all take, and the causal
-convolution with a carried tail of `kda` and `shortconv`.
+"""What the RMSNorm-family layer types share (`kda`, `gdn`, `mla`, `gqa`,
+`swiglu`, `moe`, `shortconv`, `parallel`, and the OUTPUT layer under a
+`HeadSpec`): the norms, rotary positions, the one matrix product they all
+take, and the causal convolution with a carried tail of `kda`, `gdn` and
+`shortconv`.
 
 Their precision contract: parameters are kept in `conf.dtype`, matrix
 products take operands in `compute_dtype(conf)` and accumulate in float32,
 and everything between the products (the residual stream, norms, softmax,
-router scores, the KDA state) is float32.
+router scores, the delta rule's state) is float32.
 """
 
 from __future__ import annotations

@@ -31,41 +31,46 @@ BENCHMARK = os.path.dirname(os.path.abspath(families.__path__[0]))
 def test_a_model_type_with_no_family_ends_the_run():
     """The original expects the message to list GPT-2 alone."""
     with pytest.raises(SystemExit, match=r"'mamba'.*\['cohere2_moe', 'dots3_note', 'gpt2', "
-                                         r"'lfm2_moe', 'ling3', 'mellum'\]"):
+                                         r"'lfm2_moe', 'ling3', 'mellum', 'qwen3_next'\]"):
         families.of({"model_type": "mamba"})
 
 
 def test_no_file_outside_a_family_names_one():
-    """The family grep, for six families: GPT-2's keys and leaves appear
+    """The family grep, for seven families: GPT-2's keys and leaves appear
     under `families/gpt2`, in the configuration files and in tests, and
     nowhere else; nor do Ling's under anything but `families/ling3`, nor
     Mellum's under anything but `families/mellum`, nor dots3-note-prev's
     under anything but `families/dots3_note`, nor LFM2's under anything but
     `families/lfm2_moe`, nor Command A+'s under anything but
-    `families/cohere2_moe`.  The original's pattern takes
+    `families/cohere2_moe`, nor Qwen3-Next's under anything but
+    `families/qwen3_next`.  The original's pattern takes
     `num_attention_heads`, a published key of the second family, for GPT-2's
     `n_head`; a key is matched whole here.  A key that several families
     publish alike (`kv_lora_rank` and the latent's leaves of the second and
-    the fourth, `first_k_dense_replace` of those and the sixth, `q_norm` of
-    the third, the fourth and the fifth, `k_norm` of the third and the
-    fifth, `rope_parameters` of the third, the fifth and the sixth,
-    `sliding_window` of the third and the sixth) belongs to all of them: it
-    is found everywhere but under any of them."""
+    the fourth, `first_k_dense_replace` of those and the sixth, `A_log` of
+    the second and the seventh, `q_norm` and `k_norm` of the third, the
+    fourth or the fifth and the seventh, `rope_parameters` of the third,
+    the fifth and the sixth, `sliding_window` of the third, the sixth and
+    the seventh) belongs to all of them: it is found everywhere but under
+    any of them."""
     own = [({"gpt2"}, r"char_transformer|\b(n_embd|n_head|n_inner|wte)\b|Wqkv|gpt2"),
-           ({"ling3"}, r"layer_group_size|\b(conv_q|A_log)\b|ling3"),
+           ({"ling3"}, r"layer_group_size|\bconv_q\b|ling3"),
+           ({"ling3", "qwen3_next"}, r"\bA_log\b"),
            ({"ling3", "dots3_note"}, r"kv_lora_rank|\b(Wkva|Wkvb)\b"),
            ({"ling3", "dots3_note", "cohere2_moe"}, r"first_k_dense_replace"),
            ({"mellum"}, r"mlp_layer_types|mellum"),
-           ({"mellum", "cohere2_moe"}, r"sliding_window(?!_size)"),
-           ({"mellum", "lfm2_moe"}, r"\bk_norm\b"),
+           ({"mellum", "cohere2_moe", "qwen3_next"}, r"sliding_window(?!_size)"),
+           ({"mellum", "lfm2_moe", "qwen3_next"}, r"\bk_norm\b"),
            ({"mellum", "lfm2_moe", "cohere2_moe"}, r"rope_parameters"),
-           ({"mellum", "dots3_note", "lfm2_moe"}, r"\bq_norm\b"),
+           ({"mellum", "dots3_note", "lfm2_moe", "qwen3_next"}, r"\bq_norm\b"),
            ({"lfm2_moe"}, r"conv_L_cache|num_dense_layers|use_expert_bias|"
                           r"\b(in_proj|out_proj)\b|lfm2"),
            ({"dots3_note"},
             r"index_topk|sliding_window_size|\b(Wiq|Wik|Wiw)\b|dots3"),
            ({"cohere2_moe"}, r"use_parallel_block|shared_expert_combination_strategy|"
-                             r"position_embedding_type|cohere2")]
+                             r"position_embedding_type|cohere2"),
+           ({"qwen3_next"}, r"linear_num_value_heads|full_attention_interval|"
+                            r"\bconv1d\b|qwen3")]
     found = []
     for where, _, files in os.walk(BENCHMARK):
         rel = os.path.relpath(where, BENCHMARK)

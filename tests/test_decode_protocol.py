@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.nn import decode, layers
-from deeplearning4j_tpu.nn.conf import (Activation, GQASpec, KDASpec, LayerType,
-                                        LossFunction, MLASpec, MoESpec,
+from deeplearning4j_tpu.nn.conf import (Activation, GDNSpec, GQASpec, KDASpec,
+                                        LayerType, LossFunction, MLASpec, MoESpec,
                                         MultiLayerConfiguration,
                                         NeuralNetConfiguration, ParallelSpec,
                                         ShortConvSpec, SwiGLUSpec)
@@ -41,6 +41,8 @@ SPECS = {
         attn=GQASpec(n_heads=4, n_kv_heads=2, head_dim=4, window=4, rotary="pairs"),
         ffn=MoESpec(n_routed=8, n_held=4, hidden=8, shared_hidden=16, top_k=2,
                     router_bias=False, shared_scale=0.5))),
+    # one key head serving two value heads, a decay a value head
+    LayerType.GDN: dict(layer_spec=GDNSpec(n_k_heads=1, n_v_heads=2, k_dim=4, v_dim=4)),
 }
 
 

@@ -103,10 +103,10 @@ def test_a_layer_without_rotary_scores_the_same_at_every_position(model):
     def score(layer, p_query, p_key):
         c = conf.conf(layer)
         attn = c.replace(layer_spec=c.layer_spec.attn)
-        q, _, _ = gqa.GQALayer._project(params[layer]["attn"], attn, u[:1],
-                                        jnp.asarray([p_query]))
-        _, k, _ = gqa.GQALayer._project(params[layer]["attn"], attn, u[1:],
-                                        jnp.asarray([p_key]))
+        q, *_ = gqa.GQALayer._project(params[layer]["attn"], attn, u[:1],
+                                      jnp.asarray([p_query]))
+        _, k, *_ = gqa.GQALayer._project(params[layer]["attn"], attn, u[1:],
+                                         jnp.asarray([p_key]))
         return np.asarray(jnp.einsum("bgrh,bgh->bgr", q, k))
 
     np.testing.assert_allclose(score(4, 9, 3), score(4, 40, 1), atol=1e-6)

@@ -1,9 +1,11 @@
 """What one completed decode step read, counted by `nn.decode.StepCounts` from
 the rows' positions: every `decode` span attribute and every `stats()` total
-of the serving loop, for seven stacks (the tiny GPT-2-style transformer and
-LSTM of `test_generate.py`, and the tiny Ling, Mellum, dots3, LFM2 and
-Command A+ stacks of the family tests) at four sets of row positions.  The
-integers are the ones the batcher counted before the counts moved out of it.
+of the serving loop, for eight stacks (the tiny GPT-2-style transformer and
+LSTM of `test_generate.py`, and the tiny Ling, Mellum, dots3, LFM2,
+Command A+ and Qwen3-Next stacks of the family tests) at four sets of row
+positions.  The integers are the ones the batcher counted before the counts
+moved out of it, but `carry_bytes_read` (the carries' part of
+`state_bytes_read`) and the Qwen3-Next stack's, counted by hand.
 The descriptors are asked as on the chip (`is_tpu`, blocks of 32 cells), so
 that the tables a kernel walks are counted block by block.
 
@@ -42,20 +44,21 @@ PINS = {
                                            kv_cells_spanned=1536),
     }),
     "lstm": ({"kernel_layers": 0, "state_bytes_per_row": {"table": 0, "carry": 256}}, {
-        "all-at-zero": dict(state_bytes_read=1536),
-        "across-a-window-and-a-block": dict(state_bytes_read=1536),
-        "at-the-tables-end": dict(state_bytes_read=1536),
-        "free-slots-among-live-rows": dict(state_bytes_read=1536),
+        "all-at-zero": dict(state_bytes_read=1536, carry_bytes_read=1536),
+        "across-a-window-and-a-block": dict(state_bytes_read=1536, carry_bytes_read=1536),
+        "at-the-tables-end": dict(state_bytes_read=1536, carry_bytes_read=1536),
+        "free-slots-among-live-rows": dict(state_bytes_read=1536, carry_bytes_read=1536),
     }),
     "ling": ({"kernel_layers": 0, "state_bytes_per_row": {"table": 20480, "carry": 12800},
               "experts_batched_layers": 0}, {
-        "all-at-zero": dict(E, state_bytes_read=199680, kv_cells_live=6, kv_cells_spanned=768),
-        "across-a-window-and-a-block": dict(E, state_bytes_read=199680, kv_cells_live=91,
-                                            kv_cells_spanned=768),
-        "at-the-tables-end": dict(E, state_bytes_read=199680, kv_cells_live=264,
-                                  kv_cells_spanned=768),
-        "free-slots-among-live-rows": dict(E, state_bytes_read=199680, kv_cells_live=33,
-                                           kv_cells_spanned=768),
+        "all-at-zero": dict(E, state_bytes_read=199680, carry_bytes_read=76800,
+                            kv_cells_live=6, kv_cells_spanned=768),
+        "across-a-window-and-a-block": dict(E, state_bytes_read=199680, carry_bytes_read=76800,
+                                            kv_cells_live=91, kv_cells_spanned=768),
+        "at-the-tables-end": dict(E, state_bytes_read=199680, carry_bytes_read=76800,
+                                  kv_cells_live=264, kv_cells_spanned=768),
+        "free-slots-among-live-rows": dict(E, state_bytes_read=199680, carry_bytes_read=76800,
+                                           kv_cells_live=33, kv_cells_spanned=768),
     }),
     "mellum": ({"kernel_layers": 1, "state_bytes_per_row": {"table": 155648, "carry": 0},
                 "experts_batched_layers": 0}, {
@@ -91,13 +94,14 @@ PINS = {
     }),
     "lfm2": ({"kernel_layers": 1, "state_bytes_per_row": {"table": 131072, "carry": 10240},
               "experts_batched_layers": 0}, {
-        "all-at-zero": dict(E, state_bytes_read=258048, kv_cells_live=6, kv_cells_spanned=192),
-        "across-a-window-and-a-block": dict(E, state_bytes_read=290816, kv_cells_live=91,
-                                            kv_cells_spanned=224),
-        "at-the-tables-end": dict(E, state_bytes_read=552960, kv_cells_live=264,
-                                  kv_cells_spanned=480),
-        "free-slots-among-live-rows": dict(E, state_bytes_read=356352, kv_cells_live=33,
-                                           kv_cells_spanned=288),
+        "all-at-zero": dict(E, state_bytes_read=258048, carry_bytes_read=61440,
+                            kv_cells_live=6, kv_cells_spanned=192),
+        "across-a-window-and-a-block": dict(E, state_bytes_read=290816, carry_bytes_read=61440,
+                                            kv_cells_live=91, kv_cells_spanned=224),
+        "at-the-tables-end": dict(E, state_bytes_read=552960, carry_bytes_read=61440,
+                                  kv_cells_live=264, kv_cells_spanned=480),
+        "free-slots-among-live-rows": dict(E, state_bytes_read=356352, carry_bytes_read=61440,
+                                           kv_cells_live=33, kv_cells_spanned=288),
     }),
     "commanda": ({"kernel_layers": 0, "state_bytes_per_row": {"table": 38912, "carry": 0},
                   "experts_batched_layers": 4}, {
@@ -112,6 +116,20 @@ PINS = {
         "free-slots-among-live-rows": dict(
             E, state_bytes_read=233472, kv_cells_live=84, kv_cells_spanned=912,
             ring_cells_live=51, ring_cells_spanned=144),
+    }),
+    # three delta-rule layers (4 value heads of 16 x 16 float32, 3 carried
+    # inputs of 128 channels) and a full table of heads of 16, which the
+    # kernel does not walk: read whole
+    "qwen3next": ({"kernel_layers": 0, "state_bytes_per_row": {"table": 32768, "carry": 16896},
+                   "experts_batched_layers": 4}, {
+        "all-at-zero": dict(E, state_bytes_read=297984, carry_bytes_read=101376,
+                            kv_cells_live=6, kv_cells_spanned=768),
+        "across-a-window-and-a-block": dict(E, state_bytes_read=297984, carry_bytes_read=101376,
+                                            kv_cells_live=91, kv_cells_spanned=768),
+        "at-the-tables-end": dict(E, state_bytes_read=297984, carry_bytes_read=101376,
+                                  kv_cells_live=264, kv_cells_spanned=768),
+        "free-slots-among-live-rows": dict(E, state_bytes_read=297984, carry_bytes_read=101376,
+                                           kv_cells_live=33, kv_cells_spanned=768),
     }),
 }
 
@@ -137,6 +155,8 @@ def stack(name: str):
         return _family("serve-lfm2-rag-64")
     if name == "commanda":
         return _family("serve-commanda-long-32")
+    if name == "qwen3next":
+        return _family("serve-qwen3next-decode-64")
     import dots3_model
     import ling3_model
     import mellum_model

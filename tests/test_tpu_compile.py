@@ -585,6 +585,55 @@ def test_the_parallel_blocks_decode_program_at_32_slots(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * (1 << 30)
 
 
+def _qwen3next_stack():
+    """Qwen3-Next's stack cut to one Gated DeltaNet layer and one gated full
+    layer, each with its experts, at every published width and this rank's
+    64 experts and 18,992 ids."""
+    import json
+
+    from benchmark.families.qwen3_next.program import build_conf
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "qwen3-next-80b-a3b-ep8.json")) as f:
+        cfg = json.load(f)
+    return build_conf({**cfg, "num_hidden_layers": 2, "full_attention_interval": 2})
+
+
+def test_the_delta_rules_decode_program_at_64_slots(one_chip, monkeypatch):
+    """The batcher's decode program of Qwen3-Next's stack at the cell's 64
+    slots and 2,048 positions, its state donated, on the chip's path: the
+    full layer walks its table of heads of 256 through `dl4j_gqa_decode`,
+    neither the delta rule's state (`f32[64, 32, 128, 128]`) nor the
+    table (`bf16[64, 2, 2048, 256]`) is copied, and the step's temporaries
+    are under a tenth of a GiB."""
+    from deeplearning4j_tpu.nn import decode
+    from deeplearning4j_tpu.nn.layers import gqa
+    from deeplearning4j_tpu.nn.multilayer import init_params
+    from deeplearning4j_tpu.optimize.infer_cache import _decode_program
+
+    monkeypatch.setattr(pk, "is_tpu", lambda: True)
+    monkeypatch.setattr(gqa, "is_tpu", lambda: True)
+    conf = _qwen3next_stack()
+    assert [str(c.layer_type) for c in conf.confs] == [
+        "embedding", "gdn", "moe", "gqa", "moe", "output"]
+    assert decode.kernel_layers(conf, 2048) == 1
+    shaped = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = shaped(jax.eval_shape(lambda k: init_params(conf, k), jax.random.PRNGKey(0)))
+    state = shaped(jax.eval_shape(lambda: decode.init_state(conf, 64, 2048)))
+    assert (state[1]["S"].shape, state[3]["k"].shape) == ((64, 32, 128, 128),
+                                                           (64, 2, 2048, 256))
+    rows = lambda dt, *shape: shaped(jax.ShapeDtypeStruct((64,) + shape, dt))  # noqa: E731
+    compiled = jax.jit(_decode_program(conf), donate_argnums=(1,)).lower(
+        params, state, rows(jnp.int32), rows(jnp.int32), rows(jnp.uint32, 2),
+        rows(jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "dl4j_gqa_decode" in text
+    assert _table_copies(text, "f32", 64 * 32 * 128 * 128) == 0
+    assert _table_copies(text, "bf16", 64 * 2 * 2048 * 256) == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * (1 << 30)
+
+
 def _gpt2_block():
     """GPT-2 1.3B cut to one block, at every published width: 16 heads of
     128, d 2,048, 50,257 ids."""
